@@ -36,7 +36,9 @@ Env knobs: BENCH_MC_SF (default 2.0 — large enough that per-device
 compute dominates fake-device dispatch overhead; the first run pays
 a ~3 min single-core ingest, cached under BENCH_MC_DIR after),
 BENCH_MC_REPEATS (default 3),
-BENCH_MC_DEVICES (default "1,2,4,8"), BENCH_MC_DIR (persistent dataset
+BENCH_MC_DEVICES (default: the widths of 1,2,4,8 the backend has — a
+probe child asks it; the CPU backend makes virtual devices to order),
+BENCH_MC_DIR (persistent dataset
 dir, default .benchdata/multichip_sf<sf>), MULTICHIP_OUT (artifact
 path; "0" disables writing, default MULTICHIP_r<next>.json).
 """
@@ -95,8 +97,6 @@ def _child(n_devices: int) -> None:
                           host_device_count=n_devices)
     import jax
 
-    if len(jax.devices()) < n_devices:
-        ensure_jax_configured(platform="cpu")
     if len(jax.devices()) < n_devices:
         raise RuntimeError(
             f"need {n_devices} devices, have {len(jax.devices())}")
@@ -241,13 +241,39 @@ def _next_artifact_path() -> str:
     return os.path.join(ROOT, f"MULTICHIP_r{nxt:02d}.json")
 
 
+def _backend_widths() -> list[int]:
+    """The mesh widths the backend can run, asked of a child so the
+    parent stays off JAX.  The CPU backend makes virtual devices to
+    order (each width's child sets its own count); an accelerator has
+    the devices it has."""
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--probe"],
+        cwd=ROOT, capture_output=True, text=True, timeout=600,
+        check=True)
+    dev = json.loads(proc.stdout.strip().splitlines()[-1])
+    if dev["platform"] == "cpu":
+        return [1, 2, 4, 8]
+    return [w for w in (1, 2, 4, 8) if w <= dev["count"]]
+
+
 def main() -> int:
     if sys.argv[1:2] == ["--child"]:
         _child(int(sys.argv[2]))
         return 0
+    if sys.argv[1:2] == ["--probe"]:
+        from citus_tpu.runtime import ensure_jax_configured
 
-    device_counts = [int(x) for x in os.environ.get(
-        "BENCH_MC_DEVICES", "1,2,4,8").split(",")]
+        ensure_jax_configured(
+            platform=os.environ.get("JAX_PLATFORMS") or None)
+        import jax
+
+        print(json.dumps({"platform": jax.devices()[0].platform,
+                          "count": len(jax.devices())}))
+        return 0
+
+    env_counts = os.environ.get("BENCH_MC_DEVICES")
+    device_counts = ([int(x) for x in env_counts.split(",")]
+                     if env_counts else _backend_widths())
     tail_lines: list[str] = []
     rc = 0
     # widest mesh first: the first child to touch an empty dataset dir

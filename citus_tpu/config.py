@@ -222,8 +222,7 @@ _register(ConfigVar(
     "placement, column by column; 'device' = host pipeline plus "
     "on-device decode — frame-of-reference packed ints, dictionary-"
     "coded low-NDV columns and bit-packed validity planes cross the "
-    "wire and expand on the mesh (Pallas kernels on TPU, XLA "
-    "formulations elsewhere). 'auto' picks device on accelerator "
+    "wire and expand on the mesh (XLA formulations). 'auto' picks device on accelerator "
     "backends and host on CPU meshes, engaging only above a small "
     "row floor (same measurement-gated contract as join_probe_kernel "
     "/ group_by_kernel). No reference GUC — the analogue is the "

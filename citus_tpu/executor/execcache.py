@@ -338,7 +338,7 @@ class ExecutableCache:
             # while adopting a persisted executable must end in a
             # counted reject + clean recompile, exactly like real rot
             fault_point("executor.exec_cache_load")
-            entry = self._load_verified(h, meta_path, stamp)
+            entry = self._load_verified(h, meta_path, stamp, mesh)
         except (QueryCanceled, StatementTimeout):
             raise  # the statement's own deadline/cancel, not rot
         except Exception as e:  # graftlint: ignore[swallowed-fault-seam] — not swallowed into silence: THE contract of this seam is that rot (injected or real) downgrades to a counted reject + clean recompile, never a crash or a stale executable
@@ -371,7 +371,8 @@ class ExecutableCache:
             key = key_from_json(meta["key"])
             if entry_hash(key, stamp) != h:
                 raise ValueError("exec-cache entry hash mismatch")
-            entry = self._load_verified(h, meta_path, stamp, meta=meta)
+            entry = self._load_verified(h, meta_path, stamp, mesh,
+                                        meta=meta)
         except Exception:
             with self._mu:
                 self.rejects_total += 1
@@ -405,7 +406,7 @@ class ExecutableCache:
             raise ValueError("exec-cache entry environment skew")
         return meta
 
-    def _load_verified(self, h: str, meta_path: str, stamp: dict,
+    def _load_verified(self, h: str, meta_path: str, stamp: dict, mesh,
                        meta: dict | None = None):
         import pickle
 
@@ -419,8 +420,12 @@ class ExecutableCache:
         if zlib.crc32(data) != meta["payload_crc32"]:
             raise ValueError("exec-cache payload checksum mismatch")
         exe, it, ot = _unframe(data, 3)
+        # without execution_devices the executable loads onto EVERY
+        # device of the backend, and a mesh narrower than the backend
+        # then fails at dispatch with a shard-count mismatch
         compiled = _se.deserialize_and_load(
-            exe, pickle.loads(it), pickle.loads(ot))
+            exe, pickle.loads(it), pickle.loads(ot),
+            execution_devices=list(mesh.devices.flat))
         out_meta = [(kind, cid, np.dtype(dt))
                     for kind, cid, dt in meta["out_meta"]]
         stage_keys = [tuple(sk) for sk in meta["stage_keys"]]

@@ -4,14 +4,16 @@ The compute path is JAX/XLA/Pallas; this is the *host* native layer for
 per-value work that stays Python-bound otherwise — bulk string interning
 and string hash tokens (the reference's equivalents live in C:
 multi_copy.c ingest loop, hashfunc uses).  The library compiles itself on
-first use with g++ (no network, no pip); every caller has a pure-Python
-fallback, so a missing/failed toolchain only costs speed, never
-correctness.
+first use with g++ (no network, no pip) from the two .cpp files beside
+it; every caller has a pure-Python fallback, so a missing/failed
+toolchain only costs speed, never correctness — `load_error()` says
+when that happened, so a slow run can be told from a broken build.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,18 +24,38 @@ _SEP = 0x1F  # unit separator — joins packed strings
 _lock = threading.Lock()
 _lib: object = None
 _tried = False
+_load_error: Exception | None = None
 
 _I64P = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
 _I32P = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
 
 
-def _build_and_load():
-    here = os.path.dirname(os.path.abspath(__file__))
+def _sources_hash(srcs: list[str]) -> str:
+    h = hashlib.sha256()
+    for path in srcs:
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    return h.hexdigest()
+
+
+def _build_and_load(here: str | None = None):
+    """Build `_native.so` in `here` (this package's directory) unless
+    the one there was built from exactly these sources, then load it.
+    Stale is decided by a hash of the sources stored beside the
+    library — a checkout or a copy sets mtimes arbitrarily."""
+    if here is None:
+        here = os.path.dirname(os.path.abspath(__file__))
     srcs = [os.path.join(here, "hashdict.cpp"),
             os.path.join(here, "stripecodec.cpp")]
     so = os.path.join(here, "_native.so")
-    if not os.path.exists(so) or any(
-            os.path.getmtime(so) < os.path.getmtime(s) for s in srcs):
+    stamp = so + ".sha256"
+    want = _sources_hash(srcs)
+    built_from = None
+    if os.path.exists(so) and os.path.exists(stamp):
+        with open(stamp) as f:
+            built_from = f.read().strip()
+    if built_from != want:
         tmp = so + ".tmp"
         base = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", *srcs,
                 "-o", tmp, "-pthread", "-lz"]
@@ -45,6 +67,11 @@ def _build_and_load():
             subprocess.run(base + ["-DNO_ZSTD"], check=True,
                            capture_output=True, timeout=120)
         os.replace(tmp, so)  # graftlint: ignore[raw-durable-write] — compiler build artifact beside the sources, not data-dir state
+        # the stamp lands after the library: a build cut between the
+        # two leaves no stamp, which reads as stale
+        with open(stamp + ".tmp", "w") as f:  # graftlint: ignore[raw-durable-write] — build artifact stamp beside the library, not data-dir state
+            f.write(want)
+        os.replace(stamp + ".tmp", stamp)  # graftlint: ignore[raw-durable-write] — same stamp
     lib = ctypes.CDLL(so)
     lib.ct_string_hash_tokens.restype = None
     lib.ct_string_hash_tokens.argtypes = [
@@ -73,17 +100,25 @@ def _build_and_load():
 
 def get_lib():
     """The loaded native library, or None (pure-Python fallback)."""
-    global _lib, _tried
+    global _lib, _tried, _load_error
     if _tried:
         return _lib
     with _lock:
         if not _tried:
             try:
                 _lib = _build_and_load()
-            except Exception:
+            except Exception as e:  # graftlint: ignore[silent-exception] — not silent: kept for load_error(); callers have an exact pure-Python fallback
                 _lib = None
+                _load_error = e
             _tried = True
     return _lib
+
+
+def load_error() -> Exception | None:
+    """Why the native library is absent (the build or load exception),
+    or None when it loaded.  Tries the load if nothing has yet."""
+    get_lib()
+    return _load_error
 
 
 def pack_strings(values) -> tuple[bytes, np.ndarray, np.ndarray] | None:

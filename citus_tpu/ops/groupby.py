@@ -151,13 +151,10 @@ def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
                           ext_pad).reshape(-1)
 
     if kernel == "pallas" and not interpret:
-        from .pallas_kernels import pallas_available
-
-        if not pallas_available() or jax.default_backend() == "cpu":
-            # same degrade rule as bucketed_unique_lookup: a config that
-            # asks for the kernel where it cannot compile falls back to
-            # the XLA formulation (identical results) instead of
-            # crashing mid-compile
+        if jax.default_backend() == "cpu":
+            # same rule as bucketed_unique_lookup: on the CPU backend a
+            # compiled pallas_call is interpret-only, so the XLA
+            # formulation (identical results) runs instead
             kernel = "xla"
 
     def _sums(colkeys: list[str], out_dtype):

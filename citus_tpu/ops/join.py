@@ -352,16 +352,10 @@ def bucketed_unique_lookup(build_key: jnp.ndarray,
     dir2d = directory.reshape(n_buckets, tile)
     loc2d = jnp.where(pvalid, packed["local"], 0)
     if kernel == "pallas" and not interpret:
-        import jax
-
-        from .pallas_kernels import pallas_available
-
-        if not pallas_available() or jax.default_backend() == "cpu":
-            # config asked for the kernel where it cannot compile — a
-            # jax build that can't import pallas, or the CPU backend
-            # (compiled pallas_call is interpret-only there): degrade
-            # to the XLA formulation (same results) rather than crash
-            # mid-compile
+        if jax.default_backend() == "cpu":
+            # config asked for the kernel on the CPU backend, where a
+            # compiled pallas_call is interpret-only: the XLA
+            # formulation gives the same results
             kernel = "xla"
     if kernel == "pallas":
         from .pallas_kernels import bucketed_probe_pallas

@@ -14,6 +14,12 @@ import os
 
 _configured = False
 
+# where JAX's persistent compilation cache lives when the environment
+# does not say (JAX_COMPILATION_CACHE_DIR): <checkout>/.jax_cache
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
 
 def ensure_jax_configured(platform: str | None = None,
                           host_device_count: int | None = None) -> None:
@@ -29,40 +35,31 @@ def ensure_jax_configured(platform: str | None = None,
 
     import jax
 
-    # NB: env vars (JAX_PLATFORMS / JAX_ENABLE_X64) are not reliably honored
-    # in every deployment (TPU plugins can win); the config API is.
+    # set through the config API, which holds whatever the environment
+    # says: int64 shard keys are not optional
     jax.config.update("jax_enable_x64", True)
     if platform is not None:
         jax.config.update("jax_platforms", platform)
     if not _configured:
         # persistent XLA executable cache: repeated plan shapes skip the
-        # (tens of seconds, on remote TPUs) cold compile across processes.
-        # CPU-backend processes skip it: XLA's CPU executable.serialize()
-        # segfaults after a few hundred distinct compilations in one
-        # process (observed killing 500-query fuzz runs), and the
-        # in-process plan cache covers repeats there anyway.
-        plat = (platform or str(getattr(jax.config, "jax_platforms", "")
-                                or os.environ.get("JAX_PLATFORMS") or ""))
-        if not plat:
-            # nothing configured explicitly: ask the backend (a plain
-            # CPU-only machine must hit the cpu opt-out too)
-            try:
-                plat = jax.default_backend()
-            except Exception:
-                plat = ""
-        cache_dir = os.environ.get(
-            "CITUS_TPU_COMPILE_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "citus_tpu_xla"))
-        try:
-            if "cpu" in plat:
-                jax.config.update("jax_enable_compilation_cache", False)
-            else:
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 1.0)
-        except (AttributeError, KeyError, ValueError):
-            pass  # older jax without persistent-cache config
+        # cold compile across processes.  CPU-backend processes skip
+        # it: XLA's CPU executable.serialize() segfaults after a few
+        # hundred distinct compilations in one process (observed
+        # killing 500-query fuzz runs), and the in-process plan cache
+        # covers repeats there anyway.  The backend itself is asked,
+        # not the configuration: `JAX_PLATFORMS=tpu,cpu` names the CPU
+        # and runs on the chip.
+        if jax.default_backend() == "cpu":
+            jax.config.update("jax_enable_compilation_cache", False)
+        else:
+            # the directory is part of the cache key, so it is either
+            # the one the environment names or one fixed place inside
+            # the checkout — never a path that moves with $HOME
+            if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+                jax.config.update("jax_compilation_cache_dir",
+                                  COMPILE_CACHE_DIR)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 1.0)
     _configured = True
 
 

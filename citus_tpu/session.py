@@ -1512,6 +1512,8 @@ class Session:
                 sum(d["bytes_in_use"] for d in dev) if dev else None)
             cols["device_bytes_limit"] = (
                 min(d["bytes_limit"] for d in dev) if dev else None)
+            cols["device_peak_bytes_in_use"] = (
+                max(d["peak_bytes_in_use"] for d in dev) if dev else None)
             return ResultSet(list(cols),
                              {k: [v] for k, v in cols.items()}, 1)
         elif e.name == "citus_stat_mesh":

@@ -99,6 +99,39 @@ class TestColdLoad:
             "restart recompiled a shape the disk cache held"
         s2.close()
 
+    def test_reload_targets_the_mesh_not_the_whole_backend(
+            self, tmp_path):
+        """The execution_devices repair, by name: an executable stored
+        from a 2-device mesh on the 8-device backend, reloaded by a
+        fresh ExecutableCache, must load onto those two devices.
+        Loaded onto every device of the backend it dispatches with
+        `Expected args to execute_sharded_on_local_devices to have 8
+        shards, got: [2, 2]`."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
+        from citus_tpu.executor.execcache import ExecutableCache
+
+        assert len(jax.devices()) == 8
+        mesh = make_mesh(2)
+        sharding = NamedSharding(mesh, P(SHARD_AXIS))
+        x = jax.device_put(np.arange(8, dtype=np.int64), sharding)
+        compiled = jax.jit(lambda v: v * 2 + 1).lower(x).compile()
+        key = ("two-of-eight", 2)
+        assert ExecutableCache(str(tmp_path)).store(
+            key, mesh, compiled, [], [], 0)
+
+        fresh = ExecutableCache(str(tmp_path))
+        entry, status = fresh.load(key, mesh)
+        assert status == "hit"
+        out = entry[0](x)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.arange(8) * 2 + 1)
+        assert {d.id for d in out.sharding.device_set} == \
+            {d.id for d in mesh.devices.flat}
+
     def test_exec_cache_disabled_compiles(self, tmp_path):
         data_dir = str(tmp_path / "d")
         s1 = _seed(data_dir)

@@ -134,10 +134,6 @@ class TestOracleParity:
                      [(0, 53, False), (0, 7, False)])
 
     def test_pallas_kernel_parity(self, sess, monkeypatch):
-        from citus_tpu.ops.pallas_kernels import pallas_available
-
-        if not pallas_available():
-            pytest.skip("pallas unavailable")
         sess.execute("create table gp (k bigint, g bigint, v int)")
         sess.create_distributed_table("gp", "k", shard_count=4)
         sess.execute("insert into gp values " + ",".join(

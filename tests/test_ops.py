@@ -351,10 +351,6 @@ class TestBucketedUniqueLookup:
         assert int(max_fill) == int(fills.max())
 
     def test_pallas_kernel_parity(self, rng, monkeypatch):
-        from citus_tpu.ops.pallas_kernels import pallas_available
-
-        if not pallas_available():
-            pytest.skip("pallas unavailable")
         base, extent = 0, 512
         bk, bmatch, pk = self._inputs(rng, base, extent, m=300, n=2000)
         want = self._lookup(monkeypatch, bk, bmatch, pk, base, extent,

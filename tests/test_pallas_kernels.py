@@ -9,12 +9,8 @@ import pytest
 
 from citus_tpu.ops.pallas_kernels import (
     dense_grid_aggregate_pallas,
-    pallas_available,
     segment_sum_reference,
 )
-
-pytestmark = pytest.mark.skipif(not pallas_available(),
-                                reason="pallas unavailable")
 
 
 @pytest.mark.parametrize("n,total", [

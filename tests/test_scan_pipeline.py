@@ -97,11 +97,8 @@ class TestDecodeKernels:
         from citus_tpu.ops.pallas_kernels import (
             bit_unpack_pallas,
             bit_unpack_reference,
-            pallas_available,
         )
 
-        if not pallas_available():
-            pytest.skip("pallas unavailable")
         rng = np.random.default_rng(1)
         bits = rng.integers(0, 2, size=(2, 1024)).astype(bool)
         packed = np.packbits(bits, axis=-1)
@@ -110,15 +107,57 @@ class TestDecodeKernels:
         np.testing.assert_array_equal(
             got, bit_unpack_reference(packed, 1024))
 
+    def test_xla_decode_of_mesh_sharded_wire_buffers(self):
+        """The formulations every device-mode scan runs, on buffers
+        sharded over the mesh the way `_place` leaves them.  The
+        dictionary gather (replicated LUT, sharded codes) is the one an
+        explicit-axis mesh will not resolve by itself: the first chip
+        run died there."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
+        from citus_tpu.executor import scanpipe
+        from citus_tpu.ops.pallas_kernels import (
+            bit_unpack_reference,
+            dict_decode_reference,
+        )
+
+        mesh = make_mesh(4)
+        rows = NamedSharding(mesh, P(SHARD_AXIS))
+        whole = NamedSharding(mesh, P())
+        rng = np.random.default_rng(3)
+        lut = np.linspace(0, 1, 11, dtype=np.float32)
+        codes = rng.integers(0, 11, size=(4, 640)).astype(np.uint8)
+        got = scanpipe._dict_expand(jax.device_put(codes, rows),
+                                    jax.device_put(lut, whole))
+        np.testing.assert_array_equal(
+            np.asarray(got), dict_decode_reference(codes, lut))
+        assert got.sharding.is_equivalent_to(rows, 2)
+
+        bits = rng.integers(0, 2, size=(4, 640)).astype(bool)
+        packed = np.packbits(bits, axis=-1)
+        got = scanpipe._bits_expand(jax.device_put(packed, rows), 640)
+        np.testing.assert_array_equal(
+            np.asarray(got), bit_unpack_reference(packed, 640))
+
+        wire = rng.integers(0, 200, size=(4, 640)).astype(np.uint8)
+        got = scanpipe._for_expand(jax.device_put(wire, rows),
+                                   np.asarray(-7, dtype=np.int64))
+        np.testing.assert_array_equal(
+            np.asarray(got), wire.astype(np.int64) - 7)
+
+        n = np.array([[5], [0], [640], [17]], dtype=np.int32)
+        got = np.asarray(
+            scanpipe._valid_expand(jax.device_put(n, rows), 640))
+        assert got.sum(axis=1).tolist() == [5, 0, 640, 17]
+
     def test_dict_decode_matches_reference(self):
         from citus_tpu.ops.pallas_kernels import (
             dict_decode_pallas,
             dict_decode_reference,
-            pallas_available,
         )
 
-        if not pallas_available():
-            pytest.skip("pallas unavailable")
         rng = np.random.default_rng(2)
         lut = np.linspace(0, 1, 37, dtype=np.float32)
         codes = rng.integers(0, 37, size=(3, 700)).astype(np.uint8)

@@ -111,3 +111,111 @@ def test_trace_summarize_errors_cleanly_without_traces(tmp_path, capsys):
 
     assert trace_summarize.main([str(tmp_path)]) == 1
     assert "trace_summarize:" in capsys.readouterr().err
+
+
+# -- the native host library is built here, from the committed sources --
+
+def test_native_library_is_not_tracked():
+    import subprocess
+
+    import pytest
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if not os.path.isdir(os.path.join(root, ".git")):
+        pytest.skip("not a git checkout")
+    tracked = subprocess.run(
+        ["git", "ls-files", "citus_tpu/native"], cwd=root, check=True,
+        capture_output=True, text=True).stdout.split()
+    assert tracked and not [f for f in tracked if ".so" in f], tracked
+
+
+def test_native_library_loads_here():
+    import citus_tpu.native as native
+
+    assert native.load_error() is None
+    assert native.get_lib() is not None
+
+
+def test_changed_source_hash_forces_a_native_rebuild(tmp_path):
+    """Stale is decided by a hash of the two .cpp files stored beside
+    the library, not by mtimes a checkout or a copy sets arbitrarily."""
+    import shutil
+
+    import citus_tpu.native as native
+
+    build = str(tmp_path)
+    for f in ("hashdict.cpp", "stripecodec.cpp"):
+        shutil.copy(os.path.join(os.path.dirname(native.__file__), f),
+                    build)
+    so = os.path.join(build, "_native.so")
+    native._build_and_load(build)
+    with open(so + ".sha256") as f:
+        first = f.read()
+    built_at = os.stat(so).st_mtime_ns
+
+    # same sources, library made to look OLDER than them: no rebuild
+    os.utime(so, ns=(1, 1))
+    native._build_and_load(build)
+    assert os.stat(so).st_mtime_ns == 1
+
+    # one changed byte in a source: rebuilt, whatever the mtimes say
+    with open(os.path.join(build, "hashdict.cpp"), "a") as f:
+        f.write("\n// changed\n")
+    os.utime(os.path.join(build, "hashdict.cpp"), ns=(0, 0))
+    native._build_and_load(build)
+    with open(so + ".sha256") as f:
+        assert f.read() != first
+    assert os.stat(so).st_mtime_ns >= built_at
+
+    # a library with no stamp beside it is one built elsewhere: rebuilt
+    os.unlink(so + ".sha256")
+    os.utime(so, ns=(1, 1))
+    native._build_and_load(build)
+    assert os.stat(so).st_mtime_ns != 1 and os.path.exists(so + ".sha256")
+
+
+# -- where JAX's persistent compilation cache goes (citus_tpu/runtime.py) --
+
+def _configure_as(monkeypatch, backend, env_dir):
+    """Run ensure_jax_configured's first-call branch against a recording
+    jax.config.update, as the backend `backend` would see it."""
+    import jax
+
+    import citus_tpu.runtime as runtime
+
+    updates = {}
+    monkeypatch.setattr(runtime, "_configured", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    runtime.ensure_jax_configured()
+    return updates
+
+
+def test_compile_cache_dir_is_the_environments_when_it_names_one(
+        monkeypatch, tmp_path):
+    updates = _configure_as(monkeypatch, "tpu", str(tmp_path))
+    assert "jax_compilation_cache_dir" not in updates
+    assert "jax_enable_compilation_cache" not in updates
+
+
+def test_compile_cache_dir_is_fixed_inside_the_checkout_otherwise(
+        monkeypatch):
+    import citus_tpu.runtime as runtime
+
+    updates = _configure_as(monkeypatch, "tpu", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert updates["jax_compilation_cache_dir"] == \
+        os.path.join(root, ".jax_cache") == runtime.COMPILE_CACHE_DIR
+
+
+def test_compile_cache_stays_off_on_the_cpu_backend(monkeypatch, tmp_path):
+    # even when JAX_PLATFORMS would read "tpu,cpu": the backend is asked
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    updates = _configure_as(monkeypatch, "cpu", str(tmp_path))
+    assert updates["jax_enable_compilation_cache"] is False
+    assert "jax_compilation_cache_dir" not in updates

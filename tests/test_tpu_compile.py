@@ -1,0 +1,242 @@
+"""The chip's compiler, asked without the chip.
+
+The TPU compiler is installed here and compiles for a device that is
+described, not attached (on-chip-measurement guide §2, third rehearsal).
+These tests compile — never run — the kernels and the XLA formulations
+the planner picks on `tpu`, at the shapes TPC-H SF1 produces on one
+chip (6.0 M lineitem rows, 1.5 M orders, a 6 M-slot order-key
+directory), with `jax_enable_x64` on as the engine runs.  A refusal
+that interpret mode cannot show (tiling, 64-bit types, an unsupported
+gather) shows here and costs no chip time.
+
+A kernel the compiler refuses stays as `xfail(strict=True)` carrying
+its message, and is statically off the default path (scanpipe.py calls
+the XLA formulations); when a later PR repairs or deletes the kernel,
+the strict xfail turns red and the marker goes with it.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: the driver runs several xdist workers, each imports every test
+file, and only one process at a time may load the TPU library.  Keep
+every such compile in THIS file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from citus_tpu.executor import scanpipe
+from citus_tpu.executor.compiler import _round_cap
+from citus_tpu.ops import pallas_kernels as pk
+from citus_tpu.ops.groupby import (
+    GROUP_TILE_SLOTS,
+    bucketed_grid_aggregate,
+    group_bucket_count,
+)
+from citus_tpu.ops.join import (
+    PROBE_TILE_SLOTS,
+    bucketed_unique_lookup,
+    probe_bucket_count,
+)
+
+# TPC-H SF1 on one chip (ingest/tpch.py): padded feed capacities
+LINEITEM_CAP = _round_cap(6_001_520)
+ORDERS_CAP = _round_cap(1_500_000)
+ORDERKEY_EXTENT = 6_000_000          # o_orderkey = 4i + 1, i < 1.5 M
+PROBE_BUCKETS = probe_bucket_count(ORDERKEY_EXTENT)
+# runner.py sizes a probe bucket at expectation × bucket factor (2.0)
+PROBE_BUCKET_CAP = _round_cap(int(LINEITEM_CAP / PROBE_BUCKETS * 2.0))
+
+
+def _group_cap(rows: int, buckets: int) -> int:
+    # runner.py: expectation × agg_bucket_capacity_factor (2.0) + 128
+    return _round_cap(int(-(-rows // buckets) * 2.0) + 128)
+
+
+# group by l_orderkey: the packed slot space is the key extent plus the
+# null slot, in 4096-slot tiles
+ORDERKEY_GROUP_BUCKETS = group_bucket_count(ORDERKEY_EXTENT + 1)
+ORDERKEY_GROUP_CAP = _group_cap(LINEITEM_CAP, ORDERKEY_GROUP_BUCKETS)
+
+# what Mosaic says to every lane-dimension take_along_axis whose source
+# is wider than one 128-lane vreg (a 32768-slot directory tile, a
+# dictionary LUT): the formulation itself, not its block shapes
+_GATHER = ("Mosaic: 'Not implemented: Multiple source vregs along "
+           "gather dimension' (tpu.dynamic_gather gathers within one "
+           "128-lane vreg, and only when source and indices have one "
+           "shape)")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """shape-and-dtype → an abstract argument placed on one v5e chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return arg
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+# -- Pallas kernels a config var selects: repaired, must compile --------
+
+def test_dense_grid_aggregate_pallas_compiles(chip):
+    c = _compile(lambda s, v: pk.dense_grid_aggregate_pallas(
+        s, v, GROUP_TILE_SLOTS),
+        chip((LINEITEM_CAP,), jnp.int32),
+        chip((LINEITEM_CAP, 4), jnp.float32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("buckets,cap", [
+    # group by o_custkey: 150 k groups over 1.5 M orders
+    pytest.param(group_bucket_count(150_001),
+                 _group_cap(ORDERS_CAP, group_bucket_count(150_001)),
+                 id="o_custkey"),
+    # group by l_orderkey: 6 M slots over 6 M lineitem rows
+    pytest.param(
+        ORDERKEY_GROUP_BUCKETS, ORDERKEY_GROUP_CAP, id="l_orderkey",
+        marks=pytest.mark.xfail(
+            strict=True, raises=jax.errors.JaxRuntimeError,
+            reason="'RESOURCE_EXHAUSTED: XLA:TPU compile permanent "
+                   "error. Ran out of memory in memory space hbm. Used "
+                   "15.92G of 15.75G hbm ... Extra memory due to "
+                   "padding: 6.39G (128.0x expansion)': the kernel "
+                   "takes slots as [rows, 1] and values as [rows, 128], "
+                   "rows on sublanes, which the chip's tiled HBM layout "
+                   "pads 128-fold")),
+])
+def test_bucketed_groupby_sums_pallas_compiles(chip, buckets, cap):
+    c = _compile(lambda loc, stack: pk.bucketed_groupby_sums_pallas(
+        loc, stack, GROUP_TILE_SLOTS),
+        chip((buckets, cap), jnp.int32),
+        chip((buckets, cap, 3), jnp.float32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+# -- Pallas kernels the compiler refuses: off every default path --------
+
+@pytest.mark.xfail(
+    strict=True, raises=RecursionError,
+    reason="as written the uint8→int32 widen of the packed block never "
+           "lowers (Pallas TPU convert rule recurses on unsigned 8-bit); "
+           "behind it, its (1, 128)/(1, 1024) uint8 blocks miss the "
+           "(32, 128) tiling and its byte-select is a lane gather: "
+           + _GATHER)
+def test_bit_unpack_pallas_compiles(chip):
+    _compile(lambda p: pk.bit_unpack_pallas(p, LINEITEM_CAP),
+             chip((1, LINEITEM_CAP // 8), jnp.uint8))
+
+
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="'64-bit types are not supported': under jax_enable_x64 "
+           "take_along_axis widens its indices to int64 inside the "
+           "kernel, whatever the LUT dtype; behind it, (1, 512) blocks "
+           "miss the (8, 128) tiling and the decode is a lane gather "
+           "over the whole LUT: " + _GATHER)
+@pytest.mark.parametrize("lut_dtype", [jnp.float32, jnp.float64])
+@pytest.mark.parametrize("code_dtype", [jnp.uint8, jnp.uint16])
+def test_dict_decode_pallas_compiles(chip, code_dtype, lut_dtype):
+    # l_quantity has 50 distinct values, l_extendedprice ~40 k at SF1
+    nv = 50 if code_dtype == jnp.uint8 else 40_000
+    _compile(pk.dict_decode_pallas,
+             chip((1, LINEITEM_CAP), code_dtype), chip((nv,), lut_dtype))
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="'The Pallas TPU lowering currently requires that the last "
+           "two dimensions of your block shape are divisible by 8 and "
+           "128 respectively, or be equal to the respective dimensions "
+           "of the overall array' for its (1, 32768) and (1, 512) "
+           "blocks; behind that, the probe is a lane gather of 512 "
+           "slots from a 32768-slot tile: " + _GATHER)
+def test_bucketed_probe_pallas_compiles(chip):
+    _compile(pk.bucketed_probe_pallas,
+             chip((PROBE_BUCKETS, PROBE_TILE_SLOTS), jnp.int32),
+             chip((PROBE_BUCKETS, PROBE_BUCKET_CAP), jnp.int32))
+
+
+# -- XLA formulations the planner picks on `tpu` -------------------------
+
+def test_bucketed_unique_lookup_xla_compiles(chip):
+    """Q3's lineitem ⋈ orders probe at SF1: 6 M int64 probe keys into
+    the 6 M-slot order-key directory (the bucketed probe pick,
+    planner/plan.py `probe_bucketed`)."""
+    c = _compile(
+        lambda bk, bm, pkey: bucketed_unique_lookup(
+            bk, bm, pkey, 1, ORDERKEY_EXTENT, PROBE_BUCKET_CAP,
+            kernel="xla"),
+        chip((ORDERS_CAP,), jnp.int64), chip((ORDERS_CAP,), jnp.bool_),
+        chip((LINEITEM_CAP,), jnp.int64))
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+def test_bucketed_grid_aggregate_xla_compiles(chip):
+    """The high-cardinality group-by at SF1 (group by l_orderkey over
+    6 M rows; planner/plan.py `group_bucketed`)."""
+    total, rows, cap = ORDERKEY_EXTENT + 1, LINEITEM_CAP, ORDERKEY_GROUP_CAP
+    c = _compile(
+        lambda slot, valid, v, n: bucketed_grid_aggregate(
+            slot, valid, [(v, "sum"), (n, "count")], total, cap,
+            kernel="xla"),
+        chip((rows,), jnp.int32), chip((rows,), jnp.bool_),
+        chip((rows,), jnp.float32), chip((rows,), jnp.int32))
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+# -- the on-device scan decode every TPU scan now takes (scanpipe.py) ---
+
+@pytest.mark.parametrize("lut_dtype", [jnp.float32, jnp.float64])
+@pytest.mark.parametrize("code_dtype", [jnp.uint8, jnp.uint16])
+def test_scan_dict_expand_compiles(chip, code_dtype, lut_dtype):
+    nv = 50 if code_dtype == jnp.uint8 else 40_000
+    _compile(scanpipe._dict_expand,
+             chip((1, LINEITEM_CAP), code_dtype), chip((nv,), lut_dtype))
+
+
+@pytest.mark.parametrize("wire,decoded", [
+    (jnp.uint8, jnp.int32),     # l_linenumber, dictionary codes
+    (jnp.uint16, jnp.int32),    # dates
+    (jnp.uint32, jnp.int64),    # order / part / supplier keys
+])
+def test_scan_for_expand_compiles(chip, wire, decoded):
+    _compile(scanpipe._for_expand,
+             chip((1, LINEITEM_CAP), wire), chip((), decoded))
+
+
+def test_scan_bits_and_valid_expand_compile(chip):
+    # the validity plane at the customer table's size (150 k rows), not
+    # lineitem's: this reshape formulation compiles in time linear in
+    # its rows — 4 s here, 155 s at 6 M (PERF.md, open questions) — and
+    # no TPC-H column is nullable, so SF1 never compiles it at all
+    cap = _round_cap(150_000)
+    _compile(lambda p: scanpipe._bits_expand(p, cap),
+             chip((1, cap // 8), jnp.uint8))
+    _compile(lambda r: scanpipe._valid_expand(r, LINEITEM_CAP),
+             chip((1, 1), jnp.int32))
