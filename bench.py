@@ -131,11 +131,10 @@ def trace_phase_keys(doc, wall_seconds=None, sql=None):
     return out
 
 
-def trace_acceptance_keys(sess, export_path=None, sql=None):
+def trace_acceptance_keys(sess, sql=None):
     """Acceptance evidence for the newest measured statement: the
-    top-level-spans-sum-to-wall share of ITS trace, p50/p99 of its
-    statement class from the DDSketch histograms, and (optionally) a
-    Chrome-trace JSON export next to the artifact.  `sql` guards
+    top-level-spans-sum-to-wall share of ITS trace and p50/p99 of its
+    statement class from the DDSketch histograms.  `sql` guards
     against last_trace() returning a different (auto-degrade-sampled)
     statement's trace — see trace_phase_keys."""
     from citus_tpu.stats.tracing import clamp_sql
@@ -156,14 +155,6 @@ def trace_acceptance_keys(sess, export_path=None, sql=None):
             out["trace_p99_ms"] = row["p99_ms"]
             out["trace_calls"] = row["calls"]
             break
-    if export_path:
-        from citus_tpu.stats.trace_export import chrome_trace_events
-
-        payload = {"traceEvents": chrome_trace_events(doc),
-                   "displayTimeUnit": "ms"}
-        with open(export_path, "w") as f:
-            json.dump(payload, f, indent=1)
-        out["trace_export"] = os.path.basename(export_path)
     return out
 
 
@@ -1534,16 +1525,12 @@ def main() -> None:
                 rate, best = bench_query(
                     s10, QUERIES["Q3"], n_cust10 + n_ord10 + n_li10, r)
                 # the acceptance run: EXPLAIN-equal phase walls from
-                # the trace, a Chrome-trace export next to the
-                # artifacts, and the class's DDSketch p50/p99
+                # the trace and the class's DDSketch p50/p99
                 extra = trace_phase_keys(
                     s10.stats.tracing.last_trace(), wall_seconds=best,
                     sql=QUERIES["Q3"])
                 extra.update(trace_acceptance_keys(
-                    s10, sql=QUERIES["Q3"],
-                    export_path=os.path.join(
-                        os.path.dirname(os.path.abspath(__file__)),
-                        "TRACE_sf10_q3.json")))
+                    s10, sql=QUERIES["Q3"]))
                 emit("tpch_q3_sf10_rows_per_sec", rate, best,
                      sf10_scale, reps=r, sess_obj=s10, extra=extra)
 

@@ -16,7 +16,10 @@ by scattered ad-hoc tests (or not at all):
   ``trace_span("name")`` / ``span_name("name")`` record site (the
   flight recorder's EXPLAIN_TAGS analogue: bench drivers and
   trace_summarize key on these strings, so a silently renamed span is
-  a silently broken phase attribution).
+  a silently broken phase attribution), and under the same rule
+  ``STAGE_NAMES`` vs every ``stage_scope("name")`` site and every
+  capacity-stage kind of ``self._record(nid, "kind", ...)`` (the
+  benchmark's stage metrics key on the ``ct.<name>`` scopes).
 
 Both directions are findings: a name used but not registered is
 ``*-registry: unregistered``, a registered name never used is
@@ -84,9 +87,10 @@ def _registered_config_vars(tree: ast.AST) -> dict[str, int]:
 
 # -- use-site extraction ----------------------------------------------------
 def _str_arg_calls(modules: list[Module], fn_name: str,
-                   skip_paths: tuple = (),
+                   skip_paths: tuple = (), arg: int = 0,
                    ) -> list[tuple[str, str, int, str]]:
-    """(name, relpath, line, ctx) for every `fn_name("literal")` call."""
+    """(name, relpath, line, ctx) for every `fn_name("literal")` call
+    (`arg`: which positional argument holds the literal)."""
     out = []
     for m in modules:
         if m.relpath in skip_paths:
@@ -98,10 +102,10 @@ def _str_arg_calls(modules: list[Module], fn_name: str,
             name = (fn.id if isinstance(fn, ast.Name)
                     else fn.attr if isinstance(fn, ast.Attribute)
                     else None)
-            if name == fn_name and node.args and \
-                    isinstance(node.args[0], ast.Constant) and \
-                    isinstance(node.args[0].value, str):
-                out.append((node.args[0].value, m.relpath,
+            if name == fn_name and len(node.args) > arg and \
+                    isinstance(node.args[arg], ast.Constant) and \
+                    isinstance(node.args[arg].value, str):
+                out.append((node.args[arg].value, m.relpath,
                             node.lineno, ctx))
     return out
 
@@ -283,6 +287,25 @@ def check(modules: list[Module], partial: bool = False) -> list[Finding]:
                 "span-registry", TRACING_MOD, registry[name],
                 f"span name {name!r} is registered but never recorded "
                 "via trace_span()/span_name()"))
+        # stage names: the same contract for the device programs'
+        # `ct.<stage>` scopes (absent from a tree without STAGE_NAMES)
+        stages = _dict_literal_keys(tmod.tree, "STAGE_NAMES")
+        # PlanCompiler._record(nid, "kind", ...): capacity stages
+        uses = (_str_arg_calls(modules, "stage_scope")
+                + _str_arg_calls(modules, "_record", arg=1)
+                ) if stages else []
+        used = {u[0] for u in uses}
+        for name, path, line, ctx in sorted(uses):
+            if name not in stages:
+                findings.append(Finding(
+                    "span-registry", path, line,
+                    f"stage name {name!r} is not declared in "
+                    "STAGE_NAMES (stats/tracing.py)", ctx))
+        for name in (() if partial else sorted(set(stages) - used)):
+            findings.append(Finding(
+                "span-registry", TRACING_MOD, stages[name],
+                f"stage name {name!r} is registered but no "
+                "stage_scope() carries it"))
     return findings
 
 

@@ -507,16 +507,9 @@ _register(ConfigVar(
     "Statements slower than this persist their full span tree as "
     "JSON under <data_dir>/slow_traces/ through the durable-write "
     "seam (newest 32 kept; tools/trace_summarize.py prints the "
-    "newest one, python -m citus_tpu.stats.trace_export renders it "
-    "for chrome://tracing).  0 disables the slow-query log "
+    "newest one).  0 disables the slow-query log "
     "(PostgreSQL log_min_duration_statement analogue).",
     int, min_value=0, max_value=86_400_000))
-_register(ConfigVar(
-    "trace_sample_every", 1,
-    "Record a full span tree for 1 in N statements (histograms "
-    "always update).  1 = every statement; raise it if a workload "
-    "ever shows the recorder in its profile (PERF_NOTES round 16).",
-    int, min_value=1, max_value=1_000_000))
 _register(ConfigVar(
     "trace_fast_statement_ms", 5.0,
     "Auto-degrade threshold: statement classes whose OBSERVED mean "

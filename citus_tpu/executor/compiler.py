@@ -59,6 +59,7 @@ from ..planner.plan import (
     WindowNode,
 )
 from ..distributed.mesh import SHARD_AXIS
+from ..stats.tracing import STAGE_NAMES, stage_scope
 from .batch import Block
 from .exprs import ColumnSource, evaluate, predicate_mask
 
@@ -368,7 +369,8 @@ class PlanCompiler:
                 set_device_params({idx: arr[0] for idx, arr in
                                    zip(self._param_idx, param_args)})
             try:
-                blocks = self._unpack_feeds(flat_feeds)
+                with stage_scope("feed_unpack"):
+                    blocks = self._unpack_feeds(flat_feeds)
                 self._overflow = jnp.zeros((), dtype=jnp.int64)
                 self._dense_oob = jnp.zeros((), dtype=jnp.int64)
                 self._stage_actual = {}
@@ -398,17 +400,12 @@ class PlanCompiler:
                             out.valid.shape))
                 topk = self.plan.device_topk
                 if topk is not None and out.valid.shape[0] > topk:
-                    out = self._device_topk(out, topk)
+                    with stage_scope("topk"):
+                        out = self._device_topk(out, topk)
             finally:
                 # traced scalars must not leak into host-side evaluation
                 # on this thread after the trace completes
                 set_device_params(None)
-            cols = {cid: jnp.broadcast_to(out.columns[cid],
-                                          out.valid.shape)[None, :]
-                    for cid in out_cids}
-            nulls = {cid: jnp.broadcast_to(out.null_mask(cid),
-                                           out.valid.shape)[None, :]
-                     for cid in out_cids}
             # overflow block per device: [capacity_overflow, dense_oob,
             # *stage_actuals] — the host grows buffers for the first,
             # drops stale dense structures for the second, and tightens
@@ -419,9 +416,17 @@ class PlanCompiler:
             self.stage_keys = [
                 (self._walk_order.get(nid, -1), kind,
                  self._stage_width[(nid, kind)]) for nid, kind in skeys]
-            return (cols, nulls, out.valid[None, :],
-                    jnp.stack([self._overflow, self._dense_oob]
-                              + [self._stage_actual[k] for k in skeys]))
+            with stage_scope("output_pack"):
+                cols = {cid: jnp.broadcast_to(out.columns[cid],
+                                              out.valid.shape)[None, :]
+                        for cid in out_cids}
+                nulls = {cid: jnp.broadcast_to(out.null_mask(cid),
+                                               out.valid.shape)[None, :]
+                         for cid in out_cids}
+                return (cols, nulls, out.valid[None, :],
+                        jnp.stack([self._overflow, self._dense_oob]
+                                  + [self._stage_actual[k]
+                                     for k in skeys]))
 
         mapped = shard_map(body, mesh=self.mesh,
                            in_specs=tuple(in_specs), out_specs=out_specs,
@@ -442,12 +447,13 @@ class PlanCompiler:
 
         def packed_fn(*flat_feeds):
             cols, nulls, valid, overflow = mapped(*flat_feeds)
-            rows = []
-            for kind, cid, _dt in out_meta:
-                arr = (cols[cid] if kind == "col"
-                       else nulls[cid] if kind == "null" else valid)
-                rows.append(_to_bits64(arr))
-            return jnp.stack(rows), overflow
+            with stage_scope("output_pack"):
+                rows = []
+                for kind, cid, _dt in out_meta:
+                    arr = (cols[cid] if kind == "col"
+                           else nulls[cid] if kind == "null" else valid)
+                    rows.append(_to_bits64(arr))
+                return jnp.stack(rows), overflow
 
         # the cached executable closes over this compiler (via body); drop
         # the FeedSpec device arrays so the plan cache pins only code +
@@ -491,9 +497,13 @@ class PlanCompiler:
         back to pre-sort row positions (unique indices — vectorized on
         TPU), so the input block passes through unchanged with the
         window columns appended."""
+        blk = self._exec(node.input, feeds)
+        with stage_scope("window"):
+            return self._window_over(node, blk)
+
+    def _window_over(self, node, blk: Block) -> Block:
         from ..ops.aggregate import _segmented_scan
 
-        blk = self._exec(node.input, feeds)
         if node.combine == "repartition":
             cap = self.caps.repartition[id(node)]
             # routing keys with explicit NULL flags (zeroed value + flag),
@@ -698,6 +708,7 @@ class PlanCompiler:
         same (node, kind) — e.g. repart_both's two shuffles, or the two
         sort-path aggregation levels — merge by max: the shared buffer
         must cover the larger."""
+        STAGE_NAMES[kind]  # a capacity stage is a stage: one vocabulary
         key = (nid, kind)
         c = count.astype(jnp.int64)
         if key in self._stage_actual:
@@ -712,18 +723,20 @@ class PlanCompiler:
         if isinstance(node, ScanNode):
             blk = feeds[id(node)]
             if node.filter is not None:
-                mask = predicate_mask(node.filter,
-                                      _src(blk), jnp)
-                blk = blk.with_filter(mask)
-                self._record(id(node), "scan_out", blk.valid.sum(),
-                             blk.valid.shape[0])
-                k = self.caps.scan_out.get(id(node))
-                if k is not None and k < blk.valid.shape[0]:
-                    blk = self._compact(blk, k)
+                with stage_scope("scan_out"):
+                    mask = predicate_mask(node.filter,
+                                          _src(blk), jnp)
+                    blk = blk.with_filter(mask)
+                    self._record(id(node), "scan_out", blk.valid.sum(),
+                                 blk.valid.shape[0])
+                    k = self.caps.scan_out.get(id(node))
+                    if k is not None and k < blk.valid.shape[0]:
+                        blk = self._compact(blk, k)
             return blk
         if isinstance(node, ProjectNode):
             blk = self._exec(node.input, feeds)
-            return self._project(blk, node.exprs)
+            with stage_scope("project"):
+                return self._project(blk, node.exprs)
         if isinstance(node, JoinNode):
             return self._exec_join(node, feeds)
         if isinstance(node, WindowNode):
@@ -875,70 +888,75 @@ class PlanCompiler:
         aggregate combine) only need internal consistency and use the
         64-bit combine folded to token space.
         """
-        if key_arrays is None:
-            key_arrays, valid = self._eval_keys(blk, keys)
-            if keep_null_rows:
-                # outer-preserved side: NULL-key rows ride the shuffle
-                # (routed by their zeroed storage value — deterministic;
-                # they match nothing but must still emit null-extended)
-                valid = blk.valid
-        if len(key_arrays) == 1:
-            token = hash_token_jax(key_arrays[0])
-        else:
-            from ..ops.hashing import combine_hash64
-
-            h = combine_hash64(key_arrays)
-            token = ((h & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
-                     .astype(jnp.int64) + INT32_MIN).astype(jnp.int32)
-        if bounds is not None:
-            # range-aware routing: shard bounds are arbitrary after splits
-            mins = jnp.asarray(np.asarray(bounds, dtype=np.int64))
-            shard = (jnp.searchsorted(mins, token.astype(jnp.int64),
-                                      side="right") - 1).clip(
-                0, shard_count - 1).astype(jnp.int32)
-        else:
-            increment = HASH_TOKEN_COUNT // shard_count
-            shard = jnp.minimum(
-                (token.astype(jnp.int64) - INT32_MIN) // increment,
-                shard_count - 1).astype(jnp.int32)
-        placement_arr = jnp.asarray(np.asarray(placement, dtype=np.int32))
-        target = placement_arr[shard]
-        if record_nid is not None:
-            # the binding constraint on this buffer is the largest
-            # (source device → target device) bucket
-            sent = jnp.zeros(self.n_dev, jnp.int32).at[target].add(
-                valid.astype(jnp.int32), mode="drop")
-            self._record(record_nid, "repartition", sent.max(), capacity)
-
-        all_cols = dict(blk.columns)
-        for cid, nmask in blk.nulls.items():
-            all_cols[NULL_PREFIX + cid] = nmask
-        packed, pvalid, overflow = pack_by_target(
-            all_cols, valid, target, self.n_dev, capacity)
-        self._overflow = self._overflow + overflow.astype(jnp.int64)
-
-        exchanged = {}
-        for cid, arr in packed.items():
-            exchanged[cid] = jax.lax.all_to_all(
-                arr, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True)
-        new_valid = jax.lax.all_to_all(
-            pvalid, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True)
-        # mesh-wide exchange volume of this stage (each device moves its
-        # whole [n_dev, cap] pack) — static shapes make it knowable at
-        # trace time, surfaced via the Mesh: EXPLAIN line and
-        # shuffle_bytes_total
-        self._shuffle_bytes += self.n_dev * int(
-            sum(int(a.size) * a.dtype.itemsize for a in packed.values())
-            + int(pvalid.size) * pvalid.dtype.itemsize)
-        flat_n = self.n_dev * capacity
-        cols, nulls = {}, {}
-        for cid, arr in exchanged.items():
-            flat = arr.reshape(flat_n)
-            if cid.startswith(NULL_PREFIX):
-                nulls[cid[len(NULL_PREFIX):]] = flat
+        with stage_scope("repartition"):
+            if key_arrays is None:
+                key_arrays, valid = self._eval_keys(blk, keys)
+                if keep_null_rows:
+                    # outer-preserved side: NULL-key rows ride the shuffle
+                    # (routed by their zeroed storage value — deterministic;
+                    # they match nothing but must still emit null-extended)
+                    valid = blk.valid
+            if len(key_arrays) == 1:
+                token = hash_token_jax(key_arrays[0])
             else:
-                cols[cid] = flat
-        return Block(cols, new_valid.reshape(flat_n), nulls)
+                from ..ops.hashing import combine_hash64
+
+                h = combine_hash64(key_arrays)
+                token = ((h & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+                         .astype(jnp.int64) + INT32_MIN).astype(jnp.int32)
+            if bounds is not None:
+                # range-aware routing: shard bounds are arbitrary after splits
+                mins = jnp.asarray(np.asarray(bounds, dtype=np.int64))
+                shard = (jnp.searchsorted(mins, token.astype(jnp.int64),
+                                          side="right") - 1).clip(
+                    0, shard_count - 1).astype(jnp.int32)
+            else:
+                increment = HASH_TOKEN_COUNT // shard_count
+                shard = jnp.minimum(
+                    (token.astype(jnp.int64) - INT32_MIN) // increment,
+                    shard_count - 1).astype(jnp.int32)
+            placement_arr = jnp.asarray(np.asarray(placement, dtype=np.int32))
+            target = placement_arr[shard]
+            if record_nid is not None:
+                # the binding constraint on this buffer is the largest
+                # (source device → target device) bucket
+                sent = jnp.zeros(self.n_dev, jnp.int32).at[target].add(
+                    valid.astype(jnp.int32), mode="drop")
+                self._record(record_nid, "repartition", sent.max(), capacity)
+
+            all_cols = dict(blk.columns)
+            for cid, nmask in blk.nulls.items():
+                all_cols[NULL_PREFIX + cid] = nmask
+            packed, pvalid, overflow = pack_by_target(
+                all_cols, valid, target, self.n_dev, capacity)
+            self._overflow = self._overflow + overflow.astype(jnp.int64)
+
+            with stage_scope("exchange"):
+                exchanged = {}
+                for cid, arr in packed.items():
+                    exchanged[cid] = jax.lax.all_to_all(
+                        arr, SHARD_AXIS, split_axis=0, concat_axis=0,
+                        tiled=True)
+                new_valid = jax.lax.all_to_all(
+                    pvalid, SHARD_AXIS, split_axis=0, concat_axis=0,
+                    tiled=True)
+            # mesh-wide exchange volume of this stage (each device moves its
+            # whole [n_dev, cap] pack) — static shapes make it knowable at
+            # trace time, surfaced via the Mesh: EXPLAIN line and
+            # shuffle_bytes_total
+            self._shuffle_bytes += self.n_dev * int(
+                sum(int(a.size) * a.dtype.itemsize for a in packed.values())
+                + int(pvalid.size) * pvalid.dtype.itemsize)
+            with stage_scope("unpack"):
+                flat_n = self.n_dev * capacity
+                cols, nulls = {}, {}
+                for cid, arr in exchanged.items():
+                    flat = arr.reshape(flat_n)
+                    if cid.startswith(NULL_PREFIX):
+                        nulls[cid[len(NULL_PREFIX):]] = flat
+                    else:
+                        cols[cid] = flat
+                return Block(cols, new_valid.reshape(flat_n), nulls)
 
     def _join_inputs(self, node: JoinNode, feeds):
         """Execute both sides + repartition stages + key evaluation.
@@ -997,16 +1015,17 @@ class PlanCompiler:
         else:
             raise ExecutionError(f"bad join strategy {node.strategy}")
 
-        key_int32 = getattr(node, "key_int32", ())
-        lkeys, lmatch = self._eval_keys(lblk, node.left_keys, key_int32)
-        rkeys, rmatch = self._eval_keys(rblk, node.right_keys, key_int32)
-        # ON single-side gates: restrict MATCHING without dropping rows
-        if node.left_match_filter is not None:
-            lmatch = lmatch & predicate_mask(node.left_match_filter,
-                                             _src(lblk), jnp)
-        if node.right_match_filter is not None:
-            rmatch = rmatch & predicate_mask(node.right_match_filter,
-                                             _src(rblk), jnp)
+        with stage_scope("join_out"):
+            key_int32 = getattr(node, "key_int32", ())
+            lkeys, lmatch = self._eval_keys(lblk, node.left_keys, key_int32)
+            rkeys, rmatch = self._eval_keys(rblk, node.right_keys, key_int32)
+            # ON single-side gates: restrict MATCHING without dropping rows
+            if node.left_match_filter is not None:
+                lmatch = lmatch & predicate_mask(node.left_match_filter,
+                                                 _src(lblk), jnp)
+            if node.right_match_filter is not None:
+                rmatch = rmatch & predicate_mask(node.right_match_filter,
+                                                 _src(rblk), jnp)
         return lblk, rblk, lkeys, lmatch, rkeys, rmatch
 
     def _exec_lookup_join(self, node: JoinNode, lblk, rblk, lkeys, lmatch,
@@ -1039,59 +1058,63 @@ class PlanCompiler:
             # tile, probe tile-locally — random HBM gathers become
             # streaming tile traffic.  Same oob/duplicate retry contract
             # as the single gather; bucket skew overflows → grown retry.
-            bidx, counts, dense_oob, boverflow, bfill = \
-                bucketed_unique_lookup(bkeys[0], bmatch, pkeys[0],
-                                       dense[0], dense[1], bucket_cap,
-                                       kernel=self.probe_kernel)
-            self._overflow = self._overflow + boverflow
-            self._record(id(node), "bucket_probe", bfill, bucket_cap)
-            counts = jnp.where(pmatch, counts, 0)
+            with stage_scope("bucket_probe"):
+                bidx, counts, dense_oob, boverflow, bfill = \
+                    bucketed_unique_lookup(bkeys[0], bmatch, pkeys[0],
+                                           dense[0], dense[1], bucket_cap,
+                                           kernel=self.probe_kernel)
+                self._overflow = self._overflow + boverflow
+                self._record(id(node), "bucket_probe", bfill, bucket_cap)
+                counts = jnp.where(pmatch, counts, 0)
         elif dense is not None and len(bkeys) == 1:
             # unique build key (the fused-lookup planner claim): scatter
             # directory, NO build-side argsort per execution
-            bidx, counts, dense_oob = dense_unique_lookup(
-                bkeys[0], bmatch, pkeys[0], dense[0], dense[1])
-            counts = jnp.where(pmatch, counts, 0)
+            with stage_scope("lookup_join"):
+                bidx, counts, dense_oob = dense_unique_lookup(
+                    bkeys[0], bmatch, pkeys[0], dense[0], dense[1])
+                counts = jnp.where(pmatch, counts, 0)
         else:
-            order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys,
-                                               dense)
-            counts = jnp.where(pmatch, hi - lo, 0)
-            m0 = bkeys[0].shape[0]
-            bidx = order[jnp.clip(lo, 0, m0 - 1)]
-        self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64) + \
-            jnp.maximum(counts - 1, 0).sum().astype(jnp.int64)
-        found = counts > 0
-        probe_outer = node.join_type == "left"
-        out_valid = pblk.valid if probe_outer else found
-        if not probe_outer and node.residual is None:
-            self._record(id(node), "join_out", out_valid.sum(),
-                         out_valid.shape[0])
-        # selective FK join: compact the probe side BEFORE gathering
-        # build columns, so the gathers and everything downstream run at
-        # the join-estimate size instead of the probe capacity
-        k = self.caps.join_out.get(id(node))
-        if (not probe_outer and node.residual is None and k is not None
-                and k < out_valid.shape[0]):
-            marker = "__bidx__"
-            tmp = Block({**pblk.columns, marker: bidx}, out_valid,
-                        pblk.nulls)
-            tmp = self._compact(tmp, k)
-            bidx = tmp.columns.pop(marker)
-            pblk = Block(tmp.columns, tmp.valid, tmp.nulls)
-            out_valid = tmp.valid
-        cols = dict(pblk.columns)
-        nulls = dict(pblk.nulls)
-        for cid, arr in bblk.columns.items():
-            cols[cid] = arr[bidx]
-            nm = bblk.nulls.get(cid)
-            gathered = nm[bidx] if nm is not None else None
-            if probe_outer:
-                missing = ~found
-                nulls[cid] = (missing if gathered is None
-                              else (gathered | missing))
-            elif gathered is not None:
-                nulls[cid] = gathered
-        return Block(cols, out_valid, nulls)
+            with stage_scope("lookup_join"):
+                order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys,
+                                                   dense)
+                counts = jnp.where(pmatch, hi - lo, 0)
+                m0 = bkeys[0].shape[0]
+                bidx = order[jnp.clip(lo, 0, m0 - 1)]
+        with stage_scope("join_out"):
+            self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64) + \
+                jnp.maximum(counts - 1, 0).sum().astype(jnp.int64)
+            found = counts > 0
+            probe_outer = node.join_type == "left"
+            out_valid = pblk.valid if probe_outer else found
+            if not probe_outer and node.residual is None:
+                self._record(id(node), "join_out", out_valid.sum(),
+                             out_valid.shape[0])
+            # selective FK join: compact the probe side BEFORE gathering
+            # build columns, so the gathers and everything downstream run at
+            # the join-estimate size instead of the probe capacity
+            k = self.caps.join_out.get(id(node))
+            if (not probe_outer and node.residual is None and k is not None
+                    and k < out_valid.shape[0]):
+                marker = "__bidx__"
+                tmp = Block({**pblk.columns, marker: bidx}, out_valid,
+                            pblk.nulls)
+                tmp = self._compact(tmp, k)
+                bidx = tmp.columns.pop(marker)
+                pblk = Block(tmp.columns, tmp.valid, tmp.nulls)
+                out_valid = tmp.valid
+            cols = dict(pblk.columns)
+            nulls = dict(pblk.nulls)
+            for cid, arr in bblk.columns.items():
+                cols[cid] = arr[bidx]
+                nm = bblk.nulls.get(cid)
+                gathered = nm[bidx] if nm is not None else None
+                if probe_outer:
+                    missing = ~found
+                    nulls[cid] = (missing if gathered is None
+                                  else (gathered | missing))
+                elif gathered is not None:
+                    nulls[cid] = gathered
+            return Block(cols, out_valid, nulls)
 
     def _exec_join(self, node: JoinNode, feeds) -> Block:
         lblk, rblk, lkeys, lmatch, rkeys, rmatch = \
@@ -1102,55 +1125,57 @@ class PlanCompiler:
         if getattr(node, "fuse_lookup", False) and not self.caps.dense_off:
             blk = self._exec_lookup_join(node, lblk, rblk, lkeys, lmatch,
                                          rkeys, rmatch)
+            with stage_scope("join_out"):
+                if node.residual is not None:
+                    blk = blk.with_filter(predicate_mask(node.residual,
+                                                         _src(blk), jnp))
+                    if node.join_type == "inner":
+                        # post-residual compaction: the residual-selective
+                        # fused join can still shrink to its feedback size
+                        self._record(id(node), "join_out", blk.valid.sum(),
+                                     blk.valid.shape[0])
+                        k = self.caps.join_out.get(id(node))
+                        if k is not None and k < blk.valid.shape[0]:
+                            blk = self._compact(blk, k)
+            return blk
+        with stage_scope("join_out"):
+            out_cap = self.caps.join_out[id(node)]
+
+            if node.join_type == "inner":
+                # the planner picks the smaller side as build (sorted /
+                # directory side); pair emission is symmetric for inner joins
+                if getattr(node, "build_side", "right") == "left":
+                    bkeys, bmatch, bblk = lkeys, lmatch, lblk
+                    pkeys, pmatch, pblk = rkeys, rmatch, rblk
+                    extents = getattr(node, "left_key_extents", ())
+                else:
+                    bkeys, bmatch, bblk = rkeys, rmatch, rblk
+                    pkeys, pmatch, pblk = lkeys, lmatch, lblk
+                    extents = getattr(node, "right_key_extents", ())
+                dense = self._dense_for(extents, bkeys)
+                bidx, pidx, out_valid, _miss, overflow, dense_oob = \
+                    expand_join_pairs(bkeys, bmatch, pkeys, pmatch, pmatch,
+                                      out_cap, probe_outer=False, dense=dense)
+                self._overflow = self._overflow + overflow.astype(jnp.int64)
+                self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
+                self._record(id(node), "join_out", out_valid.sum(), out_cap)
+                cols, nulls = {}, {}
+                for cid, arr in pblk.columns.items():
+                    cols[cid] = arr[pidx]
+                for cid, nmask in pblk.nulls.items():
+                    nulls[cid] = nmask[pidx]
+                for cid, arr in bblk.columns.items():
+                    cols[cid] = arr[bidx]
+                for cid, nmask in bblk.nulls.items():
+                    nulls[cid] = nmask[bidx]
+                blk = Block(cols, out_valid, nulls)
+            else:
+                blk = self._exec_outer_expand(node, lblk, rblk, lkeys, lmatch,
+                                              rkeys, rmatch, out_cap)
             if node.residual is not None:
                 blk = blk.with_filter(predicate_mask(node.residual,
                                                      _src(blk), jnp))
-                if node.join_type == "inner":
-                    # post-residual compaction: the residual-selective
-                    # fused join can still shrink to its feedback size
-                    self._record(id(node), "join_out", blk.valid.sum(),
-                                 blk.valid.shape[0])
-                    k = self.caps.join_out.get(id(node))
-                    if k is not None and k < blk.valid.shape[0]:
-                        blk = self._compact(blk, k)
             return blk
-        out_cap = self.caps.join_out[id(node)]
-
-        if node.join_type == "inner":
-            # the planner picks the smaller side as build (sorted /
-            # directory side); pair emission is symmetric for inner joins
-            if getattr(node, "build_side", "right") == "left":
-                bkeys, bmatch, bblk = lkeys, lmatch, lblk
-                pkeys, pmatch, pblk = rkeys, rmatch, rblk
-                extents = getattr(node, "left_key_extents", ())
-            else:
-                bkeys, bmatch, bblk = rkeys, rmatch, rblk
-                pkeys, pmatch, pblk = lkeys, lmatch, lblk
-                extents = getattr(node, "right_key_extents", ())
-            dense = self._dense_for(extents, bkeys)
-            bidx, pidx, out_valid, _miss, overflow, dense_oob = \
-                expand_join_pairs(bkeys, bmatch, pkeys, pmatch, pmatch,
-                                  out_cap, probe_outer=False, dense=dense)
-            self._overflow = self._overflow + overflow.astype(jnp.int64)
-            self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
-            self._record(id(node), "join_out", out_valid.sum(), out_cap)
-            cols, nulls = {}, {}
-            for cid, arr in pblk.columns.items():
-                cols[cid] = arr[pidx]
-            for cid, nmask in pblk.nulls.items():
-                nulls[cid] = nmask[pidx]
-            for cid, arr in bblk.columns.items():
-                cols[cid] = arr[bidx]
-            for cid, nmask in bblk.nulls.items():
-                nulls[cid] = nmask[bidx]
-            blk = Block(cols, out_valid, nulls)
-        else:
-            blk = self._exec_outer_expand(node, lblk, rblk, lkeys, lmatch,
-                                          rkeys, rmatch, out_cap)
-        if node.residual is not None:
-            blk = blk.with_filter(predicate_mask(node.residual,
-                                                 _src(blk), jnp))
-        return blk
 
     def _exec_semi_join(self, node: JoinNode, lblk: Block, rblk: Block,
                         lkeys, lmatch, rkeys, rmatch) -> Block:
@@ -1166,53 +1191,54 @@ class PlanCompiler:
         over a sharded build) the per-device flags psum across the mesh.
         Reference semantics: semi/anti join rewrites in
         planner/recursive_planning.c:223."""
-        from ..ops.join import _bounds
+        with stage_scope("join_out"):
+            from ..ops.join import _bounds
 
-        dense = self._dense_for(getattr(node, "right_key_extents", ()),
-                                rkeys)
-        n = lkeys[0].shape[0] if lkeys else lblk.valid.shape[0]
-        if node.residual is None:
-            order, lo, hi, dense_oob = _bounds(rkeys, rmatch, lkeys, dense)
-            self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
-            matched = lmatch & (hi > lo)
-        else:
-            from ..planner.expr import expr_columns
+            dense = self._dense_for(getattr(node, "right_key_extents", ()),
+                                    rkeys)
+            n = lkeys[0].shape[0] if lkeys else lblk.valid.shape[0]
+            if node.residual is None:
+                order, lo, hi, dense_oob = _bounds(rkeys, rmatch, lkeys, dense)
+                self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
+                matched = lmatch & (hi > lo)
+            else:
+                from ..planner.expr import expr_columns
 
-            cap = self.caps.join_out[id(node)]
-            bidx, pidx, out_valid, _miss, overflow, dense_oob = \
-                expand_join_pairs(rkeys, rmatch, lkeys, lmatch, lmatch,
-                                  cap, probe_outer=False, dense=dense)
-            self._overflow = self._overflow + overflow.astype(jnp.int64)
-            self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
-            self._record(id(node), "join_out", out_valid.sum(), cap)
-            # gather ONLY the residual's columns at pair capacity — the
-            # output block is the probe block, so everything else would
-            # be wasted HBM traffic on the widest intermediate
-            need = expr_columns(node.residual)
-            cols, nulls = {}, {}
-            for cid in need:
-                if cid in lblk.columns:
-                    cols[cid] = lblk.columns[cid][pidx]
-                    nm = lblk.nulls.get(cid)
-                    if nm is not None:
-                        nulls[cid] = nm[pidx]
-                elif cid in rblk.columns:
-                    cols[cid] = rblk.columns[cid][bidx]
-                    nm = rblk.nulls.get(cid)
-                    if nm is not None:
-                        nulls[cid] = nm[bidx]
-            pair = Block(cols, out_valid, nulls)
-            ok = out_valid & predicate_mask(node.residual, _src(pair), jnp)
-            matched = (jnp.zeros(n, jnp.int32)
-                       .at[pidx].max(ok.astype(jnp.int32))) > 0
-        if getattr(node, "flag_combine", False):
-            matched = jax.lax.psum(matched.astype(jnp.int32),
-                                   SHARD_AXIS) > 0
-        if node.join_type == "anti":
-            valid = lblk.valid & ~matched
-        else:
-            valid = lblk.valid & matched
-        return Block(dict(lblk.columns), valid, dict(lblk.nulls))
+                cap = self.caps.join_out[id(node)]
+                bidx, pidx, out_valid, _miss, overflow, dense_oob = \
+                    expand_join_pairs(rkeys, rmatch, lkeys, lmatch, lmatch,
+                                      cap, probe_outer=False, dense=dense)
+                self._overflow = self._overflow + overflow.astype(jnp.int64)
+                self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
+                self._record(id(node), "join_out", out_valid.sum(), cap)
+                # gather ONLY the residual's columns at pair capacity — the
+                # output block is the probe block, so everything else would
+                # be wasted HBM traffic on the widest intermediate
+                need = expr_columns(node.residual)
+                cols, nulls = {}, {}
+                for cid in need:
+                    if cid in lblk.columns:
+                        cols[cid] = lblk.columns[cid][pidx]
+                        nm = lblk.nulls.get(cid)
+                        if nm is not None:
+                            nulls[cid] = nm[pidx]
+                    elif cid in rblk.columns:
+                        cols[cid] = rblk.columns[cid][bidx]
+                        nm = rblk.nulls.get(cid)
+                        if nm is not None:
+                            nulls[cid] = nm[bidx]
+                pair = Block(cols, out_valid, nulls)
+                ok = out_valid & predicate_mask(node.residual, _src(pair), jnp)
+                matched = (jnp.zeros(n, jnp.int32)
+                           .at[pidx].max(ok.astype(jnp.int32))) > 0
+            if getattr(node, "flag_combine", False):
+                matched = jax.lax.psum(matched.astype(jnp.int32),
+                                       SHARD_AXIS) > 0
+            if node.join_type == "anti":
+                valid = lblk.valid & ~matched
+            else:
+                valid = lblk.valid & matched
+            return Block(dict(lblk.columns), valid, dict(lblk.nulls))
 
     def _exec_outer_expand(self, node: JoinNode, lblk: Block, rblk: Block,
                            lkeys, lmatch, rkeys, rmatch,
@@ -1476,10 +1502,11 @@ class PlanCompiler:
             pblk, pkeys, pmatch = rblk, rkeys, rmatch
             bkeys, bmatch = lkeys, lmatch
             extents = getattr(j, "left_key_extents", ())
-        dense = self._dense_for(extents, bkeys)
-        _order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys, dense)
-        self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
-        counts = jnp.where(pmatch, (hi - lo).astype(jnp.int64), 0)
+        with stage_scope("lookup_join"):
+            dense = self._dense_for(extents, bkeys)
+            _order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys, dense)
+            self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
+            counts = jnp.where(pmatch, (hi - lo).astype(jnp.int64), 0)
         return self._agg_from_match_counts(node, pblk, counts)
 
     # psum'd count directories stay worthwhile while the collective
@@ -1516,15 +1543,16 @@ class PlanCompiler:
 
         lblk = self._exec(j.left, feeds)
         rblk = self._exec(j.right, feeds)
-        key_int32 = getattr(j, "key_int32", ())
-        lkeys, lmatch = self._eval_keys(lblk, j.left_keys, key_int32)
-        rkeys, rmatch = self._eval_keys(rblk, j.right_keys, key_int32)
-        if j.left_match_filter is not None:
-            lmatch = lmatch & predicate_mask(j.left_match_filter,
-                                             _src(lblk), jnp)
-        if j.right_match_filter is not None:
-            rmatch = rmatch & predicate_mask(j.right_match_filter,
-                                             _src(rblk), jnp)
+        with stage_scope("join_out"):
+            key_int32 = getattr(j, "key_int32", ())
+            lkeys, lmatch = self._eval_keys(lblk, j.left_keys, key_int32)
+            rkeys, rmatch = self._eval_keys(rblk, j.right_keys, key_int32)
+            if j.left_match_filter is not None:
+                lmatch = lmatch & predicate_mask(j.left_match_filter,
+                                                 _src(lblk), jnp)
+            if j.right_match_filter is not None:
+                rmatch = rmatch & predicate_mask(j.right_match_filter,
+                                                 _src(rblk), jnp)
         if agg_side == "left":
             pblk, pkeys, pmatch = lblk, lkeys, lmatch
             bkeys, bmatch = rkeys, rmatch
@@ -1532,24 +1560,25 @@ class PlanCompiler:
             pblk, pkeys, pmatch = rblk, rkeys, rmatch
             bkeys, bmatch = lkeys, lmatch
 
-        # build-side rows outside the planned extent would silently
-        # miss the directory — count them into dense_oob so stale
-        # statistics recompile on the repartition path.  Probe-side
-        # out-of-extent keys simply match nothing (exact, no retry).
-        raw_b = bkeys[0].astype(jnp.int64) - jnp.int64(base)
-        b_in = (raw_b >= 0) & (raw_b < extent)
-        self._dense_oob = self._dense_oob + \
-            (bmatch & ~b_in).sum().astype(jnp.int64)
-        idx = jnp.where(bmatch & b_in, raw_b,
-                        jnp.int64(extent)).astype(jnp.int32)
-        dirc = jnp.zeros(extent + 1, jnp.int32).at[idx].add(
-            jnp.int32(1), mode="drop")[:extent]
-        dirc = jax.lax.psum(dirc, SHARD_AXIS)
-        raw_p = pkeys[0].astype(jnp.int64) - jnp.int64(base)
-        p_in = (raw_p >= 0) & (raw_p < extent)
-        pidx = jnp.clip(raw_p, 0, extent - 1).astype(jnp.int32)
-        counts = jnp.where(pmatch & p_in, dirc[pidx],
-                           jnp.int32(0)).astype(jnp.int64)
+        with stage_scope("lookup_join"):
+            # build-side rows outside the planned extent would silently
+            # miss the directory — count them into dense_oob so stale
+            # statistics recompile on the repartition path.  Probe-side
+            # out-of-extent keys simply match nothing (exact, no retry).
+            raw_b = bkeys[0].astype(jnp.int64) - jnp.int64(base)
+            b_in = (raw_b >= 0) & (raw_b < extent)
+            self._dense_oob = self._dense_oob + \
+                (bmatch & ~b_in).sum().astype(jnp.int64)
+            idx = jnp.where(bmatch & b_in, raw_b,
+                            jnp.int64(extent)).astype(jnp.int32)
+            dirc = jnp.zeros(extent + 1, jnp.int32).at[idx].add(
+                jnp.int32(1), mode="drop")[:extent]
+            dirc = jax.lax.psum(dirc, SHARD_AXIS)
+            raw_p = pkeys[0].astype(jnp.int64) - jnp.int64(base)
+            p_in = (raw_p >= 0) & (raw_p < extent)
+            pidx = jnp.clip(raw_p, 0, extent - 1).astype(jnp.int32)
+            counts = jnp.where(pmatch & p_in, dirc[pidx],
+                               jnp.int32(0)).astype(jnp.int64)
         return self._agg_from_match_counts(node, pblk, counts,
                                            counts_global=True)
 
@@ -1560,33 +1589,35 @@ class PlanCompiler:
         device's build rows (the psum-directory path) — the cross-
         device combine over PROBE rows is identical either way, since
         each probe row lives on exactly one device."""
-        values = self._agg_values(node, pblk)
-        cols, nulls = {}, {}
-        for (a, cid), (v, kind, vv) in zip(node.aggs, values):
-            contrib = pblk.valid if vv is None else (pblk.valid & vv)
-            w = jnp.where(contrib, counts, 0)
-            if kind == "count":
-                total = jax.lax.psum(w.sum(), SHARD_AXIS)
-                cols[cid] = total[None].astype(jnp.int64)
-                continue
-            if kind == "sum":
-                local = (jnp.where(contrib, v, jnp.zeros((), v.dtype))
-                         * w.astype(v.dtype)).sum()
-                total = jax.lax.psum(local, SHARD_AXIS)
-            elif kind == "min":
-                local = jnp.where(contrib & (w > 0), v, _big(v.dtype)).min()
-                total = jax.lax.pmin(local, SHARD_AXIS)
-            elif kind == "max":
-                local = jnp.where(contrib & (w > 0), v,
-                                  _small(v.dtype)).max()
-                total = jax.lax.pmax(local, SHARD_AXIS)
-            else:
-                raise ExecutionError(f"bad agg kind {kind}")
-            cols[cid] = total[None].astype(v.dtype)
-            any_pairs = jax.lax.psum(w.sum(), SHARD_AXIS) > 0
-            nulls[cid] = (~any_pairs)[None]
-        my_dev = jax.lax.axis_index(SHARD_AXIS)
-        return Block(cols, jnp.asarray([my_dev == 0]), nulls)
+        with stage_scope("agg_global"):
+            values = self._agg_values(node, pblk)
+            cols, nulls = {}, {}
+            for (a, cid), (v, kind, vv) in zip(node.aggs, values):
+                contrib = pblk.valid if vv is None else (pblk.valid & vv)
+                w = jnp.where(contrib, counts, 0)
+                if kind == "count":
+                    total = jax.lax.psum(w.sum(), SHARD_AXIS)
+                    cols[cid] = total[None].astype(jnp.int64)
+                    continue
+                if kind == "sum":
+                    local = (jnp.where(contrib, v, jnp.zeros((), v.dtype))
+                             * w.astype(v.dtype)).sum()
+                    total = jax.lax.psum(local, SHARD_AXIS)
+                elif kind == "min":
+                    local = jnp.where(contrib & (w > 0), v,
+                                      _big(v.dtype)).min()
+                    total = jax.lax.pmin(local, SHARD_AXIS)
+                elif kind == "max":
+                    local = jnp.where(contrib & (w > 0), v,
+                                      _small(v.dtype)).max()
+                    total = jax.lax.pmax(local, SHARD_AXIS)
+                else:
+                    raise ExecutionError(f"bad agg kind {kind}")
+                cols[cid] = total[None].astype(v.dtype)
+                any_pairs = jax.lax.psum(w.sum(), SHARD_AXIS) > 0
+                nulls[cid] = (~any_pairs)[None]
+            my_dev = jax.lax.axis_index(SHARD_AXIS)
+            return Block(cols, jnp.asarray([my_dev == 0]), nulls)
 
     def _exec_aggregate(self, node: AggregateNode, feeds) -> Block:
         pushed = self._try_join_agg_pushdown(node, feeds)
@@ -1600,77 +1631,86 @@ class PlanCompiler:
                                  blk.valid.shape))
         if node.dense_keys is not None and not self.caps.dense_off and \
                 node.combine in ("local", "repartition"):
-            return self._exec_dense_aggregate(node, blk)
+            with stage_scope("agg_grid"):
+                return self._exec_dense_aggregate(node, blk)
         if self.agg_bucket_shape(node, self.group_kernel,
                                  self.caps.dense_off) and \
                 id(node) in self.caps.agg_bucket:
-            bucketed = self._exec_bucketed_aggregate(node, blk)
+            with stage_scope("agg_bucket"):
+                bucketed = self._exec_bucketed_aggregate(node, blk)
             if bucketed is not None:
                 return bucketed
             # None is a defensive invariant check (see the helper) —
             # with today's _agg_inputs/bucket_keys invariants it cannot
             # fire; falling through lands on the sort path regardless
-        key_arrays, key_meta, values = self._agg_inputs(node, blk)
+        with stage_scope("agg_global" if node.combine == "global"
+                         else "agg_sort"):
+            key_arrays, key_meta, values = self._agg_inputs(node, blk)
 
         if node.combine == "global":
-            # no GROUP BY: reduce to one row per device, psum/pmin/pmax
-            cols, nulls = {}, {}
-            for (a, cid), (v, kind, vv) in zip(node.aggs, values):
-                contrib_valid = blk.valid if vv is None else (blk.valid & vv)
-                if kind == "count":
-                    local = contrib_valid.astype(jnp.int64).sum()
-                    total = jax.lax.psum(local, SHARD_AXIS)
-                elif kind == "sum":
-                    local = jnp.where(contrib_valid, v,
-                                      jnp.zeros((), v.dtype)).sum()
-                    total = jax.lax.psum(local, SHARD_AXIS)
-                elif kind == "min":
-                    big = _big(v.dtype)
-                    local = jnp.where(contrib_valid, v, big).min()
-                    total = jax.lax.pmin(local, SHARD_AXIS)
-                elif kind == "max":
-                    small = _small(v.dtype)
-                    local = jnp.where(contrib_valid, v, small).max()
-                    total = jax.lax.pmax(local, SHARD_AXIS)
-                else:
-                    raise ExecutionError(f"bad agg kind {kind}")
-                cols[cid] = total[None].astype(v.dtype) \
-                    if kind != "count" else total[None].astype(jnp.int64)
-                # COUNT of zero rows is 0, not NULL; others are NULL on empty
-                if kind != "count":
-                    any_rows = jax.lax.psum(
-                        contrib_valid.sum(), SHARD_AXIS) > 0
-                    nulls[cid] = (~any_rows)[None]
-            # emit exactly one valid row on device 0
-            my_dev = jax.lax.axis_index(SHARD_AXIS)
-            valid = jnp.asarray([my_dev == 0])
-            return Block(cols, valid, nulls)
+            with stage_scope("agg_global"):
+                # no GROUP BY: reduce to one row per device, psum/pmin/pmax
+                cols, nulls = {}, {}
+                for (a, cid), (v, kind, vv) in zip(node.aggs, values):
+                    contrib_valid = (blk.valid if vv is None
+                                     else blk.valid & vv)
+                    if kind == "count":
+                        local = contrib_valid.astype(jnp.int64).sum()
+                        total = jax.lax.psum(local, SHARD_AXIS)
+                    elif kind == "sum":
+                        local = jnp.where(contrib_valid, v,
+                                          jnp.zeros((), v.dtype)).sum()
+                        total = jax.lax.psum(local, SHARD_AXIS)
+                    elif kind == "min":
+                        big = _big(v.dtype)
+                        local = jnp.where(contrib_valid, v, big).min()
+                        total = jax.lax.pmin(local, SHARD_AXIS)
+                    elif kind == "max":
+                        small = _small(v.dtype)
+                        local = jnp.where(contrib_valid, v, small).max()
+                        total = jax.lax.pmax(local, SHARD_AXIS)
+                    else:
+                        raise ExecutionError(f"bad agg kind {kind}")
+                    cols[cid] = total[None].astype(v.dtype) \
+                        if kind != "count" else total[None].astype(jnp.int64)
+                    # COUNT of zero rows is 0, not NULL; others are NULL
+                    # on empty
+                    if kind != "count":
+                        any_rows = jax.lax.psum(
+                            contrib_valid.sum(), SHARD_AXIS) > 0
+                        nulls[cid] = (~any_rows)[None]
+                # emit exactly one valid row on device 0
+                my_dev = jax.lax.axis_index(SHARD_AXIS)
+                valid = jnp.asarray([my_dev == 0])
+                return Block(cols, valid, nulls)
 
-        # companion contribution-counts per value aggregate: an all-NULL
-        # group must yield NULL (not the reduction identity) for
-        # sum/min/max/avg — count of contributors == 0 ⇒ NULL
-        companions = []
-        for (a, cid), (v, kind, vv) in zip(node.aggs, values):
-            if kind != "count":
-                companions.append((v, "count", vv))
-            else:
-                companions.append(None)
-        all_values = values + [c for c in companions if c is not None]
-        gk, res, gvalid, ngroups = self._segment_aggregate_maybe_packed(
-            node, key_arrays, key_meta, all_values, blk.valid)
-        gk, res, gvalid = self._slice_groups(node, gk, res, gvalid, ngroups)
-        main_res = res[:len(values)]
-        comp_res = res[len(values):]
-        partial = self._partial_block(node, key_meta, gk, main_res, gvalid)
-        ci = 0
-        for (a, cid), comp in zip(node.aggs, companions):
-            if comp is not None:
-                cnt = comp_res[ci]
-                ci += 1
-                partial = Block(
-                    {**partial.columns, f"__cnt_{cid}": cnt},
-                    partial.valid,
-                    {**partial.nulls, cid: cnt == 0})
+        with stage_scope("agg_sort"):
+            # companion contribution-counts per value aggregate: an all-NULL
+            # group must yield NULL (not the reduction identity) for
+            # sum/min/max/avg — count of contributors == 0 ⇒ NULL
+            companions = []
+            for (a, cid), (v, kind, vv) in zip(node.aggs, values):
+                if kind != "count":
+                    companions.append((v, "count", vv))
+                else:
+                    companions.append(None)
+            all_values = values + [c for c in companions if c is not None]
+            gk, res, gvalid, ngroups = self._segment_aggregate_maybe_packed(
+                node, key_arrays, key_meta, all_values, blk.valid)
+            gk, res, gvalid = self._slice_groups(node, gk, res, gvalid,
+                                                 ngroups)
+            main_res = res[:len(values)]
+            comp_res = res[len(values):]
+            partial = self._partial_block(node, key_meta, gk, main_res, gvalid)
+            ci = 0
+            for (a, cid), comp in zip(node.aggs, companions):
+                if comp is not None:
+                    cnt = comp_res[ci]
+                    ci += 1
+                    partial = Block(
+                        {**partial.columns, f"__cnt_{cid}": cnt},
+                        partial.valid,
+                        {**partial.nulls, cid: cnt == 0})
 
         if node.combine == "local":
             return partial
@@ -1682,56 +1722,59 @@ class PlanCompiler:
         # (routed by flag+zero value, consistently on every device).
         # repart_keys (DISTINCT rewrite) restricts ROUTING to a key
         # subset — co-routed rows still merge by the full key set
-        route_idx = (set(node.repart_keys)
-                     if getattr(node, "repart_keys", None) is not None
-                     else None)
-        shuffle_keys = []
-        for ki, (cid, has_null) in enumerate(key_meta):
-            if route_idx is not None and ki not in route_idx:
-                continue
-            v = partial.columns[cid]
-            if jnp.issubdtype(v.dtype, jnp.floating):
-                v = jax.lax.bitcast_convert_type(
-                    v, jnp.int32 if v.dtype == jnp.float32 else jnp.int64)
-            shuffle_keys.append(v.astype(jnp.int64))
-            if has_null:
-                nm = partial.null_mask(cid)
-                # zero the value under NULL so routing is deterministic
-                shuffle_keys[-1] = jnp.where(nm, 0, shuffle_keys[-1])
-                shuffle_keys.append(nm.astype(jnp.int64))
+        with stage_scope("repartition"):
+            route_idx = (set(node.repart_keys)
+                         if getattr(node, "repart_keys", None) is not None
+                         else None)
+            shuffle_keys = []
+            for ki, (cid, has_null) in enumerate(key_meta):
+                if route_idx is not None and ki not in route_idx:
+                    continue
+                v = partial.columns[cid]
+                if jnp.issubdtype(v.dtype, jnp.floating):
+                    v = jax.lax.bitcast_convert_type(
+                        v, jnp.int32 if v.dtype == jnp.float32 else jnp.int64)
+                shuffle_keys.append(v.astype(jnp.int64))
+                if has_null:
+                    nm = partial.null_mask(cid)
+                    # zero the value under NULL so routing is deterministic
+                    shuffle_keys[-1] = jnp.where(nm, 0, shuffle_keys[-1])
+                    shuffle_keys.append(nm.astype(jnp.int64))
         cap = self.caps.repartition[id(node)]
         shuffled = self._repartition(partial, None, self.n_dev,
                                      tuple(range(self.n_dev)), cap,
                                      key_arrays=shuffle_keys,
                                      valid=partial.valid,
                                      record_nid=id(node))
-        key_arrays2 = []
-        for cid, has_null in key_meta:
-            key_arrays2.append(shuffled.columns[cid])
-            if has_null:
-                key_arrays2.append(
-                    shuffled.null_mask(cid).astype(jnp.int32))
-        values2 = []
-        comp_cids = []
-        for a, cid in node.aggs:
-            v = shuffled.columns[cid]
-            kind = {"count": "sum", "count_star": "sum", "sum": "sum",
-                    "avg": "sum", "min": "min", "max": "max"}[a.kind]
-            values2.append((v, kind, None))
-            if f"__cnt_{cid}" in shuffled.columns:
-                comp_cids.append(cid)
-        for cid in comp_cids:
-            values2.append((shuffled.columns[f"__cnt_{cid}"], "sum", None))
-        gk2, res2, gvalid2, ngroups2 = self._segment_aggregate_maybe_packed(
-            node, key_arrays2, key_meta, values2, shuffled.valid)
-        gk2, res2, gvalid2 = self._slice_groups(node, gk2, res2, gvalid2,
-                                                ngroups2)
-        final = self._partial_block(node, key_meta, gk2,
-                                    res2[:len(node.aggs)], gvalid2)
-        for cid, cnt in zip(comp_cids, res2[len(node.aggs):]):
-            final = Block(final.columns, final.valid,
-                          {**final.nulls, cid: cnt == 0})
-        return final
+        with stage_scope("agg_sort"):
+            key_arrays2 = []
+            for cid, has_null in key_meta:
+                key_arrays2.append(shuffled.columns[cid])
+                if has_null:
+                    key_arrays2.append(
+                        shuffled.null_mask(cid).astype(jnp.int32))
+            values2 = []
+            comp_cids = []
+            for a, cid in node.aggs:
+                v = shuffled.columns[cid]
+                kind = {"count": "sum", "count_star": "sum", "sum": "sum",
+                        "avg": "sum", "min": "min", "max": "max"}[a.kind]
+                values2.append((v, kind, None))
+                if f"__cnt_{cid}" in shuffled.columns:
+                    comp_cids.append(cid)
+            for cid in comp_cids:
+                values2.append((shuffled.columns[f"__cnt_{cid}"], "sum", None))
+            gk2, res2, gvalid2, ngroups2 = \
+                self._segment_aggregate_maybe_packed(
+                    node, key_arrays2, key_meta, values2, shuffled.valid)
+            gk2, res2, gvalid2 = self._slice_groups(node, gk2, res2, gvalid2,
+                                                    ngroups2)
+            final = self._partial_block(node, key_meta, gk2,
+                                        res2[:len(node.aggs)], gvalid2)
+            for cid, cnt in zip(comp_cids, res2[len(node.aggs):]):
+                final = Block(final.columns, final.valid,
+                              {**final.nulls, cid: cnt == 0})
+            return final
 
     def _exec_dense_aggregate(self, node: AggregateNode, blk: Block) -> Block:
         """Dense-grid aggregation: group keys with known small value ranges
@@ -2000,7 +2043,8 @@ class PlanCompiler:
         # (underestimates overflow and regrow like every static buffer)
         k = self.caps.agg_out.get(id(node))
         if k is not None and k < total:
-            out = self._compact(out, k)
+            with stage_scope("agg_out"):
+                out = self._compact(out, k)
         return out
 
     # one-hot MXU segment-sum eligibility bound: bench_kernels.py on
@@ -2036,14 +2080,15 @@ class PlanCompiler:
     def _slice_groups(self, node: AggregateNode, gk, res, gvalid, ngroups):
         """Slice front-packed group slots down to the planner's estimated
         capacity; groups beyond it count as overflow (→ retry, doubled)."""
-        self._record(id(node), "agg_out", ngroups, gvalid.shape[0])
-        agg_cap = self.caps.agg_out.get(id(node))
-        if agg_cap is None or agg_cap >= gvalid.shape[0]:
-            return gk, res, gvalid
-        self._overflow = self._overflow + jnp.maximum(
-            ngroups.astype(jnp.int64) - agg_cap, 0)
-        return ([k[:agg_cap] for k in gk], [r[:agg_cap] for r in res],
-                gvalid[:agg_cap])
+        with stage_scope("agg_out"):
+            self._record(id(node), "agg_out", ngroups, gvalid.shape[0])
+            agg_cap = self.caps.agg_out.get(id(node))
+            if agg_cap is None or agg_cap >= gvalid.shape[0]:
+                return gk, res, gvalid
+            self._overflow = self._overflow + jnp.maximum(
+                ngroups.astype(jnp.int64) - agg_cap, 0)
+            return ([k[:agg_cap] for k in gk], [r[:agg_cap] for r in res],
+                    gvalid[:agg_cap])
 
     def _partial_block(self, node: AggregateNode, key_meta, gk, res,
                        gvalid) -> Block:

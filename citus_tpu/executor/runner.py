@@ -182,15 +182,17 @@ class Executor:
 
     # ------------------------------------------------------------------
     def execute_plan(self, plan: QueryPlan, raw: bool = False) -> ResultSet:
+        from ..stats.tracing import trace_span
         from .fastpath import try_execute_fast_path
 
         # cross-session read-committed visibility: another session over
         # this data_dir may have committed since our manifest was cached
         # (one stat() per scanned table; writers refresh under the DML
         # lock, this is the readers' counterpart)
-        for node in walk_plan(plan.root):
-            if isinstance(node, ScanNode):
-                self.store.refresh_if_stale(node.rel.table)
+        with trace_span("route"):
+            for node in walk_plan(plan.root):
+                if isinstance(node, ScanNode):
+                    self.store.refresh_if_stale(node.rel.table)
 
         # the degradation ladder peeks at the in-flight plan to decide
         # which rungs can help this statement's shape
@@ -214,8 +216,6 @@ class Executor:
         packed, out_meta, caps, retries, feeds = self._run_resident(
             plan, compute_dtype)
         self.count_groupby_bucketed(plan, caps)
-        from ..stats.tracing import trace_span
-
         with trace_span("combine"):
             cols, nulls, valid = unpack_outputs(packed, out_meta)
             result = self._host_combine(plan, cols, nulls, valid, raw)
@@ -242,26 +242,27 @@ class Executor:
                                 accountant=self.accountant,
                                 no_cache_nodes=no_cache_nodes,
                                 stats=self.scan_stats)
-        # device_topk + its ORDER BY keys are traced into the program
-        topk_sig = (plan.device_topk, tuple(
-            (repr(e), d, nf) for e, d, nf in plan.host_order_by)
-            if plan.device_topk is not None else ())
-        orp = plan.output_repart
-        orp_sig = (None if orp is None
-                   else (orp[0], orp[1], orp[2], repr(orp[3])))
-        # group_by_kernel changes which CAPACITY TABLES exist
-        # (agg_bucket vs sort-path buffers), so converged sizes memoized
-        # under one mode must not be replayed under another — it joins
-        # the fingerprint, unlike join_probe_kernel which only swaps the
-        # inner formulation at unchanged shapes
-        fingerprint = (node_fingerprint(plan.root), plan.n_devices,
-                       str(compute_dtype), feeds_signature(plan, feeds),
-                       topk_sig, orp_sig,
-                       self.settings.get("group_by_kernel"))
-        with self._caps_lock:
-            memo = self._caps_memo.get(fingerprint)
-        caps = (self._caps_from_order(plan, memo) if memo is not None
-                else self._initial_capacities(plan, feeds))
+        with trace_span("caps"):
+            # device_topk + its ORDER BY keys are traced into the program
+            topk_sig = (plan.device_topk, tuple(
+                (repr(e), d, nf) for e, d, nf in plan.host_order_by)
+                if plan.device_topk is not None else ())
+            orp = plan.output_repart
+            orp_sig = (None if orp is None
+                       else (orp[0], orp[1], orp[2], repr(orp[3])))
+            # group_by_kernel changes which CAPACITY TABLES exist
+            # (agg_bucket vs sort-path buffers), so converged sizes memoized
+            # under one mode must not be replayed under another — it joins
+            # the fingerprint, unlike join_probe_kernel which only swaps the
+            # inner formulation at unchanged shapes
+            fingerprint = (node_fingerprint(plan.root), plan.n_devices,
+                           str(compute_dtype), feeds_signature(plan, feeds),
+                           topk_sig, orp_sig,
+                           self.settings.get("group_by_kernel"))
+            with self._caps_lock:
+                memo = self._caps_memo.get(fingerprint)
+            caps = (self._caps_from_order(plan, memo) if memo is not None
+                    else self._initial_capacities(plan, feeds))
         packed, out_meta, caps, retries = self.run_with_retry(
             plan, feeds, caps, fingerprint, compute_dtype)
         return packed, out_meta, caps, retries, feeds
@@ -312,6 +313,7 @@ class Executor:
         selectivities over correlated columns are statically
         unestimable).  An over-tightened buffer (data changed) simply
         overflows and regrows through the normal retry path."""
+        from ..stats.tracing import trace_span
         from ..utils.cancellation import check_cancel
 
         limit = self.settings.get("max_plan_buffer_bytes")
@@ -319,9 +321,10 @@ class Executor:
         tightened = False
         while True:
             check_cancel()  # overflow-retry iterations are cancel seams
-            if limit:
+            with trace_span("caps"):
+                # one estimate serves the guard here and the lease below
                 est = _plan_buffer_bytes(plan, caps)
-                if est > limit:
+                if limit and est > limit:
                     if self._plan_degradable(plan):
                         # eligible over-limit plans route into the OOM
                         # degradation ladder (stream / multi-pass)
@@ -338,15 +341,13 @@ class Executor:
                         f"{limit / 1e9:.1f} GB) — usually a cartesian "
                         "or extreme-fanout join; rewrite the query or "
                         "raise the limit")
-            probe_kernel = self.settings.get("join_probe_kernel")
-            # group_by_kernel already rides in `fingerprint` (it shapes
-            # the capacity tables); probe_kernel only swaps the inner
-            # formulation so it joins the key here
-            group_kernel = self.settings.get("group_by_kernel")
-            from ..stats.tracing import trace_span
-
-            key = fingerprint + (caps_signature(plan, caps), probe_kernel)
-            entry = self.plan_cache.get(key)
+                probe_kernel = self.settings.get("join_probe_kernel")
+                # group_by_kernel already rides in `fingerprint` (it shapes
+                # the capacity tables); probe_kernel only swaps the inner
+                # formulation so it joins the key here
+                group_kernel = self.settings.get("group_by_kernel")
+                key = fingerprint + (caps_signature(plan, caps), probe_kernel)
+                entry = self.plan_cache.get(key)
             if entry is None:
                 from ..utils.faultinjection import fault_point
 
@@ -383,8 +384,7 @@ class Executor:
             # Python cannot see them — the lease makes the estimate
             # visible to the measured ledger (and to an armed MemSim)
             # for exactly the execution window
-            est_per_dev = _plan_buffer_bytes(plan, caps) \
-                // max(1, plan.n_devices)
+            est_per_dev = est // max(1, plan.n_devices)
 
             def _dispatch():
                 # mesh seams: a device dying mid-collective kills the
@@ -435,42 +435,43 @@ class Executor:
                     raise
                 with self.accountant.lease("plan", est_per_dev):
                     packed, overflow = _dispatch()
-            ov = np.asarray(overflow).reshape(-1, 2 + len(stage_keys))
-            cap_overflow = int(ov[:, 0].sum())
-            dense_oob = int(ov[:, 1].sum())
-            if cap_overflow == 0 and dense_oob == 0:
-                first_tighten = False
-                if allow_tighten and not tightened and \
-                        self.settings.get("enable_capacity_feedback"):
-                    with self._caps_lock:
-                        if fingerprint not in self._tightened_fps:
-                            if len(self._tightened_fps) > 512:
-                                self._tightened_fps.clear()
-                            self._tightened_fps.add(fingerprint)
-                            first_tighten = True
-                if first_tighten:
-                    tight = self._tighten_caps(
-                        plan, caps, stage_keys,
-                        ov[:, 2:].max(axis=0) if len(stage_keys) else [])
-                    if tight is not None:
-                        caps = tight
-                        tightened = True
+            with trace_span("settle"):
+                ov = np.asarray(overflow).reshape(-1, 2 + len(stage_keys))
+                cap_overflow = int(ov[:, 0].sum())
+                dense_oob = int(ov[:, 1].sum())
+                if cap_overflow == 0 and dense_oob == 0:
+                    first_tighten = False
+                    if allow_tighten and not tightened and \
+                            self.settings.get("enable_capacity_feedback"):
+                        with self._caps_lock:
+                            if fingerprint not in self._tightened_fps:
+                                if len(self._tightened_fps) > 512:
+                                    self._tightened_fps.clear()
+                                self._tightened_fps.add(fingerprint)
+                                first_tighten = True
+                    if first_tighten:
+                        tight = self._tighten_caps(
+                            plan, caps, stage_keys,
+                            ov[:, 2:].max(axis=0) if len(stage_keys) else [])
+                        if tight is not None:
+                            caps = tight
+                            tightened = True
+                            self._memoize_caps(fingerprint, plan, caps)
+                            continue  # recompile tight + re-execute
+                    if retries or tightened:
                         self._memoize_caps(fingerprint, plan, caps)
-                        continue  # recompile tight + re-execute
-                if retries or tightened:
-                    self._memoize_caps(fingerprint, plan, caps)
-                if self.counters is not None and shuffle_bytes:
-                    # TRACED all_to_all volume of the converged
-                    # execution (PlanCompiler counts the exchange
-                    # stages that actually exist — the psum-directory
-                    # pushdown compiles shuffles away; stream paths
-                    # pass here per batch, so the counter scales with
-                    # what actually crossed the mesh)
-                    from ..stats import counters as sc
+                    if self.counters is not None and shuffle_bytes:
+                        # TRACED all_to_all volume of the converged
+                        # execution (PlanCompiler counts the exchange
+                        # stages that actually exist — the psum-directory
+                        # pushdown compiles shuffles away; stream paths
+                        # pass here per batch, so the counter scales with
+                        # what actually crossed the mesh)
+                        from ..stats import counters as sc
 
-                    self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
-                                            shuffle_bytes)
-                return packed, out_meta, caps, retries
+                        self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
+                                                shuffle_bytes)
+                    return packed, out_meta, caps, retries
             retries += 1
             from ..utils.faultinjection import fault_point
 

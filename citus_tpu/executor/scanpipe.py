@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..errors import DeviceMemoryExhausted
+from ..stats.tracing import stage_scope
 
 # below this many table rows 'auto' keeps the eager path: a producer
 # thread + per-column reads cost more than they hide on tiny feeds
@@ -206,7 +207,8 @@ def encode_column(buf: np.ndarray):
 
 @jax.jit
 def _for_expand(wire, base):
-    return wire.astype(base.dtype) + base
+    with stage_scope("decode"), stage_scope("for"):
+        return wire.astype(base.dtype) + base
 
 
 @jax.jit
@@ -214,20 +216,23 @@ def _dict_expand(codes, lut):
     # the decoded column keeps the codes' sharding; said outright,
     # because a gather from the replicated LUT by mesh-sharded indices
     # is one jax will not resolve by itself on an explicit-axis mesh
-    return lut.at[codes.astype(jnp.int32)].get(
-        out_sharding=jax.typeof(codes).sharding)
+    with stage_scope("decode"), stage_scope("dict"):
+        return lut.at[codes.astype(jnp.int32)].get(
+            out_sharding=jax.typeof(codes).sharding)
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
 def _bits_expand(packed, cap):
-    shifts = jnp.arange(7, -1, -1, dtype=jnp.uint8)
-    bits = (packed[..., None] >> shifts) & jnp.uint8(1)
-    return bits.reshape(packed.shape[:-1] + (cap,)).astype(bool)
+    with stage_scope("decode"), stage_scope("bits"):
+        shifts = jnp.arange(7, -1, -1, dtype=jnp.uint8)
+        bits = (packed[..., None] >> shifts) & jnp.uint8(1)
+        return bits.reshape(packed.shape[:-1] + (cap,)).astype(bool)
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
 def _valid_expand(rows, cap):
-    return jnp.arange(cap, dtype=jnp.int32)[None, :] < rows
+    with stage_scope("decode"), stage_scope("valid"):
+        return jnp.arange(cap, dtype=jnp.int32)[None, :] < rows
 
 
 # ---------------------------------------------------------------------------

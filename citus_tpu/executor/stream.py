@@ -482,27 +482,31 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
     host combine and returns (parts, rows_scanned, retries, batches,
     caps) — flattened per-batch column/null dicts the caller merges
     across its own passes before ONE host combine."""
+    from ..stats.tracing import trace_span
+
     settings = executor.settings
-    budget = settings.get("max_feed_bytes_per_device")
-    if budget <= 0:
-        return None
-    # the accountant may know a REAL ceiling below the configured one
-    # (armed MemSim, hbm_budget_bytes, backend bytes_limit): size the
-    # stream against it so the statement streams at the true budget
-    # up front instead of discovering it through an OOM round-trip
-    hw = executor.accountant.budget_bytes(settings)
-    if hw:
-        budget = min(budget, hw)
-    compute_dtype = np.dtype(settings.get("compute_dtype"))
-    n_dev = plan.n_devices
-    oom = executor.oom
-    picked = pick_stream_node(plan, executor.catalog, executor.store,
-                              n_dev, compute_dtype, budget,
-                              settings.get("stream_batch_rows"),
-                              shrink=oom.batch_shrink,
-                              force=oom.force_stream,
-                              prefetch_depth=settings.get(
-                                  "scan_prefetch_depth"))
+    with trace_span("route"):
+        budget = settings.get("max_feed_bytes_per_device")
+        if budget <= 0:
+            return None
+        # the accountant may know a REAL ceiling below the configured
+        # one (armed MemSim, hbm_budget_bytes, backend bytes_limit):
+        # size the stream against it so the statement streams at the
+        # true budget up front instead of discovering it through an OOM
+        # round-trip
+        hw = executor.accountant.budget_bytes(settings)
+        if hw:
+            budget = min(budget, hw)
+        compute_dtype = np.dtype(settings.get("compute_dtype"))
+        n_dev = plan.n_devices
+        oom = executor.oom
+        picked = pick_stream_node(plan, executor.catalog, executor.store,
+                                  n_dev, compute_dtype, budget,
+                                  settings.get("stream_batch_rows"),
+                                  shrink=oom.batch_shrink,
+                                  force=oom.force_stream,
+                                  prefetch_depth=settings.get(
+                                      "scan_prefetch_depth"))
     if picked is None:
         return None
     stream_node, batch_cap = picked
@@ -627,8 +631,6 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
             # compiled program, and per-batch actuals vary — tightening
             # on batch 1 would risk a recompile-overflow-regrow cycle
             # on a later, fuller batch
-            from ..stats.tracing import trace_span
-
             with trace_span("stream.batch", batch=n_consumed - 1):
                 packed, out_meta, caps, r = executor.run_with_retry(
                     plan, feeds, caps, fingerprint, compute_dtype,

@@ -36,6 +36,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..stats.tracing import stage_scope
+
 SUPPORTED_AGGS = ("sum", "count", "min", "max")
 
 
@@ -118,79 +120,82 @@ def segment_aggregate(keys: list[jnp.ndarray],
       n_groups:    scalar int32.
     """
     n = valid.shape[0]
-    if out_keys is not None:
-        # packed mode: the single int64 key already encodes invalid rows
-        # as the int64-max sentinel, so this is a TRUE single-operand
-        # argsort (adding the validity operand back would re-create the
-        # two-operand lexsort the packing exists to avoid)
-        order = jnp.argsort(keys[0], stable=True).astype(jnp.int32)
-    else:
-        order = _sort_order(keys, valid)
-    keys_s = [k[order] for k in keys]
-    valid_s = valid[order]
-
-    # boundary: first row of each (valid) group
-    def _shift_ne(a):
-        return jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                                a[1:] != a[:-1]])
-
-    diff = jnp.zeros(n, dtype=jnp.bool_)
-    for k in keys_s:
-        diff = diff | _shift_ne(k)
-    boundary = diff & valid_s
-    seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    n_groups = boundary.sum().astype(jnp.int32)
-    # invalid rows (sorted last) land in the last group's run with
-    # identity contributions; the clip only guards the all-invalid case
-    seg_id = jnp.clip(seg_id, 0, None)
-
-    # group g's run is [starts[g], ends[g]) in sorted space.  One
-    # boundary per group ⇒ the scatter indices are unique ⇒ scatter-set
-    # (no combining — fast on TPU, unlike scatter-add/min)
-    gpos = jnp.full(n + 1, n, jnp.int32).at[
-        jnp.where(boundary, seg_id, n + 1)].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")
-    starts = gpos[:n]
-    ends = gpos[1:]  # last real group runs to n (trailing invalid rows
-    #                  carry identity contributions, as before)
-
-    group_keys = []
-    first_c = jnp.minimum(starts, n - 1)
-    if out_keys is None:
-        group_keys = [k[first_c] for k in keys_s]
-    else:
-        first_idx = order[first_c]
-        group_keys = [k[first_idx] for k in out_keys]
-
-    results = []
-    for arr, kind, value_valid in values:
-        arr_s = arr[order]
-        contrib_valid = valid_s if value_valid is None else (
-            valid_s & value_valid[order])
-        if kind == "count":
-            res = _run_sum(contrib_valid.astype(jnp.int32), starts, ends,
-                           jnp.int32).astype(jnp.int64)
-        elif kind == "sum":
-            z = jnp.zeros((), dtype=arr_s.dtype)
-            x = jnp.where(contrib_valid, arr_s, z)
-            acc = (jnp.float64 if jnp.issubdtype(arr_s.dtype, jnp.floating)
-                   else jnp.int64)
-            res = _run_sum(x, starts, ends, acc).astype(arr_s.dtype)
-        elif kind in ("min", "max"):
-            ident = _identity_for(arr_s.dtype, kind)
-            x = jnp.where(contrib_valid, arr_s, ident)
-            op = jnp.minimum if kind == "min" else jnp.maximum
-            sv = _segmented_scan(x, boundary, op)
-            res = sv[jnp.clip(ends - 1, 0, n - 1)]
+    with stage_scope("sort"):
+        if out_keys is not None:
+            # packed mode: the single int64 key already encodes invalid rows
+            # as the int64-max sentinel, so this is a TRUE single-operand
+            # argsort (adding the validity operand back would re-create the
+            # two-operand lexsort the packing exists to avoid)
+            order = jnp.argsort(keys[0], stable=True).astype(jnp.int32)
         else:
-            raise ValueError(f"unsupported aggregate kind {kind!r}")
-        results.append(res)
+            order = _sort_order(keys, valid)
+        keys_s = [k[order] for k in keys]
+        valid_s = valid[order]
 
-    group_valid = jnp.arange(n) < n_groups
-    group_keys = [jnp.where(group_valid, k,
-                            jnp.zeros((), dtype=k.dtype)) for k in group_keys]
-    results = [jnp.where(group_valid, r, jnp.zeros((), dtype=r.dtype))
-               for r in results]
+    with stage_scope("reduce"):
+        # boundary: first row of each (valid) group
+        def _shift_ne(a):
+            return jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                    a[1:] != a[:-1]])
+
+        diff = jnp.zeros(n, dtype=jnp.bool_)
+        for k in keys_s:
+            diff = diff | _shift_ne(k)
+        boundary = diff & valid_s
+        seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+        n_groups = boundary.sum().astype(jnp.int32)
+        # invalid rows (sorted last) land in the last group's run with
+        # identity contributions; the clip only guards the all-invalid case
+        seg_id = jnp.clip(seg_id, 0, None)
+
+        # group g's run is [starts[g], ends[g]) in sorted space.  One
+        # boundary per group ⇒ the scatter indices are unique ⇒ scatter-set
+        # (no combining — fast on TPU, unlike scatter-add/min)
+        gpos = jnp.full(n + 1, n, jnp.int32).at[
+            jnp.where(boundary, seg_id, n + 1)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+        starts = gpos[:n]
+        ends = gpos[1:]  # last real group runs to n (trailing invalid rows
+        #                  carry identity contributions, as before)
+
+        group_keys = []
+        first_c = jnp.minimum(starts, n - 1)
+        if out_keys is None:
+            group_keys = [k[first_c] for k in keys_s]
+        else:
+            first_idx = order[first_c]
+            group_keys = [k[first_idx] for k in out_keys]
+
+        results = []
+        for arr, kind, value_valid in values:
+            arr_s = arr[order]
+            contrib_valid = valid_s if value_valid is None else (
+                valid_s & value_valid[order])
+            if kind == "count":
+                res = _run_sum(contrib_valid.astype(jnp.int32), starts, ends,
+                               jnp.int32).astype(jnp.int64)
+            elif kind == "sum":
+                z = jnp.zeros((), dtype=arr_s.dtype)
+                x = jnp.where(contrib_valid, arr_s, z)
+                acc = (jnp.float64 if jnp.issubdtype(arr_s.dtype, jnp.floating)
+                       else jnp.int64)
+                res = _run_sum(x, starts, ends, acc).astype(arr_s.dtype)
+            elif kind in ("min", "max"):
+                ident = _identity_for(arr_s.dtype, kind)
+                x = jnp.where(contrib_valid, arr_s, ident)
+                op = jnp.minimum if kind == "min" else jnp.maximum
+                sv = _segmented_scan(x, boundary, op)
+                res = sv[jnp.clip(ends - 1, 0, n - 1)]
+            else:
+                raise ValueError(f"unsupported aggregate kind {kind!r}")
+            results.append(res)
+
+        group_valid = jnp.arange(n) < n_groups
+        group_keys = [jnp.where(group_valid, k,
+                                jnp.zeros((), dtype=k.dtype))
+                      for k in group_keys]
+        results = [jnp.where(group_valid, r, jnp.zeros((), dtype=r.dtype))
+                   for r in results]
     return group_keys, results, group_valid, n_groups
 
 

@@ -39,6 +39,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..stats.tracing import stage_scope
+
 
 # dense-directory planning limits: the starts[] table costs O(extent)
 # build work and 4·extent bytes of HBM, so it must stay proportional to
@@ -324,17 +326,18 @@ def bucketed_unique_lookup(build_key: jnp.ndarray,
     n_buckets = max(1, -(-extent // tile))
     ext_pad = n_buckets * tile
 
-    # directory build + duplicate detection: identical accounting to
-    # dense_unique_lookup (padding slots [extent, ext_pad) stay empty)
-    idx = build_key.astype(jnp.int64) - jnp.int64(base)
-    inb = build_matchable & (idx >= 0) & (idx < extent)
-    oob = (build_matchable & ~inb).sum().astype(jnp.int64)
-    slot = jnp.where(inb, idx, ext_pad).astype(jnp.int32)
-    iota_m = jnp.arange(m, dtype=jnp.int32)
-    directory = jnp.full(ext_pad, m, jnp.int32).at[slot].set(
-        iota_m, mode="drop")
-    dup = (inb & (jnp.minimum(directory[jnp.minimum(slot, ext_pad - 1)], m)
-                  != iota_m)).sum().astype(jnp.int64)
+    with stage_scope("probe"):
+        # directory build + duplicate detection: identical accounting to
+        # dense_unique_lookup (padding slots [extent, ext_pad) stay empty)
+        idx = build_key.astype(jnp.int64) - jnp.int64(base)
+        inb = build_matchable & (idx >= 0) & (idx < extent)
+        oob = (build_matchable & ~inb).sum().astype(jnp.int64)
+        slot = jnp.where(inb, idx, ext_pad).astype(jnp.int32)
+        iota_m = jnp.arange(m, dtype=jnp.int32)
+        directory = jnp.full(ext_pad, m, jnp.int32).at[slot].set(
+            iota_m, mode="drop")
+        dup = (inb & (jnp.minimum(directory[jnp.minimum(slot, ext_pad - 1)], m)
+                      != iota_m)).sum().astype(jnp.int64)
 
     pin, pc = _probe_slots(probe_key, base, extent)
     from .hashing import tile_buckets
@@ -349,27 +352,29 @@ def bucketed_unique_lookup(build_key: jnp.ndarray,
     # overflowed run the retry regrows before feedback ever fires
     bucket_max_fill = pvalid.sum(axis=1).max().astype(jnp.int64)
 
-    dir2d = directory.reshape(n_buckets, tile)
-    loc2d = jnp.where(pvalid, packed["local"], 0)
-    if kernel == "pallas" and not interpret:
-        if jax.default_backend() == "cpu":
-            # config asked for the kernel on the CPU backend, where a
-            # compiled pallas_call is interpret-only: the XLA
-            # formulation gives the same results
-            kernel = "xla"
-    if kernel == "pallas":
-        from .pallas_kernels import bucketed_probe_pallas
+    with stage_scope("probe"):
+        dir2d = directory.reshape(n_buckets, tile)
+        loc2d = jnp.where(pvalid, packed["local"], 0)
+        if kernel == "pallas" and not interpret:
+            if jax.default_backend() == "cpu":
+                # config asked for the kernel on the CPU backend, where a
+                # compiled pallas_call is interpret-only: the XLA
+                # formulation gives the same results
+                kernel = "xla"
+        if kernel == "pallas":
+            from .pallas_kernels import bucketed_probe_pallas
 
-        raw2d = bucketed_probe_pallas(dir2d, loc2d, interpret=interpret)
-    else:
-        raw2d = jnp.take_along_axis(dir2d, loc2d, axis=1)
+            raw2d = bucketed_probe_pallas(dir2d, loc2d, interpret=interpret)
+        else:
+            raw2d = jnp.take_along_axis(dir2d, loc2d, axis=1)
 
-    pos = jnp.where(pvalid, packed["pos"], n).reshape(-1)
-    raw = jnp.full(n, m, jnp.int32).at[pos].set(
-        raw2d.reshape(-1), mode="drop")
-    found = pin & (raw != m)
-    bidx = jnp.minimum(raw, m - 1)
-    counts = found.astype(jnp.int32)
+    with stage_scope("scatter_back"):
+        pos = jnp.where(pvalid, packed["pos"], n).reshape(-1)
+        raw = jnp.full(n, m, jnp.int32).at[pos].set(
+            raw2d.reshape(-1), mode="drop")
+        found = pin & (raw != m)
+        bidx = jnp.minimum(raw, m - 1)
+        counts = found.astype(jnp.int32)
     return bidx, counts, oob + dup, overflow.astype(jnp.int64), \
         bucket_max_fill
 

@@ -21,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..stats.tracing import stage_scope
+
 # counting-rank eligibility bound: the pack's sort key has only
 # n_targets+1 distinct values, so for the mesh-shuffle case (targets =
 # devices, ≤ 8 on a v5e-8) a counting formulation — one 1-D cumsum per
@@ -43,47 +45,49 @@ def pack_by_target(columns: dict[str, jnp.ndarray], valid: jnp.ndarray,
     overflow_count — rows dropped because their partition exceeded capacity).
     Overflow > 0 ⇒ results incomplete ⇒ host retries with larger capacity.
     """
-    n = target.shape[0]
-    t = jnp.where(valid, target, n_targets).astype(jnp.int32)
-    if n_targets <= COUNTING_PACK_MAX_TARGETS:
-        # counting rank: row i's position within its target's run is
-        # the inclusive prefix count of its target minus one; `order`
-        # (sorted position → source row) lands by unique-index scatter.
-        # Bit-identical to the stable argsort (both preserve source
-        # order within a target).
-        rank = jnp.zeros(n, jnp.int32)
-        counts_l = []
-        for d in range(n_targets):
-            is_d = t == d
-            c = jnp.cumsum(is_d.astype(jnp.int32))
-            rank = jnp.where(is_d, c - 1, rank)
-            counts_l.append(c[n - 1] if n else jnp.int32(0))
-        counts = jnp.stack(counts_l)
-        starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                  jnp.cumsum(counts, dtype=jnp.int32)]
-                                 )[:-1]
-        out_idx = jnp.where(t < n_targets, starts[t] + rank, n)
-        order = jnp.zeros(n, jnp.int32).at[out_idx].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
-    else:
-        order = jnp.argsort(t, stable=True).astype(jnp.int32)
-        counts = jax.ops.segment_sum(valid.astype(jnp.int32), t,
-                                     num_segments=n_targets + 1)[:n_targets]
-        starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                  jnp.cumsum(counts, dtype=jnp.int32)]
-                                 )[:-1]
+    with stage_scope("pack"):
+        n = target.shape[0]
+        t = jnp.where(valid, target, n_targets).astype(jnp.int32)
+        if n_targets <= COUNTING_PACK_MAX_TARGETS:
+            # counting rank: row i's position within its target's run is
+            # the inclusive prefix count of its target minus one; `order`
+            # (sorted position → source row) lands by unique-index scatter.
+            # Bit-identical to the stable argsort (both preserve source
+            # order within a target).
+            rank = jnp.zeros(n, jnp.int32)
+            counts_l = []
+            for d in range(n_targets):
+                is_d = t == d
+                c = jnp.cumsum(is_d.astype(jnp.int32))
+                rank = jnp.where(is_d, c - 1, rank)
+                counts_l.append(c[n - 1] if n else jnp.int32(0))
+            counts = jnp.stack(counts_l)
+            starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                      jnp.cumsum(counts, dtype=jnp.int32)]
+                                     )[:-1]
+            out_idx = jnp.where(t < n_targets, starts[t] + rank, n)
+            order = jnp.zeros(n, jnp.int32).at[out_idx].set(
+                jnp.arange(n, dtype=jnp.int32), mode="drop")
+        else:
+            order = jnp.argsort(t, stable=True).astype(jnp.int32)
+            counts = jax.ops.segment_sum(
+                valid.astype(jnp.int32), t,
+                num_segments=n_targets + 1)[:n_targets]
+            starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                      jnp.cumsum(counts, dtype=jnp.int32)]
+                                     )[:-1]
 
-    # slot (t, r) ← sorted position starts[t] + r (gather, no scatter)
-    slots = jnp.arange(n_targets * capacity, dtype=jnp.int32)
-    ti = slots // capacity
-    r = slots - ti * capacity
-    packed_valid = r < counts[ti]
-    sp = jnp.clip(starts[ti] + r, 0, max(n - 1, 0))
-    src_row = order[sp]
-    packed = {}
-    for name, col in columns.items():
-        buf = jnp.where(packed_valid, col[src_row],
-                        jnp.zeros((), col.dtype))
-        packed[name] = buf.reshape(n_targets, capacity)
-    overflow = jnp.maximum(counts - capacity, 0).sum()
-    return packed, packed_valid.reshape(n_targets, capacity), overflow
+        # slot (t, r) ← sorted position starts[t] + r (gather, no scatter)
+        slots = jnp.arange(n_targets * capacity, dtype=jnp.int32)
+        ti = slots // capacity
+        r = slots - ti * capacity
+        packed_valid = r < counts[ti]
+        sp = jnp.clip(starts[ti] + r, 0, max(n - 1, 0))
+        src_row = order[sp]
+        packed = {}
+        for name, col in columns.items():
+            buf = jnp.where(packed_valid, col[src_row],
+                            jnp.zeros((), col.dtype))
+            packed[name] = buf.reshape(n_targets, capacity)
+        overflow = jnp.maximum(counts - capacity, 0).sum()
+        return packed, packed_valid.reshape(n_targets, capacity), overflow

@@ -986,7 +986,10 @@ class Session:
                 os.path.join(self.data_dir, "catalog.json"))
 
     def _execute_statement(self, stmt: ast.Statement):
-        self._replica_gate(stmt)
+        from .stats.tracing import trace_span
+
+        with trace_span("gate"):
+            self._replica_gate(stmt)
         if isinstance(stmt, ast.Select):
             udf = self._try_udf(stmt)
             if udf is not None:
@@ -1952,11 +1955,12 @@ class Session:
         # the shared LRU, provably as-of the latest journaled LSN for
         # every table it reads (CDC-driven invalidation + the manifest-
         # identity backstop — serving/result_cache.py, ROADMAP item 3)
+        from .stats.tracing import trace_span
+
         fill = None
         cache = self._serving_cache()
         if cache is not None:
             from .serving.result_cache import cache_key
-            from .stats.tracing import trace_span
 
             with trace_span("serving.cache_lookup"):
                 keyed = cache_key(sel, params, self.catalog,
@@ -1988,7 +1992,8 @@ class Session:
                              for t in tables},
                             cache.fill_token())
         plan, cleanup = self._plan_select(sel, params)
-        self._count_plan_shape(plan)
+        with trace_span("route"):
+            self._count_plan_shape(plan)
         try:
             result = self.executor.execute_plan(plan)
         finally:

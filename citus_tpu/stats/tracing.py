@@ -33,17 +33,35 @@ the reference, grown into a flight recorder:
   in memory (span count per trace capped, so an 8-session hammer
   cannot grow memory without bound); statements slower than
   `trace_slow_statement_ms` persist their full tree as JSON through
-  utils/io (newest ``SLOW_TRACE_KEEP`` kept).  ``python -m
-  citus_tpu.stats.trace_export`` renders any persisted (or in-ring)
-  trace as Chrome-trace/Perfetto JSON.
+  utils/io (newest ``SLOW_TRACE_KEEP`` kept);
+  ``tools/trace_summarize.py`` reads them back.
+* **The profiler's clock** — every span also enters a
+  ``jax.profiler.TraceAnnotation("ct:" + name)`` and leaves it where
+  the span closes, so a `jax.profiler` trace of a live session shows
+  the statement's phases over the device's operations on one clock.
+  The root carries ``stmt=<n>`` (``Trace.stmt_id``; ring, slow log and
+  profiler trace join on it) and so does every span recorded under an
+  adopted context, which is on another thread's line; on the
+  statement's own thread containment in time is the parent link.
+  Outside a profiler session an annotation is a check of one atomic;
+  a statement that is sampled out or runs with `trace_enabled` off
+  builds none.  The device programs' side of the same vocabulary is
+  ``STAGE_NAMES`` / :func:`stage_scope`: ``ct.<stage>`` name scopes
+  in the operations' ``op_name`` metadata.
 
 Overhead: an unarmed `trace_span` is one thread-local read and a None
-check; an active span is two `perf_counter` calls plus one small
-object.  bench.py's serving scenario A/Bs `trace_enabled` on/off and
-stamps the measured overhead (PERF_NOTES round 16); the
-`trace_sample_every` knob degrades full-tree recording to 1-in-N
-statements (histograms always update) if that overhead ever matters
-on a workload.
+check; an active span is two `perf_counter` calls, one small object
+and one annotation enter/exit pair.  Measured (PERF.md §6, PR 27):
+an annotation enter/exit pair is 0.43 µs outside a profiler session
+and 0.60 µs inside one (0.58 / 0.94 µs with `stmt`); a warm 16-span
+statement costs the recorder 51.8 µs, 9.4 µs of it the annotations
+(this sandbox's CPU, JAX 0.9.0; the chip's host runs the same code
+slower: its `plan` span reads 1.08 ms, 0.62 ms here).  On the chip,
+`tpch1.q1` at 9.3 ms a statement, six seeds a side: with no session
+the change's p50 read 0.4 % under the parent's, inside the cell's 2 %
+spread (the off-cost is not resolvable); with the profiler on, p50
+inside the profiled stretch is 1.0 % over p50 outside it (the parent's
+own: 0.5 %).
 """
 
 from __future__ import annotations
@@ -51,6 +69,9 @@ from __future__ import annotations
 import os
 import threading
 import time
+
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
 
 # -- span-name registry ------------------------------------------------------
 # Every named span a statement can record.  Render/record sites call
@@ -63,8 +84,14 @@ SPAN_NAMES: dict[str, str] = {
     "parse": "lexer+parser (hot-statement memo makes repeats ~free)",
     "queue": "WLM admission: classification + slot/HBM queue wait",
     "execute": "one execution attempt under the resilience envelope",
+    "gate": "session gate before a statement runs: the replica's "
+            "read-only and staleness checks",
     "plan": "recursive planning + bind + distributed planning",
+    "route": "path choice between plan and feed: plan-shape "
+             "counters, manifest staleness refresh, stream eligibility",
     "feed": "device feed build (eager, pipelined or per-batch)",
+    "caps": "capacity resolution: plan fingerprint, capacity memo or "
+            "initial capacities, buffer guard, plan-cache key + lookup",
     "compile": "plan-cache resolution (meta cache=hit|miss; a miss "
                "traces + XLA-compiles the mesh program)",
     "compile.cache_load": "persistent executable cache probe: meta + "
@@ -76,6 +103,8 @@ SPAN_NAMES: dict[str, str] = {
                   "adopted into the plan cache pre-admission",
     "mesh.dispatch": "compiled program dispatch + on-mesh collectives",
     "mesh.fetch": "device→host pull of outputs + overflow counters",
+    "settle": "after the fetch: overflow verdict, capacity feedback "
+              "and memo, shuffle counter",
     "combine": "host-side combine (having/order/limit/decode)",
     "fastpath": "single-shard host execution (router fast path)",
     "scan.prefetch": "scanpipe: stripe read + host decode (producer)",
@@ -108,13 +137,17 @@ SPAN_NAMES: dict[str, str] = {
 PHASE_OF: dict[str, str] = {
     "parse": "parse",
     "queue": "queue",
+    "gate": "plan",
     "plan": "plan",
+    "route": "plan",
     "feed": "feed",
+    "caps": "compile",
     "compile": "compile",
     "compile.cache_load": "compile",
     "compile.single_flight_wait": "compile",
     "mesh.dispatch": "device",
     "mesh.fetch": "device",
+    "settle": "combine",
     "combine": "combine",
     "fastpath": "fastpath",
     "serving.cache_lookup": "serving",
@@ -132,6 +165,59 @@ PHASE_OF: dict[str, str] = {
 PHASE_ORDER = ("parse", "queue", "plan", "feed", "compile", "device",
                "combine", "fastpath", "serving", "retry", "degrade",
                "replication")
+
+# -- stage-name registry -----------------------------------------------------
+# Every `ct.<stage>` name scope a device program can carry in its
+# operations' op_name metadata, under the span-registry rule's two
+# directions like SPAN_NAMES.  The kinds PlanCompiler._record gives
+# capacity stages are names of this registry, so a scope, a capacity
+# stage and chip_smoke.py's `compiled_stages` are one vocabulary.  An
+# operation belongs to the innermost stage of its path; a name marked
+# "sub:" only ever appears inside the stages it lists.
+STAGE_NAMES: dict[str, str] = {
+    "feed_unpack": "shard_map inputs → per-scan Blocks",
+    "decode": "scanpipe: on-mesh expand of one wire payload",
+    "for": "sub: decode — frame-of-reference expand",
+    "dict": "sub: decode — dictionary gather",
+    "bits": "sub: decode — packed-bit expand",
+    "valid": "sub: decode — row-validity expand",
+    "scan_out": "scan filter mask + compaction to the filtered size",
+    "repartition": "shuffle: route, pack, all_to_all, flatten",
+    "pack": "sub: repartition, bucket_probe, agg_bucket — "
+            "pack_by_target's radix pack",
+    "exchange": "sub: repartition — the all_to_all",
+    "unpack": "sub: repartition — flatten of the exchanged pack",
+    "bucket_probe": "lookup join through the bucketed (tiled) probe",
+    "probe": "sub: bucket_probe — tile-local directory build + probe",
+    "scatter_back": "sub: bucket_probe — results back to probe order",
+    "lookup_join": "lookup join through one dense directory (or the "
+                   "sorted-bounds fallback) and match counting",
+    "join_out": "join keys, pair emission / build-column gathers, "
+                "residual filter, compaction",
+    "agg_grid": "dense-grid group-by + psum combine",
+    "agg_bucket": "bucketed dense-grid group-by",
+    "agg_sort": "sort-path group-by (both levels of a repartition "
+                "combine)",
+    "sort": "sub: agg_sort — the key sort",
+    "reduce": "sub: agg_sort — boundaries + segment reductions",
+    "agg_out": "group slots cut to the planned capacity",
+    "agg_global": "no GROUP BY: per-device reduce + psum/pmin/pmax "
+                  "(also the join-aggregate pushdown's finish)",
+    "topk": "per-device ORDER BY + LIMIT",
+    "window": "window functions: partition sort + segmented scans",
+    "project": "projection expressions",
+    "output_pack": "outputs bit-packed into the two fetched arrays",
+}
+
+
+def stage_scope(name: str):
+    """`with stage_scope("repartition"):` — operations traced inside
+    carry `ct.repartition` in their op_name path.  Metadata only: the
+    compiled program and every cache key stay what they were.
+    KeyError on an unregistered stage."""
+    STAGE_NAMES[name]
+    return jax.named_scope("ct." + name)
+
 
 # spans kept per trace: a runaway statement (thousands of stripes ×
 # columns) truncates instead of growing the ring without bound
@@ -177,7 +263,7 @@ class Span:
     budget — a separate handle object measurably costs QPS."""
 
     __slots__ = ("name", "t0", "t1", "tid", "meta", "children",
-                 "_stk", "_tr")
+                 "_stk", "_tr", "_ann")
 
     def __init__(self, name: str, t0: float, tid: int,
                  meta: dict | None = None, stk: list | None = None,
@@ -193,6 +279,7 @@ class Span:
         self.children: list[Span] = []
         self._stk = stk
         self._tr = tr
+        self._ann = None
 
     def duration(self) -> float:
         return (self.t1 if self.t1 is not None
@@ -203,6 +290,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb, _pc=time.perf_counter):
         self.t1 = _pc()
+        _leave(self)
         if exc_type is not None:
             m = self.meta or {}
             m["error"] = exc_type.__name__
@@ -215,6 +303,7 @@ class Span:
             stray = stack.pop()
             if stray.t1 is None:
                 stray.t1 = self.t1
+            _leave(stray)
             if self._tr is not None:
                 self._tr.leaked += 1
         if stack:
@@ -222,14 +311,33 @@ class Span:
         return False
 
 
+def _annotate(sp: Span, stmt_id: int | None) -> None:
+    """Enter the span's event on the profiler's clock; `stmt_id` where
+    the event's line does not say which statement it belongs to."""
+    ann = (_Annotation("ct:" + sp.name) if stmt_id is None
+           else _Annotation("ct:" + sp.name, stmt=stmt_id))
+    ann.__enter__()
+    sp._ann = ann
+
+
+def _leave(sp: Span) -> None:
+    ann = sp._ann
+    if ann is not None:
+        sp._ann = None
+        ann.__exit__(None, None, None)
+
+
 class Trace:
     """One statement's span tree plus bookkeeping flags."""
 
     __slots__ = ("sql", "cls", "root", "spans", "truncated", "leaked",
-                 "wall_ms", "error")
+                 "wall_ms", "error", "stmt_id")
 
-    def __init__(self, sql: str, root: Span):
+    def __init__(self, sql: str, root: Span, stmt_id: int = 0):
         self.sql = sql
+        # the recorder's statement number, `stmt` of the root's event
+        # in a profiler trace
+        self.stmt_id = stmt_id
         self.cls: str | None = None
         self.root = root
         # `spans`/`leaked` are bumped with plain `+=` from the
@@ -265,6 +373,7 @@ class Trace:
 
         root = span_dict(self.root)
         return {"schema": 1, "sql": self.sql, "class": self.cls,
+                "stmt_id": self.stmt_id,
                 "wall_ms": self.wall_ms, "spans": exact,
                 "truncated": self.truncated, "leaked": self.leaked,
                 "error": self.error, "root": root}
@@ -282,7 +391,8 @@ _stacks: dict[int, list] = {}
 def _tls_state():
     st = getattr(_tls, "state", None)
     if st is None:
-        st = _tls.state = {"trace": None, "stack": []}
+        # "stmt": the statement number while a context is adopted
+        st = _tls.state = {"trace": None, "stack": [], "stmt": None}
         tid = threading.get_ident()
         with _stacks_lock:
             live = {t.ident for t in threading.enumerate()}
@@ -334,6 +444,7 @@ def trace_span(name: str, _pc=time.perf_counter,
     tr.spans += 1
     stack[-1].children.append(sp)
     stack.append(sp)
+    _annotate(sp, st["stmt"])
     return sp
 
 
@@ -359,9 +470,10 @@ class _AdoptCtx:
             return None
         trace, parent = self.token
         st = _tls_state()
-        self.prev = (st["trace"], list(st["stack"]))
+        self.prev = (st["trace"], list(st["stack"]), st["stmt"])
         st["trace"] = trace
         st["stack"][:] = [parent]
+        st["stmt"] = trace.stmt_id
         return trace
 
     def __exit__(self, exc_type, exc, tb):
@@ -377,10 +489,9 @@ class _AdoptCtx:
             sp = st["stack"].pop()
             if sp.t1 is None:
                 sp.t1 = now
+            _leave(sp)
             trace.leaked += 1
-        prev_trace, prev_stack = self.prev
-        st["trace"] = prev_trace
-        st["stack"][:] = prev_stack
+        st["trace"], st["stack"][:], st["stmt"] = self.prev
         return False
 
 
@@ -454,12 +565,9 @@ class TraceRecorder:
         self._mu = threading.Lock()
         self._ring: list[Trace] = []
         self._hists: dict[str, ClassHist] = {}
-        self._seq = itertools.count(1)
-        # separate tick stream for the fast-class auto-degrade: fed
-        # from _seq, an even trace_sample_every would alias the two
-        # modulos (survivors of the first check always land on the
-        # same residue at the second) and fast classes would never
-        # record a tree at all
+        # statement numbers of the recorded trees (Trace.stmt_id)
+        self._stmt_seq = itertools.count(1)
+        # tick stream of the fast-class auto-degrade
         self._fast_seq = itertools.count(1)
         self._slow_seq = 0
         self.max_hist_classes = 512
@@ -468,17 +576,16 @@ class TraceRecorder:
         self._cfg_memo = None
 
     def _cfg(self):
-        """(enabled, sample_every, ring_keep, slow_ms, fast_ms,
-        fast_every) — memoized per settings version (a benign race
-        installs the same tuple)."""
+        """(enabled, ring_keep, slow_ms, fast_ms, fast_every) —
+        memoized per settings version (a benign race installs the
+        same tuple)."""
         settings = self.settings
         if settings is None:
-            return (True, 1, 128, 0, 0.0, 1)
+            return (True, 128, 0, 0.0, 1)
         c = self._cfg_memo
         if c is not None and c[0] == settings.version:
             return c[1]
         vals = (bool(settings.get("trace_enabled")),
-                max(1, int(settings.get("trace_sample_every"))),
                 max(1, int(settings.get("trace_ring_statements"))),
                 settings.get("trace_slow_statement_ms"),
                 float(settings.get("trace_fast_statement_ms")),
@@ -497,11 +604,9 @@ class TraceRecorder:
             # statement's wall already covers it, so a histogram entry
             # here would double-count the time
             return _StatementHandle(sql, t0, None, nested=True)
-        enabled, every, _keep, _slow, fast_ms, fast_every = self._cfg()
+        enabled, _keep, _slow, fast_ms, fast_every = self._cfg()
         if not enabled:
             return _StatementHandle(sql, t0, None, nested=True)
-        if every > 1 and next(self._seq) % every:
-            return _StatementHandle(sql, t0, None)
         if fast_ms > 0.0 and fast_every > 1:
             # auto-degrade to sampling for PROVEN-fast statement
             # classes (the serving cache-hit hammer): a class whose
@@ -520,9 +625,10 @@ class TraceRecorder:
                     next(self._fast_seq) % fast_every:
                 return _StatementHandle(sql, t0, None)
         root = Span(span_name("statement"), t0, threading.get_ident())
-        trace = Trace(_clamp(sql), root)
+        trace = Trace(_clamp(sql), root, next(self._stmt_seq))
         st["trace"] = trace
         st["stack"].append(root)
+        _annotate(root, trace.stmt_id)
         return _StatementHandle(sql, t0, trace)
 
     def end(self, h: _StatementHandle, error: BaseException | None = None,
@@ -540,8 +646,10 @@ class TraceRecorder:
                 sp = st["stack"].pop()
                 if sp.t1 is None:
                     sp.t1 = t1
+                _leave(sp)
                 trace.leaked += 1
             root.t1 = t1
+            _leave(root)
             if st["stack"]:
                 st["stack"].pop()
             st["trace"] = None
@@ -566,11 +674,11 @@ class TraceRecorder:
             hist.record(wall_ms)
             if trace is not None:
                 self._ring.append(trace)
-                keep = self._cfg()[2]
+                keep = self._cfg()[1]
                 if len(self._ring) > keep:
                     del self._ring[:len(self._ring) - keep]
         if trace is not None:
-            slow_ms = self._cfg()[3]
+            slow_ms = self._cfg()[2]
             if slow_ms and wall_ms >= slow_ms and self.data_dir:
                 try:
                     self._persist_slow(trace)

@@ -2,7 +2,7 @@
 
 from .planner.explain import explain_tag
 from .stats import counters as sc
-from .stats.tracing import trace_span
+from .stats.tracing import stage_scope, trace_span
 from .utils.faultinjection import FAULT_POINTS  # noqa: F401
 
 
@@ -28,4 +28,8 @@ def run(settings):
     explain_tag("Live Tag")              # registered: clean
     explain_tag("Ghost Tag")             # explain-tag-registry
     trace_span("live.span")              # registered: clean
-    return trace_span("ghost.span")      # span-registry
+    trace_span("ghost.span")             # span-registry
+    stage_scope("live_stage")            # registered: clean
+    counters._record(0, "live_kind", 0, 0)   # a capacity stage: clean
+    counters._record(0, "ghost_kind", 0, 0)  # span-registry
+    return stage_scope("ghost_stage")    # span-registry

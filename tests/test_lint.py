@@ -117,7 +117,10 @@ GOLDEN = {
     ("explain-tag-registry", "citus_tpu/uses.py", 29),
     ("explain-tag-registry", "citus_tpu/planner/explain.py", 5),
     ("span-registry", "citus_tpu/uses.py", 31),
+    ("span-registry", "citus_tpu/uses.py", 34),
+    ("span-registry", "citus_tpu/uses.py", 35),
     ("span-registry", "citus_tpu/stats/tracing.py", 5),
+    ("span-registry", "citus_tpu/stats/tracing.py", 11),
 }
 
 

@@ -50,8 +50,8 @@ def test_budget_cutoff_mirrors_conftest_front_loading():
 
 
 # ---------------------------------------------------------------------------
-# tools/trace_summarize.py + stats/trace_export.py smoke (tier-1): a
-# recorded slow trace is summarizable and chrome-exportable end to end
+# tools/trace_summarize.py smoke (tier-1): a recorded slow trace is
+# summarizable end to end
 # ---------------------------------------------------------------------------
 def _record_slow_trace(data_dir: str):
     """Drive the recorder directly (no Session): one statement with a
@@ -82,28 +82,6 @@ def test_trace_summarize_prints_phase_breakdown(tmp_path, capsys):
     assert "phase breakdown" in out
     assert "plan" in out and "total" in out
     assert "slowest spans" in out
-
-
-def test_trace_export_emits_chrome_json(tmp_path):
-    import json
-
-    from citus_tpu.stats.trace_export import main as export_main
-
-    _record_slow_trace(str(tmp_path))
-    out = tmp_path / "chrome.json"
-    assert export_main([str(tmp_path), "-o", str(out)]) == 0
-    doc = json.loads(out.read_text())
-    events = doc["traceEvents"]
-    names = {e["name"] for e in events}
-    assert {"statement", "plan", "execute", "combine"} <= names
-    spans = [e for e in events if e["ph"] == "X"]
-    assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in spans)
-    # top-level spans tile the statement wall (the export is what the
-    # acceptance check sums)
-    root = next(e for e in spans if e["name"] == "statement")
-    kids = [e for e in spans
-            if e["name"] in ("plan", "execute")]
-    assert sum(k["dur"] for k in kids) <= root["dur"] * 1.001
 
 
 def test_trace_summarize_errors_cleanly_without_traces(tmp_path, capsys):

@@ -11,9 +11,9 @@ The contracts under test (ISSUE 14):
 * DDSketch latency histograms (citus_stat_latency) report honest
   quantiles; sampling and trace_enabled degrade recording, never
   correctness;
-* the slow-query log persists through the io seam and the Chrome
-  export's top-level spans sum to statement wall (the acceptance
-  shape, exercised here at test scale and by bench.py at SF10).
+* the slow-query log persists through the io seam and the persisted
+  tree's phases sum to statement wall (the acceptance shape,
+  exercised here at test scale and by bench.py at SF10).
 """
 
 import json
@@ -309,8 +309,13 @@ class TestBoundedness:
 
     def test_sampling_records_histograms_for_every_statement(
             self, tmp_path):
-        sess = _mk(str(tmp_path / "d"), trace_sample_every=5)
+        # every class counts as fast, so after its 8 proving calls a
+        # class records a tree 1 in 5 times
+        sess = _mk(str(tmp_path / "d"), trace_fast_statement_ms=60_000,
+                   trace_fast_sample_every=5)
         _seed(sess, n=200)
+        for i in range(8):
+            sess.execute(f"SELECT count(*) FROM kv WHERE v = {i}")
         r0 = len(sess.stats.tracing.traces())
         for i in range(10):
             sess.execute(f"SELECT count(*) FROM kv WHERE v = {i}")
@@ -319,20 +324,17 @@ class TestBoundedness:
         rows = {r["statement_class"]: r
                 for r in sess.stats.tracing.latency_rows()}
         cls = [c for c in rows if "count" in c and "kv" in c]
-        assert cls and rows[cls[0]]["calls"] == 10  # hist sees ALL
+        assert cls and rows[cls[0]]["calls"] == 18  # hist sees ALL
+        assert open_span_count() == 0
         sess.close()
 
     def test_fast_class_auto_degrade_still_samples_trees(self):
-        """Regression (review): the auto-degrade tick stream must be
-        independent of trace_sample_every's — with an even
-        trace_sample_every the shared counter aliased the two modulos
-        and proven-fast classes recorded ZERO trees instead of
-        1-in-N."""
+        """A proven-fast class still records trees, 1 in N: the first
+        8 calls prove it, then 392/16 sample in."""
         from citus_tpu.config import Settings
         from citus_tpu.stats.tracing import TraceRecorder
 
         rec = TraceRecorder(None, Settings({
-            "trace_sample_every": 2,
             "trace_fast_statement_ms": 10_000,  # every class "fast"
             "trace_fast_sample_every": 16,
             "trace_ring_statements": 1000}))
@@ -340,10 +342,12 @@ class TestBoundedness:
             rec.end(rec.begin("select 1"))
         rows = rec.latency_rows()
         assert rows and rows[0]["calls"] == 400
-        # ~400/2 survive manual sampling, ~1/16 of those record —
-        # anything >0 proves the streams no longer alias
         recorded = len(rec.traces())
-        assert 0 < recorded < 40, recorded
+        assert 8 + 392 // 16 - 1 <= recorded <= 8 + 392 // 16 + 1, \
+            recorded
+        # the numbers of the recorded trees are one sequence
+        ids = [t.stmt_id for t in rec.traces()]
+        assert ids == list(range(1, recorded + 1))
 
     def test_trace_enabled_off_records_nothing(self, tmp_path):
         sess = _mk(str(tmp_path / "d"), trace_enabled=False)
@@ -384,36 +388,32 @@ class TestLatencyHistograms:
 
 
 # ---------------------------------------------------------------------------
-# slow-query log + chrome export + EXPLAIN Timing (the acceptance
-# shape at test scale; bench.py runs it at SF10)
+# slow-query log + EXPLAIN Timing (the acceptance shape at test scale;
+# bench.py runs it at SF10)
 # ---------------------------------------------------------------------------
 class TestSlowLogAndExport:
     def test_slow_log_persists_and_chrome_sums_to_wall(self, tmp_path):
-        from citus_tpu.stats.trace_export import (
-            chrome_trace_events,
-            load_trace,
-        )
-
         d = str(tmp_path / "d")
         sess = _mk(d, trace_slow_statement_ms=1)
         _seed(sess)
         sess.executor.feed_cache.clear()
         sess.execute("SELECT sum(v), sum(w) FROM kv WHERE v > 2")
         assert os.path.isdir(os.path.join(d, "slow_traces"))
-        doc = load_trace(d)
+        names = sorted(os.listdir(os.path.join(d, "slow_traces")))
+        with open(os.path.join(d, "slow_traces", names[-1])) as f:
+            doc = json.load(f)
         _assert_tiles_wall(doc)
-        events = chrome_trace_events(doc)
-        spans = [e for e in events if e.get("ph") == "X"]
-        root = next(e for e in spans if e["name"] == "statement")
-        tops = [e for e in spans
-                if e["name"] in ("parse", "queue", "execute",
-                                 "retry.backoff", "oom.degrade",
-                                 "mesh.degrade")]
-        # acceptance: exported top-level spans sum to wall within 5%
-        # (small statements get a small absolute allowance for glue)
-        covered = sum(e["dur"] for e in tops)
-        assert covered <= root["dur"] * 1.001
-        assert root["dur"] - covered <= max(0.05 * root["dur"], 5000)
+        assert doc["stmt_id"] == sess.stats.tracing.traces()[-1].stmt_id
+        # acceptance: the persisted tree's phases sum to wall within
+        # 5% (small statements get a small absolute allowance for glue)
+        ph = phase_breakdown(doc["root"])
+        assert ph["total"] * 1000.0 == pytest.approx(doc["wall_ms"],
+                                                     rel=0.01, abs=0.05)
+        from citus_tpu.stats.tracing import PHASE_ORDER
+
+        named = sum(ph[p] for p in PHASE_ORDER)
+        assert named + ph["other"] == pytest.approx(ph["total"])
+        assert ph["other"] <= max(0.10 * ph["total"], 0.010)
         sess.close()
 
     def test_slow_log_bounded(self, tmp_path):
@@ -454,3 +454,241 @@ class TestSlowLogAndExport:
         assert attributed <= ph["total"] * 1.001
         assert ph["other"] >= 0
         sess.close()
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: spans as `ct:` events, stages as `ct.` scopes
+# ---------------------------------------------------------------------------
+def _profile(tmp_path, body):
+    """Run `body()` under a jax.profiler session; the trace's `ct:`
+    events by host line (benchmark/xspans.py's reading of them)."""
+    import jax.profiler
+
+    from benchmark import xspans, xtrace
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    log_dir = str(tmp_path / "profile")
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return xspans.read_events(xtrace.newest_xplane(log_dir))["host"]
+
+
+def _names(tree, keep):
+    """The tree as nested [name, [children]] of the nodes `keep` takes."""
+    return [tree["name"], [_names(c, keep) for c in sorted(
+        tree["children"], key=lambda c: c["t0"]) if keep(c)]]
+
+
+class TestProfilerClock:
+    def test_statement_tree_appears_as_nested_ct_events(self, tmp_path):
+        from benchmark import xspans
+
+        sess = _mk(str(tmp_path / "d"), scan_pipeline="host")
+        _seed(sess)
+        sql = "SELECT sum(v), sum(w) FROM kv"
+        sess.execute(sql)
+
+        def body():
+            sess.executor.feed_cache.clear()
+            sess.execute(sql)
+
+        host = _profile(tmp_path, body)
+        doc = sess.stats.tracing.last_trace()
+        trees = xspans.build_trees(host)
+        assert trees["orphans"] == 0
+        root = trees["statements"][doc["stmt_id"]]
+        assert root["name"] == "statement" and root["stmt"] == doc["stmt_id"]
+        # on the statement's own line containment in time IS the span
+        # tree; the root alone carries `stmt` there
+        tid = doc["root"]["tid"]
+
+        def doc_names(span, keep):
+            return [span["name"], [doc_names(c, keep)
+                                   for c in span.get("children", ())
+                                   if keep(c)]]
+
+        own = _names(root, lambda c: c["line_no"] == root["line_no"])
+        assert own == doc_names(doc["root"], lambda c: c["tid"] == tid)
+        assert own[1], "the statement recorded no phase"
+
+        def stmts_on_own_line(n):
+            return [c["stmt"] for c in n["children"]
+                    if c["line_no"] == root["line_no"]] + [
+                s for c in n["children"]
+                if c["line_no"] == root["line_no"]
+                for s in stmts_on_own_line(c)]
+
+        assert set(stmts_on_own_line(root)) == {None}
+        # the scanpipe producer's spans: another line, the same stmt
+        other = [c for c in root["children"]
+                 if c["line_no"] != root["line_no"]]
+        assert other and {c["stmt"] for c in other} == {doc["stmt_id"]}
+        assert {c["name"] for c in other} >= {"scan.prefetch"}
+        prefetch = []
+
+        def find(span):
+            if span["name"] == "scan.prefetch":
+                prefetch.append(span)
+            for c in span.get("children", ()):
+                find(c)
+
+        find(doc["root"])
+        assert len([c for c in other if c["name"] == "scan.prefetch"]) \
+            == len([p for p in prefetch if p["tid"] != tid])
+        assert open_span_count() == 0
+        sess.close()
+
+    def test_without_a_session_no_annotation_outlives_its_span(self):
+        """No profiler session: the tree is what it always was, and
+        every force-close path leaves its annotation too."""
+        from citus_tpu.stats.tracing import (
+            TraceRecorder,
+            adopt_context,
+            capture_context,
+            trace_span,
+        )
+
+        rec = TraceRecorder(None, None)
+        # an exception unwinding through two spans
+        h = rec.begin("select 1")
+        err = None
+        try:
+            with trace_span("execute"):
+                with trace_span("plan"):
+                    raise ValueError("boom")
+        except ValueError as e:
+            err = e
+        tr = rec.end(h, error=err)
+        assert open_span_count() == 0
+        doc = tr.to_dict()
+        assert doc["error"] == "ValueError" and doc["leaked"] == 0
+        assert [c["name"] for c in doc["root"]["children"]] == ["execute"]
+        assert doc["root"]["children"][0]["children"][0]["meta"] == {
+            "error": "ValueError"}
+        # an abandoned producer: its span is closed for it
+        h = rec.begin("select 2")
+        left_open = []
+
+        def producer(token):
+            with adopt_context(token):
+                left_open.append(trace_span("scan.prefetch"))
+
+        with trace_span("feed"):
+            t = threading.Thread(target=producer,
+                                 args=(capture_context(),))
+            t.start()
+            t.join(30)
+            assert not t.is_alive()
+        # a span the statement's own thread never closes
+        stray = trace_span("combine")
+        tr = rec.end(h)
+        assert open_span_count() == 0
+        assert tr.leaked == 2
+        for sp in left_open + [stray, tr.root]:
+            assert sp.t1 is not None and sp._ann is None
+        # sampled out or tracing off: no span, so no annotation
+        assert trace_span("plan") is not None and \
+            trace_span("plan").__enter__() is None
+
+    def test_session_edge_inside_a_statement_loses_only_that_one(
+            self, tmp_path):
+        import jax.profiler
+
+        from benchmark import xspans, xtrace
+        from citus_tpu.stats.tracing import TraceRecorder, trace_span
+
+        rec = TraceRecorder(None, None)
+
+        def statement(inside=None):
+            h = rec.begin("select 1")
+            with trace_span("execute"):
+                with trace_span("plan"):
+                    if inside is not None:
+                        inside()
+                with trace_span("combine"):
+                    pass
+            return rec.end(h)
+
+        log_dir = str(tmp_path / "profile")
+        a = statement(lambda: jax.profiler.start_trace(log_dir))
+        b = statement()
+        c = statement(jax.profiler.stop_trace)
+        d = statement()
+        host = xspans.read_events(xtrace.newest_xplane(log_dir))["host"]
+        trees = xspans.build_trees(host)
+        # b is whole; a's root began before the session and c's ended
+        # after it: neither is in the trace, and nothing else is lost
+        assert sorted(trees["statements"]) == [b.stmt_id]
+        assert _names(trees["statements"][b.stmt_id], lambda c: True) == [
+            "statement", [["execute", [["plan", []], ["combine", []]]]]]
+        for tr in (a, b, c, d):
+            doc = tr.to_dict()
+            assert doc["leaked"] == 0 and doc["spans"] == 4
+        assert [t.stmt_id for t in (a, b, c, d)] == [1, 2, 3, 4]
+        assert open_span_count() == 0
+
+    def test_stage_registry_covers_every_capacity_stage(self):
+        import ast
+
+        from citus_tpu.executor import compiler
+        from citus_tpu.stats.tracing import STAGE_NAMES, stage_scope
+
+        tree = ast.parse(open(compiler.__file__).read())
+        kinds = {n.args[1].value for n in ast.walk(tree)
+                 if isinstance(n, ast.Call)
+                 and isinstance(n.func, ast.Attribute)
+                 and n.func.attr == "_record" and len(n.args) >= 2
+                 and isinstance(n.args[1], ast.Constant)}
+        assert kinds >= {"scan_out", "repartition", "bucket_probe",
+                         "join_out", "agg_bucket", "agg_grid", "agg_out"}
+        assert kinds <= set(STAGE_NAMES)
+        with pytest.raises(KeyError):
+            stage_scope("not_a_stage")
+        import jax
+        import jax.numpy as jnp
+
+        def f(x):
+            with stage_scope("scan_out"):
+                return x * 2
+
+        text = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+        assert "ct.scan_out" in text
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_q3_program_carries_stage_scopes(self, tmp_path, n_devices):
+        import re
+
+        from citus_tpu.ingest.tpch import QUERIES, load_into_session
+
+        sess = citus_tpu.connect(data_dir=str(tmp_path / "d"),
+                                 n_devices=n_devices,
+                                 serving_result_cache_bytes=0)
+        load_into_session(sess, sf=0.002)
+        sess.execute(QUERIES["Q3"]).rows()
+        programs = [e[0].as_text()
+                    for e in sess.executor.plan_cache._entries.values()]
+        sess.close()
+        assert programs
+        glue = {"parameter", "constant", "broadcast", "iota", "tuple",
+                "get-tuple-element", "bitcast"}
+        for text in programs:
+            scopes = set(re.findall(r"ct\.(\w+)", text))
+            assert scopes & {"bucket_probe", "lookup_join"}
+            assert scopes >= {"agg_sort", "sort", "reduce", "topk",
+                              "scan_out", "join_out", "output_pack"}
+            if n_devices > 1:
+                # (`unpack` is reshapes, which compile to nothing)
+                assert scopes >= {"repartition", "pack", "exchange"}
+            # instructions traced from the program (their op_name is a
+            # path from the jit down), constants and the like aside
+            traced = [m.group(2) for m in re.finditer(
+                r'= \S+ ([\w\-]+)\(.*op_name="(jit\([^"]*)"', text)
+                if m.group(1) not in glue]
+            outside = [p for p in traced if "ct." not in p]
+            assert len(traced) > 100
+            assert len(outside) < 0.05 * len(traced), outside[:10]

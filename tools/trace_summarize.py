@@ -8,18 +8,47 @@ Given a data_dir, picks the NEWEST slow-query trace under
 Output: the statement, its wall clock, the per-phase attribution the
 EXPLAIN ANALYZE ``Timing:`` line shows (same phase names — both come
 from stats/tracing.phase_breakdown), and the N slowest individual
-spans with their tree paths — the "where did the time go" answer
-without opening chrome://tracing (``python -m
-citus_tpu.stats.trace_export`` renders the same trace there).
+spans with their tree paths.  For a timeline over the device's
+operations, capture a `jax.profiler` trace of the live session: the
+same spans are in it as ``ct:`` events (stats/tracing.py).
 """
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
     __file__)), ".."))
+
+
+def newest_slow_trace(data_dir: str) -> str | None:
+    from citus_tpu.stats.tracing import SLOW_TRACE_DIR
+
+    d = os.path.join(data_dir, SLOW_TRACE_DIR)
+    if not os.path.isdir(d):
+        return None
+    names = sorted(n for n in os.listdir(d)
+                   if n.startswith("trace_") and n.endswith(".json"))
+    return os.path.join(d, names[-1]) if names else None
+
+
+def load_trace(path: str) -> dict:
+    """`path` is a trace JSON file, a data_dir, or a slow_traces dir."""
+    from citus_tpu.stats.tracing import SLOW_TRACE_DIR
+
+    if os.path.isdir(path):
+        if os.path.basename(path) == SLOW_TRACE_DIR:
+            path = os.path.dirname(path)
+        p = newest_slow_trace(path)
+        if p is None:
+            raise FileNotFoundError(
+                f"no slow-query traces under {path!r} (is "
+                "trace_slow_statement_ms set low enough?)")
+        path = p
+    with open(path) as f:
+        return json.load(f)
 
 
 def summarize(doc: dict, top: int = 10) -> list[str]:
@@ -89,8 +118,6 @@ def main(argv: list[str] | None = None) -> int:
         print("usage: python tools/trace_summarize.py "
               "<data_dir | trace.json> [--top N]", file=sys.stderr)
         return 2
-    from citus_tpu.stats.trace_export import load_trace
-
     try:
         doc = load_trace(args[0])
     except (OSError, ValueError) as e:
