@@ -117,13 +117,17 @@ def main(regimes=None):
     return rows
 
 
-def _slope_time(fn, repeats=3, reps=8):
+def _slope_time_once(fn, repeats=3, reps=6):
     """Per-op device time for fn(i) -> int64 scalar, slope-timed (see
-    timeit: a single execution carries the dispatch round trip)."""
+    timeit: a single execution carries the dispatch round trip), with a
+    traced repeat count: ONE compile a variant (a 7.5 M-row sort
+    compiles for half a minute or more).  Returns (seconds an iteration,
+    seconds of the first call, fn(0))."""
     f = jax.jit(lambda r: jax.lax.fori_loop(
-        0, r, lambda i, acc: acc + fn(i), jnp.zeros((), jnp.int64)),
-        static_argnums=0)
-    jax.device_get(f(1))
+        0, r, lambda i, acc: acc + fn(i), jnp.zeros((), jnp.int64)))
+    t0 = time.perf_counter()
+    first = int(jax.device_get(f(1)))
+    compile_s = time.perf_counter() - t0
     jax.device_get(f(reps))
     t1 = tr = float("inf")
     for _ in range(repeats):
@@ -133,7 +137,131 @@ def _slope_time(fn, repeats=3, reps=8):
         t0 = time.perf_counter()
         jax.device_get(f(reps))
         tr = min(tr, time.perf_counter() - t0)
-    return max((tr - t1) / (reps - 1), 1e-9)
+    return max((tr - t1) / (reps - 1), 1e-9), compile_s, first
+
+
+def _slope_time(fn, repeats=3, reps=8):
+    return _slope_time_once(fn, repeats, reps)[0]
+
+
+def bench_lookup(mode="full", small=False, out="chiprun_out"):
+    """The two arms of a fused unique-key lookup join (PR 28): the
+    single gather from a dense directory (`dense_unique_lookup`) against
+    sort-and-scan (`sorted_unique_lookup`), over key extents from 2^18
+    to TPC-H SF1's 6.0 M order-key slots and over the shapes Q3 gives
+    the join on one chip (build 1.5 M, probe 6.0 M) and on four (375 k,
+    1.5 M).  The dense arm's time a probe row against the extent is the
+    knee `ops.join.SORTED_LOOKUP_MIN_EXTENT` is set from; the sorted
+    arm's is flat in the extent.  Both sides' keys move with the
+    iteration, so nothing is hoisted out of the timed loop: each
+    iteration builds its directory, or sorts its build side, as a
+    statement does.
+
+    mode `knee`: the dense arm alone below 2^18 slots under 1.5 M probe
+    rows, and the sorted arm once at that shape.  Mode `q3`: the sorted
+    arm alone at Q3's two shapes, and its parts.  Each mode writes
+    `<out>/bench_lookup_<mode>.json`.
+
+    Usage:  python bench_kernels.py lookup       (the chip)
+            python bench_kernels.py lookup knee  (the chip)
+            python bench_kernels.py lookup q3    (the chip)
+            python bench_kernels.py lookup small (a rehearsal anywhere)
+    """
+    import json
+    import os
+
+    from citus_tpu.runtime import ensure_jax_configured
+
+    ensure_jax_configured()
+    import citus_tpu.ops.join as J
+    dev = jax.devices()[0]
+    print(f"backend: {dev.platform} ({dev.device_kind})")
+    rng = np.random.default_rng(0)
+    base = 1
+
+    def inputs(extent, m, n):
+        bk0 = jnp.asarray(rng.permutation(extent)[:m].astype(np.int32))
+        pk0 = jnp.asarray(rng.integers(0, extent, n).astype(np.int32))
+        bm = jnp.asarray(rng.random(m) < 0.9)
+        return bk0, bm, pk0
+
+    def arm(name, extent, bk0, bm, pk0):
+        def run(i):
+            i = i.astype(jnp.int32)
+            bk = base + (bk0 + i) % extent
+            pk = base + (pk0 + 7 * i) % extent
+            if name == "dense":
+                b, c, o = J.dense_unique_lookup(bk, bm, pk, base, extent)
+            else:
+                b, c, o = J.sorted_unique_lookup(bk, bm, pk)
+            # every output is consumed, so nothing is dead code
+            return (jnp.where(c > 0, b, 0).sum(dtype=jnp.int64)
+                    + c.sum(dtype=jnp.int64) + o)
+        return run
+
+    scale = 64 if small else 1
+    q3_1 = (6_000_000 // scale, 1_500_032 // scale, 6_001_536 // scale)
+    q3_4 = (6_000_000 // scale, 375_168 // scale, 1_501_440 // scale)
+    cases = []
+    for e in (1 << 18, 1 << 19, 1 << 20, 1 << 21, 1 << 22):
+        e //= scale
+        cases.append((e, e // 4, e, ("dense",)))
+    cases.append(((1 << 18) // scale, (1 << 16) // scale,
+                  (1 << 18) // scale, ("sort",)))
+    cases.append(q3_1 + (("dense", "sort"),))
+    cases.append(q3_4 + (("dense", "sort"),))
+    # a large extent with a small probe side: where the sort of m+n rows
+    # stops paying against n gathers
+    cases.append((q3_1[0], q3_1[1], 65_536 // scale, ("dense", "sort")))
+    if mode == "knee":
+        n = 1_501_440 // scale
+        cases = [(e // scale, e // scale // 4, n, ("dense",))
+                 for e in (1 << 14, 1 << 15, 1 << 16, 1 << 17, 150_016,
+                           3 << 16, 1 << 18, 1 << 20)]
+        cases.append(((1 << 17) // scale, (1 << 15) // scale, n,
+                      ("sort",)))
+    elif mode == "q3":
+        cases = [q3_1 + (("sort",),), q3_4 + (("sort",),)]
+    rows = []
+    for extent, m, n, arms in cases:
+        bk0, bm, pk0 = inputs(extent, m, n)
+        want = None
+        for name in arms:
+            t, compile_s, got = _slope_time_once(
+                arm(name, extent, bk0, bm, pk0))
+            want = got if want is None else want
+            rows.append({"arm": name, "extent": extent, "build": m,
+                         "probe": n, "ms": t * 1e3,
+                         "ns_per_probe_row": t * 1e9 / n,
+                         "ns_per_row": t * 1e9 / (m + n),
+                         "compile_s": compile_s, "agrees": got == want})
+            print(json.dumps(rows[-1]), flush=True)
+    # the parts of the sorted arm at Q3's one-chip size
+    tot = 0 if mode == "knee" else q3_1[1] + q3_1[2]
+    k0 = jnp.asarray(rng.integers(0, 1 << 30, tot).astype(np.int32))
+    x0 = jnp.arange(tot, dtype=jnp.int32)
+    parts = {
+        "sort_2key_stable": lambda k: jax.lax.sort(
+            (k, x0), num_keys=2)[1],
+        "sort_2key": lambda k: jax.lax.sort(
+            (k, x0), num_keys=2, is_stable=False)[1],
+        "sort_1key": lambda k: jax.lax.sort(
+            (k, x0), num_keys=1, is_stable=False)[1],
+        "cumsum_cummax": lambda k: jnp.cumsum(k >> 8, dtype=jnp.int32)
+        + jax.lax.cummax(k),
+    }
+    for name, part in parts.items() if tot else ():
+        t, compile_s, _ = _slope_time_once(
+            lambda i: part(k0 ^ i.astype(jnp.int32))[tot // 2]
+            .astype(jnp.int64))
+        rows.append({"part": name, "rows": tot, "ms": t * 1e3,
+                     "ns_per_row": t * 1e9 / tot, "compile_s": compile_s})
+        print(json.dumps(rows[-1]), flush=True)
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, f"bench_lookup_{mode}.json"), "w") as f:
+        json.dump({"device": [dev.platform, dev.device_kind],
+                   "rows": rows}, f, indent=1)
+    return rows
 
 
 def bench_probe(regimes=None, repeats=3, reps=8):
@@ -141,8 +269,9 @@ def bench_probe(regimes=None, repeats=3, reps=8):
     the hash-bucketed, VMEM-tiled `bucketed_unique_lookup` in its XLA
     and Pallas formulations — the probe-path analogue of the
     segment-aggregation A/B above, and the measurement behind the
-    planner's `probe_bucket_eligible` threshold and the
-    `join_probe_kernel` config var.
+    `join_probe_kernel` config var.  (No plan picks the bucketed probe
+    since PR 28: `bench_lookup` below is the A/B of the two arms the
+    planner chooses between.)
 
     Prints a probes/s table across (extent, build_rows, probe_rows)
     regimes spanning the cache knee and a winner histogram.  Runs on any
@@ -422,6 +551,9 @@ if __name__ == "__main__":
 
     if len(sys.argv) > 1 and sys.argv[1] == "probe":
         bench_probe()
+    elif len(sys.argv) > 1 and sys.argv[1] == "lookup":
+        bench_lookup(next((a for a in sys.argv[2:] if a != "small"),
+                          "full"), small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "groupby":
         bench_groupby()
     else:

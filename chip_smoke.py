@@ -52,7 +52,8 @@ DML_ROWS = 1000
 # counters whose per-statement deltas say which paths actually ran
 _COUNTERS = ("capacity_retries", "retries_total", "oom_events_total",
              "queries_repartition", "queries_streamed",
-             "groupby_bucketed_total", "device_decoded_bytes_total",
+             "groupby_bucketed_total", "lookup_sorted_total",
+             "device_decoded_bytes_total",
              "shuffle_bytes_total", "queries_fast_path",
              "point_index_lookups", "exec_cache_hits_total",
              "exec_cache_misses_total", "exec_cache_rejects_total")
@@ -305,10 +306,12 @@ class Smoke:
     @staticmethod
     def programs(sess) -> dict:
         """What the executable cache holds for this data_dir: entry →
-        the capacity stages compiled into that program (`bucket_probe`
-        means the bucketed probe is in it, `agg_bucket` the bucketed
-        group-by).  EXPLAIN's tags come from row estimates; these come
-        from the programs that were built."""
+        the capacity stages compiled into that program (`agg_bucket`
+        means the bucketed group-by is in it).  EXPLAIN's tags come from
+        row estimates; these come from the programs that were built.  A
+        sort-and-scan lookup join has no capacity and so no stage here:
+        the `lookup_sorted_total` counter and EXPLAIN's `sorted lookup`
+        tag say that it ran."""
         from citus_tpu.utils.io import read_json_checked
 
         cache_dir = sess.executor.exec_cache.dir

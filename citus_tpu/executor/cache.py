@@ -64,6 +64,7 @@ def node_fingerprint(node: PlanNode) -> str:
                 f"{node.build_side};{node.left_key_extents};"
                 f"{node.right_key_extents};{node.key_int32};"
                 f"{node.fuse_lookup};{node.probe_bucketed};"
+                f"{node.lookup_sorted};"
                 f"{node.flag_combine};"
                 f"{node_fingerprint(node.left)};"
                 f"{node_fingerprint(node.right)};"

@@ -1038,7 +1038,7 @@ class PlanCompiler:
         stale: the surplus is reported as dense_oob so the host retries
         on the general expansion path (never silently dropped pairs)."""
         from ..ops.join import (_bounds, bucketed_unique_lookup,
-                                dense_unique_lookup)
+                                dense_unique_lookup, sorted_unique_lookup)
 
         if node.join_type == "inner" and \
                 getattr(node, "build_side", "right") == "left":
@@ -1052,7 +1052,15 @@ class PlanCompiler:
         dense = self._dense_for(extents, bkeys)
         bucket_cap = (self.caps.bucket_probe.get(id(node))
                       if getattr(node, "probe_bucketed", False) else None)
-        if dense is not None and len(bkeys) == 1 and bucket_cap is not None:
+        if self.sorted_lookup_shape(node, self.caps.dense_off):
+            # a key extent past the knee of the directory gather (the
+            # planner's pick, ops.join.sorted_lookup_eligible): no
+            # directory, so no capacity and nothing to retry but oob
+            with stage_scope("lookup_join"):
+                bidx, counts, dense_oob = sorted_unique_lookup(
+                    bkeys[0], bmatch, pkeys[0])
+                counts = jnp.where(pmatch, counts, 0)
+        elif dense is not None and len(bkeys) == 1 and bucket_cap is not None:
             # bucketed probe (the planner's size-threshold pick for
             # large directories): pack probes by VMEM-sized directory
             # tile, probe tile-locally — random HBM gathers become
@@ -1420,6 +1428,17 @@ class PlanCompiler:
             return True
         # auto: the planner's measurement-gated (TPU-only) pick
         return bool(getattr(node, "group_bucketed", False))
+
+    @staticmethod
+    def sorted_lookup_shape(node: JoinNode, dense_off: bool) -> bool:
+        """Single decision point for the sort-and-scan lookup arm: the
+        compiler's dispatch, capacity planning (no bucket buffer), the
+        lookup_sorted_total counter and EXPLAIN's tag agree because all
+        of them ask here.  The pick itself is the planner's
+        (`lookup_sorted`, from ops.join.sorted_lookup_eligible)."""
+        return bool(getattr(node, "lookup_sorted", False)
+                    and getattr(node, "fuse_lookup", False)
+                    and not dense_off)
 
     @staticmethod
     def agg_pushdown_shape(node: AggregateNode) -> bool:

@@ -215,7 +215,7 @@ class Executor:
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
         packed, out_meta, caps, retries, feeds = self._run_resident(
             plan, compute_dtype)
-        self.count_groupby_bucketed(plan, caps)
+        self.count_picks(plan, caps)
         with trace_span("combine"):
             cols, nulls, valid = unpack_outputs(packed, out_meta)
             result = self._host_combine(plan, cols, nulls, valid, raw)
@@ -284,12 +284,12 @@ class Executor:
         if streamed is not None:
             parts, scanned, retries, batches, caps = streamed
             if caps is not None:
-                self.count_groupby_bucketed(plan, caps)
+                self.count_picks(plan, caps)
             return parts, scanned, retries, batches
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
         packed, out_meta, caps, retries, _feeds = self._run_resident(
             plan, compute_dtype, no_cache_nodes=frozenset({split_nid}))
-        self.count_groupby_bucketed(plan, caps)
+        self.count_picks(plan, caps)
         cols, nulls, valid = unpack_outputs(packed, out_meta)
         scanned = int(np.asarray(valid).size)
         return [_flatten_batch(cols, nulls, valid)], scanned, retries, 0
@@ -754,25 +754,36 @@ class Executor:
         return evicted
 
     # ------------------------------------------------------------------
-    def count_groupby_bucketed(self, plan: QueryPlan,
-                               caps: Capacities) -> None:
-        """groupby_bucketed_total: bump once per executed STATEMENT
-        whose converged plan ran the bucketed dense-grid group-by —
-        callers invoke this after their retry loop settles (the
-        streamed path calls it once after the batch loop, not per
-        batch), and a dense_oob fallback onto the sort path
-        (caps.dense_off) correctly counts nothing."""
+    def count_picks(self, plan: QueryPlan, caps: Capacities) -> None:
+        """groupby_bucketed_total and lookup_sorted_total: each bumped
+        once per executed STATEMENT whose converged plan ran the
+        bucketed dense-grid group-by (by its number of such aggregates)
+        or at least one sort-and-scan lookup join — callers invoke this
+        after their retry loop settles (the streamed path calls it once
+        after the batch loop, not per batch), and a dense_oob fallback
+        onto the general paths (caps.dense_off) correctly counts
+        nothing."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
         group_kernel = self.settings.get("group_by_kernel")
-        nbk = sum(1 for nd in walk_plan(plan.root)
+        nodes = list(walk_plan(plan.root))
+        nbk = sum(1 for nd in nodes
                   if isinstance(nd, AggregateNode)
                   and PlanCompiler.agg_bucket_shape(
                       nd, group_kernel, caps.dense_off))
         if nbk:
             self.counters.increment(sc.GROUPBY_BUCKETED_TOTAL, nbk)
+        # a join under the aggregate pushdown is probed through _bounds
+        # and never fuses its lookup
+        pushed = {id(nd.input) for nd in nodes
+                  if isinstance(nd, AggregateNode)
+                  and PlanCompiler.agg_pushdown_shape(nd)}
+        if any(isinstance(nd, JoinNode) and id(nd) not in pushed
+               and PlanCompiler.sorted_lookup_shape(nd, caps.dense_off)
+               for nd in nodes):
+            self.counters.increment(sc.LOOKUP_SORTED_TOTAL)
 
     # ------------------------------------------------------------------
     CAPS_MEMO_VERSION = 6  # bump when capacity semantics change

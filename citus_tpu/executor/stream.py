@@ -680,7 +680,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
     if caps is not None:
         # once per STATEMENT, after the batch loop (run_with_retry runs
         # per batch and must not inflate the statement-level counter)
-        executor.count_groupby_bucketed(plan, caps)
+        executor.count_picks(plan, caps)
     return result
 
 

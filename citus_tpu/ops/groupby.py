@@ -64,8 +64,7 @@ def group_bucket_eligible(total: int, rows: int) -> bool:
     space must be small enough to materialize as a result grid AND the
     input dense enough to amortize reducing every tile (a sparse
     group-by over a huge key space would stream mostly-empty tiles —
-    the sort path stays cheaper there).  Mirrors the shape of
-    ops.join.probe_bucket_eligible."""
+    the sort path stays cheaper there)."""
     return total <= GROUP_BUCKET_MAX_SLOTS and rows * 4 >= total
 
 

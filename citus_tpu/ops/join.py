@@ -1,4 +1,5 @@
-"""Equi-join kernels: dense-directory lookup + sorted binary-search join.
+"""Equi-join kernels: dense-directory lookup, sort-and-scan lookup,
+sorted binary-search join.
 
 TPU-native replacement for the reference's hash build/probe executed per
 shard on workers (co-located pushdown joins,
@@ -7,18 +8,25 @@ repartition merge tasks, multi_physical_planner.c BuildMapMergeJob): no
 pointer-chasing hash tables — the build side is arranged once (sort or
 counting-sort) and probes resolve to a contiguous run of matches.
 
-Two probe paths, chosen at trace time:
+Probe paths, chosen at trace time:
 
-* **Dense directory** (the TPU fast path): when the build key's value
-  range [base, base+extent) is known from table statistics (manifest
-  min/max — exact for committed data), a counting-sort directory
-  `starts[extent+1]` maps each key value straight to its sorted run.
-  Probing is TWO O(1) gathers instead of 2·log2(M) serial gather steps —
-  on a v5e this turns a 6.5 s binary-search phase into ~100 ms.  Build
-  rows outside the declared range (stale stats / uncommitted overlay
-  rows) are counted into a separate `dense_oob` overflow output; the host
-  retries with the directory disabled, so stale statistics cost one
-  recompile, never wrong answers.
+* **Dense directory**: when the build key's value range
+  [base, base+extent) is known from table statistics (manifest min/max —
+  exact for committed data), a counting-sort directory `starts[extent+1]`
+  maps each key value straight to its sorted run.  Probing is TWO O(1)
+  gathers instead of 2·log2(M) serial gather steps: a gather costs
+  6.7–8.3 ns an element on a v5e whatever the directory's size, and the
+  binary search's 38 took 418 ms for 1.5 M probe rows in tpch4.q3 (my
+  chip run, PR 28; ledger, PR 27).  Build rows outside the declared
+  range (stale stats / uncommitted overlay rows) are counted into a
+  separate `dense_oob` overflow output; the host retries with the
+  directory disabled, so stale statistics cost one recompile, never
+  wrong answers.
+
+* **Sort and scan** (`sorted_unique_lookup`): a fused lookup against a
+  unique single-column key over an extent of SORTED_LOOKUP_MIN_EXTENT
+  slots or more gathers nothing — the chip sorts a row in about 2 ns
+  and scans one in under 1.
 
 * **Lexicographic binary search** (general path): multi-column or
   unbounded keys fall back to an exact vectorized binary search.  The
@@ -47,19 +55,28 @@ from ..stats.tracing import stage_scope
 # the build side (sparse 64-bit keys fall back to binary search)
 DENSE_MAX_SLOTS = 1 << 26
 
-# bucketed probe path: directory slots per bucket tile.  An int32 tile of
-# 2^15 slots is 128 KB — VMEM-resident with pipelining headroom on a
-# 16 MB/core budget, and small enough that a probe stream sorted by
-# bucket turns the random directory gather into sequential tile traffic.
+# bucketed probe path: directory slots per bucket tile (an int32 tile of
+# 2^15 slots is 128 KB).  The planner no longer picks this path: packing
+# the probe rows by tile cost 385.9 ms of tpch1.q3's statement, the
+# tile-local probe 114.8 and the scatter back 52.8 (my chip run, PR 27)
 PROBE_TILE_SLOTS = 1 << 15
-# below this extent the whole directory is cache-sized and the single
-# random gather is already bandwidth-friendly; the bucketed path's
-# pack (one int32 argsort over the probe side) would cost more than the
-# locality it buys.  Threshold = the measured knee where dense_unique_
-# lookup's probe throughput collapses (~16 MB of directory, PERF_NOTES
-# round-5 table: random gathers over 60M entries run ~300× below
-# roofline while small directories ride the caches).
-PROBE_BUCKET_MIN_EXTENT = 1 << 22
+
+# From this key extent up (slots) a fused lookup sorts and scans
+# (sorted_unique_lookup) instead of gathering from a directory.  The chip
+# showed no knee to put it at: a gather or scatter costs 6.7–8.3 ns an
+# element whether the directory holds 2^14 slots (64 KB) or 6.0 M
+# (24 MB), and the sorted arm 3.6–6.2 ns a row of both sides, rising
+# with their number and flat in the extent; it won every pairing
+# measured — 1.17 against 2.68 ms at 2^18 slots (build 65 k, probe
+# 262 k), 46.2 against 61.9 at TPC-H SF1's 6.0 M (1.5 M, 6.0 M), 9.9
+# against 21.9 with a 65 k-row probe side there, 6.9 against 10.5 at
+# 2^17 slots under 1.5 M probe rows — and that with stable sorts; as it
+# stands it takes 29.6 ms at SF1's shape (my chip run, PR 28; `python
+# bench_kernels.py lookup`, `lookup knee`, `lookup q3`; PERF.md §6).  So
+# the constant is the smallest extent at which both arms were timed at
+# a join's own proportions; under it, where the sides are small and
+# either arm takes well under a millisecond, the one gather stays
+SORTED_LOOKUP_MIN_EXTENT = 1 << 18
 
 
 def probe_bucket_count(extent: int) -> int:
@@ -67,13 +84,15 @@ def probe_bucket_count(extent: int) -> int:
     return max(1, -(-extent // PROBE_TILE_SLOTS))
 
 
-def probe_bucket_eligible(extent: int, probe_rows: int) -> bool:
-    """Planner cost threshold for the bucketed probe path: the directory
-    must be past the cache knee AND the probe stream must be dense enough
-    to amortize streaming every tile once (a sparse probe over a huge
-    directory still favors the single gather — most tiles would stream
-    in for a handful of probes)."""
-    return extent >= PROBE_BUCKET_MIN_EXTENT and probe_rows * 4 >= extent
+def sorted_lookup_eligible(extent: int) -> bool:
+    """The pick between the two arms of a fused single-key lookup join,
+    from the build key's extent: True sorts and scans, False gathers
+    from the dense directory.  The two sides' rows are no part of it:
+    at a fixed extent no shape measured turned the pick
+    (SORTED_LOOKUP_MIN_EXTENT).  Asked by the planner once a join;
+    EXPLAIN, the compiler, the plan fingerprint and the counter read
+    its answer from the plan (`lookup_sorted`)."""
+    return extent >= SORTED_LOOKUP_MIN_EXTENT
 
 
 def dense_directory_ok(extent: int, build_size: int) -> bool:
@@ -254,10 +273,10 @@ def dense_unique_lookup(build_key: jnp.ndarray,
     queries at SF1 on real TPUs).
 
     Returns (bidx [N], counts [N], oob_count).  Probing costs ONE gather
-    per probe row: random HBM gathers are the measured wall of this path
-    (~80M probes/s on v5e — 2 gathers over a 60M-entry directory put
-    TPC-H Q3's SF10 probe stage at 1 s alone), so per-probe match counts
-    come from the directory hit itself (0/1) rather than a second
+    per probe row: gathers are the cost of this path (10.2 ns a probe
+    row with a build side a quarter of the probe side, at any extent
+    from 2^18 to 6.0 M slots; my chip run, PR 28), so per-probe match
+    counts come from the directory hit itself (0/1) rather than a second
     per_slot gather.  Duplicate build keys — the stale-uniqueness case —
     are detected BUILD-side: scatter-then-gather-back over the m build
     rows; overwritten rows read back a different index.  dups feed oob
@@ -280,6 +299,81 @@ def dense_unique_lookup(build_key: jnp.ndarray,
     return bidx, counts, oob + dup
 
 
+def sorted_unique_lookup(build_key: jnp.ndarray,
+                         build_matchable: jnp.ndarray,
+                         probe_key: jnp.ndarray,
+                         ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Gather-free lookup for a UNIQUE-keyed build side: sort the two
+    sides' keys together, carry each build row's index forward to the
+    probe rows that follow it, and put the result back in probe order.
+
+    Returns (bidx [N], counts [N], oob_count) under dense_unique_lookup's
+    contract: bidx indexes the ORIGINAL build arrays in ORIGINAL probe
+    order, counts is 0/1, a non-matchable build row matches nothing, and
+    duplicate matchable build keys (stale uniqueness) are counted into
+    oob so the caller's retry on the general path still fires.  There is
+    no directory, so no base, no extent and nothing out of range.
+
+      1. the build side alone by (key, p), m rows: p is a row's 1-based
+         position, negated when the row is not matchable, so a key's
+         matchable row is the last of its run.  Neighbours in that order
+         give every row the DIFFERENCE of its p to its predecessor's,
+         and the matchable duplicates;
+      2. one sort of the m+n keys, second operand `x`: a build row's
+         difference shifted below zero (build rows precede the probe
+         rows of their key), a probe row's position;
+      3. two scans in sorted order: the running sum of the differences
+         is the p of the last build row — whatever order the sort gave
+         a run's build rows, past the run the sum has telescoped to its
+         last row's p — and the running max of the build rows' keys is
+         that run's key.  A probe row found its match when that p is
+         positive (matchable) and that key is its own.  Nothing is
+         gathered by a scan's result;
+      4. back to probe order by a second sort on `x` and a static
+         slice.  (One unique-index scatter in its place made the whole
+         lookup 74.9 ms where this made it 46.2, at build 1.5 M and
+         probe 6.0 M rows and with stable sorts; my chip run, PR 28.)"""
+    m = build_key.shape[0]
+    n = probe_key.shape[0]
+    if m == 0 or n == 0:
+        return (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
+                jnp.zeros((), jnp.int64))
+    with stage_scope("sort"):
+        p = jnp.arange(1, m + 1, dtype=jnp.int32)
+        # (no sort here needs to be stable — ties are duplicates, which
+        # go to oob — and a stable one sorts a third operand, an iota)
+        bkey, p = jax.lax.sort(
+            (build_key, jnp.where(build_matchable, p, -p)), num_keys=2,
+            is_stable=False)
+        dup = ((p[1:] > 0) & (p[:-1] > 0)
+               & (bkey[1:] == bkey[:-1])).sum().astype(jnp.int64)
+        step = p - jnp.concatenate([jnp.zeros(1, jnp.int32), p[:-1]])
+        shift = 2 * m + 1  # |step| <= 2m
+        skey, sx = jax.lax.sort(
+            (jnp.concatenate([bkey, probe_key.astype(bkey.dtype)]),
+             jnp.concatenate([step - shift,
+                              jnp.arange(n, dtype=jnp.int32)])),
+            num_keys=2, is_stable=False)
+    with stage_scope("carry"):
+        is_build = sx < 0
+        last_p = jnp.cumsum(jnp.where(is_build, sx + shift, 0),
+                            dtype=jnp.int32)
+        mark = skey
+        if mark.dtype.itemsize > 4:
+            # 64-bit scans are emulated on the chip: scan the number of
+            # the key's run instead, which is as monotone as the key
+            mark = jnp.cumsum(jnp.concatenate(
+                [jnp.zeros(1, jnp.bool_), skey[1:] != skey[:-1]]),
+                dtype=jnp.int32)
+        last_key = jax.lax.cummax(
+            jnp.where(is_build, mark, jnp.iinfo(mark.dtype).min))
+        hit = jnp.where(~is_build & (last_p > 0) & (last_key == mark),
+                        last_p, 0)
+    with stage_scope("sort"):
+        hit = jax.lax.sort((sx, hit), num_keys=1, is_stable=False)[1][m:]
+    return jnp.maximum(hit - 1, 0), (hit > 0).astype(jnp.int32), dup
+
+
 def bucketed_unique_lookup(build_key: jnp.ndarray,
                            build_matchable: jnp.ndarray,
                            probe_key: jnp.ndarray, base: int, extent: int,
@@ -288,17 +382,19 @@ def bucketed_unique_lookup(build_key: jnp.ndarray,
                            ) -> tuple[jnp.ndarray, jnp.ndarray,
                                       jnp.ndarray, jnp.ndarray,
                                       jnp.ndarray]:
-    """Hash-bucketed, VMEM-tiled variant of dense_unique_lookup.
+    """Hash-bucketed, VMEM-tiled variant of dense_unique_lookup.  No
+    plan picks it since PR 28: in XLA's form the locality it packs for
+    does not show — its tile-local gathers cost what any gather costs,
+    and at TPC-H SF1 it took 553.6 ms (pack 385.9, probe 114.8, scatter
+    back 52.8; my chip run, PR 27) where dense_unique_lookup takes 61.9
+    and sorted_unique_lookup 29.6 (my chip run, PR 28).
 
-    The single-gather probe is latency-bound: random HBM touches over a
-    multi-hundred-MB directory run ~300× below the memory roofline
-    (~80M probes/s measured on v5e at SF10 sizes — PERF_NOTES).  This
-    path restores locality the radix-join way (Theseus, arXiv
-    2508.05029; shared-nothing multicore joins, arXiv 1804.09324;
-    reference repartition machinery, multi_physical_planner.c
-    BuildMapMergeJob): partition the probe stream by directory tile
-    until each tile fits fast memory, then probe tile-by-tile so the
-    directory streams through VMEM exactly once.
+    The radix-join way (Theseus, arXiv 2508.05029; shared-nothing
+    multicore joins, arXiv 1804.09324; reference repartition machinery,
+    multi_physical_planner.c BuildMapMergeJob): partition the probe
+    stream by directory tile until each tile fits fast memory, then
+    probe tile-by-tile so the directory streams through VMEM exactly
+    once.
 
       1. build the dense directory as usual (one scatter; duplicate
          build keys detected build-side exactly like dense_unique_lookup

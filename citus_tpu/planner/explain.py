@@ -40,6 +40,8 @@ EXPLAIN_TAGS: dict[str, str] = {
     "point index lookup": "scan answered by the persistent PK index",
     "dense directory": "join build side is a dense key directory",
     "fused lookup": "PK-lookup join fused into the probe gather",
+    "sorted lookup": "fused lookup by sort and scan, no directory "
+                     "(key extent past the directory gather's knee)",
     "bucketed probe": "VMEM-tiled hash-bucketed probe path",
     "bucketed group-by": "dense-grid bucketed aggregation path",
     "Chunks Skipped": "chunk groups pruned by min/max skip nodes",
@@ -157,14 +159,17 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
             label = f"{node.join_type.capitalize()} Outer {label}"
         conds = ", ".join(f"{l} = {r}" for l, r in
                           zip(node.left_keys, node.right_keys))
+        from ..executor.compiler import PlanCompiler
         from ..ops.join import dense_directory_ok
 
         build = node.left if node.build_side == "left" else node.right
         ext = (node.left_key_extents if node.build_side == "left"
                else node.right_key_extents)
+        # the compiler's own decision point: this arm has no directory
+        sorted_lookup = PlanCompiler.sorted_lookup_shape(node, False)
         # same predicate the executor applies (est_rows stands in for the
         # padded build capacity)
-        dense = (bool(ext) and ext[0] is not None
+        dense = (not sorted_lookup and bool(ext) and ext[0] is not None
                  and len(node.left_keys) == 1
                  and dense_directory_ok(ext[0][1], build.est_rows))
         bucketed = dense and node.fuse_lookup and node.probe_bucketed
@@ -173,6 +178,8 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
             mods.append(explain_tag("dense directory"))
         if node.fuse_lookup:
             mods.append(explain_tag("fused lookup"))
+        if sorted_lookup:
+            mods.append(explain_tag("sorted lookup"))
         if bucketed:
             mods.append(explain_tag("bucketed probe"))
         lines.append(f"{pad}-> {label} on ({conds})  "

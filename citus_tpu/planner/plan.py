@@ -130,14 +130,18 @@ class JoinNode(PlanNode):
     # A runtime duplicate (stale stats) surfaces as dense_oob and retries
     # on the general expansion path
     fuse_lookup: bool = False
-    # fused-lookup probe strategy: True routes the probe through the
-    # hash-bucketed, VMEM-tiled path (ops.join.bucketed_unique_lookup)
-    # instead of the single random directory gather.  Chosen by the
-    # size-threshold rule probe_bucket_eligible — large directories are
-    # latency-bound under random gathers (~80M probes/s measured at
-    # SF10, ~300× below roofline; PERF_NOTES round 5/6), small ones ride
-    # the caches and keep the single gather.  Per-bucket probe capacity
-    # is a static buffer with the usual overflow-retry + feedback.
+    # fused-lookup arm over a large key extent: True finds each probe
+    # row's build row by sort and scan (ops.join.sorted_unique_lookup)
+    # instead of a gather from a directory of the extent's size.  Picked
+    # by ops.join.sorted_lookup_eligible from the extent; EXPLAIN, the
+    # compiler, the plan fingerprint and the lookup_sorted_total counter
+    # all read this flag
+    lookup_sorted: bool = False
+    # the hash-bucketed, tiled probe (ops.join.bucketed_unique_lookup).
+    # The planner no longer picks it: its pack alone cost 385.9 ms of
+    # tpch1.q3's 621 ms statement, three times the probe it served (my
+    # chip run, PR 27).  Kept for its tests until ROADMAP D3/D4's pair
+    # of PRs removes it with its stage name
     probe_bucketed: bool = False
 
 
@@ -1007,24 +1011,13 @@ class DistributedPlanner:
             build_uniq = (uniq_l if node.build_side == "left" else uniq_r)
             node.fuse_lookup = (build_uniq and node.join_type
                                 in ("inner", "left"))
-        if node.fuse_lookup:
-            import jax
-
-            from ..ops.join import probe_bucket_eligible
+        if node.fuse_lookup and len(node.left_keys) == 1:
+            from ..ops.join import sorted_lookup_eligible
 
             ext = (node.left_key_extents if node.build_side == "left"
                    else node.right_key_extents)
-            probe = (node.right if node.build_side == "left"
-                     else node.left)
-            if ext and ext[0] is not None and \
-                    jax.default_backend() == "tpu":
-                # TPU-only pick: the bucketed pack spends an argsort to
-                # buy gather locality — a win where random HBM gathers
-                # run ~300× below roofline (TPU), a large loss where
-                # sorts are the slow op and gathers ride caches
-                # (XLA:CPU — bench_kernels.bench_probe table)
-                node.probe_bucketed = probe_bucket_eligible(
-                    int(ext[0][1]), probe.est_rows)
+            if ext[0] is not None:
+                node.lookup_sorted = sorted_lookup_eligible(int(ext[0][1]))
         if node.fuse_lookup and node.join_type == "inner":
             # PK-side build: P(probe row matches) ≈ surviving build
             # fraction — the FK-join selectivity the generic estimate

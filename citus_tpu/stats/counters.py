@@ -44,6 +44,10 @@ DEVICE_DECODED_BYTES_TOTAL = "device_decoded_bytes_total"
 # statements whose plan executed the bucketed dense-grid group-by
 # (ops/groupby.py) instead of the sort path
 GROUPBY_BUCKETED_TOTAL = "groupby_bucketed_total"
+# statements dispatched with at least one fused lookup join on the
+# sort-and-scan arm (ops.join.sorted_unique_lookup: a key extent past
+# the knee of the directory gather)
+LOOKUP_SORTED_TOTAL = "lookup_sorted_total"
 # static all_to_all shuffle buffer volume the executed plans moved over
 # the mesh (per-device capacity × devices² × row width, summed over the
 # plan's repartition stages and every stream batch) — the EXPLAIN
@@ -121,6 +125,7 @@ ALL_COUNTERS = [
     CAPACITY_RETRIES, DEVICE_ROWS_SCANNED,
     INSERT_SELECT_PUSHDOWN, INSERT_SELECT_REPARTITION, INSERT_SELECT_PULL,
     CHUNKS_SKIPPED, QUERIES_STREAMED, GROUPBY_BUCKETED_TOTAL,
+    LOOKUP_SORTED_TOTAL,
     SHUFFLE_BYTES_TOTAL,
     CHUNKS_PREFETCHED_TOTAL, PREFETCH_STALLS_TOTAL,
     DEVICE_DECODED_BYTES_TOTAL,

@@ -190,15 +190,19 @@ STAGE_NAMES: dict[str, str] = {
     "bucket_probe": "lookup join through the bucketed (tiled) probe",
     "probe": "sub: bucket_probe — tile-local directory build + probe",
     "scatter_back": "sub: bucket_probe — results back to probe order",
-    "lookup_join": "lookup join through one dense directory (or the "
-                   "sorted-bounds fallback) and match counting",
+    "lookup_join": "lookup join: dense directory, or sort-and-scan over "
+                   "a large extent (or the sorted-bounds fallback), and "
+                   "match counting",
+    "carry": "sub: lookup_join — the scans that carry each build row's "
+             "index forward to its probe rows",
     "join_out": "join keys, pair emission / build-column gathers, "
                 "residual filter, compaction",
     "agg_grid": "dense-grid group-by + psum combine",
     "agg_bucket": "bucketed dense-grid group-by",
     "agg_sort": "sort-path group-by (both levels of a repartition "
                 "combine)",
-    "sort": "sub: agg_sort — the key sort",
+    "sort": "sub: agg_sort, lookup_join — the key sort (lookup_join: "
+            "build side, both sides together, back to probe order)",
     "reduce": "sub: agg_sort — boundaries + segment reductions",
     "agg_out": "group slots cut to the planned capacity",
     "agg_global": "no GROUP BY: per-device reduce + psum/pmin/pmax "

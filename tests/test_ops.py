@@ -298,6 +298,95 @@ class TestBlock:
         assert b.column("x").dtype == jnp.float32
 
 
+class TestSortedUniqueLookup:
+    """Sort-and-scan lookup (ops.join.sorted_unique_lookup) vs the
+    single-gather dense_unique_lookup and a dict oracle: the contract
+    is dense_unique_lookup's, without a base or an extent."""
+
+    def _lookup(self, bk, bmatch, pk):
+        from citus_tpu.ops.join import sorted_unique_lookup
+
+        return tuple(np.asarray(x) for x in sorted_unique_lookup(
+            jnp.asarray(bk), jnp.asarray(bmatch), jnp.asarray(pk)))
+
+    def _inputs(self, rng, dtype, base=1000, extent=1000, m=600, n=5000):
+        bk = (base + rng.permutation(extent)[:m]).astype(dtype)
+        bmatch = rng.random(m) > 0.1
+        # a non-matchable build row may carry a matchable row's key
+        # (padding rows do): it must match nothing and count no duplicate
+        dead = np.flatnonzero(~bmatch)
+        bk[dead[::2]] = rng.choice(bk[bmatch], len(dead[::2]))
+        pk = rng.integers(base - 100, base + extent + 100, n).astype(dtype)
+        return bk, bmatch, pk
+
+    @pytest.mark.parametrize("dtype", [np.int32, np.int64])
+    def test_matches_dense_lookup_and_oracle(self, rng, dtype):
+        from citus_tpu.ops.join import dense_unique_lookup
+
+        base, extent = 1000, 1000
+        bk, bmatch, pk = self._inputs(rng, dtype, base, extent)
+        bidx, counts, oob = self._lookup(bk, bmatch, pk)
+        assert int(oob) == 0
+        assert bidx.shape == counts.shape == pk.shape
+        assert bidx.min() >= 0 and bidx.max() < len(bk)
+        live = np.flatnonzero(bmatch)
+        dbidx, dcounts, _ = (np.asarray(x) for x in dense_unique_lookup(
+            jnp.asarray(bk[live]), jnp.ones(len(live), bool),
+            jnp.asarray(pk), base, extent))
+        np.testing.assert_array_equal(counts, dcounts)
+        np.testing.assert_array_equal(bidx[counts > 0],
+                                      live[dbidx[dcounts > 0]])
+        # dict oracle, row by row: bidx is in ORIGINAL probe order and
+        # indexes the ORIGINAL build arrays
+        table = {int(k): i for i, k in enumerate(bk) if bmatch[i]}
+        for i in range(len(pk)):
+            hit = int(pk[i]) in table
+            assert bool(counts[i]) == hit
+            if hit:
+                assert int(bidx[i]) == table[int(pk[i])]
+
+    @pytest.mark.parametrize("dtype", [np.int32, np.int64])
+    def test_keys_at_the_ends_of_the_type(self, dtype):
+        # the scan's "no build row yet" must not be taken for the
+        # smallest key, nor the largest key for a sentinel
+        lo, hi = np.iinfo(dtype).min, np.iinfo(dtype).max
+        bk = np.array([hi, 5, lo + 1], dtype)
+        pk = np.array([lo, lo + 1, 5, hi, hi - 1, lo], dtype)
+        bidx, counts, oob = self._lookup(bk, np.ones(3, bool), pk)
+        assert counts.tolist() == [0, 1, 1, 1, 0, 0]
+        assert bidx[counts > 0].tolist() == [2, 1, 0]
+        assert int(oob) == 0
+        bidx, counts, _ = self._lookup(
+            np.array([lo], dtype), np.ones(1, bool), pk)
+        assert counts.tolist() == [1, 0, 0, 0, 0, 1]
+
+    @pytest.mark.parametrize("m,n,live", [(0, 7, 0), (5, 0, 5), (5, 7, 0)])
+    def test_empty_sides(self, m, n, live):
+        # zero-length arrays, and a build side with no matchable row
+        bk = np.arange(m, dtype=np.int64)
+        bidx, counts, oob = self._lookup(
+            bk, np.arange(m) < live, np.arange(n, dtype=np.int64))
+        assert bidx.shape == counts.shape == (n,)
+        assert int(counts.sum()) == 0 and int(oob) == 0
+        assert (bidx == 0).all()
+
+    @pytest.mark.parametrize("dtype", [np.int32, np.int64])
+    def test_duplicate_build_keys_counted_as_oob(self, dtype):
+        # stale uniqueness claim: duplicates among MATCHABLE rows must
+        # surface through oob (the host retries on the general
+        # expansion path — never a silent arbitrary winner); a
+        # non-matchable twin is no duplicate
+        bk = np.array([1, 2, 2, 3, 900, 2, 3], dtype)
+        bmatch = np.array([True, True, True, True, True, True, False])
+        pk = np.arange(1, 5).astype(dtype)
+        _, _, oob = self._lookup(bk, bmatch, pk)
+        assert int(oob) == 2
+        _, counts, oob = self._lookup(
+            bk, np.array([True, True, False, True, True, False, False]),
+            pk)
+        assert int(oob) == 0 and counts.tolist() == [1, 1, 1, 0]
+
+
 class TestBucketedUniqueLookup:
     """VMEM-tiled bucketed probe (ops.join.bucketed_unique_lookup) vs
     the single-gather dense_unique_lookup and a dict oracle.  The tile

@@ -608,6 +608,166 @@ def test_bucketed_probe_skew_overflow_regrows(sess, monkeypatch):
     assert sorted(tuple(r) for r in result.rows()) == expect
 
 
+def _spy_lookup_arms(monkeypatch):
+    """Count the traces of the two arms of a fused single-key lookup
+    (the compiler imports them from ops.join as it traces)."""
+    import citus_tpu.ops.join as J
+
+    calls = {"sorted": 0, "dense": 0}
+
+    def spy(name, orig):
+        def wrapped(*a, **kw):
+            calls[name] += 1
+            return orig(*a, **kw)
+        return wrapped
+
+    monkeypatch.setattr(J, "sorted_unique_lookup",
+                        spy("sorted", J.sorted_unique_lookup))
+    monkeypatch.setattr(J, "dense_unique_lookup",
+                        spy("dense", J.dense_unique_lookup))
+    return calls
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_q3_sorted_lookup_matches_oracle(tmp_path, monkeypatch, n_devices):
+    """Q3 with its first join (lineitem into orders' 12,000-slot key
+    extent at this scale) on the sort-and-scan arm and its second
+    (orders into customer's 300 slots) on the dense directory, as at
+    SF1 on the chip: the knee is lowered to between the two, nothing
+    else is set.  Held to the sqlite oracle on one device and on a
+    four-device mesh."""
+    import citus_tpu.ops.join as J
+    from citus_tpu.ingest import tpch
+    from citus_tpu.stats import counters as sc
+    from oracle import compare_results, make_oracle, run_oracle
+
+    monkeypatch.setattr(J, "SORTED_LOOKUP_MIN_EXTENT", 4096)
+    calls = _spy_lookup_arms(monkeypatch)
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "q3"),
+                             n_devices=n_devices, compute_dtype="float64",
+                             serving_result_cache_bytes=0)
+    try:
+        tpch.load_into_session(sess, sf=0.002, seed=7)
+        conn = make_oracle(tpch.generate_tables(0.002, seed=7),
+                           {"orders": ["o_orderdate"],
+                            "lineitem": ["l_shipdate", "l_commitdate",
+                                         "l_receiptdate"]})
+        sql = tpch.QUERIES["Q3"]
+        joins = [r[0] for r in sess.execute("explain " + sql).rows()
+                 if "[build: " in r[0]]
+        assert ["sorted lookup" in ln for ln in joins] == [False, True]
+        assert ["dense directory" in ln for ln in joins] == [True, False]
+        result = sess.execute(sql)
+        assert result.retries == 0  # no capacity, nothing to regrow
+        compare_results(result.rows(), run_oracle(conn, sql), True, 1e-6)
+        assert calls["sorted"] >= 1 and calls["dense"] >= 1
+        # the arm's operations carry the stage the benchmark reads the
+        # join by, and the bucketed probe is in no program
+        for entry in sess.executor.plan_cache._entries.values():
+            text = entry[0].as_text()
+            assert "ct.lookup_join/ct.sort" in text
+            assert "ct.lookup_join/ct.carry" in text
+            assert "ct.bucket_probe" not in text
+        counters = sess.stats.counters.snapshot()
+        # once a statement, however many of its joins sort
+        assert counters[sc.LOOKUP_SORTED_TOTAL] == 1
+        sess.execute(sql)
+        assert sess.stats.counters.snapshot()[
+            sc.LOOKUP_SORTED_TOTAL] == 2
+    finally:
+        sess.close()
+
+
+def test_sorted_lookup_duplicate_build_keys_fallback(sess):
+    """Stale uniqueness under the sort-and-scan arm: duplicate build
+    keys must surface dense_oob and retry on the general expansion
+    path, exactly like dense_unique_lookup — never an arbitrary single
+    match."""
+    from citus_tpu.executor.feed import walk_plan
+    from citus_tpu.planner.plan import JoinNode, ScanNode
+    from citus_tpu.sql.parser import parse_one
+    from citus_tpu.stats import counters as sc
+
+    sess.execute("create table sda (k bigint, v int)")
+    sess.create_distributed_table("sda", "k", shard_count=4)
+    sess.execute("create table sdb (k bigint, w int)")
+    sess.create_distributed_table("sdb", "k", shard_count=4)
+    sess.execute("insert into sda values (1,10),(2,20),(3,30)")
+    # build side duplicates k=2: the correct result needs BOTH matches
+    sess.execute("insert into sdb values (1,1),(2,2),(2,5),(3,3)")
+    plan, _cleanup = sess._plan_select(parse_one(
+        "select v, w from sda, sdb where sda.k = sdb.k"))
+    for node in walk_plan(plan.root):
+        if isinstance(node, JoinNode):
+            left_is_build = isinstance(node.left, ScanNode) and \
+                node.left.rel.table == "sdb"
+            node.fuse_lookup = True
+            node.lookup_sorted = True
+            node.build_side = "left" if left_is_build else "right"
+    result = sess.executor.execute_plan(plan)
+    assert result.retries >= 1
+    assert sorted(tuple(r) for r in result.rows()) == \
+        [(10, 1), (20, 2), (20, 5), (30, 3)]
+    # the converged execution ran the general path: not counted
+    assert sess.stats.counters.snapshot()[sc.LOOKUP_SORTED_TOTAL] == 0
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_lookup_arm_follows_key_extent(tmp_path, monkeypatch, n_devices):
+    """The pick between the dense directory and the sort-and-scan arm
+    reads the build key's extent, at one device and at four alike, and
+    EXPLAIN's tag is the arm the compiler traces."""
+    import citus_tpu.ops.join as J
+
+    knee = 10_000
+    monkeypatch.setattr(J, "SORTED_LOOKUP_MIN_EXTENT", knee)
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "d"),
+                             n_devices=n_devices, compute_dtype="float64",
+                             serving_result_cache_bytes=0)
+    try:
+        sess.execute("create table fact (k bigint, k50 bigint, "
+                     "k51 bigint, w int)")
+        sess.create_distributed_table("fact", "k", shard_count=4)
+        for name, stride in (("near", 1), ("edge", 50), ("far", 51)):
+            # 200 unique keys, extent 199·stride + 1: 200, 9,951, 10,150
+            sess.execute(f"create table {name} (k bigint, v int)")
+            sess.create_distributed_table(name, "k", shard_count=4)
+            sess.execute(f"insert into {name} values " + ",".join(
+                f"({k * stride},{k})" for k in range(1, 201)))
+        sess.execute("insert into fact values " + ",".join(
+            f"({k},{k * 50},{k * 51},{i})"
+            for i, k in ((i, i % 250 + 1) for i in range(400))))
+        for name, stride, col in (("near", 1, "k"), ("edge", 50, "k50"),
+                                  ("far", 51, "k51")):
+            calls = _spy_lookup_arms(monkeypatch)
+            sql = (f"select v, w from {name}, fact "
+                   f"where {name}.k = fact.{col}")
+            line = next(r[0] for r in sess.execute(
+                "explain " + sql).rows() if "[build: " in r[0])
+            assert "fused lookup" in line
+            rows = sess.execute(sql).rows()
+            assert sorted(tuple(r) for r in rows) == sorted(
+                (i % 250 + 1, i) for i in range(400) if i % 250 < 200)
+            over = 199 * stride + 1 >= knee
+            assert over == (name == "far")
+            assert ("sorted lookup" in line) == over
+            assert ("dense directory" in line) == (not over)
+            assert (calls["sorted"] > 0) == over
+            assert (calls["dense"] > 0) == (not over)
+        # LEFT join through the sorted arm: an unmatched probe row stays,
+        # with the build side's columns NULL
+        calls = _spy_lookup_arms(monkeypatch)
+        rows = sess.execute("select w, v from fact left join far "
+                            "on far.k = fact.k51").rows()
+        assert calls["sorted"] > 0
+        assert sorted((int(w), v if v is None else int(v))
+                      for w, v in rows) == sorted(
+            (i, k if k <= 200 else None)
+            for i, k in ((i, i % 250 + 1) for i in range(400)))
+    finally:
+        sess.close()
+
+
 def test_stripe_row_limit_splits_and_stays_atomic(tmp_path):
     """graftlint round: columnar_stripe_row_limit was a registered,
     documented, test-SET knob consumed by nothing.  Now the ingest

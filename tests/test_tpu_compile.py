@@ -37,6 +37,7 @@ from citus_tpu.ops.join import (
     PROBE_TILE_SLOTS,
     bucketed_unique_lookup,
     probe_bucket_count,
+    sorted_unique_lookup,
 )
 
 # TPC-H SF1 on one chip (ingest/tpch.py): padded feed capacities
@@ -195,6 +196,23 @@ def test_bucketed_unique_lookup_xla_compiles(chip):
         chip((ORDERS_CAP,), jnp.int64), chip((ORDERS_CAP,), jnp.bool_),
         chip((LINEITEM_CAP,), jnp.int64))
     assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+def test_sorted_unique_lookup_compiles(chip):
+    """Q3's lineitem ⋈ orders lookup on the sort-and-scan arm (the
+    planner's pick over a 6 M-slot key extent, planner/plan.py
+    `lookup_sorted`) at the shape one chip of the four-chip mesh gives
+    it at SF1, keys narrowed to int32 as Q3's are.  The program is the
+    one-chip shape's but for its sizes, and compiles in 71 s to its 82
+    (compiler here, PR 28): the three sorts are the cost, at any size."""
+    build, probe = _round_cap(1_500_000 // 4), _round_cap(6_001_520 // 4)
+    c = _compile(
+        sorted_unique_lookup,
+        chip((build,), jnp.int32), chip((build,), jnp.bool_),
+        chip((probe,), jnp.int32))
+    text = c.as_text()
+    assert "gather" not in text and "scatter" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_bucketed_grid_aggregate_xla_compiles(chip):
