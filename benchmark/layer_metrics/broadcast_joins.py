@@ -1,0 +1,11 @@
+"""Broadcast joins a statement of the window ran (`broadcast_joins_total`
+over the window's statements).  None where the program has no such
+counter (any commit before PR 29): `reduce.py`'s `window_counter` would
+raise there, so this reader asks first."""
+
+
+def read(run):
+    counters = run.window.get("counters", {})
+    if "broadcast_joins_total" not in counters:
+        return None
+    return counters["broadcast_joins_total"] / max(len(run.records), 1)

@@ -1,0 +1,7 @@
+"""Plain reference for `statements/ssb_q3_4.sql` (SSB Q3.4): its
+description in `references/ssb.py`, whose one function answers all
+thirteen."""
+
+from .ssb import reference_for
+
+build, compare, tolerance = reference_for("q3_4")

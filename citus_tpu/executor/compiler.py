@@ -1441,6 +1441,27 @@ class PlanCompiler:
                     and not dense_off)
 
     @staticmethod
+    def dense_lookup_shape(node: JoinNode, dense_off: bool) -> bool:
+        """Static mirror of _exec_lookup_join's dispatch onto
+        ops.join.dense_unique_lookup, for the lookup_dense_total
+        counter: a fused single-key lookup whose build key's extent is
+        known and that neither sorts nor buckets.  Exact, though the
+        dispatch itself asks dense_directory_ok with the padded build
+        capacity: every extent the sorted arm leaves here is under
+        SORTED_LOOKUP_MIN_EXTENT, and dense_directory_ok holds for
+        those whatever the build side's size."""
+        if dense_off or not getattr(node, "fuse_lookup", False) or \
+                len(node.left_keys) != 1 or \
+                getattr(node, "lookup_sorted", False) or \
+                getattr(node, "probe_bucketed", False):
+            return False
+        build_left = node.join_type == "inner" and \
+            getattr(node, "build_side", "right") == "left"
+        extents = getattr(node, "left_key_extents" if build_left
+                          else "right_key_extents", ())
+        return bool(extents) and extents[0] is not None
+
+    @staticmethod
     def agg_pushdown_shape(node: AggregateNode) -> bool:
         """Static mirror of _try_join_agg_pushdown's eligibility: True ⇒
         the pushdown will handle this aggregate WITHOUT pair emission, so

@@ -755,14 +755,17 @@ class Executor:
 
     # ------------------------------------------------------------------
     def count_picks(self, plan: QueryPlan, caps: Capacities) -> None:
-        """groupby_bucketed_total and lookup_sorted_total: each bumped
-        once per executed STATEMENT whose converged plan ran the
-        bucketed dense-grid group-by (by its number of such aggregates)
-        or at least one sort-and-scan lookup join — callers invoke this
-        after their retry loop settles (the streamed path calls it once
-        after the batch loop, not per batch), and a dense_oob fallback
-        onto the general paths (caps.dense_off) correctly counts
-        nothing."""
+        """groupby_bucketed_total, lookup_sorted_total,
+        lookup_dense_total and broadcast_joins_total: each bumped once
+        per executed STATEMENT whose converged plan ran the bucketed
+        dense-grid group-by (by its number of such aggregates), at
+        least one sort-and-scan lookup join, at least one dense
+        directory lookup join, or broadcast joins (by their number) —
+        callers invoke this after their retry loop settles (the
+        streamed path calls it once after the batch loop, not per
+        batch), and a dense_oob fallback onto the general paths
+        (caps.dense_off) correctly counts no pick (its broadcast joins
+        stay broadcast joins)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
@@ -780,10 +783,17 @@ class Executor:
         pushed = {id(nd.input) for nd in nodes
                   if isinstance(nd, AggregateNode)
                   and PlanCompiler.agg_pushdown_shape(nd)}
-        if any(isinstance(nd, JoinNode) and id(nd) not in pushed
-               and PlanCompiler.sorted_lookup_shape(nd, caps.dense_off)
-               for nd in nodes):
+        joins = [nd for nd in nodes if isinstance(nd, JoinNode)]
+        fused = [nd for nd in joins if id(nd) not in pushed]
+        if any(PlanCompiler.sorted_lookup_shape(nd, caps.dense_off)
+               for nd in fused):
             self.counters.increment(sc.LOOKUP_SORTED_TOTAL)
+        if any(PlanCompiler.dense_lookup_shape(nd, caps.dense_off)
+               for nd in fused):
+            self.counters.increment(sc.LOOKUP_DENSE_TOTAL)
+        nbc = sum(1 for nd in joins if nd.strategy == "broadcast")
+        if nbc:
+            self.counters.increment(sc.BROADCAST_JOINS_TOTAL, nbc)
 
     # ------------------------------------------------------------------
     CAPS_MEMO_VERSION = 6  # bump when capacity semantics change

@@ -282,20 +282,21 @@ def dense_unique_lookup(build_key: jnp.ndarray,
     rows; overwritten rows read back a different index.  dups feed oob
     so the caller's retry-on-general-path protocol still always fires."""
     m = build_key.shape[0]
-    idx = build_key.astype(jnp.int64) - jnp.int64(base)
-    inb = build_matchable & (idx >= 0) & (idx < extent)
-    oob = (build_matchable & ~inb).sum().astype(jnp.int64)
-    slot = jnp.where(inb, idx, extent).astype(jnp.int32)
-    iota_m = jnp.arange(m, dtype=jnp.int32)
-    directory = jnp.full(extent, m, jnp.int32).at[slot].set(
-        iota_m, mode="drop")
-    dup = (inb & (jnp.minimum(directory[jnp.minimum(slot, extent - 1)], m)
-                  != iota_m)).sum().astype(jnp.int64)
-    pin, pc = _probe_slots(probe_key, base, extent)
-    raw = directory[pc]
-    found = pin & (raw != m)
-    bidx = jnp.minimum(raw, m - 1)
-    counts = found.astype(jnp.int32)
+    with stage_scope("dense"):
+        idx = build_key.astype(jnp.int64) - jnp.int64(base)
+        inb = build_matchable & (idx >= 0) & (idx < extent)
+        oob = (build_matchable & ~inb).sum().astype(jnp.int64)
+        slot = jnp.where(inb, idx, extent).astype(jnp.int32)
+        iota_m = jnp.arange(m, dtype=jnp.int32)
+        directory = jnp.full(extent, m, jnp.int32).at[slot].set(
+            iota_m, mode="drop")
+        dup = (inb & (jnp.minimum(directory[jnp.minimum(slot, extent - 1)],
+                                  m) != iota_m)).sum().astype(jnp.int64)
+        pin, pc = _probe_slots(probe_key, base, extent)
+        raw = directory[pc]
+        found = pin & (raw != m)
+        bidx = jnp.minimum(raw, m - 1)
+        counts = found.astype(jnp.int32)
     return bidx, counts, oob + dup
 
 

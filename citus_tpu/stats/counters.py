@@ -48,6 +48,12 @@ GROUPBY_BUCKETED_TOTAL = "groupby_bucketed_total"
 # sort-and-scan arm (ops.join.sorted_unique_lookup: a key extent past
 # the knee of the directory gather)
 LOOKUP_SORTED_TOTAL = "lookup_sorted_total"
+# … and on the other arm, the gather from a dense directory
+# (ops.join.dense_unique_lookup: a key extent under that)
+LOOKUP_DENSE_TOTAL = "lookup_dense_total"
+# broadcast joins (a replicated side — a reference table — joined in
+# place on every device) in the executed statements' converged plans
+BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
 # static all_to_all shuffle buffer volume the executed plans moved over
 # the mesh (per-device capacity × devices² × row width, summed over the
 # plan's repartition stages and every stream batch) — the EXPLAIN
@@ -125,7 +131,7 @@ ALL_COUNTERS = [
     CAPACITY_RETRIES, DEVICE_ROWS_SCANNED,
     INSERT_SELECT_PUSHDOWN, INSERT_SELECT_REPARTITION, INSERT_SELECT_PULL,
     CHUNKS_SKIPPED, QUERIES_STREAMED, GROUPBY_BUCKETED_TOTAL,
-    LOOKUP_SORTED_TOTAL,
+    LOOKUP_SORTED_TOTAL, LOOKUP_DENSE_TOTAL, BROADCAST_JOINS_TOTAL,
     SHUFFLE_BYTES_TOTAL,
     CHUNKS_PREFETCHED_TOTAL, PREFETCH_STALLS_TOTAL,
     DEVICE_DECODED_BYTES_TOTAL,

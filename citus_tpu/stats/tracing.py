@@ -193,6 +193,9 @@ STAGE_NAMES: dict[str, str] = {
     "lookup_join": "lookup join: dense directory, or sort-and-scan over "
                    "a large extent (or the sorted-bounds fallback), and "
                    "match counting",
+    "dense": "sub: lookup_join — the dense directory's build (one "
+             "scatter, the duplicate check's gather) and its probe (one "
+             "gather a probe row)",
     "carry": "sub: lookup_join — the scans that carry each build row's "
              "index forward to its probe rows",
     "join_out": "join keys, pair emission / build-column gathers, "

@@ -258,3 +258,97 @@ def test_scan_bits_and_valid_expand_compile(chip):
              chip((1, cap // 8), jnp.uint8))
     _compile(lambda r: scanpipe._valid_expand(r, LINEITEM_CAP),
              chip((1, 1), jnp.int32))
+
+
+# -- a whole statement's program ------------------------------------------
+
+def test_ssb_q4_1_program_compiles(topo, tmp_path):
+    """The benchmark cell `ssb1.q4_1`'s program at SSB SF1's shapes —
+    6.0 M fact rows through four broadcast lookup joins on the dense
+    directory, compacted 6.0 M → 1.8 M → 360 k slots on the way, 35
+    groups on the dense grid — as `Executor._compile_or_load` builds it,
+    for one described v5e chip.  The rows are the benchmark's own at
+    SF1 (the columns Q4.1 reads), so statistics, extents and capacities
+    are the cell's; nothing is executed.  22.8 s in this sandbox
+    (compiler here, PR 29), printed below."""
+    import dataclasses
+    import json
+    import os
+    import re
+    import sys
+    import time
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import citus_tpu
+    from benchmark.datasets import ssb
+    from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
+    from citus_tpu.executor.compiler import PlanCompiler
+    from citus_tpu.executor.feed import build_feeds
+    from citus_tpu.ingest.copy_from import _ingest_batch
+    from citus_tpu.sql.parser import parse_one
+
+    with open(os.path.join(root, "benchmark", "statements",
+                           "ssb_q4_1.json")) as f:
+        st = json.load(f)
+    with open(os.path.join(root, "benchmark", "statements", st["sql"])) as f:
+        sql = f.read()
+    params = {"scale_factor": 1.0, "shard_count": 8}
+    data = ssb.generate(params, 5)
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "ssb"), n_devices=1)
+    try:
+        # the five tables cut to the columns Q4.1 reads (and the fact
+        # table's distribution key): the program is the same, the load
+        # is a tenth
+        for table, cols in st["reads"].items():
+            names = cols + [ssb.FACT_KEY] * (table == ssb.FACT)
+            types = dict(re.findall(r"(\w+) (int|bigint|text)\b",
+                                    ssb.SCHEMAS[table]))
+            sess.execute(f"create table {table} (" + ", ".join(
+                f"{c} {types[c]}" for c in names) + ")")
+            if table == ssb.FACT:
+                sess.create_distributed_table(table, ssb.FACT_KEY,
+                                              shard_count=8)
+            else:
+                sess.create_reference_table(table)
+            _ingest_batch(sess, table, names, [
+                list(data[table][c]) if data[table][c].dtype == object
+                else data[table][c] for c in names], pre_typed=True)
+        del data
+        ex = sess.executor
+        plan, _cleanup = sess._plan_select(parse_one(sql))
+        feeds = build_feeds(plan, ex.catalog, ex.store, ex.mesh,
+                            np.dtype("float32"))
+        # SF1 converges at its initial capacities: no buffer is far
+        # enough over its actual to tighten (seen here at SF1 on the CPU)
+        caps = ex._initial_capacities(plan, feeds)
+        mesh = make_mesh(devices=topo.devices[:1])
+
+        def abstract(arr, sharded):
+            return jax.ShapeDtypeStruct(
+                arr.shape, arr.dtype, sharding=NamedSharding(
+                    mesh, P(SHARD_AXIS) if sharded else P()))
+
+        feeds = {nid: dataclasses.replace(
+            f, arrays={c: abstract(a, f.sharded) for c, a in f.arrays.items()},
+            nulls={c: abstract(a, f.sharded) for c, a in f.nulls.items()},
+            valid=abstract(f.valid, f.sharded)) for nid, f in feeds.items()}
+        assert max(f.capacity for f in feeds.values()) == _round_cap(5_999_224)
+        fn, feed_arrays, _meta, stage_keys = PlanCompiler(
+            plan, mesh, feeds, caps, np.dtype("float32")).build()
+        t0 = time.perf_counter()
+        c = fn.lower(*feed_arrays).compile()
+        print(f"ssb q4_1 at SF1 shapes: compiled for a described v5e in "
+              f"{time.perf_counter() - t0:.1f} s; join_out stages "
+              f"{[w for _, kind, w in stage_keys if kind == 'join_out']}")
+    finally:
+        sess.close()
+    text = c.as_text()
+    assert "ct.lookup_join/ct.dense" in text
+    assert "ct.lookup_join/ct.sort" not in text  # no key extent reaches 2^18
+    assert "ct.join_out" in text and "ct.agg_grid" in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
