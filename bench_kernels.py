@@ -264,6 +264,98 @@ def bench_lookup(mode="full", small=False, out="chiprun_out"):
     return rows
 
 
+def bench_compact(small=False, out="chiprun_out"):
+    """`PlanCompiler._compact`'s two ways to the survivors' positions
+    (PR 30): the inclusive `cumsum` of the validity mask and a
+    unique-index scatter of each survivor's position into its rank (the
+    form every program ran until PR 30, kept here alone) against
+    `survivor_positions`' one sort of the positions, over the sizes the
+    benchmark's programs compact (a dimension table of 30 k rows, one
+    of 150 k, a chip's quarter of `lineitem`, SSB's second compaction,
+    a whole fact table) at a shrink of 30 and of 3.3, the rule's edge.
+    The mask moves with the iteration, so nothing is hoisted out of the
+    timed loop; both forms' positions are consumed alike.  The parts at
+    the largest size price what the sort replaced and what stays.
+    Writes `<out>/bench_compact.json`.
+
+    Usage:  python bench_kernels.py compact        (the chip)
+            python bench_kernels.py compact small  (a rehearsal anywhere)
+    """
+    import json
+    import os
+
+    from citus_tpu.runtime import ensure_jax_configured
+
+    ensure_jax_configured()
+    from citus_tpu.executor.compiler import _round_cap, survivor_positions
+    dev = jax.devices()[0]
+    print(f"backend: {dev.platform} ({dev.device_kind})")
+    rng = np.random.default_rng(0)
+
+    def positions_scatter(valid, k):
+        n = valid.shape[0]
+        rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        por = jnp.zeros(k, jnp.int32).at[
+            jnp.where(valid & (rank < k), rank, k)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+        return por, rank[n - 1] + 1
+
+    forms = {"scatter": positions_scatter, "sort": survivor_positions}
+
+    def mask(r0, i, k):
+        # four fifths of the k slots filled, the draw shifted by i
+        thr = int(65536 * 0.8 * k / r0.shape[0])
+        return ((r0 + i.astype(jnp.int32) * 7919) & 0xFFFF) < thr
+
+    def run(form, r0, k):
+        def fn(i):
+            por, n_valid = form(mask(r0, i, k), k)
+            live = jnp.arange(k, dtype=jnp.int32) < n_valid
+            return (jnp.where(live, por, 0).sum(dtype=jnp.int64)
+                    + n_valid)
+        return fn
+
+    scale = 64 if small else 1
+    rows = []
+    sizes = [_round_cap(n // scale) for n in
+             (30_000, 150_000, 1_501_440, 1_800_320, 6_001_536)]
+    for n in sizes:
+        r0 = jnp.asarray(rng.integers(0, 1 << 16, n).astype(np.int32))
+        for shrink in (30, 3.3):
+            k = _round_cap(int(n / shrink))
+            want = None
+            for name, form in forms.items():
+                t, compile_s, got = _slope_time_once(run(form, r0, k))
+                want = got if want is None else want
+                rows.append({"form": name, "n": n, "k": k, "ms": t * 1e3,
+                             "ns_per_row": t * 1e9 / n,
+                             "compile_s": compile_s,
+                             "agrees": got == want})
+                print(json.dumps(rows[-1]), flush=True)
+    # the parts, at the largest size: the scan that went with the
+    # scatter, and the column gather at the compacted size that stays
+    n = sizes[-1]
+    r0 = jnp.asarray(rng.integers(0, 1 << 16, n).astype(np.int32))
+    parts = {"cumsum": (n, lambda i: jnp.cumsum(
+        mask(r0, i, n // 30).astype(jnp.int32))[n // 2].astype(jnp.int64))}
+    for shrink in (30, 3.3):
+        k = _round_cap(int(n / shrink))
+        idx = jnp.asarray(np.sort(rng.permutation(n)[:k]).astype(np.int32))
+        parts[f"gather_k_{k}"] = (k, lambda i, idx=idx, k=k: (
+            r0 + i.astype(jnp.int32))[idx].sum(dtype=jnp.int64))
+    for name, (elems, part) in parts.items():
+        t, compile_s, _ = _slope_time_once(part)
+        rows.append({"part": name, "n": n, "elements": elems,
+                     "ms": t * 1e3, "ns_per_element": t * 1e9 / elems,
+                     "compile_s": compile_s})
+        print(json.dumps(rows[-1]), flush=True)
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "bench_compact.json"), "w") as f:
+        json.dump({"device": [dev.platform, dev.device_kind],
+                   "rows": rows}, f, indent=1)
+    return rows
+
+
 def bench_probe(regimes=None, repeats=3, reps=8):
     """Join-probe A/B (round 6): single-gather `dense_unique_lookup` vs
     the hash-bucketed, VMEM-tiled `bucketed_unique_lookup` in its XLA
@@ -554,6 +646,8 @@ if __name__ == "__main__":
     elif len(sys.argv) > 1 and sys.argv[1] == "lookup":
         bench_lookup(next((a for a in sys.argv[2:] if a != "small"),
                           "full"), small="small" in sys.argv[2:])
+    elif len(sys.argv) > 1 and sys.argv[1] == "compact":
+        bench_compact(small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "groupby":
         bench_groupby()
     else:

@@ -268,6 +268,25 @@ class Capacities:
                           {k: g(v) for k, v in self.agg_bucket.items()})
 
 
+def survivor_positions(valid, k: int):
+    """→ (the positions of the first k True rows of `valid`, in row
+    order; the count of True rows), by ONE sort: survivors keep their
+    position as key, the rest take the sentinel n, so the first k of the
+    sorted keys are the survivors (slots past the count hold n).  Keys
+    are distinct, so the sort need not be stable.
+
+    A sort, not a scatter of each survivor's rank: on the v5e XLA's
+    scatter and the `cumsum` that feeds it cost 4.7–6.6 ns a row of the
+    uncompacted size (36.7 ms for 6.0 M rows) where the one-operand
+    sort costs 0.4–1.5 (5.0 ms), at every size from 30 k rows up
+    (`python bench_kernels.py compact`; PERF.md §6, my chip run,
+    PR 30)."""
+    n = valid.shape[0]
+    key = jnp.where(valid, jnp.arange(n, dtype=jnp.int32), n)
+    return (jax.lax.sort(key, is_stable=False)[:k],
+            valid.sum(dtype=jnp.int32))
+
+
 class PlanCompiler:
     """One instance per (plan, feeds, capacities) — produces a jitted fn."""
 
@@ -750,23 +769,21 @@ class PlanCompiler:
 
         A selective filter leaves the block mostly padding; every
         downstream sort/shuffle/join still pays for the full capacity.
-        Compaction costs one cumsum + one unique-index scatter + one
-        gather per column at the OLD size, and shrinks everything after
-        it to the filtered-estimate size.  More survivors than k counts
-        as capacity overflow (host retries with doubled slots)."""
-        n = blk.valid.shape[0]
-        rank = jnp.cumsum(blk.valid.astype(jnp.int32)) - 1
-        n_valid = jnp.where(n > 0, rank[n - 1] + 1, 0)
-        # the j-th surviving row's position, via unique-index scatter-set
-        por = jnp.zeros(k, jnp.int32).at[
-            jnp.where(blk.valid & (rank < k), rank, k)].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
-        out_valid = jnp.arange(k, dtype=jnp.int32) < jnp.minimum(n_valid, k)
-        cols = {cid: arr[por] for cid, arr in blk.columns.items()}
-        nulls = {cid: nm[por] for cid, nm in blk.nulls.items()}
-        self._overflow = self._overflow + \
-            jnp.maximum(n_valid - k, 0).astype(jnp.int64)
-        return Block(cols, out_valid, nulls)
+        Compaction costs one sort of the positions at the OLD size
+        (`survivor_positions`) and one gather per column at the NEW
+        size, and shrinks everything after it to the filtered-estimate
+        size.  More survivors than k counts as capacity overflow (host
+        retries with doubled slots)."""
+        with stage_scope("compact"):
+            por, n_valid = survivor_positions(blk.valid, k)
+            out_valid = jnp.arange(k, dtype=jnp.int32) < n_valid
+            # padding slots read row 0, never the sentinel
+            por = jnp.where(out_valid, por, 0)
+            cols = {cid: arr[por] for cid, arr in blk.columns.items()}
+            nulls = {cid: nm[por] for cid, nm in blk.nulls.items()}
+            self._overflow = self._overflow + \
+                jnp.maximum(n_valid - k, 0).astype(jnp.int64)
+            return Block(cols, out_valid, nulls)
 
     def _project(self, blk: Block, exprs) -> Block:
         cols, nulls = {}, {}

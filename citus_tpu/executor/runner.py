@@ -924,10 +924,13 @@ class Executor:
     # recompile costs real time on remote-attached chips).  The
     # threshold is PER KIND: repartition/agg_out are pure buffer sizes
     # (tightening is free — smaller shuffles and slices), but
-    # scan_out/join_out tightening can INTRODUCE a compaction pass that
-    # costs ~(n_cols+1) output-sized gathers — and TPU gathers run at
-    # ~80M elem/s (bench_kernels), so a 60M→42M "win" measured 2.5 s
-    # SLOWER on Q3 SF10.  Compaction must shrink ≥3× to pay for itself.
+    # scan_out/join_out tightening can INTRODUCE a compaction pass: one
+    # sort of the positions at the uncompacted size (0.68–0.84 ns a row
+    # on the v5e: PERF.md §6, my chip run, PR 30) and n_cols gathers at
+    # the compacted size, 6.6–7.0 ns an element (my chip runs, PR 28 to
+    # PR 30).  "Compaction must shrink ≥3× to pay for itself" dates
+    # from a scatter at 5.9 ns a row in the sort's place and is not
+    # re-derived from the sort's price yet (PERF.md §7).
     TIGHTEN_SLACK = 1.3
     # agg_grid = the bucketed grid's live-group count: it shares the
     # agg_out capacity table but shrinking it INSTALLS a compaction
@@ -1033,8 +1036,10 @@ class Executor:
                 # slack over the uniform-assumption estimate; an
                 # under-estimate overflows and retries doubled, and the
                 # converged sizes are memoized per plan fingerprint).
-                # Compaction pays ~(n_cols+1) output-sized gathers at
-                # ~80M elem/s — only a ≥3× shrink is worth the pass
+                # Compaction pays one sort of the positions at the old
+                # size and n_cols gathers at the new one (prices beside
+                # TIGHTEN_THRESHOLD) — a ≥3× shrink is taken as worth
+                # the pass
                 est = max(1, node.est_rows)
                 per_dev = (est if not feeds[id(node)].sharded
                            else -(-est // n_dev))

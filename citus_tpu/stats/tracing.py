@@ -200,6 +200,9 @@ STAGE_NAMES: dict[str, str] = {
              "index forward to its probe rows",
     "join_out": "join keys, pair emission / build-column gathers, "
                 "residual filter, compaction",
+    "compact": "sub: scan_out, join_out, agg_out — survivors' positions "
+               "by one sort, then one gather a column at the compacted "
+               "size",
     "agg_grid": "dense-grid group-by + psum combine",
     "agg_bucket": "bucketed dense-grid group-by",
     "agg_sort": "sort-path group-by (both levels of a repartition "

@@ -37,3 +37,15 @@ def test_dense_aggregate_harness_smoke():
     assert len(rows) == 1
     n, k, t_seg, t_oh, _t_pl, ok = rows[0]
     assert t_seg > 0 and t_oh > 0 and ok
+
+
+def test_compact_harness_smoke(tmp_path):
+    """`python bench_kernels.py compact small`: the scatter form kept
+    in the tool and `survivor_positions` agree at every shape, and the
+    table is written where asked."""
+    import bench_kernels
+
+    rows = bench_kernels.bench_compact(small=True, out=str(tmp_path))
+    forms = [r for r in rows if "form" in r]
+    assert len(forms) == 20 and all(r["agrees"] for r in forms)
+    assert (tmp_path / "bench_compact.json").exists()

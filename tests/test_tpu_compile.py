@@ -215,6 +215,42 @@ def test_sorted_unique_lookup_compiles(chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_compact_compiles(chip):
+    """Q3's `join_out` compaction on one chip at SF1 — 6,001,536 probe
+    rows to 195,072 slots, with the columns the join carries there (the
+    build index, two narrowed keys, two measures) — alone: one sort of
+    the positions, one gather a column at the compacted size, no
+    scatter and no scan.  The compile seconds are printed (PR 30)."""
+    import time
+    from types import SimpleNamespace
+
+    from citus_tpu.executor.batch import Block
+    from citus_tpu.executor.compiler import PlanCompiler
+
+    # k: the slots the cell's program plans there (PERF.md §5)
+    n, k = LINEITEM_CAP, 195_072
+
+    def compact(blk):
+        this = SimpleNamespace(_overflow=jnp.zeros((), jnp.int64))
+        return PlanCompiler._compact(this, blk, k), this._overflow
+
+    blk = Block({"__bidx__": chip((n,), jnp.int32),
+                 "l_orderkey": chip((n,), jnp.int32),
+                 "l_shipdate": chip((n,), jnp.int32),
+                 "l_extendedprice": chip((n,), jnp.float32),
+                 "l_discount": chip((n,), jnp.float32)},
+                chip((n,), jnp.bool_), {})
+    t0 = time.perf_counter()
+    c = _compile(compact, blk)
+    print(f"_compact {n} -> {k}: compiled for a described v5e in "
+          f"{time.perf_counter() - t0:.1f} s")
+    text = c.as_text()
+    ops = [ln for ln in text.splitlines() if "ct.compact" in ln]
+    assert any(" sort(" in ln for ln in ops)
+    assert "scatter" not in text and "reduce-window" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_bucketed_grid_aggregate_xla_compiles(chip):
     """The high-cardinality group-by at SF1 (group by l_orderkey over
     6 M rows; planner/plan.py `group_bucketed`)."""
@@ -270,7 +306,9 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
     for one described v5e chip.  The rows are the benchmark's own at
     SF1 (the columns Q4.1 reads), so statistics, extents and capacities
     are the cell's; nothing is executed.  22.8 s in this sandbox
-    (compiler here, PR 29), printed below."""
+    (compiler here, PR 29); 11.2 s with the compactions' two sorts
+    against 10.8 s with their scatters, in one sitting (compiler here,
+    PR 30); printed below."""
     import dataclasses
     import json
     import os
@@ -351,4 +389,10 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
     assert "ct.lookup_join/ct.dense" in text
     assert "ct.lookup_join/ct.sort" not in text  # no key extent reaches 2^18
     assert "ct.join_out" in text and "ct.agg_grid" in text
+    # both compactions find their survivors by a sort (PR 30): no
+    # scatter carries the sub-scope's path
+    compact_ops = [ln for ln in text.splitlines()
+                   if "ct.join_out/ct.compact" in ln]
+    assert sum(" sort(" in ln for ln in compact_ops) == 2
+    assert not any("scatter" in ln for ln in compact_ops)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
