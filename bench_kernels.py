@@ -356,107 +356,13 @@ def bench_compact(small=False, out="chiprun_out"):
     return rows
 
 
-def bench_probe(regimes=None, repeats=3, reps=8):
-    """Join-probe A/B (round 6): single-gather `dense_unique_lookup` vs
-    the hash-bucketed, VMEM-tiled `bucketed_unique_lookup` in its XLA
-    and Pallas formulations — the probe-path analogue of the
-    segment-aggregation A/B above, and the measurement behind the
-    `join_probe_kernel` config var.  (No plan picks the bucketed probe
-    since PR 28: `bench_lookup` below is the A/B of the two arms the
-    planner chooses between.)
-
-    Prints a probes/s table across (extent, build_rows, probe_rows)
-    regimes spanning the cache knee and a winner histogram.  Runs on any
-    backend — the 8-device CPU test mesh included (smaller default
-    regimes there; the harness shape is identical).  The authoritative
-    hardware numbers are whatever the driver captures on a real chip.
-    Pallas is TIMED only off-CPU (interpret mode is not a measurement)
-    but its outputs are parity-checked via a small interpreted run.
-
-    Usage:  python bench_kernels.py probe
-    """
-    from citus_tpu.runtime import ensure_jax_configured
-
-    ensure_jax_configured()  # int64 keys need x64 in standalone runs
-    import citus_tpu.ops.join as J
-    platform = jax.devices()[0].platform
-    if regimes is None:
-        regimes = ([(1 << 16, 1 << 15, 1 << 18),
-                    (1 << 20, 1 << 19, 1 << 20),
-                    (1 << 22, 1 << 21, 1 << 21)]
-                   if platform == "cpu" else
-                   # TPU: below / at / past the SF10 directory sizes
-                   [(1 << 20, 1 << 19, 1 << 22),
-                    (1 << 24, 1 << 23, 1 << 24),
-                    (1 << 26, 1 << 24, 1 << 25)])
-    print(f"backend: {platform} ({jax.devices()[0].device_kind}); "
-          f"tile = {J.PROBE_TILE_SLOTS} slots")
-    rng = np.random.default_rng(0)
-    base = 1000
-    rows = []
-    for extent, m, n in regimes:
-        bk = jnp.asarray(
-            base + rng.permutation(extent)[:m].astype(np.int64))
-        bmatch = jnp.ones(m, bool)
-        pk0 = jnp.asarray(rng.integers(0, extent, n).astype(np.int64))
-        nb = J.probe_bucket_count(extent)
-        # uniform probes with 2× skew headroom: overflow-free by design
-        cap = -(-n // nb) * 2 + 128
-
-        def single(i):
-            pk = base + (pk0 + i) % extent
-            _b, counts, _o = J.dense_unique_lookup(bk, bmatch, pk, base,
-                                                   extent)
-            return counts.sum().astype(jnp.int64)
-
-        def bucketed(i, kernel="xla"):
-            pk = base + (pk0 + i) % extent
-            _b, counts, _o, ov, _f = J.bucketed_unique_lookup(
-                bk, bmatch, pk, base, extent, cap, kernel=kernel)
-            # fold the overflow count in so a capacity bug cannot be
-            # silently timed as a win (it stays 0 by construction)
-            return (counts.sum() + ov).astype(jnp.int64)
-
-        # correctness gate before timing: identical hit totals
-        want = int(jax.device_get(single(jnp.int64(0))))
-        got = int(jax.device_get(bucketed(jnp.int64(0))))
-        ok = want == got
-        t_sg = _slope_time(single, repeats, reps)
-        t_bx = _slope_time(bucketed, repeats, reps)
-        t_bp = None
-        if platform != "cpu":
-            try:
-                f_bp = functools.partial(bucketed, kernel="pallas")
-                ok &= want == int(jax.device_get(f_bp(jnp.int64(0))))
-                t_bp = _slope_time(f_bp, repeats, reps)
-            except Exception as e:
-                print(f"  pallas failed at extent={extent}: "
-                      f"{str(e).splitlines()[0][:120]}")
-        rows.append((extent, m, n, t_sg, t_bx, t_bp, ok))
-        bp = ("n/a" if t_bp is None
-              else f"{n / t_bp / 1e6:8.1f}M/s")
-        print(f"extent=2^{extent.bit_length() - 1} m={m:>9} n={n:>9}  "
-              f"single={n / t_sg / 1e6:8.1f}M/s  "
-              f"bucketed_xla={n / t_bx / 1e6:8.1f}M/s  "
-              f"bucketed_pallas={bp}  correct={ok}")
-    best = {"single": 0, "bucketed_xla": 0, "bucketed_pallas": 0}
-    for _e, _m, n, t_sg, t_bx, t_bp, ok in rows:
-        opts = {"single": t_sg, "bucketed_xla": t_bx}
-        if t_bp is not None and ok:
-            opts["bucketed_pallas"] = t_bp
-        best[min(opts, key=opts.get)] += 1
-    print("winner histogram:", best)
-    return rows
-
-
 def bench_groupby(regimes=None, repeats=3, reps=8):
     """High-cardinality GROUP BY A/B (round 7): the sort path
     (packed-key `segment_aggregate`, exactly what the executor runs)
     vs the bucketed dense-grid path (`ops.groupby.
     bucketed_grid_aggregate`) in its XLA and Pallas formulations — the
-    aggregation twin of `bench_probe`, and the measurement behind the
-    planner's `group_bucket_eligible` gate and the `group_by_kernel`
-    config var.
+    measurement behind the planner's `group_bucket_eligible` gate and
+    the `group_by_kernel` config var.
 
     Prints a rows/s table across (n, k) regimes (k = packed slot-space
     size) and a winner histogram.  Runs on any backend — the 8-device
@@ -641,9 +547,7 @@ def bench_stripe_codec(gb: float = 0.5):
 if __name__ == "__main__":
     import sys
 
-    if len(sys.argv) > 1 and sys.argv[1] == "probe":
-        bench_probe()
-    elif len(sys.argv) > 1 and sys.argv[1] == "lookup":
+    if len(sys.argv) > 1 and sys.argv[1] == "lookup":
         bench_lookup(next((a for a in sys.argv[2:] if a != "small"),
                           "full"), small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "compact":

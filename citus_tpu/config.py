@@ -106,21 +106,6 @@ _register(ConfigVar(
     "Static aggregate-output headroom over the estimated group count.",
     float, min_value=1.0, max_value=64.0))
 _register(ConfigVar(
-    "join_probe_bucket_factor", 2.0,
-    "Per-bucket probe-slot headroom over the uniform-hash expectation "
-    "for bucketed fused lookups (ops.join.bucketed_unique_lookup). "
-    "Skewed buckets overflow and regrow through the normal retry path; "
-    "capacity feedback tightens converged sizes.",
-    float, min_value=1.0, max_value=64.0))
-_register(ConfigVar(
-    "join_probe_kernel", "xla",
-    "Bucketed-probe inner formulation: 'xla' (batched take_along_axis) "
-    "or 'pallas' (tile-resident VMEM kernel, ops/pallas_kernels.py). "
-    "bench_kernels.bench_probe() A/Bs both on the target hardware; the "
-    "default stays xla until a measurement says otherwise (same "
-    "contract as the aggregation kernel).",
-    str, choices=("xla", "pallas")))
-_register(ConfigVar(
     "group_by_kernel", "auto",
     "High-cardinality GROUP BY path: 'auto' (planner pick — bucketed "
     "dense-grid aggregation on TPU where structurally eligible, sort "
@@ -224,8 +209,8 @@ _register(ConfigVar(
     "coded low-NDV columns and bit-packed validity planes cross the "
     "wire and expand on the mesh (XLA formulations). 'auto' picks device on accelerator "
     "backends and host on CPU meshes, engaging only above a small "
-    "row floor (same measurement-gated contract as join_probe_kernel "
-    "/ group_by_kernel). No reference GUC — the analogue is the "
+    "row floor (same measurement-gated contract as "
+    "group_by_kernel). No reference GUC — the analogue is the "
     "columnar reader's chunk streaming, columnar_reader.c:323.",
     str, choices=("auto", "off", "host", "device")))
 _register(ConfigVar(

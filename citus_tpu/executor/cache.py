@@ -63,8 +63,7 @@ def node_fingerprint(node: PlanNode) -> str:
         return (f"J({node.strategy};{node.join_type};{node.repart_key_idx};"
                 f"{node.build_side};{node.left_key_extents};"
                 f"{node.right_key_extents};{node.key_int32};"
-                f"{node.fuse_lookup};{node.probe_bucketed};"
-                f"{node.lookup_sorted};"
+                f"{node.fuse_lookup};{node.lookup_sorted};"
                 f"{node.flag_combine};"
                 f"{node_fingerprint(node.left)};"
                 f"{node_fingerprint(node.right)};"
@@ -105,8 +104,6 @@ def caps_signature(plan: QueryPlan, caps) -> tuple:
             caps.dense_off,
             tuple(sorted((order[k], v) for k, v in caps.scan_out.items())),
             caps.output_repart,
-            tuple(sorted((order[k], v)
-                         for k, v in caps.bucket_probe.items())),
             tuple(sorted((order[k], v)
                          for k, v in caps.agg_bucket.items())))
 

@@ -229,10 +229,6 @@ class Capacities:
     # per-(source, target) bucket slots for the INSERT..SELECT output
     # shuffle (QueryPlan.output_repart); None when the plan has none
     output_repart: int | None = None
-    # per-bucket probe slots for bucketed fused lookups (JoinNode.
-    # probe_bucketed): the packed probe buffer is [n_buckets, this];
-    # skew overflows and regrows through the normal retry path
-    bucket_probe: dict[int, int] = None
     # per-bucket row slots for bucketed dense-grid aggregation
     # (AggregateNode.bucket_keys): the packed input buffer is
     # [n_buckets, this]; a hot bucket overflows and regrows through
@@ -244,8 +240,6 @@ class Capacities:
             self.agg_out = {}
         if self.scan_out is None:
             self.scan_out = {}
-        if self.bucket_probe is None:
-            self.bucket_probe = {}
         if self.agg_bucket is None:
             self.agg_bucket = {}
 
@@ -264,7 +258,6 @@ class Capacities:
                           {k: g(v) for k, v in self.scan_out.items()},
                           g(self.output_repart)
                           if self.output_repart else None,
-                          {k: g(v) for k, v in self.bucket_probe.items()},
                           {k: g(v) for k, v in self.agg_bucket.items()})
 
 
@@ -292,24 +285,19 @@ class PlanCompiler:
 
     def __init__(self, plan: QueryPlan, mesh: Mesh,
                  feeds: dict[int, FeedSpec], caps: Capacities,
-                 compute_dtype=np.float32, probe_kernel: str = "xla",
-                 group_kernel: str = "auto"):
+                 compute_dtype=np.float32, group_kernel: str = "auto"):
         self.plan = plan
         self.mesh = mesh
         self.feeds = feeds
         self.caps = caps
         self.n_dev = plan.n_devices
         self.compute_dtype = compute_dtype
-        # bucketed-probe inner formulation ('xla' | 'pallas'): a
-        # hardware-measured choice (bench_kernels.bench_probe), part of
-        # the plan-cache key in the runner
-        self.probe_kernel = probe_kernel
         # group-by path pick ('auto' | 'sort' | 'bucketed' |
         # 'bucketed_pallas'): auto defers to the planner's TPU-gated
         # group_bucketed annotation; the rest override it where the
         # plan is structurally eligible (bench_kernels.py groupby is
-        # the measurement behind the default).  Part of the plan-cache
-        # key in the runner, like probe_kernel.
+        # the measurement behind the default).  Rides in the plan
+        # fingerprint, so in the plan-cache key.
         self.group_kernel = group_kernel
 
     # ------------------------------------------------------------------
@@ -1054,8 +1042,8 @@ class PlanCompiler:
         probe with >1 match means the planner's uniqueness claim was
         stale: the surplus is reported as dense_oob so the host retries
         on the general expansion path (never silently dropped pairs)."""
-        from ..ops.join import (_bounds, bucketed_unique_lookup,
-                                dense_unique_lookup, sorted_unique_lookup)
+        from ..ops.join import (_bounds, dense_unique_lookup,
+                                sorted_unique_lookup)
 
         if node.join_type == "inner" and \
                 getattr(node, "build_side", "right") == "left":
@@ -1067,8 +1055,6 @@ class PlanCompiler:
             pblk, pkeys, pmatch = lblk, lkeys, lmatch
             extents = getattr(node, "right_key_extents", ())
         dense = self._dense_for(extents, bkeys)
-        bucket_cap = (self.caps.bucket_probe.get(id(node))
-                      if getattr(node, "probe_bucketed", False) else None)
         if self.sorted_lookup_shape(node, self.caps.dense_off):
             # a key extent past the knee of the directory gather (the
             # planner's pick, ops.join.sorted_lookup_eligible): no
@@ -1076,20 +1062,6 @@ class PlanCompiler:
             with stage_scope("lookup_join"):
                 bidx, counts, dense_oob = sorted_unique_lookup(
                     bkeys[0], bmatch, pkeys[0])
-                counts = jnp.where(pmatch, counts, 0)
-        elif dense is not None and len(bkeys) == 1 and bucket_cap is not None:
-            # bucketed probe (the planner's size-threshold pick for
-            # large directories): pack probes by VMEM-sized directory
-            # tile, probe tile-locally — random HBM gathers become
-            # streaming tile traffic.  Same oob/duplicate retry contract
-            # as the single gather; bucket skew overflows → grown retry.
-            with stage_scope("bucket_probe"):
-                bidx, counts, dense_oob, boverflow, bfill = \
-                    bucketed_unique_lookup(bkeys[0], bmatch, pkeys[0],
-                                           dense[0], dense[1], bucket_cap,
-                                           kernel=self.probe_kernel)
-                self._overflow = self._overflow + boverflow
-                self._record(id(node), "bucket_probe", bfill, bucket_cap)
                 counts = jnp.where(pmatch, counts, 0)
         elif dense is not None and len(bkeys) == 1:
             # unique build key (the fused-lookup planner claim): scatter
@@ -1449,10 +1421,10 @@ class PlanCompiler:
     @staticmethod
     def sorted_lookup_shape(node: JoinNode, dense_off: bool) -> bool:
         """Single decision point for the sort-and-scan lookup arm: the
-        compiler's dispatch, capacity planning (no bucket buffer), the
-        lookup_sorted_total counter and EXPLAIN's tag agree because all
-        of them ask here.  The pick itself is the planner's
-        (`lookup_sorted`, from ops.join.sorted_lookup_eligible)."""
+        compiler's dispatch, the lookup_sorted_total counter and
+        EXPLAIN's tag agree because all of them ask here.  The pick
+        itself is the planner's (`lookup_sorted`, from
+        ops.join.sorted_lookup_eligible)."""
         return bool(getattr(node, "lookup_sorted", False)
                     and getattr(node, "fuse_lookup", False)
                     and not dense_off)
@@ -1462,15 +1434,14 @@ class PlanCompiler:
         """Static mirror of _exec_lookup_join's dispatch onto
         ops.join.dense_unique_lookup, for the lookup_dense_total
         counter: a fused single-key lookup whose build key's extent is
-        known and that neither sorts nor buckets.  Exact, though the
-        dispatch itself asks dense_directory_ok with the padded build
-        capacity: every extent the sorted arm leaves here is under
+        known and that does not sort.  Exact, though the dispatch itself
+        asks dense_directory_ok with the padded build capacity: every
+        extent the sorted arm leaves here is under
         SORTED_LOOKUP_MIN_EXTENT, and dense_directory_ok holds for
         those whatever the build side's size."""
         if dense_off or not getattr(node, "fuse_lookup", False) or \
                 len(node.left_keys) != 1 or \
-                getattr(node, "lookup_sorted", False) or \
-                getattr(node, "probe_bucketed", False):
+                getattr(node, "lookup_sorted", False):
             return False
         build_left = node.join_type == "inner" and \
             getattr(node, "build_side", "right") == "left"

@@ -55,6 +55,24 @@ from .feed import build_feeds, walk_plan
 
 MAX_RETRIES = 4
 
+# What a compaction pass must save to be installed.  It pays one sort of
+# the positions at the uncompacted size (0.68–0.84 ns a row on the v5e:
+# PERF.md §6, my chip run, PR 30) and n_cols gathers at the compacted
+# size, 6.6–7.0 ns an element (my chip runs, PR 28 to PR 30).  "Must
+# shrink ≥3× to pay for itself" dates from a scatter at 5.9 ns a row in
+# the sort's place and is not re-derived from the sort's price yet
+# (PERF.md §7).
+COMPACTION_MIN_SHRINK = 3
+
+
+def compaction_pays(k: int, n: int) -> bool:
+    """Whether compacting n slots down to k is taken as worth the pass:
+    asked by capacity planning for scan, join and bucketed-grid outputs;
+    feedback tightening holds the same kinds to the same ratio
+    (TIGHTEN_THRESHOLD)."""
+    return k * COMPACTION_MIN_SHRINK < n
+
+
 # degradation ladder bounds: each batch-shrink rung halves the stream
 # batch (one memoized recompile per level); beyond this the rung is
 # spent and the ladder moves on
@@ -253,8 +271,7 @@ class Executor:
             # group_by_kernel changes which CAPACITY TABLES exist
             # (agg_bucket vs sort-path buffers), so converged sizes memoized
             # under one mode must not be replayed under another — it joins
-            # the fingerprint, unlike join_probe_kernel which only swaps the
-            # inner formulation at unchanged shapes
+            # the fingerprint
             fingerprint = (node_fingerprint(plan.root), plan.n_devices,
                            str(compute_dtype), feeds_signature(plan, feeds),
                            topk_sig, orp_sig,
@@ -341,12 +358,8 @@ class Executor:
                         f"{limit / 1e9:.1f} GB) — usually a cartesian "
                         "or extreme-fanout join; rewrite the query or "
                         "raise the limit")
-                probe_kernel = self.settings.get("join_probe_kernel")
-                # group_by_kernel already rides in `fingerprint` (it shapes
-                # the capacity tables); probe_kernel only swaps the inner
-                # formulation so it joins the key here
                 group_kernel = self.settings.get("group_by_kernel")
-                key = fingerprint + (caps_signature(plan, caps), probe_kernel)
+                key = fingerprint + (caps_signature(plan, caps),)
                 entry = self.plan_cache.get(key)
             if entry is None:
                 from ..utils.faultinjection import fault_point
@@ -355,8 +368,7 @@ class Executor:
                 # leave the plan cache without a half-built entry
                 fault_point("executor.plan_cache_fill")
                 entry = self._compile_or_load(plan, feeds, caps,
-                                              compute_dtype,
-                                              probe_kernel, group_kernel,
+                                              compute_dtype, group_kernel,
                                               key)
                 self.plan_cache.put(key, entry)
                 fn, out_meta, stage_keys, shuffle_bytes = entry
@@ -505,8 +517,6 @@ class Executor:
                               for k, v in fresh.scan_out.items()},
                     output_repart=max(fresh.output_repart or 0,
                                       caps.output_repart or 0) or None,
-                    bucket_probe={k: max(v, caps.bucket_probe.get(k, 0))
-                                  for k, v in fresh.bucket_probe.items()},
                     agg_bucket={k: max(v, caps.agg_bucket.get(k, 0))
                                 for k, v in fresh.agg_bucket.items()})
             if cap_overflow:
@@ -530,8 +540,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _compile_or_load(self, plan: QueryPlan, feeds, caps: Capacities,
-                         compute_dtype, probe_kernel, group_kernel,
-                         key) -> tuple:
+                         compute_dtype, group_kernel, key) -> tuple:
         """Plan-cache miss resolution, restart-survivable.  The whole
         resolve — disk load AND compile — runs single-flight through
         the per-data_dir gate: N sessions hitting a cold shape produce
@@ -559,7 +568,6 @@ class Executor:
             with trace_span("compile", cache="miss"):
                 compiler = PlanCompiler(plan, self.mesh, feeds,
                                         caps, compute_dtype,
-                                        probe_kernel=probe_kernel,
                                         group_kernel=group_kernel)
                 fn, feed_arrays, out_meta, stage_keys = \
                     compiler.build()
@@ -796,7 +804,7 @@ class Executor:
             self.counters.increment(sc.BROADCAST_JOINS_TOTAL, nbc)
 
     # ------------------------------------------------------------------
-    CAPS_MEMO_VERSION = 6  # bump when capacity semantics change
+    CAPS_MEMO_VERSION = 7  # bump when capacity semantics change
 
     def _memo_path(self) -> str:
         import os
@@ -924,21 +932,17 @@ class Executor:
     # recompile costs real time on remote-attached chips).  The
     # threshold is PER KIND: repartition/agg_out are pure buffer sizes
     # (tightening is free — smaller shuffles and slices), but
-    # scan_out/join_out tightening can INTRODUCE a compaction pass: one
-    # sort of the positions at the uncompacted size (0.68–0.84 ns a row
-    # on the v5e: PERF.md §6, my chip run, PR 30) and n_cols gathers at
-    # the compacted size, 6.6–7.0 ns an element (my chip runs, PR 28 to
-    # PR 30).  "Compaction must shrink ≥3× to pay for itself" dates
-    # from a scatter at 5.9 ns a row in the sort's place and is not
-    # re-derived from the sort's price yet (PERF.md §7).
+    # scan_out/join_out tightening can INTRODUCE a compaction pass, so
+    # those shrink only by compaction_pays' ratio.
     TIGHTEN_SLACK = 1.3
     # agg_grid = the bucketed grid's live-group count: it shares the
     # agg_out capacity table but shrinking it INSTALLS a compaction
     # pass over the slot grid, so it pays the compaction economics
     TIGHTEN_THRESHOLD = {"repartition": 0.85, "agg_out": 0.85,
-                         "bucket_probe": 0.85, "agg_bucket": 0.85,
-                         "scan_out": 1.0 / 3.0, "join_out": 1.0 / 3.0,
-                         "agg_grid": 1.0 / 3.0}
+                         "agg_bucket": 0.85,
+                         "scan_out": 1.0 / COMPACTION_MIN_SHRINK,
+                         "join_out": 1.0 / COMPACTION_MIN_SHRINK,
+                         "agg_grid": 1.0 / COMPACTION_MIN_SHRINK}
 
     def _tighten_caps(self, plan: QueryPlan, caps: Capacities,
                       stage_keys, actuals) -> Capacities | None:
@@ -953,7 +957,6 @@ class Executor:
                "join_out": dict(caps.join_out),
                "agg_out": dict(caps.agg_out),
                "scan_out": dict(caps.scan_out),
-               "bucket_probe": dict(caps.bucket_probe),
                "agg_bucket": dict(caps.agg_bucket)}
         changed = False
         for (widx, kind, width), actual in zip(stage_keys, actuals):
@@ -971,7 +974,7 @@ class Executor:
         return Capacities(new["repartition"], new["join_out"],
                           new["agg_out"], caps.dense_off,
                           new["scan_out"], caps.output_repart,
-                          new["bucket_probe"], new["agg_bucket"])
+                          new["agg_bucket"])
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -987,7 +990,6 @@ class Executor:
                 caps.dense_off,
                 {order[k]: v for k, v in caps.scan_out.items()},
                 caps.output_repart,
-                {order[k]: v for k, v in caps.bucket_probe.items()},
                 {order[k]: v for k, v in caps.agg_bucket.items()})
 
     @staticmethod
@@ -995,16 +997,15 @@ class Executor:
         from .cache import plan_order
 
         rev = {i: nid for nid, i in plan_order(plan).items()}
-        return Capacities({rev[i]: v for i, v in memo[0].items()},
-                          {rev[i]: v for i, v in memo[1].items()},
-                          {rev[i]: v for i, v in memo[2].items()},
-                          memo[3],
-                          {rev[i]: v for i, v in memo[4].items()},
-                          memo[5] if len(memo) > 5 else None,
-                          {rev[i]: v for i, v in memo[6].items()}
-                          if len(memo) > 6 else None,
-                          {rev[i]: v for i, v in memo[7].items()}
-                          if len(memo) > 7 else None)
+        repart, join_out, agg_out, dense_off, scan_out, output_repart, \
+            agg_bucket = memo
+
+        def by_node(table: dict) -> dict:
+            return {rev[i]: v for i, v in table.items()}
+
+        return Capacities(by_node(repart), by_node(join_out),
+                          by_node(agg_out), dense_off, by_node(scan_out),
+                          output_repart, by_node(agg_bucket))
 
     def _initial_capacities(self, plan: QueryPlan, feeds,
                             dense_off: bool = False) -> Capacities:
@@ -1012,7 +1013,6 @@ class Executor:
         repart_factor = self.settings.get("repartition_capacity_factor")
         join_factor = self.settings.get("join_output_capacity_factor")
         group_factor = self.settings.get("agg_group_capacity_factor")
-        bucket_factor = self.settings.get("join_probe_bucket_factor")
         agg_bucket_factor = self.settings.get("agg_bucket_capacity_factor")
         group_kernel = self.settings.get("group_by_kernel")
         n_dev = plan.n_devices
@@ -1020,7 +1020,6 @@ class Executor:
         join_out: dict[int, int] = {}
         agg_out: dict[int, int] = {}
         scan_out: dict[int, int] = {}
-        bucket_probe: dict[int, int] = {}
         agg_bucket: dict[int, int] = {}
 
         def cap_of(node, skip_emit: bool = False) -> int:
@@ -1035,16 +1034,12 @@ class Executor:
                 # size by the filtered estimate, not the table (1.5×
                 # slack over the uniform-assumption estimate; an
                 # under-estimate overflows and retries doubled, and the
-                # converged sizes are memoized per plan fingerprint).
-                # Compaction pays one sort of the positions at the old
-                # size and n_cols gathers at the new one (prices beside
-                # TIGHTEN_THRESHOLD) — a ≥3× shrink is taken as worth
-                # the pass
+                # converged sizes are memoized per plan fingerprint)
                 est = max(1, node.est_rows)
                 per_dev = (est if not feeds[id(node)].sharded
                            else -(-est // n_dev))
                 k = _round_cap(int(per_dev * 1.5) + 512)
-                if k * 3 < base:
+                if compaction_pays(k, base):
                     scan_out[id(node)] = k
                     return k
                 return base
@@ -1076,7 +1071,7 @@ class Executor:
                 if skip_emit:
                     # aggregate pushdown consumes the join through
                     # _bounds (no fused lookup, no pair emission): no
-                    # emission OR bucket-probe buffer exists
+                    # emission buffer exists
                     return max(lcap, rcap)
                 if getattr(node, "fuse_lookup", False) and not dense_off \
                         and node.left_keys:
@@ -1086,24 +1081,10 @@ class Executor:
                     # aggregates/joins size by the join estimate
                     out = (rcap if node.join_type == "inner"
                            and node.build_side == "left" else lcap)
-                    if getattr(node, "probe_bucketed", False):
-                        # bucketed probe: per-bucket slots at the
-                        # uniform-hash expectation × skew headroom;
-                        # a hot bucket overflows and regrows through
-                        # the normal retry path, feedback tightens
-                        ext = (node.left_key_extents
-                               if node.build_side == "left"
-                               else node.right_key_extents)
-                        if ext and ext[0] is not None:
-                            from ..ops.join import probe_bucket_count
-
-                            nb = probe_bucket_count(int(ext[0][1]))
-                            bucket_probe[id(node)] = _round_cap(
-                                int(out / nb * bucket_factor))
                     if node.join_type == "inner" and node.residual is None:
                         est = max(1, node.est_rows)
                         k = _round_cap(int(-(-est // n_dev) * 1.5) + 512)
-                        if k * 3 < out:  # same ≥3× compaction economics
+                        if compaction_pays(k, out):
                             out = k
                     join_out[id(node)] = out
                     return out
@@ -1158,8 +1139,7 @@ class Executor:
                     # skew headroom (a hot bucket overflows and
                     # regrows; feedback tightens converged sizes), and
                     # the [bucket_total] output grid compacts to the
-                    # estimated group count under the same ≥3×
-                    # economics as every compaction pass
+                    # estimated group count where compaction pays
                     from ..ops.groupby import group_bucket_count
 
                     nb = group_bucket_count(node.bucket_total)
@@ -1170,7 +1150,7 @@ class Executor:
                     if est_g:
                         k = _round_cap(
                             min(out, int(est_g * group_factor) + 16))
-                        if k * 3 < out:
+                        if compaction_pays(k, out):
                             agg_out[id(node)] = k
                             out = k
                     return out
@@ -1200,7 +1180,7 @@ class Executor:
             out_rp = _round_cap(
                 int(-(-root_cap // n_dev) * repart_factor) + 256)
         return Capacities(repart, join_out, agg_out, dense_off, scan_out,
-                          out_rp, bucket_probe, agg_bucket)
+                          out_rp, agg_bucket)
 
     # ------------------------------------------------------------------
     def _host_combine(self, plan: QueryPlan, cols, nulls, valid,
@@ -1356,24 +1336,11 @@ def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities) -> int:
             ncols = len(node.out_columns) if node is not None else 4
             worst = max(worst,
                         cap * factor * (ncols + 2) * 8 * plan.n_devices)
-    for nid, cap in caps.bucket_probe.items():
-        # bucketed-probe pack: [n_buckets, cap] int32 × (local, pos,
-        # gathered output) per device.  A hot-bucket overflow retry
-        # regrows the PER-BUCKET cap, so this is the buffer that can
-        # explode under skew — it must be visible to the guard.
-        node = nodes.get(nid)
-        ext = (() if node is None else
-               (node.left_key_extents if node.build_side == "left"
-                else node.right_key_extents))
-        if ext and ext[0] is not None:
-            from ..ops.join import probe_bucket_count
-
-            nb = probe_bucket_count(int(ext[0][1]))
-            worst = max(worst, cap * nb * 3 * 4 * plan.n_devices)
     for nid, cap in caps.agg_bucket.items():
         # bucketed group-by: the [n_buckets, cap] pack per value column
-        # (int64-worst, per device — the hot-bucket regrow path, same
-        # skew-explosion exposure as the probe pack above) AND the
+        # (int64-worst, per device — a hot-bucket overflow retry
+        # regrows the PER-BUCKET cap, so this is the buffer that can
+        # explode under skew and must be visible to the guard) AND the
         # [bucket_total]-slot result grid (results + companions + key
         # reconstruction), which at the 2^24 slot cap is the largest
         # buffer this path allocates when no agg_out compaction applies

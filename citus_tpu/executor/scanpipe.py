@@ -200,10 +200,7 @@ def encode_column(buf: np.ndarray):
 
 
 # ---------------------------------------------------------------------------
-# on-device decode.  XLA formulations on every mesh: the Pallas
-# bit-unpack / dictionary-gather kernels (ops/pallas_kernels.py) are
-# refused by the TPU kernel compiler (tests/test_tpu_compile.py carries
-# its message), so no scan calls them.
+# on-device decode, XLA formulations on every mesh
 
 @jax.jit
 def _for_expand(wire, base):

@@ -13,7 +13,6 @@ from .hashing import (
     tile_buckets,
 )
 from .join import (
-    bucketed_unique_lookup,
     dense_unique_lookup,
     expand_join,
     expand_join_pairs,
@@ -31,7 +30,7 @@ __all__ = [
     "combine_hash64", "fmix32_jax",
     "hash_token_jax", "shard_index_for_values_jax", "shard_index_from_token",
     "tile_buckets",
-    "bucketed_unique_lookup", "dense_unique_lookup",
+    "dense_unique_lookup",
     "expand_join", "expand_join_pairs", "lookup_join", "lower_bound",
     "match_counts",
     "sort_build_side", "pack_by_target",

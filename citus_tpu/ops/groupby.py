@@ -9,8 +9,7 @@ matmul it rides was measured 2-10x faster than segment_sum only while
 the slot space stays <= ~4096 wide.
 
 This module removes the cap the radix-partition way (Theseus, arXiv
-2508.05029; the GPU hash-aggregation pipeline, arXiv 2606.24647; the
-aggregation twin of ops.join.bucketed_unique_lookup):
+2508.05029; the GPU hash-aggregation pipeline, arXiv 2606.24647):
 
   1. rows carry a PACKED dense slot id (the planner's `key_ranges`
      machinery — every group key's value range statically known, one
@@ -23,7 +22,7 @@ aggregation twin of ops.join.bucketed_unique_lookup):
   3. each bucket reduces over its <= GROUP_TILE_SLOTS-wide dense tile:
      sums/counts through the measured-fastest one-hot `dot_general`
      formulation (batched over buckets; a Pallas variant is A/B'd by
-     `bench_kernels.py groupby` exactly like the probe kernel),
+     `bench_kernels.py groupby`),
      min/max through per-tile scatter (segment) reductions — tiles are
      small and bucket-major packing makes the scatters local,
   4. the [total]-slot grid emits exactly like the dense grid today:
@@ -118,8 +117,8 @@ def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
       kernel: 'xla' (batched take-free one-hot dot_general) or 'pallas'
               (ops.pallas_kernels.bucketed_groupby_sums_pallas for the
               f32/int32 sum stacks; min/max and wide dtypes stay on the
-              XLA segment ops either way, mirroring the probe kernel's
-              split).  Degrades to 'xla' where pallas cannot compile.
+              XLA segment ops either way).  Degrades to 'xla' where
+              pallas cannot compile.
 
     Returns (results, rows_per_slot, overflow, bucket_max_fill):
       results:       [total] array per input value, same order,
@@ -151,9 +150,9 @@ def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
 
     if kernel == "pallas" and not interpret:
         if jax.default_backend() == "cpu":
-            # same rule as bucketed_unique_lookup: on the CPU backend a
-            # compiled pallas_call is interpret-only, so the XLA
-            # formulation (identical results) runs instead
+            # on the CPU backend a compiled pallas_call is
+            # interpret-only, so the XLA formulation (identical
+            # results) runs instead
             kernel = "xla"
 
     def _sums(colkeys: list[str], out_dtype):

@@ -79,14 +79,14 @@ def shard_index_for_values_jax(values: jnp.ndarray, shard_count: int) -> jnp.nda
 
 def tile_buckets(slots: jnp.ndarray, tile_slots: int,
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Directory slot → (bucket, tile-local slot) for the VMEM-tiled
-    probe pack (ops.join.bucketed_unique_lookup).
+    """Dense slot → (bucket, tile-local slot) for the tiled group-by's
+    pack (ops.groupby.bucketed_grid_aggregate).
 
     Buckets are contiguous slot ranges — value-range partitioning, the
     degenerate perfect hash over an already-dense slot space — so every
-    probe landing in bucket b touches only directory tile b, and the
-    pack (ops.partition.pack_by_target) turns random directory traffic
-    into per-tile streams.  Lives beside the routing hashes because it
+    row landing in bucket b touches only tile b, and the pack
+    (ops.partition.pack_by_target) turns random slot traffic into
+    per-tile streams.  Lives beside the routing hashes because it
     is the same partition-for-locality contract the shard tokens
     implement cross-device, minus the mixing step (dense directory
     slots need no avalanche; sparse keys would hash first)."""

@@ -55,12 +55,6 @@ from ..stats.tracing import stage_scope
 # the build side (sparse 64-bit keys fall back to binary search)
 DENSE_MAX_SLOTS = 1 << 26
 
-# bucketed probe path: directory slots per bucket tile (an int32 tile of
-# 2^15 slots is 128 KB).  The planner no longer picks this path: packing
-# the probe rows by tile cost 385.9 ms of tpch1.q3's statement, the
-# tile-local probe 114.8 and the scatter back 52.8 (my chip run, PR 27)
-PROBE_TILE_SLOTS = 1 << 15
-
 # From this key extent up (slots) a fused lookup sorts and scans
 # (sorted_unique_lookup) instead of gathering from a directory.  The chip
 # showed no knee to put it at: a gather or scatter costs 6.7–8.3 ns an
@@ -77,11 +71,6 @@ PROBE_TILE_SLOTS = 1 << 15
 # a join's own proportions; under it, where the sides are small and
 # either arm takes well under a millisecond, the one gather stays
 SORTED_LOOKUP_MIN_EXTENT = 1 << 18
-
-
-def probe_bucket_count(extent: int) -> int:
-    """Number of VMEM-sized directory tiles covering [0, extent)."""
-    return max(1, -(-extent // PROBE_TILE_SLOTS))
 
 
 def sorted_lookup_eligible(extent: int) -> bool:
@@ -373,107 +362,6 @@ def sorted_unique_lookup(build_key: jnp.ndarray,
     with stage_scope("sort"):
         hit = jax.lax.sort((sx, hit), num_keys=1, is_stable=False)[1][m:]
     return jnp.maximum(hit - 1, 0), (hit > 0).astype(jnp.int32), dup
-
-
-def bucketed_unique_lookup(build_key: jnp.ndarray,
-                           build_matchable: jnp.ndarray,
-                           probe_key: jnp.ndarray, base: int, extent: int,
-                           bucket_cap: int, kernel: str = "xla",
-                           interpret: bool = False,
-                           ) -> tuple[jnp.ndarray, jnp.ndarray,
-                                      jnp.ndarray, jnp.ndarray,
-                                      jnp.ndarray]:
-    """Hash-bucketed, VMEM-tiled variant of dense_unique_lookup.  No
-    plan picks it since PR 28: in XLA's form the locality it packs for
-    does not show — its tile-local gathers cost what any gather costs,
-    and at TPC-H SF1 it took 553.6 ms (pack 385.9, probe 114.8, scatter
-    back 52.8; my chip run, PR 27) where dense_unique_lookup takes 61.9
-    and sorted_unique_lookup 29.6 (my chip run, PR 28).
-
-    The radix-join way (Theseus, arXiv 2508.05029; shared-nothing
-    multicore joins, arXiv 1804.09324; reference repartition machinery,
-    multi_physical_planner.c BuildMapMergeJob): partition the probe
-    stream by directory tile until each tile fits fast memory, then
-    probe tile-by-tile so the directory streams through VMEM exactly
-    once.
-
-      1. build the dense directory as usual (one scatter; duplicate
-         build keys detected build-side exactly like dense_unique_lookup
-         so the stale-uniqueness retry contract cannot diverge),
-      2. pack probe rows by bucket = slot // PROBE_TILE_SLOTS with the
-         same counting-sort gather the repartition shuffle uses
-         (pack_by_target) into a [n_buckets, bucket_cap] buffer,
-      3. probe bucket-by-bucket — each bucket's tile is VMEM-sized and
-         its probes are contiguous (kernel='xla': a batched row-local
-         take_along_axis; kernel='pallas': the tile-resident kernel in
-         ops/pallas_kernels.py),
-      4. scatter hits back to original probe positions (unique-index).
-
-    Returns (bidx [N], counts [N], oob_count, bucket_overflow,
-    bucket_max_fill): oob_count follows the dense_unique_lookup contract
-    (out-of-range + duplicate build rows → the host retries on the
-    general path); bucket_overflow counts probe rows dropped because
-    their bucket exceeded bucket_cap — results are incomplete and the
-    host retries with grown per-bucket capacity (the same
-    count-then-emit protocol every static buffer uses).  bucket_max_fill
-    is the realized per-bucket maximum (capacity-feedback input)."""
-    tile = PROBE_TILE_SLOTS
-    m = build_key.shape[0]
-    n = probe_key.shape[0]
-    n_buckets = max(1, -(-extent // tile))
-    ext_pad = n_buckets * tile
-
-    with stage_scope("probe"):
-        # directory build + duplicate detection: identical accounting to
-        # dense_unique_lookup (padding slots [extent, ext_pad) stay empty)
-        idx = build_key.astype(jnp.int64) - jnp.int64(base)
-        inb = build_matchable & (idx >= 0) & (idx < extent)
-        oob = (build_matchable & ~inb).sum().astype(jnp.int64)
-        slot = jnp.where(inb, idx, ext_pad).astype(jnp.int32)
-        iota_m = jnp.arange(m, dtype=jnp.int32)
-        directory = jnp.full(ext_pad, m, jnp.int32).at[slot].set(
-            iota_m, mode="drop")
-        dup = (inb & (jnp.minimum(directory[jnp.minimum(slot, ext_pad - 1)], m)
-                      != iota_m)).sum().astype(jnp.int64)
-
-    pin, pc = _probe_slots(probe_key, base, extent)
-    from .hashing import tile_buckets
-    from .partition import pack_by_target
-
-    bucket, local = tile_buckets(pc, tile)
-
-    packed, pvalid, overflow = pack_by_target(
-        {"local": local, "pos": jnp.arange(n, dtype=jnp.int32)},
-        pin, bucket, n_buckets, bucket_cap)
-    # realized skew (max bucket fill) feeds capacity tightening; on an
-    # overflowed run the retry regrows before feedback ever fires
-    bucket_max_fill = pvalid.sum(axis=1).max().astype(jnp.int64)
-
-    with stage_scope("probe"):
-        dir2d = directory.reshape(n_buckets, tile)
-        loc2d = jnp.where(pvalid, packed["local"], 0)
-        if kernel == "pallas" and not interpret:
-            if jax.default_backend() == "cpu":
-                # config asked for the kernel on the CPU backend, where a
-                # compiled pallas_call is interpret-only: the XLA
-                # formulation gives the same results
-                kernel = "xla"
-        if kernel == "pallas":
-            from .pallas_kernels import bucketed_probe_pallas
-
-            raw2d = bucketed_probe_pallas(dir2d, loc2d, interpret=interpret)
-        else:
-            raw2d = jnp.take_along_axis(dir2d, loc2d, axis=1)
-
-    with stage_scope("scatter_back"):
-        pos = jnp.where(pvalid, packed["pos"], n).reshape(-1)
-        raw = jnp.full(n, m, jnp.int32).at[pos].set(
-            raw2d.reshape(-1), mode="drop")
-        found = pin & (raw != m)
-        bidx = jnp.minimum(raw, m - 1)
-        counts = found.astype(jnp.int32)
-    return bidx, counts, oob + dup, overflow.astype(jnp.int64), \
-        bucket_max_fill
 
 
 def _bounds(build_keys, build_matchable, probe_keys,

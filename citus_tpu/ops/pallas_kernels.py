@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the aggregation and join-probe hot paths.
+"""Pallas TPU kernels for the aggregation hot paths.
 
 BASELINE.json's north star calls for hand kernels on the hot ops (the
 reference's equivalents are C inner loops: per-tuple hash-aggregate
@@ -36,8 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 ROW_TILE = 1024       # rows per grid step
 K_CHUNK = 512         # one-hot width per MXU feed
-PROBE_CHUNK = 512     # probe rows streamed per step through one tile
-BITS_CHUNK = 128      # packed bytes per bit-unpack step (→ 1024 lanes)
 # block index 0 for index maps: under jax_enable_x64 a Python literal
 # traces as int64, which the TPU kernel compiler refuses in an index map
 _Z = np.int32(0)
@@ -109,51 +107,6 @@ def dense_grid_aggregate_pallas(slot: jnp.ndarray,
         interpret=interpret,
     )(slot_p, vals_p)
     return out[:total, :a]
-
-
-def _probe_kernel(tile_ref, loc_ref, out_ref):
-    """One grid step: gather PROBE_CHUNK probes against the resident
-    directory tile.  The tile block's index map ignores the chunk
-    grid dimension, so Pallas keeps it in VMEM across all of a
-    bucket's probe chunks — the directory streams HBM→VMEM exactly
-    once while probe chunks pipeline through it."""
-    out_ref[:] = jnp.take_along_axis(tile_ref[:], loc_ref[:], axis=1)
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bucketed_probe_pallas(dir2d: jnp.ndarray, loc2d: jnp.ndarray,
-                          interpret: bool = False) -> jnp.ndarray:
-    """VMEM-tiled directory probe for the bucketed join path.
-
-    dir2d [n_buckets, tile] int32 — directory values per bucket tile
-    (tile is VMEM-sized, ops.join.PROBE_TILE_SLOTS by default);
-    loc2d [n_buckets, cap] int32 — tile-local probe slots, packed by
-    bucket (garbage lanes must hold a clipped in-range slot).
-    Returns [n_buckets, cap] int32 gathered directory values.
-
-    Grid = (bucket, probe chunk); the in-kernel gather is a 2D
-    lane-dimension take_along_axis.  The TPU kernel compiler refuses
-    it as written (tests/test_tpu_compile.py carries its message: a
-    lane gather reaches one 128-lane vreg, not a 32768-slot tile), so
-    it runs in interpret mode only; the executor routes through XLA
-    unless join_probe_kernel says otherwise."""
-    k, tile = dir2d.shape
-    _, cap = loc2d.shape
-    cap_pad = _round_up(max(cap, PROBE_CHUNK), PROBE_CHUNK)
-    if cap_pad != cap:
-        loc2d = jnp.zeros((k, cap_pad), jnp.int32).at[:, :cap].set(
-            loc2d)
-    out = pl.pallas_call(
-        _probe_kernel,
-        grid=(k, cap_pad // PROBE_CHUNK),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, PROBE_CHUNK), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, PROBE_CHUNK), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, cap_pad), jnp.int32),
-        interpret=interpret,
-    )(dir2d, loc2d)
-    return out[:, :cap]
 
 
 def _groupby_kernel(slot_ref, val_ref, out_ref, acc_ref, *,
@@ -241,82 +194,8 @@ def bucketed_groupby_sums_pallas(loc2d: jnp.ndarray,
     return out.reshape(nb, k_pad, a_pad)[:, :tile, :a]
 
 
-def _bitunpack_kernel(packed_ref, out_ref):
-    """One grid step: unpack BITS_CHUNK packed bytes into
-    BITS_CHUNK×8 byte-per-bit lanes (MSB-first — numpy packbits
-    order).  A lane-dimension gather picks each output bit's source
-    byte (like the probe kernel's take_along_axis, and refused by the
-    TPU kernel compiler for the same reason)."""
-    p = packed_ref[:].astype(jnp.int32)            # [1, C]
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, p.shape[1] * 8), 1)
-    byte = jnp.take_along_axis(p, j // 8, axis=1)
-    out_ref[:] = ((byte >> (7 - (j % 8))) & 1).astype(jnp.uint8)
-
-@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
-def bit_unpack_pallas(packed: jnp.ndarray, cap: int,
-                      interpret: bool = False) -> jnp.ndarray:
-    """Validity-plane unpack: packed [rows, cap//8] uint8 (numpy
-    packbits, MSB-first) → [rows, cap] bool.  Interpret mode only: the
-    TPU kernel compiler refuses it (tests/test_tpu_compile.py), so the
-    pipelined scan expands bit planes with scanpipe._bits_expand."""
-    rows, w = packed.shape
-    w_pad = _round_up(max(w, BITS_CHUNK), BITS_CHUNK)
-    if w_pad != w:
-        packed = jnp.zeros((rows, w_pad), jnp.uint8) \
-            .at[:, :w].set(packed)
-    out = pl.pallas_call(
-        _bitunpack_kernel,
-        grid=(rows, w_pad // BITS_CHUNK),
-        in_specs=[pl.BlockSpec((1, BITS_CHUNK),
-                               lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, BITS_CHUNK * 8),
-                               lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, w_pad * 8),
-                                       jnp.uint8),
-        interpret=interpret,
-    )(packed)
-    return out[:, :cap].astype(bool)
-
-def _dictdecode_kernel(lut_ref, codes_ref, out_ref):
-    """One grid step: gather PROBE_CHUNK codes against the resident
-    LUT tile (index map ignores the chunk grid dim, so the LUT
-    streams HBM→VMEM once per row — the probe kernel's pattern)."""
-    out_ref[:] = jnp.take_along_axis(lut_ref[:], codes_ref[:],
-                                     axis=1)
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def dict_decode_pallas(codes: jnp.ndarray, lut: jnp.ndarray,
-                       interpret: bool = False) -> jnp.ndarray:
-    """Dictionary decode: codes [rows, cap] (uint8/uint16 wire dtype)
-    + lut [n_values] → out[r, i] = lut[codes[r, i]].  Interpret mode
-    only: the TPU kernel compiler refuses it
-    (tests/test_tpu_compile.py), so the pipelined scan decodes with
-    scanpipe._dict_expand."""
-    rows, cap = codes.shape
-    nv = lut.shape[0]
-    l_pad = _round_up(max(nv, 128), 128)
-    lut2 = jnp.zeros((1, l_pad), lut.dtype).at[0, :nv].set(lut)
-    cap_pad = _round_up(max(cap, PROBE_CHUNK), PROBE_CHUNK)
-    c = codes.astype(jnp.int32)
-    if cap_pad != cap:
-        c = jnp.zeros((rows, cap_pad), jnp.int32).at[:, :cap].set(c)
-    out = pl.pallas_call(
-        _dictdecode_kernel,
-        grid=(rows, cap_pad // PROBE_CHUNK),
-        in_specs=[
-            pl.BlockSpec((1, l_pad), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, PROBE_CHUNK), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, PROBE_CHUNK),
-                               lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cap_pad), lut.dtype),
-        interpret=interpret,
-    )(lut2, c)
-    return out[:, :cap]
-
-
 def bit_unpack_reference(packed: np.ndarray, cap: int) -> np.ndarray:
-    """numpy oracle for the bit unpack."""
+    """numpy oracle for the bit unpack (scanpipe._bits_expand)."""
     p = np.asarray(packed)
     bits = np.unpackbits(p, axis=-1)
     return bits[..., :cap].astype(bool)
@@ -324,7 +203,7 @@ def bit_unpack_reference(packed: np.ndarray, cap: int) -> np.ndarray:
 
 def dict_decode_reference(codes: np.ndarray, lut: np.ndarray
                           ) -> np.ndarray:
-    """numpy oracle for the dictionary decode."""
+    """numpy oracle for the dictionary decode (scanpipe._dict_expand)."""
     return np.asarray(lut)[np.asarray(codes).astype(np.int64)]
 
 
@@ -338,12 +217,6 @@ def groupby_sums_reference(loc2d: np.ndarray, stack: np.ndarray,
         np.add.at(out[b], np.asarray(loc2d)[b],
                   np.asarray(stack)[b].astype(np.float32))
     return out
-
-
-def probe_gather_reference(dir2d: np.ndarray,
-                           loc2d: np.ndarray) -> np.ndarray:
-    """numpy oracle for the tiled probe gather."""
-    return np.take_along_axis(np.asarray(dir2d), np.asarray(loc2d), axis=1)
 
 
 def segment_sum_reference(slot: np.ndarray, values: np.ndarray,

@@ -42,7 +42,6 @@ EXPLAIN_TAGS: dict[str, str] = {
     "fused lookup": "PK-lookup join fused into the probe gather",
     "sorted lookup": "fused lookup by sort and scan, no directory "
                      "(key extent past the directory gather's knee)",
-    "bucketed probe": "VMEM-tiled hash-bucketed probe path",
     "bucketed group-by": "dense-grid bucketed aggregation path",
     "Chunks Skipped": "chunk groups pruned by min/max skip nodes",
     "pipelined scan":
@@ -172,7 +171,6 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
         dense = (not sorted_lookup and bool(ext) and ext[0] is not None
                  and len(node.left_keys) == 1
                  and dense_directory_ok(ext[0][1], build.est_rows))
-        bucketed = dense and node.fuse_lookup and node.probe_bucketed
         mods = [f"build: {node.build_side}"]
         if dense:
             mods.append(explain_tag("dense directory"))
@@ -180,8 +178,6 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
             mods.append(explain_tag("fused lookup"))
         if sorted_lookup:
             mods.append(explain_tag("sorted lookup"))
-        if bucketed:
-            mods.append(explain_tag("bucketed probe"))
         lines.append(f"{pad}-> {label} on ({conds})  "
                      f"[{', '.join(mods)}]")
         if node.residual is not None:

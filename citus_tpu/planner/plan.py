@@ -137,12 +137,6 @@ class JoinNode(PlanNode):
     # compiler, the plan fingerprint and the lookup_sorted_total counter
     # all read this flag
     lookup_sorted: bool = False
-    # the hash-bucketed, tiled probe (ops.join.bucketed_unique_lookup).
-    # The planner no longer picks it: its pack alone cost 385.9 ms of
-    # tpch1.q3's 621 ms statement, three times the probe it served (my
-    # chip run, PR 27).  Kept for its tests until ROADMAP D3/D4's pair
-    # of PRs removes it with its stage name
-    probe_bucketed: bool = False
 
 
 @dataclass

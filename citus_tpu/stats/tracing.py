@@ -183,13 +183,9 @@ STAGE_NAMES: dict[str, str] = {
     "valid": "sub: decode — row-validity expand",
     "scan_out": "scan filter mask + compaction to the filtered size",
     "repartition": "shuffle: route, pack, all_to_all, flatten",
-    "pack": "sub: repartition, bucket_probe, agg_bucket — "
-            "pack_by_target's radix pack",
+    "pack": "sub: repartition, agg_bucket — pack_by_target's radix pack",
     "exchange": "sub: repartition — the all_to_all",
     "unpack": "sub: repartition — flatten of the exchanged pack",
-    "bucket_probe": "lookup join through the bucketed (tiled) probe",
-    "probe": "sub: bucket_probe — tile-local directory build + probe",
-    "scatter_back": "sub: bucket_probe — results back to probe order",
     "lookup_join": "lookup join: dense directory, or sort-and-scan over "
                    "a large extent (or the sorted-bounds fallback), and "
                    "match counting",

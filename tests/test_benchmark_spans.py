@@ -37,6 +37,9 @@ def test_new_metrics_are_entries_of_the_benchmark():
         assert entries[name]["source"] == "device_trace"
         assert os.path.exists(os.path.join(
             root, "benchmark", "layer_metrics", name + ".py"))
-    assert xspans.STAGES <= set(STAGE_NAMES)
+    # exact: the yardstick still sums the retired bucketed probe's scope,
+    # which reads 0; the next `benchmark` PR drops it, this set becomes
+    # empty, and any other drift fails here as well
+    assert xspans.STAGES - set(STAGE_NAMES) == {"bucket_probe"}
     for names in xspans.IDLE_METRICS.values():
         assert set(names) <= set(SPAN_NAMES)

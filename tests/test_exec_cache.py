@@ -482,7 +482,7 @@ class TestCapsMemoRegressions:
     whole memo (every converged shape forgotten at once) and every
     memoization rewrote the whole file (O(N²) bytes under a storm)."""
 
-    _VAL = ({}, {}, {}, False, {}, None, {}, {})
+    _VAL = ({}, {}, {}, False, {}, None, {})
 
     def test_overflow_evicts_oldest_half_not_everything(self, tmp_path):
         s = _connect(str(tmp_path / "d"))
@@ -533,6 +533,48 @@ class TestCapsMemoRegressions:
         assert ex._memo_writes == writes0 + 1
         assert ("lone", 0) in ex._load_caps_memo()
         s.close()
+
+
+    def test_parent_version_memo_is_ignored_not_misread(self, tmp_path):
+        """A memo persisted at CAPS_MEMO_VERSION 6 holds eight slots a
+        statement, the retired probe's table the seventh: the seven-slot
+        reader must drop the file by its version — never unpack it —
+        and the statement converges its capacities anew."""
+        data_dir = str(tmp_path / "d")
+        sql = "SELECT x.a, y.a FROM t x JOIN t y ON x.b = y.b"
+
+        def connect():
+            # capacity feedback on (the default): the join's output
+            # tightens at its first execution, which memoizes
+            return citus_tpu.connect(data_dir=data_dir, n_devices=4,
+                                     serving_result_cache_bytes=0)
+
+        s = _seed(data_dir)
+        s.close()
+        s = connect()
+        want = sorted(_rows(s.execute(sql)))
+        assert len(want) == sum(n * n for _b, n, _s in EXPECTED)
+        s.close()
+        path = os.path.join(data_dir, "caps_memo.json")
+        with open(path) as f:
+            obj = json.load(f)
+        assert obj["version"] == 7 and len(obj["memo"]) == 1
+        # rewrite it as the parent wrote it
+        slots = obj["memo"][0][1]["t"]
+        assert len(slots) == 7
+        slots.insert(6, {"d": []})
+        obj["version"] = 6
+        with open(path, "w") as f:
+            json.dump(obj, f)
+        s = connect()
+        assert s.executor._caps_memo == {}
+        assert sorted(_rows(s.execute(sql))) == want
+        assert len(s.executor._caps_memo) == 1
+        s.close()
+        with open(path) as f:
+            obj = json.load(f)
+        assert obj["version"] == 7
+        assert [len(v["t"]) for _k, v in obj["memo"]] == [7]
 
 
 class TestHygiene:

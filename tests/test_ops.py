@@ -319,12 +319,22 @@ class TestSortedUniqueLookup:
         pk = rng.integers(base - 100, base + extent + 100, n).astype(dtype)
         return bk, bmatch, pk
 
+    # (base, extent, build rows, probe rows).  The last two are the
+    # retired bucketed probe's inputs: its parity shape, and an extent of
+    # several thousand slots that is no multiple of anything, most of it
+    # taken, under probe keys on both sides of [base, base + extent)
+    @pytest.mark.parametrize("shape", [(1000, 1000, 600, 5000),
+                                       (0, 512, 300, 2000),
+                                       (7, 5003, 4100, 3000)],
+                             ids=lambda s: "-".join(map(str, s)))
     @pytest.mark.parametrize("dtype", [np.int32, np.int64])
-    def test_matches_dense_lookup_and_oracle(self, rng, dtype):
+    def test_matches_dense_lookup_and_oracle(self, rng, dtype, shape):
         from citus_tpu.ops.join import dense_unique_lookup
 
-        base, extent = 1000, 1000
-        bk, bmatch, pk = self._inputs(rng, dtype, base, extent)
+        base, extent, m, n = shape
+        bk, bmatch, pk = self._inputs(rng, dtype, base, extent, m, n)
+        assert (pk < base).any() and (pk >= base + extent).any()
+        assert not bmatch.all()
         bidx, counts, oob = self._lookup(bk, bmatch, pk)
         assert int(oob) == 0
         assert bidx.shape == counts.shape == pk.shape
@@ -385,98 +395,6 @@ class TestSortedUniqueLookup:
             bk, np.array([True, True, False, True, True, False, False]),
             pk)
         assert int(oob) == 0 and counts.tolist() == [1, 1, 1, 0]
-
-
-class TestBucketedUniqueLookup:
-    """VMEM-tiled bucketed probe (ops.join.bucketed_unique_lookup) vs
-    the single-gather dense_unique_lookup and a dict oracle.  The tile
-    size is patched small so tiny extents still span many buckets."""
-
-    TILE = 64
-
-    def _lookup(self, monkeypatch, bk, bmatch, pk, base, extent, cap,
-                **kw):
-        import citus_tpu.ops.join as J
-
-        monkeypatch.setattr(J, "PROBE_TILE_SLOTS", self.TILE)
-        return tuple(np.asarray(x) for x in J.bucketed_unique_lookup(
-            jnp.asarray(bk), jnp.asarray(bmatch), jnp.asarray(pk),
-            base, extent, cap, **kw))
-
-    def _inputs(self, rng, base=1000, extent=1000, m=600, n=5000):
-        bk = base + rng.permutation(extent)[:m].astype(np.int64)
-        bmatch = rng.random(m) > 0.1
-        pk = rng.integers(base - 100, base + extent + 100, n).astype(
-            np.int64)
-        return bk, bmatch, pk
-
-    def test_matches_single_gather_and_oracle(self, rng, monkeypatch):
-        from citus_tpu.ops.join import dense_unique_lookup
-
-        base, extent = 1000, 1000  # NOT a tile multiple: padded tail
-        bk, bmatch, pk = self._inputs(rng, base, extent)
-        bidx, counts, oob, overflow, max_fill = self._lookup(
-            monkeypatch, bk, bmatch, pk, base, extent, cap=len(pk))
-        assert int(overflow) == 0
-        dbidx, dcounts, doob = (np.asarray(x) for x in dense_unique_lookup(
-            jnp.asarray(bk), jnp.asarray(bmatch), jnp.asarray(pk),
-            base, extent))
-        np.testing.assert_array_equal(counts, dcounts)
-        np.testing.assert_array_equal(bidx[counts > 0],
-                                      dbidx[dcounts > 0])
-        assert int(oob) == int(doob)
-        # dict oracle
-        table = {int(k): i for i, k in enumerate(bk) if bmatch[i]}
-        for i in range(len(pk)):
-            hit = int(pk[i]) in table
-            assert bool(counts[i]) == hit
-            if hit:
-                assert int(bidx[i]) == table[int(pk[i])]
-        # realized skew: max bucket fill over in-range probes
-        slots = pk - base
-        inr = (slots >= 0) & (slots < extent)
-        fills = np.bincount(slots[inr] // self.TILE,
-                            minlength=-(-extent // self.TILE))
-        assert int(max_fill) == int(fills.max())
-
-    def test_pallas_kernel_parity(self, rng, monkeypatch):
-        base, extent = 0, 512
-        bk, bmatch, pk = self._inputs(rng, base, extent, m=300, n=2000)
-        want = self._lookup(monkeypatch, bk, bmatch, pk, base, extent,
-                            cap=len(pk), kernel="xla")
-        got = self._lookup(monkeypatch, bk, bmatch, pk, base, extent,
-                           cap=len(pk), kernel="pallas", interpret=True)
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(w, g)
-
-    def test_duplicate_build_keys_counted_as_oob(self, rng, monkeypatch):
-        # stale uniqueness claim: duplicates must surface through the
-        # same oob channel dense_unique_lookup uses (host retries on the
-        # general expansion path — never a silent arbitrary winner)
-        bk = np.array([1, 2, 2, 3, 900], dtype=np.int64)
-        bmatch = np.array([True, True, True, True, True])
-        pk = np.arange(1, 5, dtype=np.int64)
-        _, _, oob, overflow, _ = self._lookup(
-            monkeypatch, bk, bmatch, pk, base=1, extent=500, cap=16)
-        # one duplicate build row + one out-of-declared-range build row
-        assert int(oob) == 2
-        assert int(overflow) == 0
-
-    def test_bucket_overflow_reported_not_dropped_silently(
-            self, rng, monkeypatch):
-        # every probe hashes to bucket 0; cap 4 → the rest must be
-        # REPORTED so the host regrows per-bucket capacity and retries
-        m, n, cap = 8, 40, 4
-        bk = np.arange(m, dtype=np.int64)
-        pk = np.zeros(n, dtype=np.int64)  # all hit slot 0
-        bidx, counts, oob, overflow, max_fill = self._lookup(
-            monkeypatch, bk, np.ones(m, bool), pk, base=0,
-            extent=self.TILE * 4, cap=cap)
-        assert int(overflow) == n - cap
-        assert int(oob) == 0
-        assert int(counts.sum()) == cap  # survivors still correct
-        assert int(max_fill) == cap  # fill is capacity-clipped
-        assert all(int(b) == 0 for b in bidx[counts > 0])
 
 
 class TestBucketedGridAggregate:
@@ -588,22 +506,3 @@ class TestBucketedGridAggregate:
             if valid[i]:
                 want[slot[i]] += vi[i]
         np.testing.assert_array_equal(res[0], want)
-
-
-@pytest.mark.slow
-def test_probe_bench_harness_smoke():
-    """The probe A/B harness (bench_kernels.bench_probe) runs on the CPU
-    mesh and its correctness gate holds at toy sizes.  slow-marked: the
-    microbench stays out of tier-1 (-m 'not slow')."""
-    import pathlib
-    import sys
-
-    root = pathlib.Path(__file__).resolve().parent.parent
-    if str(root) not in sys.path:
-        sys.path.insert(0, str(root))
-    import bench_kernels
-
-    rows = bench_kernels.bench_probe(
-        regimes=[(1 << 14, 1 << 12, 1 << 15)], repeats=1, reps=2)
-    assert len(rows) == 1
-    assert rows[0][-1] is True  # single-gather vs bucketed hit parity

@@ -97,41 +97,3 @@ class TestBucketedGroupbySums:
                                       np.asarray(rp[0][1]))
         np.testing.assert_array_equal(np.asarray(rx[1]),
                                       np.asarray(rp[1]))
-
-
-class TestBucketedProbe:
-    """VMEM-tiled probe gather (bucketed_probe_pallas) vs numpy oracle:
-    grid chunking, cap padding, and garbage-lane handling."""
-
-    @pytest.mark.parametrize("k,tile,cap", [
-        (1, 128, 512),     # single bucket, exact chunk
-        (4, 128, 100),     # cap below one chunk → padded
-        (8, 256, 700),     # cap crosses a chunk boundary
-        (3, 512, 1024),    # multiple exact chunks
-    ])
-    def test_matches_numpy_oracle(self, rng, k, tile, cap):
-        from citus_tpu.ops.pallas_kernels import (
-            bucketed_probe_pallas,
-            probe_gather_reference,
-        )
-
-        dir2d = rng.integers(0, 10**6, (k, tile)).astype(np.int32)
-        loc2d = rng.integers(0, tile, (k, cap)).astype(np.int32)
-        got = np.asarray(bucketed_probe_pallas(dir2d, loc2d,
-                                               interpret=True))
-        want = probe_gather_reference(dir2d, loc2d)
-        np.testing.assert_array_equal(got, want)
-
-    def test_each_bucket_reads_its_own_tile(self, rng):
-        # tile i holds constant i: any cross-bucket read would show
-        from citus_tpu.ops.pallas_kernels import bucketed_probe_pallas
-
-        k, tile = 6, 128
-        dir2d = np.repeat(np.arange(k, dtype=np.int32)[:, None], tile,
-                          axis=1)
-        loc2d = rng.integers(0, tile, (k, 512)).astype(np.int32)
-        got = np.asarray(bucketed_probe_pallas(dir2d, loc2d,
-                                               interpret=True))
-        want = np.repeat(np.arange(k, dtype=np.int32)[:, None], 512,
-                         axis=1)
-        np.testing.assert_array_equal(got, want)

@@ -497,117 +497,6 @@ def test_mixed_count_and_distinct_over_empty_input(sess):
     assert r == (2, 2), r
 
 
-def _force_bucketed_lookup(plan, build_table, base, extent):
-    """Flip every join in `plan` onto the fused bucketed-probe path with
-    `build_table` as the (claimed-unique) build side."""
-    from citus_tpu.executor.feed import walk_plan
-    from citus_tpu.planner.plan import JoinNode, ScanNode
-
-    for node in walk_plan(plan.root):
-        if isinstance(node, JoinNode):
-            left_is_build = isinstance(node.left, ScanNode) and \
-                node.left.rel.table == build_table
-            node.fuse_lookup = True
-            node.probe_bucketed = True
-            node.build_side = "left" if left_is_build else "right"
-            node.left_key_extents = ((base, extent),)
-            node.right_key_extents = ((base, extent),)
-
-
-def test_bucketed_probe_join_matches_oracle(sess, monkeypatch):
-    """The VMEM-tiled bucketed probe path must return exactly what the
-    single-gather path returns — pinned end-to-end on the CPU mesh with
-    the tile patched small so the 200-slot directory spans 13 buckets."""
-    import citus_tpu.ops.join as J
-    from citus_tpu.sql.parser import parse_one
-
-    monkeypatch.setattr(J, "PROBE_TILE_SLOTS", 16)
-    calls = []
-    orig = J.bucketed_unique_lookup
-
-    def spy(*a, **kw):
-        calls.append(1)
-        return orig(*a, **kw)
-
-    monkeypatch.setattr(J, "bucketed_unique_lookup", spy)
-
-    sess.execute("create table bua (k bigint, v int)")
-    sess.create_distributed_table("bua", "k", shard_count=4)
-    sess.execute("create table bub (k bigint, w int)")
-    sess.create_distributed_table("bub", "k", shard_count=4)
-    sess.execute("insert into bua values " + ",".join(
-        f"({k},{k * 10})" for k in range(1, 201)))
-    # probes: two rows per key over a wider range, so some keys miss
-    # the directory entirely and some buckets stay empty
-    sess.execute("insert into bub values " + ",".join(
-        f"({i % 250 + 1},{i})" for i in range(400)))
-    plan, _cleanup = sess._plan_select(parse_one(
-        "select v, w from bua, bub where bua.k = bub.k"))
-    _force_bucketed_lookup(plan, "bua", base=1, extent=200)
-    result = sess.executor.execute_plan(plan)
-    assert calls, "bucketed probe path was never traced"
-    assert result.retries == 0  # clean first execution, no overflow
-    expect = sorted(((i % 250 + 1) * 10, i) for i in range(400)
-                    if i % 250 + 1 <= 200)
-    assert sorted(tuple(r) for r in result.rows()) == expect
-
-
-def test_bucketed_probe_duplicate_build_keys_fallback(sess, monkeypatch):
-    """Stale uniqueness under the bucketed probe: duplicate build keys
-    must surface dense_oob and retry on the general expansion path,
-    exactly like dense_unique_lookup — never an arbitrary single match."""
-    import citus_tpu.ops.join as J
-    from citus_tpu.sql.parser import parse_one
-
-    monkeypatch.setattr(J, "PROBE_TILE_SLOTS", 16)
-    sess.execute("create table dua (k bigint, v int)")
-    sess.create_distributed_table("dua", "k", shard_count=4)
-    sess.execute("create table dub (k bigint, w int)")
-    sess.create_distributed_table("dub", "k", shard_count=4)
-    sess.execute("insert into dua values (1,10),(2,20),(3,30)")
-    # build side duplicates k=2: the correct result needs BOTH matches
-    sess.execute("insert into dub values (1,1),(2,2),(2,5),(3,3)")
-    plan, _cleanup = sess._plan_select(parse_one(
-        "select v, w from dua, dub where dua.k = dub.k"))
-    _force_bucketed_lookup(plan, "dub", base=1, extent=3)
-    result = sess.executor.execute_plan(plan)
-    assert result.retries >= 1
-    assert sorted(tuple(r) for r in result.rows()) == \
-        [(10, 1), (20, 2), (20, 5), (30, 3)]
-
-
-def test_bucketed_probe_skew_overflow_regrows(sess, monkeypatch):
-    """A hot bucket (every probe hits one key) overflows its per-bucket
-    capacity; the count-then-emit contract must regrow and retry — rows
-    must never be silently dropped.  (A row-returning join: GLOBAL
-    aggregates take the join-agg pushdown, which probes via _bounds and
-    never fuses lookups.)"""
-    import citus_tpu.ops.join as J
-    from citus_tpu.sql.parser import parse_one
-
-    monkeypatch.setattr(J, "PROBE_TILE_SLOTS", 16)
-    sess.execute("set join_probe_bucket_factor = 1.0")
-    sess.execute("create table sua (k bigint, v int)")
-    sess.create_distributed_table("sua", "k", shard_count=4)
-    sess.execute("create table sub_ (k bigint, w int)")
-    sess.create_distributed_table("sub_", "k", shard_count=4)
-    sess.execute("insert into sua values " + ",".join(
-        f"({k},{k * 10})" for k in range(1, 65)))
-    # 600 probes of k=5 — all in ONE bucket on ONE device — plus a thin
-    # uniform spread so other buckets are nonempty
-    rows = [f"(5,{i})" for i in range(600)]
-    rows += [f"({i % 64 + 1},{1000 + i})" for i in range(64)]
-    sess.execute("insert into sub_ values " + ",".join(rows))
-    plan, _cleanup = sess._plan_select(parse_one(
-        "select v, w from sua, sub_ where sua.k = sub_.k"))
-    _force_bucketed_lookup(plan, "sua", base=1, extent=64)
-    result = sess.executor.execute_plan(plan)
-    assert result.retries >= 1  # the hot bucket overflowed and regrew
-    expect = sorted([(50, i) for i in range(600)] +
-                    [((i % 64 + 1) * 10, 1000 + i) for i in range(64)])
-    assert sorted(tuple(r) for r in result.rows()) == expect
-
-
 def _spy_lookup_arms(monkeypatch):
     """Count the traces of the two arms of a fused single-key lookup
     (the compiler imports them from ops.join as it traces)."""
@@ -766,6 +655,90 @@ def test_lookup_arm_follows_key_extent(tmp_path, monkeypatch, n_devices):
             for i, k in ((i, i % 250 + 1) for i in range(400)))
     finally:
         sess.close()
+
+
+def _planned_lookup_join(tmp_path, monkeypatch, arm, n_devices, build,
+                         probe):
+    """`build` (k, v) rows with unique keys joined to `probe` (k, w)
+    rows through the planner's own pick of lookup arm — no node flag is
+    forced; `sorted` only lowers the knee under the build key's extent.
+    Asserts the arm EXPLAIN names is the one traced, a clean first
+    execution (neither live arm has a capacity of its own to overflow)
+    and rows equal to the oracle's."""
+    import citus_tpu.ops.join as J
+
+    if arm == "sorted":
+        monkeypatch.setattr(J, "SORTED_LOOKUP_MIN_EXTENT", 16)
+    calls = _spy_lookup_arms(monkeypatch)
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "d"),
+                             n_devices=n_devices,
+                             serving_result_cache_bytes=0)
+    try:
+        sess.execute("create table b (k bigint, v int)")
+        sess.create_distributed_table("b", "k", shard_count=4)
+        sess.execute("create table p (k bigint, w int)")
+        sess.create_distributed_table("p", "k", shard_count=4)
+        sess.execute("insert into b values " + ",".join(
+            f"({k},{v})" for k, v in build))
+        sess.execute("insert into p values " + ",".join(
+            f"({k},{w})" for k, w in probe))
+        sql = "select v, w from b, p where b.k = p.k"
+        line = next(r[0] for r in sess.execute("explain " + sql).rows()
+                    if "[build: " in r[0])
+        assert "fused lookup" in line
+        assert ("sorted lookup" in line) == (arm == "sorted")
+        assert ("dense directory" in line) == (arm == "dense")
+        result = sess.execute(sql)
+        assert calls[arm] > 0 and sum(calls.values()) == calls[arm]
+        assert result.retries == 0
+        v_of = dict(build)
+        assert sorted(tuple(r) for r in result.rows()) == sorted(
+            (v_of[k], w) for k, w in probe if k in v_of)
+    finally:
+        sess.close()
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("arm", ["dense", "sorted"])
+def test_lookup_join_misses_and_empty_ranges(tmp_path, monkeypatch, arm,
+                                             n_devices):
+    """200 build keys under 400 probe rows over 250 keys, two rows a
+    key: a fifth of the probe keys is past the build side's range and
+    matches nothing."""
+    _planned_lookup_join(
+        tmp_path, monkeypatch, arm, n_devices,
+        build=[(k, k * 10) for k in range(1, 201)],
+        probe=[(i % 250 + 1, i) for i in range(400)])
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("arm", ["dense", "sorted"])
+def test_lookup_join_hot_probe_key(tmp_path, monkeypatch, arm, n_devices):
+    """600 probe rows of ONE key — one shard of one device — beside a
+    thin uniform spread: every row comes back, at the first execution.
+    (A row-returning join: GLOBAL aggregates take the join-agg pushdown,
+    which probes via _bounds and never fuses lookups.)"""
+    _planned_lookup_join(
+        tmp_path, monkeypatch, arm, n_devices,
+        build=[(k, k * 10) for k in range(1, 65)],
+        probe=[(5, i) for i in range(600)]
+        + [(i % 64 + 1, 1000 + i) for i in range(64)])
+
+
+@pytest.mark.parametrize("suffix,value", [("kernel", "'xla'"),
+                                          ("bucket_factor", "2.0")])
+def test_retired_join_probe_settings_are_unknown(sess, suffix, value):
+    """The bucketed probe's two settings left with it: SET refuses them
+    as it refuses any name that was never registered.  (The names are
+    put together here so that a search of the tree for them finds
+    nothing.)"""
+    from citus_tpu import config
+    from citus_tpu.errors import ConfigError
+
+    name = "join_probe_" + suffix
+    assert name not in config.registered_vars()
+    with pytest.raises(ConfigError, match="unrecognized configuration"):
+        sess.execute(f"set {name} = {value}")
 
 
 def test_stripe_row_limit_splits_and_stays_atomic(tmp_path):

@@ -90,22 +90,7 @@ class TestWireCodec:
 
 
 class TestDecodeKernels:
-    """Pallas formulations against the numpy oracles (interpret mode —
-    the CPU-runnable contract every other kernel here follows)."""
-
-    def test_bit_unpack_matches_reference(self):
-        from citus_tpu.ops.pallas_kernels import (
-            bit_unpack_pallas,
-            bit_unpack_reference,
-        )
-
-        rng = np.random.default_rng(1)
-        bits = rng.integers(0, 2, size=(2, 1024)).astype(bool)
-        packed = np.packbits(bits, axis=-1)
-        got = np.asarray(bit_unpack_pallas(packed, 1024,
-                                           interpret=True))
-        np.testing.assert_array_equal(
-            got, bit_unpack_reference(packed, 1024))
+    """The on-mesh decode against the numpy oracles."""
 
     def test_xla_decode_of_mesh_sharded_wire_buffers(self):
         """The formulations every device-mode scan runs, on buffers
@@ -151,20 +136,6 @@ class TestDecodeKernels:
         got = np.asarray(
             scanpipe._valid_expand(jax.device_put(n, rows), 640))
         assert got.sum(axis=1).tolist() == [5, 0, 640, 17]
-
-    def test_dict_decode_matches_reference(self):
-        from citus_tpu.ops.pallas_kernels import (
-            dict_decode_pallas,
-            dict_decode_reference,
-        )
-
-        rng = np.random.default_rng(2)
-        lut = np.linspace(0, 1, 37, dtype=np.float32)
-        codes = rng.integers(0, 37, size=(3, 700)).astype(np.uint8)
-        got = np.asarray(dict_decode_pallas(codes, lut,
-                                            interpret=True))
-        np.testing.assert_allclose(
-            got, dict_decode_reference(codes, lut))
 
 
 # ---------------------------------------------------------------------------

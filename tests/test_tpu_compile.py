@@ -9,10 +9,10 @@ directory), with `jax_enable_x64` on as the engine runs.  A refusal
 that interpret mode cannot show (tiling, 64-bit types, an unsupported
 gather) shows here and costs no chip time.
 
-A kernel the compiler refuses stays as `xfail(strict=True)` carrying
-its message, and is statically off the default path (scanpipe.py calls
-the XLA formulations); when a later PR repairs or deletes the kernel,
-the strict xfail turns red and the marker goes with it.
+A shape the compiler refuses stays as `xfail(strict=True)` carrying
+its message, and is statically off the default path; when a later PR
+repairs or deletes the kernel, the strict xfail turns red and the
+marker goes with it.
 
 The topology is described inside a module-scoped fixture and nowhere
 else: the driver runs several xdist workers, each imports every test
@@ -33,20 +33,12 @@ from citus_tpu.ops.groupby import (
     bucketed_grid_aggregate,
     group_bucket_count,
 )
-from citus_tpu.ops.join import (
-    PROBE_TILE_SLOTS,
-    bucketed_unique_lookup,
-    probe_bucket_count,
-    sorted_unique_lookup,
-)
+from citus_tpu.ops.join import sorted_unique_lookup
 
 # TPC-H SF1 on one chip (ingest/tpch.py): padded feed capacities
 LINEITEM_CAP = _round_cap(6_001_520)
 ORDERS_CAP = _round_cap(1_500_000)
 ORDERKEY_EXTENT = 6_000_000          # o_orderkey = 4i + 1, i < 1.5 M
-PROBE_BUCKETS = probe_bucket_count(ORDERKEY_EXTENT)
-# runner.py sizes a probe bucket at expectation × bucket factor (2.0)
-PROBE_BUCKET_CAP = _round_cap(int(LINEITEM_CAP / PROBE_BUCKETS * 2.0))
 
 
 def _group_cap(rows: int, buckets: int) -> int:
@@ -58,15 +50,6 @@ def _group_cap(rows: int, buckets: int) -> int:
 # null slot, in 4096-slot tiles
 ORDERKEY_GROUP_BUCKETS = group_bucket_count(ORDERKEY_EXTENT + 1)
 ORDERKEY_GROUP_CAP = _group_cap(LINEITEM_CAP, ORDERKEY_GROUP_BUCKETS)
-
-# what Mosaic says to every lane-dimension take_along_axis whose source
-# is wider than one 128-lane vreg (a 32768-slot directory tile, a
-# dictionary LUT): the formulation itself, not its block shapes
-_GATHER = ("Mosaic: 'Not implemented: Multiple source vregs along "
-           "gather dimension' (tpu.dynamic_gather gathers within one "
-           "128-lane vreg, and only when source and indices have one "
-           "shape)")
-
 
 @pytest.fixture(scope="module")
 def topo():
@@ -139,64 +122,7 @@ def test_bucketed_groupby_sums_pallas_compiles(chip, buckets, cap):
     assert "tpu_custom_call" in c.as_text()
 
 
-# -- Pallas kernels the compiler refuses: off every default path --------
-
-@pytest.mark.xfail(
-    strict=True, raises=RecursionError,
-    reason="as written the uint8→int32 widen of the packed block never "
-           "lowers (Pallas TPU convert rule recurses on unsigned 8-bit); "
-           "behind it, its (1, 128)/(1, 1024) uint8 blocks miss the "
-           "(32, 128) tiling and its byte-select is a lane gather: "
-           + _GATHER)
-def test_bit_unpack_pallas_compiles(chip):
-    _compile(lambda p: pk.bit_unpack_pallas(p, LINEITEM_CAP),
-             chip((1, LINEITEM_CAP // 8), jnp.uint8))
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="'64-bit types are not supported': under jax_enable_x64 "
-           "take_along_axis widens its indices to int64 inside the "
-           "kernel, whatever the LUT dtype; behind it, (1, 512) blocks "
-           "miss the (8, 128) tiling and the decode is a lane gather "
-           "over the whole LUT: " + _GATHER)
-@pytest.mark.parametrize("lut_dtype", [jnp.float32, jnp.float64])
-@pytest.mark.parametrize("code_dtype", [jnp.uint8, jnp.uint16])
-def test_dict_decode_pallas_compiles(chip, code_dtype, lut_dtype):
-    # l_quantity has 50 distinct values, l_extendedprice ~40 k at SF1
-    nv = 50 if code_dtype == jnp.uint8 else 40_000
-    _compile(pk.dict_decode_pallas,
-             chip((1, LINEITEM_CAP), code_dtype), chip((nv,), lut_dtype))
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="'The Pallas TPU lowering currently requires that the last "
-           "two dimensions of your block shape are divisible by 8 and "
-           "128 respectively, or be equal to the respective dimensions "
-           "of the overall array' for its (1, 32768) and (1, 512) "
-           "blocks; behind that, the probe is a lane gather of 512 "
-           "slots from a 32768-slot tile: " + _GATHER)
-def test_bucketed_probe_pallas_compiles(chip):
-    _compile(pk.bucketed_probe_pallas,
-             chip((PROBE_BUCKETS, PROBE_TILE_SLOTS), jnp.int32),
-             chip((PROBE_BUCKETS, PROBE_BUCKET_CAP), jnp.int32))
-
-
 # -- XLA formulations the planner picks on `tpu` -------------------------
-
-def test_bucketed_unique_lookup_xla_compiles(chip):
-    """Q3's lineitem ⋈ orders probe at SF1: 6 M int64 probe keys into
-    the 6 M-slot order-key directory (the bucketed probe pick,
-    planner/plan.py `probe_bucketed`)."""
-    c = _compile(
-        lambda bk, bm, pkey: bucketed_unique_lookup(
-            bk, bm, pkey, 1, ORDERKEY_EXTENT, PROBE_BUCKET_CAP,
-            kernel="xla"),
-        chip((ORDERS_CAP,), jnp.int64), chip((ORDERS_CAP,), jnp.bool_),
-        chip((LINEITEM_CAP,), jnp.int64))
-    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
-
 
 def test_sorted_unique_lookup_compiles(chip):
     """Q3's lineitem ⋈ orders lookup on the sort-and-scan arm (the
