@@ -356,6 +356,87 @@ def bench_compact(small=False, out="chiprun_out"):
     return rows
 
 
+def bench_carry(small=False, out="chiprun_out"):
+    """What it costs to carry C = 1…5 columns through two compactions,
+    6,001,536 → 1,800,320 → 360,576 slots (`ssb1.q4_1`'s), read after
+    the second (PR 32): a gather of every column at each step (the form
+    every program ran until PR 32, kept here alone) against
+    `Block.take`, which carries them as a row index — one composition
+    of the two indexes at the last size, then one gather a column
+    there.  A third form reads two of five columns after the first
+    step, as Q4.1 reads two join keys there.  Both compactions'
+    positions are given (their sorts are `compact`'s sweep); the
+    columns move with the iteration, so nothing is hoisted out of the
+    timed loop.  Writes `<out>/bench_carry.json`.
+
+    Usage:  python bench_kernels.py carry        (the chip)
+            python bench_kernels.py carry small  (a rehearsal anywhere)
+    """
+    import json
+    import os
+
+    from citus_tpu.runtime import ensure_jax_configured
+
+    ensure_jax_configured()
+    from citus_tpu.executor.batch import Block
+    from citus_tpu.executor.compiler import _round_cap
+    dev = jax.devices()[0]
+    print(f"backend: {dev.platform} ({dev.device_kind})")
+    rng = np.random.default_rng(0)
+    scale = 64 if small else 1
+    n0, k1, k2 = (_round_cap(n // scale)
+                  for n in (6_001_536, 1_800_320, 360_576))
+    por1 = jnp.asarray(np.sort(rng.permutation(n0)[:k1]).astype(np.int32))
+    por2 = jnp.asarray(np.sort(rng.permutation(k1)[:k2]).astype(np.int32))
+    base = [jnp.asarray(rng.integers(0, 1 << 20, n0).astype(np.int32))
+            for _ in range(5)]
+    live1, live2 = jnp.ones(k1, bool), jnp.ones(k2, bool)
+
+    def total(arrays):
+        return sum(a.sum(dtype=jnp.int64) for a in arrays)
+
+    def eager(cols, keys):
+        mid = [c[por1] for c in cols]
+        return total([m[por2] for m in mid] + mid[:keys])
+
+    def deferred(cols, keys):
+        blk = Block({str(j): c for j, c in enumerate(cols)},
+                    jnp.ones(n0, bool))
+        mid = blk.take(por1, live1)
+        read = [mid.columns[str(j)] for j in range(keys)]
+        last = mid.take(por2, live2)
+        return total([last.columns[str(j)] for j in range(len(cols))]
+                     + read)
+
+    forms = {"eager": eager, "deferred": deferred}
+    rows = []
+    for n_cols, keys in [(c, 0) for c in range(1, 6)] + [(5, 2)]:
+        # slots gathered: every column at both steps | the columns read
+        # in between at the first, one composition if any column is
+        # left to carry, every column at the second
+        slots = {"eager": n_cols * (k1 + k2),
+                 "deferred": keys * k1 + (n_cols > keys) * k2 + n_cols * k2}
+        want = None
+        for name, form in forms.items():
+            def fn(i, form=form):
+                return form([b + i.astype(jnp.int32)
+                             for b in base[:n_cols]], keys)
+            t, compile_s, got = _slope_time_once(fn)
+            want = got if want is None else want
+            rows.append({"form": name, "columns": n_cols,
+                         "read_after_first": keys,
+                         "sizes": [n0, k1, k2], "ms": t * 1e3,
+                         "slots_gathered": slots[name],
+                         "ns_per_slot": t * 1e9 / slots[name],
+                         "compile_s": compile_s, "agrees": got == want})
+            print(json.dumps(rows[-1]), flush=True)
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "bench_carry.json"), "w") as f:
+        json.dump({"device": [dev.platform, dev.device_kind],
+                   "rows": rows}, f, indent=1)
+    return rows
+
+
 def bench_groupby(regimes=None, repeats=3, reps=8):
     """High-cardinality GROUP BY A/B (round 7): the sort path
     (packed-key `segment_aggregate`, exactly what the executor runs)
@@ -552,6 +633,8 @@ if __name__ == "__main__":
                           "full"), small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "compact":
         bench_compact(small="small" in sys.argv[2:])
+    elif len(sys.argv) > 1 and sys.argv[1] == "carry":
+        bench_carry(small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "groupby":
         bench_groupby()
     else:

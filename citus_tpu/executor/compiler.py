@@ -60,8 +60,14 @@ from ..planner.plan import (
 )
 from ..distributed.mesh import SHARD_AXIS
 from ..stats.tracing import STAGE_NAMES, stage_scope
-from .batch import Block
-from .exprs import ColumnSource, evaluate, predicate_mask
+from .batch import Block, deferred_tally
+from .exprs import (
+    ColumnSource,
+    evaluate,
+    predicate_mask,
+    set_device_float64,
+    set_device_params,
+)
 
 NULL_PREFIX = "__null__"
 
@@ -367,9 +373,15 @@ class PlanCompiler:
             # trace-time device float policy: SQL float64 evaluates in the
             # session compute dtype on device (thread-local — tracing runs
             # on the calling thread)
-            from .exprs import set_device_float64, set_device_params
-
             set_device_float64(self.compute_dtype)
+            with deferred_tally() as tally:
+                packed = traced(flat_feeds)
+            # assigned, not accumulated, like _shuffle_bytes: published
+            # as PlanCompiler.deferred after build
+            self._deferred = (tally.columns, tally.gathers)
+            return packed
+
+        def traced(flat_feeds):
             if n_params:
                 param_args = flat_feeds[-n_params:]
                 flat_feeds = flat_feeds[:-n_params]
@@ -444,6 +456,10 @@ class PlanCompiler:
         # exist in this program (the psum-directory pushdown compiles
         # shuffles away entirely — a caps-table estimate would lie)
         self.shuffle_bytes = int(self._shuffle_bytes)
+        # (columns this program carries as a row index across a
+        # compaction or a lookup, gathers it issues for them later):
+        # deferred_columns_total / deferred_gathers_total
+        self.deferred = self._deferred
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
         for cid in out_cids:
@@ -758,20 +774,21 @@ class PlanCompiler:
         A selective filter leaves the block mostly padding; every
         downstream sort/shuffle/join still pays for the full capacity.
         Compaction costs one sort of the positions at the OLD size
-        (`survivor_positions`) and one gather per column at the NEW
-        size, and shrinks everything after it to the filtered-estimate
-        size.  More survivors than k counts as capacity overflow (host
-        retries with doubled slots)."""
+        (`survivor_positions`) and gathers nothing itself: the columns
+        follow as that row index (`Block.take`) and each costs one
+        gather at the NEW size where it is first read — or, if it
+        crosses the next compaction unread, a share of one index
+        composition at that one's size.  Everything after it shrinks
+        to the filtered-estimate size.  More survivors than k counts
+        as capacity overflow (host retries with doubled slots)."""
         with stage_scope("compact"):
             por, n_valid = survivor_positions(blk.valid, k)
             out_valid = jnp.arange(k, dtype=jnp.int32) < n_valid
             # padding slots read row 0, never the sentinel
             por = jnp.where(out_valid, por, 0)
-            cols = {cid: arr[por] for cid, arr in blk.columns.items()}
-            nulls = {cid: nm[por] for cid, nm in blk.nulls.items()}
             self._overflow = self._overflow + \
                 jnp.maximum(n_valid - k, 0).astype(jnp.int64)
-            return Block(cols, out_valid, nulls)
+            return blk.take(por, out_valid)
 
     def _project(self, blk: Block, exprs) -> Block:
         cols, nulls = {}, {}
@@ -824,9 +841,7 @@ class PlanCompiler:
         invalid = ~blk.valid
         order = jnp.lexsort(tuple(operands) + (invalid,))[:k] \
             .astype(jnp.int32)
-        cols = {cid: arr[order] for cid, arr in blk.columns.items()}
-        nulls = {cid: nm[order] for cid, nm in blk.nulls.items()}
-        return Block(cols, blk.valid[order], nulls)
+        return blk.take(order, blk.valid[order])
 
     # -- joins ----------------------------------------------------------
     def _eval_keys(self, blk: Block, keys,
@@ -1038,7 +1053,10 @@ class PlanCompiler:
         """Fused PK-side lookup join: one output row per probe row.
 
         No pair-expansion buffers, no emission scan — probe columns pass
-        through untouched and build columns arrive by one gather.  A
+        through untouched and build columns follow the lookup's index
+        (`Block.take`: gathered where they are first read, so a column
+        only the aggregate reads crosses a later compaction as an
+        index).  A
         probe with >1 match means the planner's uniqueness claim was
         stale: the surplus is reported as dense_oob so the host retries
         on the general expansion path (never silently dropped pairs)."""
@@ -1086,32 +1104,25 @@ class PlanCompiler:
             if not probe_outer and node.residual is None:
                 self._record(id(node), "join_out", out_valid.sum(),
                              out_valid.shape[0])
-            # selective FK join: compact the probe side BEFORE gathering
-            # build columns, so the gathers and everything downstream run at
+            built = bblk.take(bidx, out_valid)
+            if probe_outer:
+                # null extension: a probe row without a match reads NULL
+                # in every build column, so their masks are read here;
+                # the values stay deferred
+                missing = ~found
+                built = Block(built.columns, out_valid, {
+                    cid: (built.nulls[cid] | missing
+                          if cid in built.nulls else missing)
+                    for cid in built.columns})
+            blk = pblk.joined(built, out_valid)
+            # selective FK join: compact BEFORE any build column is
+            # gathered, so those gathers and everything downstream run at
             # the join-estimate size instead of the probe capacity
             k = self.caps.join_out.get(id(node))
             if (not probe_outer and node.residual is None and k is not None
                     and k < out_valid.shape[0]):
-                marker = "__bidx__"
-                tmp = Block({**pblk.columns, marker: bidx}, out_valid,
-                            pblk.nulls)
-                tmp = self._compact(tmp, k)
-                bidx = tmp.columns.pop(marker)
-                pblk = Block(tmp.columns, tmp.valid, tmp.nulls)
-                out_valid = tmp.valid
-            cols = dict(pblk.columns)
-            nulls = dict(pblk.nulls)
-            for cid, arr in bblk.columns.items():
-                cols[cid] = arr[bidx]
-                nm = bblk.nulls.get(cid)
-                gathered = nm[bidx] if nm is not None else None
-                if probe_outer:
-                    missing = ~found
-                    nulls[cid] = (missing if gathered is None
-                                  else (gathered | missing))
-                elif gathered is not None:
-                    nulls[cid] = gathered
-            return Block(cols, out_valid, nulls)
+                blk = self._compact(blk, k)
+            return blk
 
     def _exec_join(self, node: JoinNode, feeds) -> Block:
         lblk, rblk, lkeys, lmatch, rkeys, rmatch = \
@@ -1156,16 +1167,8 @@ class PlanCompiler:
                 self._overflow = self._overflow + overflow.astype(jnp.int64)
                 self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
                 self._record(id(node), "join_out", out_valid.sum(), out_cap)
-                cols, nulls = {}, {}
-                for cid, arr in pblk.columns.items():
-                    cols[cid] = arr[pidx]
-                for cid, nmask in pblk.nulls.items():
-                    nulls[cid] = nmask[pidx]
-                for cid, arr in bblk.columns.items():
-                    cols[cid] = arr[bidx]
-                for cid, nmask in bblk.nulls.items():
-                    nulls[cid] = nmask[bidx]
-                blk = Block(cols, out_valid, nulls)
+                blk = pblk.take(pidx, out_valid).joined(
+                    bblk.take(bidx, out_valid), out_valid)
             else:
                 blk = self._exec_outer_expand(node, lblk, rblk, lkeys, lmatch,
                                               rkeys, rmatch, out_cap)
@@ -1231,11 +1234,8 @@ class PlanCompiler:
             if getattr(node, "flag_combine", False):
                 matched = jax.lax.psum(matched.astype(jnp.int32),
                                        SHARD_AXIS) > 0
-            if node.join_type == "anti":
-                valid = lblk.valid & ~matched
-            else:
-                valid = lblk.valid & matched
-            return Block(dict(lblk.columns), valid, dict(lblk.nulls))
+            return lblk.with_filter(~matched if node.join_type == "anti"
+                                    else matched)
 
     def _exec_outer_expand(self, node: JoinNode, lblk: Block, rblk: Block,
                            lkeys, lmatch, rkeys, rmatch,

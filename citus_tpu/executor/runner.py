@@ -231,9 +231,9 @@ class Executor:
         if streamed is not None:
             return streamed
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
-        packed, out_meta, caps, retries, feeds = self._run_resident(
-            plan, compute_dtype)
-        self.count_picks(plan, caps)
+        packed, out_meta, caps, retries, feeds, deferred = \
+            self._run_resident(plan, compute_dtype)
+        self.count_picks(plan, caps, deferred)
         with trace_span("combine"):
             cols, nulls, valid = unpack_outputs(packed, out_meta)
             result = self._host_combine(plan, cols, nulls, valid, raw)
@@ -280,9 +280,9 @@ class Executor:
                 memo = self._caps_memo.get(fingerprint)
             caps = (self._caps_from_order(plan, memo) if memo is not None
                     else self._initial_capacities(plan, feeds))
-        packed, out_meta, caps, retries = self.run_with_retry(
+        packed, out_meta, caps, retries, deferred = self.run_with_retry(
             plan, feeds, caps, fingerprint, compute_dtype)
-        return packed, out_meta, caps, retries, feeds
+        return packed, out_meta, caps, retries, feeds, deferred
 
     # ------------------------------------------------------------------
     def execute_pass(self, plan: QueryPlan, split_nid: int):
@@ -299,14 +299,15 @@ class Executor:
                                         no_cache_nodes=frozenset(
                                             {split_nid}))
         if streamed is not None:
-            parts, scanned, retries, batches, caps = streamed
+            parts, scanned, retries, batches, caps, deferred = streamed
             if caps is not None:
-                self.count_picks(plan, caps)
+                self.count_picks(plan, caps, deferred)
             return parts, scanned, retries, batches
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
-        packed, out_meta, caps, retries, _feeds = self._run_resident(
-            plan, compute_dtype, no_cache_nodes=frozenset({split_nid}))
-        self.count_picks(plan, caps)
+        packed, out_meta, caps, retries, _feeds, deferred = \
+            self._run_resident(plan, compute_dtype,
+                               no_cache_nodes=frozenset({split_nid}))
+        self.count_picks(plan, caps, deferred)
         cols, nulls, valid = unpack_outputs(packed, out_meta)
         scanned = int(np.asarray(valid).size)
         return [_flatten_batch(cols, nulls, valid)], scanned, retries, 0
@@ -371,11 +372,11 @@ class Executor:
                                               compute_dtype, group_kernel,
                                               key)
                 self.plan_cache.put(key, entry)
-                fn, out_meta, stage_keys, shuffle_bytes = entry
+                fn, out_meta, stage_keys, shuffle_bytes, deferred = entry
                 feed_arrays = flatten_feed_arrays(plan, feeds,
                                                   compute_dtype)
             else:
-                fn, out_meta, stage_keys, shuffle_bytes = entry
+                fn, out_meta, stage_keys, shuffle_bytes, deferred = entry
                 with trace_span("compile", cache="hit"):
                     feed_arrays = flatten_feed_arrays(plan, feeds,
                                                       compute_dtype)
@@ -483,7 +484,7 @@ class Executor:
 
                         self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
                                                 shuffle_bytes)
-                    return packed, out_meta, caps, retries
+                    return packed, out_meta, caps, retries, deferred
             retries += 1
             from ..utils.faultinjection import fault_point
 
@@ -557,7 +558,7 @@ class Executor:
            executable is serializable), persisted through the io seam.
 
         Returns the plan-cache entry ``(fn, out_meta, stage_keys,
-        shuffle_bytes)``."""
+        shuffle_bytes, deferred)``."""
         from ..stats import counters as sc
         from ..stats.tracing import trace_span
 
@@ -576,7 +577,8 @@ class Executor:
                 # deduped followers
                 fn = fn.lower(*feed_arrays).compile()
             ec.note_compile()  # actual-compile ledger (dedup asserts)
-            entry = (fn, out_meta, stage_keys, compiler.shuffle_bytes)
+            entry = (fn, out_meta, stage_keys, compiler.shuffle_bytes,
+                     compiler.deferred)
             if use_cache:
                 ec.store(key, self.mesh, *entry)
             return entry
@@ -762,7 +764,8 @@ class Executor:
         return evicted
 
     # ------------------------------------------------------------------
-    def count_picks(self, plan: QueryPlan, caps: Capacities) -> None:
+    def count_picks(self, plan: QueryPlan, caps: Capacities,
+                    deferred: tuple[int, int]) -> None:
         """groupby_bucketed_total, lookup_sorted_total,
         lookup_dense_total and broadcast_joins_total: each bumped once
         per executed STATEMENT whose converged plan ran the bucketed
@@ -773,11 +776,18 @@ class Executor:
         streamed path calls it once after the batch loop, not per
         batch), and a dense_oob fallback onto the general paths
         (caps.dense_off) correctly counts no pick (its broadcast joins
-        stay broadcast joins)."""
+        stay broadcast joins).  deferred_columns_total and
+        deferred_gathers_total take `deferred`, the two counts the
+        converged program's compiler recorded at trace time
+        (PlanCompiler.deferred; they ride in the plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
+        carried, gathered = deferred
+        if carried:
+            self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
+            self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         nbk = sum(1 for nd in nodes

@@ -54,6 +54,13 @@ LOOKUP_DENSE_TOTAL = "lookup_dense_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
+# columns (a null mask counting as one) the executed statements'
+# programs carried across a compaction or a lookup as a row index,
+# without a gather at that step (executor/batch.py Block.take) …
+DEFERRED_COLUMNS_TOTAL = "deferred_columns_total"
+# … and the gathers those programs issued for them later: values, null
+# masks and index compositions.  The difference is what deferring saved
+DEFERRED_GATHERS_TOTAL = "deferred_gathers_total"
 # static all_to_all shuffle buffer volume the executed plans moved over
 # the mesh (per-device capacity × devices² × row width, summed over the
 # plan's repartition stages and every stream batch) — the EXPLAIN
@@ -132,6 +139,7 @@ ALL_COUNTERS = [
     INSERT_SELECT_PUSHDOWN, INSERT_SELECT_REPARTITION, INSERT_SELECT_PULL,
     CHUNKS_SKIPPED, QUERIES_STREAMED, GROUPBY_BUCKETED_TOTAL,
     LOOKUP_SORTED_TOTAL, LOOKUP_DENSE_TOTAL, BROADCAST_JOINS_TOTAL,
+    DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     SHUFFLE_BYTES_TOTAL,
     CHUNKS_PREFETCHED_TOTAL, PREFETCH_STALLS_TOTAL,
     DEVICE_DECODED_BYTES_TOTAL,

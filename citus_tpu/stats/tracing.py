@@ -197,8 +197,12 @@ STAGE_NAMES: dict[str, str] = {
     "join_out": "join keys, pair emission / build-column gathers, "
                 "residual filter, compaction",
     "compact": "sub: scan_out, join_out, agg_out — survivors' positions "
-               "by one sort, then one gather a column at the compacted "
-               "size",
+               "by one sort; the columns follow as that row index and "
+               "are gathered where they are read (`deferred`)",
+    "deferred": "sub: any stage that reads a column — the gather of a "
+                "column that crossed a compaction or a lookup as a row "
+                "index (executor/batch.py Block.take), and the "
+                "composition of two such indexes",
     "agg_grid": "dense-grid group-by + psum combine",
     "agg_bucket": "bucketed dense-grid group-by",
     "agg_sort": "sort-path group-by (both levels of a repartition "

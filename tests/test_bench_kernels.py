@@ -49,3 +49,19 @@ def test_compact_harness_smoke(tmp_path):
     forms = [r for r in rows if "form" in r]
     assert len(forms) == 20 and all(r["agrees"] for r in forms)
     assert (tmp_path / "bench_compact.json").exists()
+
+
+def test_carry_harness_smoke(tmp_path):
+    """`python bench_kernels.py carry small`: the eager form kept in
+    the tool and `Block.take` agree for every column count, and the
+    table is written where asked."""
+    import bench_kernels
+
+    rows = bench_kernels.bench_carry(small=True, out=str(tmp_path))
+    assert len(rows) == 12 and all(r["agrees"] for r in rows)
+    by_form = {(r["form"], r["columns"], r["read_after_first"]): r
+               for r in rows}
+    # five columns, none read in between: ten gathers against six
+    assert by_form["deferred", 5, 0]["slots_gathered"] < \
+        by_form["eager", 5, 0]["slots_gathered"]
+    assert (tmp_path / "bench_carry.json").exists()

@@ -148,6 +148,42 @@ def test_q4_1_plan_and_counters(loaded):
     assert any("ct.lookup_join/ct.dense" in text for text in programs)
 
 
+def test_q4_1_program_gathers_what_is_read(loaded):
+    """Columns cross a compaction or a lookup as a row index and are
+    gathered where they are first read (PR 32).  At this scale the
+    first `join_out` compaction packs 300,160 probe slots into 90,624,
+    and the parent's program gathered six times at that size: the five
+    fact columns through the compaction and the next lookup's probe.
+    Now two: the one column read at that size (the next join's key) and
+    that probe — the other four cross the next compaction as an index.
+    Each statement says so in the two counters."""
+    import re
+
+    sess = loaded(1, SEEDS[0])
+    sql = statement_text("q4_1")
+    sess.execute(sql).rows()  # converge capacities
+    before = sess.stats.counters.snapshot()
+    sess.execute(sql).rows()
+    after = sess.stats.counters.snapshot()
+    carried, gathered = (after[k] - before[k] for k in (
+        sc.DEFERRED_COLUMNS_TOTAL, sc.DEFERRED_GATHERS_TOTAL))
+    assert carried > gathered > 0
+    text = list(sess.executor.plan_cache._entries.values())[-1][0].as_text()
+    sizes = [int(m.group(1)) for m in re.finditer(
+        r"= \w+\[(\d+)(?:,1)?\]\S* gather\(", text)]
+    first_compaction = 90_624
+    assert "ct.join_out/ct.compact" in text
+    assert f"[{first_compaction}]" in text
+    assert sizes.count(first_compaction) == 2    # the parent's count: 6
+    assert max(sizes) == 300_160                 # the first probe, alone
+    assert sizes.count(300_160) == 1
+    # every gather of a carried column stands under the sub-scope the
+    # benchmark's stage_deferred_ms reads
+    assert "ct.join_out/ct.deferred" in text
+    assert "ct.agg_grid/ct.deferred" in text
+    assert not re.search(r"ct\.compact/[^\"]*gather", text)
+
+
 def test_seeds_share_shapes_not_answers(loaded, rows_of):
     """`--seed` permutes the fact table's measure tuples: row counts,
     key extents and the plan (its fingerprint) stay, every answer

@@ -35,6 +35,9 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
     monkeypatch.setattr(harness, "Cell", TinyCell)
     monkeypatch.setattr(harness, "REQUIRED_PLATFORM", "cpu")
     monkeypatch.setattr(harness, "DATA_ROOT", data_root)
+    runs, judge = [], harness.judge
+    monkeypatch.setattr(harness, "judge", lambda run, head: (
+        runs.append(run), judge(run, head))[1])
     rc = harness.main(["--workload", CELL, "--seed", str(SEED),
                        "--seconds", "2", "--trace", str(trace)])
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
@@ -54,8 +57,19 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 1
     got = {name: m["value"] for name, m in result["metrics"].items()}
+    # what the window's statements carried as a row index and what they
+    # gathered for it later (PR 32): both in the window's counters,
+    # the same for every statement of one converged program
+    counters = runs[0].window["counters"]
+    carried = counters["deferred_columns_total"] / result["attempted"]
+    gathered = counters["deferred_gathers_total"] / result["attempted"]
+    assert carried == int(carried) and gathered == int(gathered)
+    assert carried > gathered > 0
     if trace:
         assert got["broadcast_joins"] == 4
+        assert got["deferred_columns"] == carried
+        # stage_deferred_ms needs a device trace, as the stages below
+        assert "stage_deferred_ms" not in got
         assert got["window_compiles"] == 0
         # device metrics need a device trace: none on the CPU
         assert "stage_lookup_dense_ms" not in got

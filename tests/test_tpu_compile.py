@@ -321,4 +321,18 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
                    if "ct.join_out/ct.compact" in ln]
     assert sum(" sort(" in ln for ln in compact_ops) == 2
     assert not any("scatter" in ln for ln in compact_ops)
+    # columns cross a compaction or a lookup as a row index and are
+    # gathered where they are first read (PR 32).  At the first
+    # compaction's 1,800,320 slots the parent gathered eight times (five
+    # fact columns, `d_year`, two probes); four are left: `lo_orderdate`
+    # and `lo_custkey`, read there as join keys, and the two probes.  At
+    # 360,576 slots seven became nine (three index compositions, five
+    # columns through them, the `part` probe), and none of it is under
+    # `ct.compact` any more
+    sizes = [int(m.group(1)) for m in re.finditer(
+        r"= \w+\[(\d+)\]\S* gather\(", text)]
+    assert sizes.count(5_999_232) == 1
+    assert sizes.count(1_800_320) == 4
+    assert sizes.count(360_576) == 9
+    assert not any(" gather(" in ln for ln in compact_ops)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
