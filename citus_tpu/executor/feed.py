@@ -29,6 +29,10 @@ from ..planner.plan import (
     ScanNode,
     WindowNode,
 )
+from ..stats.counters import (
+    FEED_CACHE_HIT_BYTES_TOTAL,
+    FEED_CACHE_MISS_BYTES_TOTAL,
+)
 from ..storage import TableStore
 from .compiler import FeedSpec, _round_cap
 
@@ -202,7 +206,11 @@ def _feed_scan_cached(node: ScanNode, catalog: Catalog, store: TableStore,
                            capacity=spec.capacity, nbytes=nbytes,
                            dev_rows=spec.dev_rows)
         cache.put(key, entry)
+        if counters is not None:
+            counters.increment(FEED_CACHE_MISS_BYTES_TOTAL, nbytes)
         return spec
+    if counters is not None:
+        counters.increment(FEED_CACHE_HIT_BYTES_TOTAL, entry.nbytes)
     return FeedSpec(node=node, sharded=entry.sharded, arrays=entry.arrays,
                     nulls=entry.nulls, valid=entry.valid,
                     capacity=entry.capacity, dev_rows=entry.dev_rows)

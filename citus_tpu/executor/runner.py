@@ -771,7 +771,9 @@ class Executor:
         per executed STATEMENT whose converged plan ran the bucketed
         dense-grid group-by (by its number of such aggregates), at
         least one sort-and-scan lookup join, at least one dense
-        directory lookup join, or broadcast joins (by their number) —
+        directory lookup join, or broadcast joins (by their number);
+        lookup_sorted_joins_total and lookup_dense_joins_total count
+        the same fused lookup joins by their number on each arm —
         callers invoke this after their retry loop settles (the
         streamed path calls it once after the batch loop, not per
         batch), and a dense_oob fallback onto the general paths
@@ -803,12 +805,16 @@ class Executor:
                   and PlanCompiler.agg_pushdown_shape(nd)}
         joins = [nd for nd in nodes if isinstance(nd, JoinNode)]
         fused = [nd for nd in joins if id(nd) not in pushed]
-        if any(PlanCompiler.sorted_lookup_shape(nd, caps.dense_off)
-               for nd in fused):
+        n_sorted = sum(PlanCompiler.sorted_lookup_shape(nd, caps.dense_off)
+                       for nd in fused)
+        if n_sorted:
             self.counters.increment(sc.LOOKUP_SORTED_TOTAL)
-        if any(PlanCompiler.dense_lookup_shape(nd, caps.dense_off)
-               for nd in fused):
+            self.counters.increment(sc.LOOKUP_SORTED_JOINS_TOTAL, n_sorted)
+        n_dense = sum(PlanCompiler.dense_lookup_shape(nd, caps.dense_off)
+                      for nd in fused)
+        if n_dense:
             self.counters.increment(sc.LOOKUP_DENSE_TOTAL)
+            self.counters.increment(sc.LOOKUP_DENSE_JOINS_TOTAL, n_dense)
         nbc = sum(1 for nd in joins if nd.strategy == "broadcast")
         if nbc:
             self.counters.increment(sc.BROADCAST_JOINS_TOTAL, nbc)

@@ -51,6 +51,11 @@ LOOKUP_SORTED_TOTAL = "lookup_sorted_total"
 # … and on the other arm, the gather from a dense directory
 # (ops.join.dense_unique_lookup: a key extent under that)
 LOOKUP_DENSE_TOTAL = "lookup_dense_total"
+# the same two picks counted by JOIN: fused lookup joins of the executed
+# statements' converged plans on each arm (a star join runs several, and
+# may mix the arms in one fragment)
+LOOKUP_SORTED_JOINS_TOTAL = "lookup_sorted_joins_total"
+LOOKUP_DENSE_JOINS_TOTAL = "lookup_dense_joins_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
@@ -61,6 +66,12 @@ DEFERRED_COLUMNS_TOTAL = "deferred_columns_total"
 # … and the gathers those programs issued for them later: values, null
 # masks and index compositions.  The difference is what deferring saved
 DEFERRED_GATHERS_TOTAL = "deferred_gathers_total"
+# device bytes of the scan feeds the executed statements were served
+# from the feed cache (executor/cache.py FeedCache: resident between
+# statements) and of those they had to build — read, decode, place —
+# because the cache held none (executor/feed.py _feed_scan_cached)
+FEED_CACHE_HIT_BYTES_TOTAL = "feed_cache_hit_bytes_total"
+FEED_CACHE_MISS_BYTES_TOTAL = "feed_cache_miss_bytes_total"
 # static all_to_all shuffle buffer volume the executed plans moved over
 # the mesh (per-device capacity × devices² × row width, summed over the
 # plan's repartition stages and every stream batch) — the EXPLAIN
@@ -138,8 +149,11 @@ ALL_COUNTERS = [
     CAPACITY_RETRIES, DEVICE_ROWS_SCANNED,
     INSERT_SELECT_PUSHDOWN, INSERT_SELECT_REPARTITION, INSERT_SELECT_PULL,
     CHUNKS_SKIPPED, QUERIES_STREAMED, GROUPBY_BUCKETED_TOTAL,
-    LOOKUP_SORTED_TOTAL, LOOKUP_DENSE_TOTAL, BROADCAST_JOINS_TOTAL,
+    LOOKUP_SORTED_TOTAL, LOOKUP_DENSE_TOTAL,
+    LOOKUP_SORTED_JOINS_TOTAL, LOOKUP_DENSE_JOINS_TOTAL,
+    BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
+    FEED_CACHE_HIT_BYTES_TOTAL, FEED_CACHE_MISS_BYTES_TOTAL,
     SHUFFLE_BYTES_TOTAL,
     CHUNKS_PREFETCHED_TOTAL, PREFETCH_STALLS_TOTAL,
     DEVICE_DECODED_BYTES_TOTAL,

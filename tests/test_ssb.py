@@ -3,8 +3,15 @@ hash-distributed, four reference-table dimensions): all thirteen
 statements through `Session.execute`, on one device and on a mesh of
 four, held exactly to the benchmark's plain numpy reference
 (benchmark/references/ssb.py) on two seeds; the shape of Q4.1's plan and
-the counters that say which lookup arm ran; and the data set's seed
-rule (same shapes, other answers)."""
+the counters that say which lookup arm ran and what the feed cache
+served; and the data set's seed rule (same shapes, other answers).
+
+Here under the lookup arms SSB SF1 picks (every join on the dense
+directory).  tests/test_ssb_sf10.py runs the cases that take the `arms`
+fixture again under those SF10 picks (`customer` and `part` by sort and
+scan) — in a file of its own because the driver gives a file to one
+worker process, and XLA's CPU backend does not survive both sets'
+compilations in one process (pytest.ini)."""
 
 import json
 import os
@@ -46,6 +53,15 @@ def plan_joins(sess, sql: str) -> list:
     return [nd for nd in walk_plan(plan.root) if isinstance(nd, JoinNode)]
 
 
+@pytest.fixture
+def arms():
+    """How many of Q4.1's four fused lookups sort and scan: none at
+    this file's scale, as at SF1 (no key extent reaches
+    ops.join.SORTED_LOOKUP_MIN_EXTENT).  tests/test_ssb_sf10.py
+    overrides this fixture."""
+    return 0
+
+
 @pytest.fixture(scope="module")
 def rows_of():
     """seed -> the generated tables, made once a module."""
@@ -84,7 +100,8 @@ def loaded(tmp_path_factory, rows_of):
 @pytest.mark.parametrize("seed", SEEDS)
 @pytest.mark.parametrize("n_devices", (1, 4))
 @pytest.mark.parametrize("name", STATEMENTS)
-def test_statement_matches_reference(loaded, rows_of, name, n_devices, seed):
+def test_statement_matches_reference(loaded, rows_of, name, n_devices, seed,
+                                     arms):
     sess = loaded(n_devices, seed)
     want = ssb_ref.answer(ssb_ref.QUERIES[name], rows_of(seed))
     rows = sess.execute(statement_text(name)).rows()
@@ -113,22 +130,29 @@ def test_reference_compare_is_exact():
     assert ssb_ref.compare([good[0], good[0], good[2]], ref)[0]
 
 
-def test_q4_1_plan_and_counters(loaded):
-    """Four broadcast joins, each a fused lookup into a dense directory:
-    `d_datekey` is `yyyymmdd`, 2,556 rows over 61,130 slots at any scale
-    factor, under ops.join.SORTED_LOOKUP_MIN_EXTENT like the other
-    three keys — so the sorted arm is in no SSB plan, and one execution
-    says so in the counters."""
+def test_q4_1_plan_and_counters(loaded, arms):
+    """Four broadcast joins, each a fused lookup.  `d_datekey` is
+    `yyyymmdd`, 2,556 rows over 61,130 slots at any scale factor, under
+    ops.join.SORTED_LOOKUP_MIN_EXTENT like the other three keys at SF1
+    — so there the sorted arm is in no SSB plan.  At SF10 `customer` and
+    `part` sort and scan, `supplier` and `dwdate` stay on the dense
+    directory: both arms in one fragment.  One execution says which in
+    the counters, by statement and by join."""
+    n_sorted = arms
     sess = loaded(1, SEEDS[0])
     sql = statement_text("q4_1")
     plan = [r[0] for r in sess.execute("explain " + sql).rows()]
     joins = [line for line in plan if "Join" in line]
     assert len(joins) == 4
-    assert all("Broadcast Join" in j and "dense directory" in j
-               and "fused lookup" in j for j in joins)
-    assert not any("sorted lookup" in j for j in joins)
-    date_join = next(nd for nd in plan_joins(sess, sql)
-                     if "d_datekey" in str(nd.right_keys[0]))
+    assert all("Broadcast Join" in j and "fused lookup" in j for j in joins)
+    assert sum("sorted lookup" in j for j in joins) == n_sorted
+    assert sum("dense directory" in j for j in joins) == 4 - n_sorted
+    keys = ("c_custkey", "s_suppkey", "p_partkey", "d_datekey")
+    by_key = {next(k for k in keys if k in str(nd.right_keys[0])): nd
+              for nd in plan_joins(sess, sql)}
+    assert {k for k, nd in by_key.items() if nd.lookup_sorted} == (
+        {"c_custkey", "p_partkey"} if n_sorted else set())
+    date_join = by_key["d_datekey"]
     assert date_join.build_side == "right"
     assert date_join.right_key_extents == (
         (19920101, 19981230 - 19920101 + 1),)
@@ -138,14 +162,42 @@ def test_q4_1_plan_and_counters(loaded):
     after = sess.stats.counters.snapshot()
     moved = {k: after[k] - before[k] for k in (
         sc.LOOKUP_SORTED_TOTAL, sc.LOOKUP_DENSE_TOTAL,
+        sc.LOOKUP_SORTED_JOINS_TOTAL, sc.LOOKUP_DENSE_JOINS_TOTAL,
         sc.BROADCAST_JOINS_TOTAL, sc.CAPACITY_RETRIES)}
-    assert moved == {sc.LOOKUP_SORTED_TOTAL: 0, sc.LOOKUP_DENSE_TOTAL: 1,
+    assert moved == {sc.LOOKUP_SORTED_TOTAL: min(n_sorted, 1),
+                     sc.LOOKUP_DENSE_TOTAL: 1,
+                     sc.LOOKUP_SORTED_JOINS_TOTAL: n_sorted,
+                     sc.LOOKUP_DENSE_JOINS_TOTAL: 4 - n_sorted,
                      sc.BROADCAST_JOINS_TOTAL: 4, sc.CAPACITY_RETRIES: 0}
-    # the directory's operations carry the sub-scope the benchmark's
-    # stage_lookup_dense_ms reads them by
-    programs = [entry[0].as_text()
-                for entry in sess.executor.plan_cache._entries.values()]
-    assert any("ct.lookup_join/ct.dense" in text for text in programs)
+    # each arm's operations carry the sub-scope the benchmark's
+    # stage_lookup_dense_ms and stage_lookup_sorted_ms read them by
+    text = list(sess.executor.plan_cache._entries.values())[-1][0].as_text()
+    assert "ct.lookup_join/ct.dense" in text
+    assert ("ct.lookup_join/ct.sort" in text) == bool(n_sorted)
+
+
+def test_q4_1_feeds_stay_resident(loaded, arms):
+    """The feed cache answers every scan of a repeated statement: the
+    first execution on an empty cache builds the five feeds and counts
+    their device bytes as missed, the second is served all of them and
+    builds none."""
+    sess = loaded(1, SEEDS[0])
+    sql = statement_text("q4_1")
+    cache = sess.executor.feed_cache
+    cache.clear()
+    snaps = [sess.stats.counters.snapshot()]
+    for _ in range(2):
+        sess.execute(sql).rows()
+        snaps.append(sess.stats.counters.snapshot())
+    (hit1, miss1), (hit2, miss2) = (
+        tuple(b[k] - a[k] for k in (sc.FEED_CACHE_HIT_BYTES_TOTAL,
+                                    sc.FEED_CACHE_MISS_BYTES_TOTAL))
+        for a, b in zip(snaps, snaps[1:]))
+    assert len(cache) == 5 and miss1 == cache.total_bytes
+    # six int32 fact columns and the validity byte, at the least
+    assert miss1 > (6 * 4 + 1) * 300_145
+    assert hit1 == 0
+    assert (hit2, miss2) == (miss1, 0)
 
 
 def test_q4_1_program_gathers_what_is_read(loaded):

@@ -224,17 +224,12 @@ def test_scan_bits_and_valid_expand_compile(chip):
 
 # -- a whole statement's program ------------------------------------------
 
-def test_ssb_q4_1_program_compiles(topo, tmp_path):
-    """The benchmark cell `ssb1.q4_1`'s program at SSB SF1's shapes —
-    6.0 M fact rows through four broadcast lookup joins on the dense
-    directory, compacted 6.0 M → 1.8 M → 360 k slots on the way, 35
-    groups on the dense grid — as `Executor._compile_or_load` builds it,
-    for one described v5e chip.  The rows are the benchmark's own at
-    SF1 (the columns Q4.1 reads), so statistics, extents and capacities
-    are the cell's; nothing is executed.  22.8 s in this sandbox
-    (compiler here, PR 29); 11.2 s with the compactions' two sorts
-    against 10.8 s with their scatters, in one sitting (compiler here,
-    PR 30); printed below."""
+def _compile_ssb_q4_1(topo, tmp_path, scale_factor: float):
+    """SSB Q4.1's program at the shapes of `scale_factor`, as
+    `Executor._compile_or_load` builds it, for one described v5e chip:
+    (compiled, stage_keys, compile seconds, fact rows).  The rows are
+    the benchmark's own (the columns Q4.1 reads), so statistics, extents
+    and capacities are the cell's; nothing is executed."""
     import dataclasses
     import json
     import os
@@ -261,8 +256,9 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
         st = json.load(f)
     with open(os.path.join(root, "benchmark", "statements", st["sql"])) as f:
         sql = f.read()
-    params = {"scale_factor": 1.0, "shard_count": 8}
+    params = {"scale_factor": scale_factor, "shard_count": 8}
     data = ssb.generate(params, 5)
+    fact_rows = ssb.row_counts(data)[ssb.FACT]
     sess = citus_tpu.connect(data_dir=str(tmp_path / "ssb"), n_devices=1)
     try:
         # the five tables cut to the columns Q4.1 reads (and the fact
@@ -301,16 +297,50 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
             f, arrays={c: abstract(a, f.sharded) for c, a in f.arrays.items()},
             nulls={c: abstract(a, f.sharded) for c, a in f.nulls.items()},
             valid=abstract(f.valid, f.sharded)) for nid, f in feeds.items()}
-        assert max(f.capacity for f in feeds.values()) == _round_cap(5_999_224)
+        assert max(f.capacity for f in feeds.values()) == _round_cap(fact_rows)
         fn, feed_arrays, _meta, stage_keys = PlanCompiler(
             plan, mesh, feeds, caps, np.dtype("float32")).build()
         t0 = time.perf_counter()
         c = fn.lower(*feed_arrays).compile()
-        print(f"ssb q4_1 at SF1 shapes: compiled for a described v5e in "
-              f"{time.perf_counter() - t0:.1f} s; join_out stages "
-              f"{[w for _, kind, w in stage_keys if kind == 'join_out']}")
+        return c, stage_keys, time.perf_counter() - t0, fact_rows
     finally:
         sess.close()
+
+
+def _ops_by_size_and_scope(text: str, op: str) -> dict:
+    """(leading dimension of the first result, `ct.` path) -> count of
+    `op` instructions in a compiled program's text."""
+    import collections
+    import re
+
+    out = collections.Counter()
+    for ln in text.splitlines():
+        m = re.search(r"= \(?\w+\[(\d+)(?:,\d+)*\]\S* (?:\S+ )*?"
+                      + op + r"\(", ln)
+        if m is None:
+            continue
+        path = re.search(r'op_name="([^"]*)"', ln)
+        scope = "/".join(re.findall(r"ct\.(\w+)", path.group(1))) \
+            if path else ""
+        out[(int(m.group(1)), scope or "unscoped")] += 1
+    return dict(sorted(out.items(), key=lambda kv: (-kv[0][0], kv[0][1])))
+
+
+def test_ssb_q4_1_program_compiles(topo, tmp_path):
+    """The benchmark cell `ssb1.q4_1`'s program at SSB SF1's shapes —
+    6.0 M fact rows through four broadcast lookup joins on the dense
+    directory, compacted 6.0 M → 1.8 M → 360 k slots on the way, 35
+    groups on the dense grid.  22.8 s in this sandbox (compiler here,
+    PR 29); 11.2 s with the compactions' two sorts against 10.8 s with
+    their scatters, in one sitting (compiler here, PR 30); printed
+    below."""
+    import re
+
+    c, stage_keys, seconds, fact_rows = _compile_ssb_q4_1(topo, tmp_path, 1.0)
+    assert fact_rows == 5_999_224
+    print(f"ssb q4_1 at SF1 shapes: compiled for a described v5e in "
+          f"{seconds:.1f} s; join_out stages "
+          f"{[w for _, kind, w in stage_keys if kind == 'join_out']}")
     text = c.as_text()
     assert "ct.lookup_join/ct.dense" in text
     assert "ct.lookup_join/ct.sort" not in text  # no key extent reaches 2^18
@@ -336,3 +366,34 @@ def test_ssb_q4_1_program_compiles(topo, tmp_path):
     assert sizes.count(360_576) == 9
     assert not any(" gather(" in ln for ln in compact_ops)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.slow
+def test_ssb10_q4_1_program_compiles(topo, tmp_path):
+    """The benchmark cell `ssb10.q4_1`'s program at SSB SF10's shapes —
+    60.0 M fact rows, `supplier` and `dwdate` probed through the dense
+    directory, `customer` (300,000 keys) and `part` (800,000) by sort
+    and scan: both lookup arms in one fragment.  Not in tier-1: the
+    rows alone take 9 GB of host memory and a minute to make.  Prints
+    what PERF.md §6's prediction is made from: compile seconds, gathers
+    and sorts by size and `ct.` path, `memory_analysis()`."""
+    c, stage_keys, seconds, fact_rows = _compile_ssb_q4_1(topo, tmp_path,
+                                                          10.0)
+    assert fact_rows == 59_999_933
+    text, mem = c.as_text(), c.memory_analysis()
+    print(f"ssb q4_1 at SF10 shapes: compiled for a described v5e in "
+          f"{seconds:.1f} s; join_out stages "
+          f"{[w for _, kind, w in stage_keys if kind == 'join_out']}; "
+          f"temporaries {mem.temp_size_in_bytes}, arguments "
+          f"{mem.argument_size_in_bytes}, output {mem.output_size_in_bytes}")
+    for op in ("gather", "sort", "scatter", "reduce-window"):
+        print(f"  {op}: {_ops_by_size_and_scope(text, op)}")
+    assert "ct.lookup_join/ct.dense" in text
+    assert "ct.lookup_join/ct.sort" in text
+    sorts = _ops_by_size_and_scope(text, "sort")
+    assert sum(n for (_size, scope), n in sorts.items()
+               if scope == "lookup_join/sort") == 6     # three a sorted lookup
+    assert sum(n for (_size, scope), n in sorts.items()
+               if scope == "join_out/compact") == 2
+    # feeds and temporaries together leave most of the chip's 15.75 GiB
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 6 << 30
