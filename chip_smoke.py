@@ -54,7 +54,8 @@ _COUNTERS = ("capacity_retries", "retries_total", "oom_events_total",
              "queries_repartition", "queries_streamed",
              "groupby_bucketed_total", "lookup_sorted_total",
              "lookup_dense_total", "lookup_sorted_joins_total",
-             "lookup_dense_joins_total", "broadcast_joins_total",
+             "lookup_dense_joins_total", "lookup_probe_slots_total",
+             "broadcast_joins_total",
              "deferred_columns_total", "deferred_gathers_total",
              "device_decoded_bytes_total",
              "shuffle_bytes_total", "queries_fast_path",

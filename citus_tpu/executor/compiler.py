@@ -377,8 +377,9 @@ class PlanCompiler:
             with deferred_tally() as tally:
                 packed = traced(flat_feeds)
             # assigned, not accumulated, like _shuffle_bytes: published
-            # as PlanCompiler.deferred after build
-            self._deferred = (tally.columns, tally.gathers)
+            # as PlanCompiler.tallies after build
+            self._tallies = (tally.columns, tally.gathers,
+                             self._lookup_probe_slots)
             return packed
 
         def traced(flat_feeds):
@@ -398,6 +399,9 @@ class PlanCompiler:
                 # eval_shape and the jit both trace this body) and
                 # published as PlanCompiler.shuffle_bytes after build
                 self._shuffle_bytes = 0
+                # probe slots of the fused lookups, over the mesh: the
+                # same rule
+                self._lookup_probe_slots = 0
                 out = self._exec(self.plan.root, blocks)
                 if self.plan.output_repart is not None:
                     # INSERT..SELECT device routing: shuffle the final
@@ -457,9 +461,10 @@ class PlanCompiler:
         # shuffles away entirely — a caps-table estimate would lie)
         self.shuffle_bytes = int(self._shuffle_bytes)
         # (columns this program carries as a row index across a
-        # compaction or a lookup, gathers it issues for them later):
-        # deferred_columns_total / deferred_gathers_total
-        self.deferred = self._deferred
+        # compaction or a lookup, gathers it issues for them later,
+        # probe slots of its fused lookups): deferred_columns_total /
+        # deferred_gathers_total / lookup_probe_slots_total
+        self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
         for cid in out_cids:
@@ -1072,6 +1077,7 @@ class PlanCompiler:
             bblk, bkeys, bmatch = rblk, rkeys, rmatch
             pblk, pkeys, pmatch = lblk, lkeys, lmatch
             extents = getattr(node, "right_key_extents", ())
+        self._lookup_probe_slots += self.n_dev * int(pblk.valid.shape[0])
         dense = self._dense_for(extents, bkeys)
         if self.sorted_lookup_shape(node, self.caps.dense_off):
             # a key extent past the knee of the directory gather (the

@@ -51,7 +51,8 @@ from ..errors import StorageError
 # as a row index) — the key holds the plan, the capacities and the
 # environment, not the engine's code, so a data directory written
 # before the upgrade must not keep serving the old program
-EXEC_CACHE_VERSION = 2
+# 3 (PR 34): beside them the probe slots of its fused lookups
+EXEC_CACHE_VERSION = 3
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)
@@ -321,7 +322,7 @@ class ExecutableCache:
     def load(self, key, mesh):
         """Resolve `key` from disk.  Returns ``(entry, status)`` where
         entry is the plan-cache tuple ``(compiled_fn, out_meta,
-        stage_keys, shuffle_bytes, deferred)`` or None, and status is
+        stage_keys, shuffle_bytes, tallies)`` or None, and status is
         ``'hit' | 'miss' | 'reject'``.  Every failure mode — torn or
         bit-flipped payload, corrupt meta, version/backend/mesh skew,
         an unloadable executable — is *detected* and reported as a
@@ -434,14 +435,15 @@ class ExecutableCache:
         out_meta = [(kind, cid, np.dtype(dt))
                     for kind, cid, dt in meta["out_meta"]]
         stage_keys = [tuple(sk) for sk in meta["stage_keys"]]
-        carried, gathered = (int(n) for n in meta["deferred"])
+        carried, gathered, probe_slots = (int(n) for n in meta["tallies"])
         return (compiled, out_meta, stage_keys,
-                int(meta["shuffle_bytes"]), (carried, gathered))
+                int(meta["shuffle_bytes"]),
+                (carried, gathered, probe_slots))
 
     # -- store ---------------------------------------------------------------
     def store(self, key, mesh, compiled, out_meta, stage_keys,
               shuffle_bytes: int,
-              deferred: tuple[int, int] = (0, 0)) -> bool:
+              tallies: tuple[int, int, int] = (0, 0, 0)) -> bool:
         """Persist one compiled entry.  Best-effort for REAL IO errors
         (the in-memory entry still answers the statement; persistence
         is a warm-start optimization, like the caps memo) — but the
@@ -478,7 +480,7 @@ class ExecutableCache:
                              for kind, cid, dt in out_meta],
                 "stage_keys": [list(sk) for sk in stage_keys],
                 "shuffle_bytes": int(shuffle_bytes),
-                "deferred": [int(n) for n in deferred],
+                "tallies": [int(n) for n in tallies],
                 "payload_crc32": zlib.crc32(data),
                 "payload_bytes": len(data),
             })

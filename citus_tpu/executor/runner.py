@@ -231,9 +231,9 @@ class Executor:
         if streamed is not None:
             return streamed
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
-        packed, out_meta, caps, retries, feeds, deferred = \
+        packed, out_meta, caps, retries, feeds, tallies = \
             self._run_resident(plan, compute_dtype)
-        self.count_picks(plan, caps, deferred)
+        self.count_picks(plan, caps, tallies)
         with trace_span("combine"):
             cols, nulls, valid = unpack_outputs(packed, out_meta)
             result = self._host_combine(plan, cols, nulls, valid, raw)
@@ -280,9 +280,9 @@ class Executor:
                 memo = self._caps_memo.get(fingerprint)
             caps = (self._caps_from_order(plan, memo) if memo is not None
                     else self._initial_capacities(plan, feeds))
-        packed, out_meta, caps, retries, deferred = self.run_with_retry(
+        packed, out_meta, caps, retries, tallies = self.run_with_retry(
             plan, feeds, caps, fingerprint, compute_dtype)
-        return packed, out_meta, caps, retries, feeds, deferred
+        return packed, out_meta, caps, retries, feeds, tallies
 
     # ------------------------------------------------------------------
     def execute_pass(self, plan: QueryPlan, split_nid: int):
@@ -299,15 +299,15 @@ class Executor:
                                         no_cache_nodes=frozenset(
                                             {split_nid}))
         if streamed is not None:
-            parts, scanned, retries, batches, caps, deferred = streamed
+            parts, scanned, retries, batches, caps, tallies = streamed
             if caps is not None:
-                self.count_picks(plan, caps, deferred)
+                self.count_picks(plan, caps, tallies)
             return parts, scanned, retries, batches
         compute_dtype = np.dtype(self.settings.get("compute_dtype"))
-        packed, out_meta, caps, retries, _feeds, deferred = \
+        packed, out_meta, caps, retries, _feeds, tallies = \
             self._run_resident(plan, compute_dtype,
                                no_cache_nodes=frozenset({split_nid}))
-        self.count_picks(plan, caps, deferred)
+        self.count_picks(plan, caps, tallies)
         cols, nulls, valid = unpack_outputs(packed, out_meta)
         scanned = int(np.asarray(valid).size)
         return [_flatten_batch(cols, nulls, valid)], scanned, retries, 0
@@ -372,11 +372,11 @@ class Executor:
                                               compute_dtype, group_kernel,
                                               key)
                 self.plan_cache.put(key, entry)
-                fn, out_meta, stage_keys, shuffle_bytes, deferred = entry
+                fn, out_meta, stage_keys, shuffle_bytes, tallies = entry
                 feed_arrays = flatten_feed_arrays(plan, feeds,
                                                   compute_dtype)
             else:
-                fn, out_meta, stage_keys, shuffle_bytes, deferred = entry
+                fn, out_meta, stage_keys, shuffle_bytes, tallies = entry
                 with trace_span("compile", cache="hit"):
                     feed_arrays = flatten_feed_arrays(plan, feeds,
                                                       compute_dtype)
@@ -484,7 +484,7 @@ class Executor:
 
                         self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
                                                 shuffle_bytes)
-                    return packed, out_meta, caps, retries, deferred
+                    return packed, out_meta, caps, retries, tallies
             retries += 1
             from ..utils.faultinjection import fault_point
 
@@ -558,7 +558,7 @@ class Executor:
            executable is serializable), persisted through the io seam.
 
         Returns the plan-cache entry ``(fn, out_meta, stage_keys,
-        shuffle_bytes, deferred)``."""
+        shuffle_bytes, tallies)``."""
         from ..stats import counters as sc
         from ..stats.tracing import trace_span
 
@@ -578,7 +578,7 @@ class Executor:
                 fn = fn.lower(*feed_arrays).compile()
             ec.note_compile()  # actual-compile ledger (dedup asserts)
             entry = (fn, out_meta, stage_keys, compiler.shuffle_bytes,
-                     compiler.deferred)
+                     compiler.tallies)
             if use_cache:
                 ec.store(key, self.mesh, *entry)
             return entry
@@ -765,7 +765,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def count_picks(self, plan: QueryPlan, caps: Capacities,
-                    deferred: tuple[int, int]) -> None:
+                    tallies: tuple[int, int, int]) -> None:
         """groupby_bucketed_total, lookup_sorted_total,
         lookup_dense_total and broadcast_joins_total: each bumped once
         per executed STATEMENT whose converged plan ran the bucketed
@@ -778,18 +778,22 @@ class Executor:
         streamed path calls it once after the batch loop, not per
         batch), and a dense_oob fallback onto the general paths
         (caps.dense_off) correctly counts no pick (its broadcast joins
-        stay broadcast joins).  deferred_columns_total and
-        deferred_gathers_total take `deferred`, the two counts the
-        converged program's compiler recorded at trace time
-        (PlanCompiler.deferred; they ride in the plan-cache entry)."""
+        stay broadcast joins).  deferred_columns_total,
+        deferred_gathers_total and lookup_probe_slots_total take
+        `tallies`, the three counts the converged program's compiler
+        recorded at trace time (PlanCompiler.tallies; they ride in the
+        plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
-        carried, gathered = deferred
+        carried, gathered, probe_slots = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
+        if probe_slots:
+            self.counters.increment(sc.LOOKUP_PROBE_SLOTS_TOTAL,
+                                    probe_slots)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         nbk = sum(1 for nd in nodes

@@ -589,7 +589,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
         (repr(e), d, nf) for e, d, nf in plan.host_order_by)
         if plan.device_topk is not None else ())
     caps = None
-    deferred = (0, 0)   # the last batch's program's, as caps is
+    tallies = (0, 0, 0)   # the last batch's program's, as caps is
     fingerprint = None
     fn = out_meta = None
     parts = []
@@ -633,7 +633,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
             # on batch 1 would risk a recompile-overflow-regrow cycle
             # on a later, fuller batch
             with trace_span("stream.batch", batch=n_consumed - 1):
-                packed, out_meta, caps, r, deferred = \
+                packed, out_meta, caps, r, tallies = \
                     executor.run_with_retry(
                         plan, feeds, caps, fingerprint, compute_dtype,
                         allow_tighten=False)
@@ -652,7 +652,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
 
     if return_parts:
         return (parts, rows_scanned, retries_total, n_consumed, caps,
-                deferred)
+                tallies)
     if agg_root is not None:
         merged_c, merged_n = merge_aggregate_parts(agg_root, parts)
     else:
@@ -683,7 +683,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
     if caps is not None:
         # once per STATEMENT, after the batch loop (run_with_retry runs
         # per batch and must not inflate the statement-level counter)
-        executor.count_picks(plan, caps, deferred)
+        executor.count_picks(plan, caps, tallies)
     return result
 
 

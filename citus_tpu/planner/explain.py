@@ -42,6 +42,10 @@ EXPLAIN_TAGS: dict[str, str] = {
     "fused lookup": "PK-lookup join fused into the probe gather",
     "sorted lookup": "fused lookup by sort and scan, no directory "
                      "(key extent past the directory gather's knee)",
+    "est keep": "fused inner lookup: estimated fraction of the rows "
+                "coming in that find a match (the looked-up side's "
+                "surviving fraction); among joins of one strategy the "
+                "planner takes the one that keeps the fewest rows first",
     "bucketed group-by": "dense-grid bucketed aggregation path",
     "Chunks Skipped": "chunk groups pruned by min/max skip nodes",
     "pipelined scan":
@@ -176,6 +180,8 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
             mods.append(explain_tag("dense directory"))
         if node.fuse_lookup:
             mods.append(explain_tag("fused lookup"))
+        if node.est_keep is not None:
+            mods.append(f"{explain_tag('est keep')} {node.est_keep:.2f}")
         if sorted_lookup:
             mods.append(explain_tag("sorted lookup"))
         lines.append(f"{pad}-> {label} on ({conds})  "

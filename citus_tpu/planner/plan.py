@@ -23,7 +23,7 @@ the placement-map equality check is the colocation test
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from ..catalog import Catalog, DistributionMethod
 from ..errors import PlanningError
@@ -137,6 +137,10 @@ class JoinNode(PlanNode):
     # compiler, the plan fingerprint and the lookup_sorted_total counter
     # all read this flag
     lookup_sorted: bool = False
+    # fused inner lookup: the estimated fraction of the FK side's rows
+    # that find their PK row, i.e. the PK side's surviving fraction
+    # (EXPLAIN's `est keep`); None for every other join
+    est_keep: Optional[float] = None
 
 
 @dataclass
@@ -259,6 +263,32 @@ class StatsProvider:
         return None
 
 
+class _PlanningStats(StatsProvider):
+    """One planning's view of a StatsProvider: each statistic is read
+    once.  A column's range is a walk over its table's stripe records,
+    and the join order asks for a key's again for every candidate it
+    weighs."""
+
+    def __init__(self, stats: StatsProvider):
+        self._stats = stats
+        self._memo: dict = {}
+
+    def _once(self, name: str, *args):
+        key = (name, *args)
+        if key not in self._memo:
+            self._memo[key] = getattr(self._stats, name)(*args)
+        return self._memo[key]
+
+    def table_rows(self, table):
+        return self._once("table_rows", table)
+
+    def column_ndv(self, table, column, dtype):
+        return self._once("column_ndv", table, column, dtype)
+
+    def column_extent(self, table, column, dtype):
+        return self._once("column_extent", table, column, dtype)
+
+
 @dataclass
 class QueryPlan:
     """Device plan + the host-side combine phase
@@ -295,7 +325,7 @@ class DistributedPlanner:
                  n_devices: int, enable_repartition: bool = True,
                  dicts=None):
         self.catalog = catalog
-        self.stats = stats
+        self.stats = _PlanningStats(stats)
         self.n_devices = n_devices
         self.enable_repartition = enable_repartition
         self.dicts = dicts  # DictProvider for string routing-token lookup
@@ -759,15 +789,24 @@ class DistributedPlanner:
         pending_residuals = list(residuals)
 
         while remaining:
-            best = None  # (rank, rel_index, join_edges)
+            # within one strategy rank the next relation is the one whose
+            # join keeps the fewest rows (every later probe, gather and
+            # sort runs at that size: an unfiltered dimension removes
+            # none and goes last); ties fall to the smaller build side,
+            # then the relation index
+            best = None  # (key, rel_index, join_edges, strategy)
             for ri, scan in remaining.items():
                 join_edges = [e for e in pending_edges
                               if e[0] <= (placed | {ri})
                               and ri in e[0]]
                 strategy = self._choose_strategy(current, scan, join_edges)
                 rank = _STRATEGY_RANK[strategy]
-                size = scan.est_rows
-                key = (rank, size, ri)
+                lk, rk = _split_edge_keys(join_edges, ri)
+                sides = ((scan, current, rk, lk)
+                         if strategy == "broadcast_left"  # _make_join swaps
+                         else (current, scan, lk, rk))
+                rows_out = self._estimate_join(*sides).rows
+                key = (rank, rows_out, scan.est_rows, ri)
                 if best is None or key < best[0]:
                     best = (key, ri, join_edges, strategy)
             _, ri, join_edges, strategy = best
@@ -840,15 +879,7 @@ class DistributedPlanner:
     def _make_join(self, left: PlanNode, right: ScanNode, join_edges,
                    strategy: str, right_rel_index: int,
                    join_type: str = "inner") -> JoinNode:
-        left_keys, right_keys = [], []
-        for _, a, b in join_edges:
-            a_rels = {n.rel_index for n in ir.walk(a) if isinstance(n, ir.BCol)}
-            if a_rels == {right_rel_index}:
-                right_keys.append(a)
-                left_keys.append(b)
-            else:
-                left_keys.append(a)
-                right_keys.append(b)
+        left_keys, right_keys = _split_edge_keys(join_edges, right_rel_index)
         if strategy == "cartesian_broadcast":
             # keyless product against a replicated relation: put the
             # replicated side on the build (right) side
@@ -960,21 +991,69 @@ class DistributedPlanner:
                 keep = frozenset()
             node.dist = Dist(node.dist.kind, keep, node.dist.shard_count,
                              node.dist.placement, node.dist.bounds)
-        node.est_expansion = self._estimate_expansion(node)
-        node.est_rows = max(int(node.left.est_rows * node.est_expansion),
-                            left.est_rows, right.est_rows)
+        node.out_columns = {**left.out_columns, **right.out_columns}
+        self._annotate_join_keys(node)
         if node.strategy == "cartesian_gather" or (
                 node.strategy == "broadcast" and not node.left_keys):
             node.est_rows = max(1, node.left.est_rows
                                 * node.right.est_rows)
-        node.out_columns = {**left.out_columns, **right.out_columns}
-        self._annotate_join_keys(node)
         return node
 
+    def _estimate_join(self, left: PlanNode, right: PlanNode, left_keys,
+                       right_keys, join_type: str = "inner"
+                       ) -> "JoinEstimate":
+        """What the statistics say of joining `left` to `right` on the
+        keys: the ONE estimate that orders the joins
+        (_plan_inner_joins ranks its candidates by `rows`) and sizes
+        them (_annotate_join_keys copies every field onto the node, and
+        capacity planning reads them there).
+
+        The build side: a provably-unique side (enables lookup fusion),
+        otherwise the smaller one; inner joins only — the outer-join
+        null-extension path is oriented build=right.  Rows out of a
+        fused inner lookup: the FK side's rows times the surviving
+        fraction of the PK side (P(an FK row finds its PK row), the
+        FK-join selectivity).  The PK side is the right one where it is
+        unique, whichever side builds: a left side that is a filtered
+        join result can be "unique" only because few rows are left of
+        it, and the fraction of the fact table that survived in it says
+        nothing of the next dimension.  Of any other join: the probe
+        rows times the matches per probe row, and at least either side
+        (the generic estimate)."""
+        exp_left = self._estimate_expansion_for(left, left_keys)
+        exp_right = self._estimate_expansion_for(right, right_keys)
+        uniq_l = exp_left is not None and exp_left <= 1.0
+        uniq_r = exp_right is not None and exp_right <= 1.0
+        build_side = "right"
+        if join_type == "inner" and left_keys:
+            if uniq_l != uniq_r:
+                build_side = "left" if uniq_l else "right"
+            elif left.est_rows < right.est_rows:
+                build_side = "left"
+        fuse = (bool(left_keys) and join_type in ("inner", "left")
+                and (uniq_l if build_side == "left" else uniq_r))
+        expansion = max(1.0, exp_right) if exp_right is not None else 1.0
+        if fuse and join_type == "inner":
+            pk, fk = (right, left) if uniq_r else (left, right)
+            base = self._unfiltered_rows(pk)
+            keep = min(1.0, pk.est_rows / base) if base > 0 else 1.0
+            return JoinEstimate(max(1, int(fk.est_rows * keep)),
+                                expansion, build_side, True, keep)
+        rows = max(int(left.est_rows * expansion), left.est_rows,
+                   right.est_rows)
+        return JoinEstimate(rows, expansion, build_side, fuse, None)
+
     def _annotate_join_keys(self, node: JoinNode) -> None:
-        """Key range stats → dense-directory extents, int32 narrowing,
-        and the build-side choice (smaller side sorts; inner joins only —
-        the outer-join null-extension path is oriented build=right)."""
+        """_estimate_join's row estimate, build side and fusion onto the
+        node; key range stats → dense-directory extents, int32
+        narrowing and the lookup's arm."""
+        est = self._estimate_join(node.left, node.right, node.left_keys,
+                                  node.right_keys, node.join_type)
+        node.est_rows = est.rows
+        node.est_expansion = est.expansion
+        node.build_side = est.build_side
+        node.fuse_lookup = est.fuse_lookup
+        node.est_keep = est.keep
         node.left_key_extents = tuple(
             self._key_extent(e) for e in node.left_keys)
         node.right_key_extents = tuple(
@@ -988,23 +1067,6 @@ class DistributedPlanner:
                 ok = lo >= -(1 << 31) and hi <= (1 << 31) - 1
             int32_ok.append(ok)
         node.key_int32 = tuple(int32_ok)
-        exp_left = self._estimate_expansion_for(node.left, node.left_keys)
-        exp_right = self._estimate_expansion_for(node.right,
-                                                 node.right_keys)
-        uniq_l = exp_left is not None and exp_left <= 1.0
-        uniq_r = exp_right is not None and exp_right <= 1.0
-        if node.join_type == "inner" and node.left_keys:
-            # prefer a provably-unique side as build (enables lookup
-            # fusion); otherwise sort the smaller side
-            if uniq_l != uniq_r:
-                node.build_side = "left" if uniq_l else "right"
-            else:
-                node.build_side = ("left" if node.left.est_rows
-                                   < node.right.est_rows else "right")
-        if node.left_keys:
-            build_uniq = (uniq_l if node.build_side == "left" else uniq_r)
-            node.fuse_lookup = (build_uniq and node.join_type
-                                in ("inner", "left"))
         if node.fuse_lookup and len(node.left_keys) == 1:
             from ..ops.join import sorted_lookup_eligible
 
@@ -1012,16 +1074,6 @@ class DistributedPlanner:
                    else node.right_key_extents)
             if ext[0] is not None:
                 node.lookup_sorted = sorted_lookup_eligible(int(ext[0][1]))
-        if node.fuse_lookup and node.join_type == "inner":
-            # PK-side build: P(probe row matches) ≈ surviving build
-            # fraction — the FK-join selectivity the generic estimate
-            # (max of side estimates) misses entirely.  Feeds join-output
-            # compaction, aggregate sizing, and group-count estimates.
-            build = node.left if node.build_side == "left" else node.right
-            probe = node.right if node.build_side == "left" else node.left
-            base = self._unfiltered_rows(build)
-            frac = min(1.0, build.est_rows / base) if base > 0 else 1.0
-            node.est_rows = max(1, int(probe.est_rows * frac))
 
     def _unfiltered_rows(self, node: PlanNode) -> int:
         """Rows the node would produce with every filter removed — the
@@ -1042,19 +1094,14 @@ class DistributedPlanner:
             return self.stats.column_extent(e.table, e.column, e.dtype)
         return None
 
-    def _estimate_expansion(self, node: JoinNode) -> float:
-        """Matches per probe row ≈ build_rows / ndv(build key) — the
-        pg_statistic-style selectivity estimate for equi-joins; min over
-        edges (every key must match), 1.0 when unknown/PK-like."""
-        best = self._estimate_expansion_for(node.right, node.right_keys)
-        return max(1.0, best) if best is not None else 1.0
-
     def _estimate_expansion_for(self, build_node: PlanNode,
                                 build_keys) -> float | None:
-        """Raw matches-per-probe estimate for one side as build; None =
-        no usable statistics.  A value <= 1.0 marks the side as
-        PK-unique on the key (lookup-fusion eligible — verified at
-        runtime, stale claims retry on the expansion path)."""
+        """Matches per probe row ≈ build_rows / ndv(build key) for one
+        side as build — the pg_statistic-style selectivity estimate for
+        equi-joins; min over edges (every key must match); None = no
+        usable statistics.  A value <= 1.0 marks the side as PK-unique
+        on the key (lookup-fusion eligible — verified at runtime, stale
+        claims retry on the expansion path)."""
         best = None
         rows = max(1, build_node.est_rows)
         for k in build_keys:
@@ -1591,6 +1638,30 @@ def _hll_estimate_expr() -> ir.BExpr:
                             ir.BCmp(">", empty, c(0.5))))
     est = ir.BCase(((cond, linear),), raw, F)
     return ir.BCast(ir.BArith("+", est, c(0.5), F), DataType.INT64)
+
+
+class JoinEstimate(NamedTuple):
+    """DistributedPlanner._estimate_join's answer for one join."""
+    rows: int              # estimated rows out
+    expansion: float       # matches a left row, right side as build, >= 1
+    build_side: str        # "left" | "right"
+    fuse_lookup: bool      # the build side is unique on the key
+    keep: Optional[float]  # fused inner lookup: P(an FK row finds its PK row)
+
+
+def _split_edge_keys(join_edges, right_rel_index: int):
+    """Equi-join edges → (left_keys, right_keys), index-aligned, the
+    right ones being the expressions over `right_rel_index`."""
+    left_keys, right_keys = [], []
+    for _, a, b in join_edges:
+        a_rels = {n.rel_index for n in ir.walk(a) if isinstance(n, ir.BCol)}
+        if a_rels == {right_rel_index}:
+            right_keys.append(a)
+            left_keys.append(b)
+        else:
+            left_keys.append(a)
+            right_keys.append(b)
+    return left_keys, right_keys
 
 
 _STRATEGY_RANK = {"broadcast": 0, "broadcast_left": 0, "local": 1,

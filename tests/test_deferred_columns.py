@@ -242,10 +242,11 @@ def test_columns_is_a_mapping_to_its_readers():
 
 def _compiler(join_out: dict | None = None) -> PlanCompiler:
     """A PlanCompiler as `_compact` and `_exec_lookup_join` see one:
-    the two accumulators, the capacities, the stage records."""
+    the accumulators, the capacities, the stage records."""
     pc = object.__new__(PlanCompiler)
     pc._overflow = jnp.zeros((), jnp.int64)
     pc._dense_oob = jnp.zeros((), jnp.int64)
+    pc._lookup_probe_slots, pc.n_dev = 0, 1
     pc._stage_actual, pc._stage_width = {}, {}
     pc.caps = Capacities({}, join_out or {})
     return pc
@@ -350,6 +351,7 @@ def test_left_join_null_extension():
     probe, build, pkey, bkey = _star()
     pc = _compiler()
     out = _lookup(pc, "left", probe, build)
+    assert pc._lookup_probe_slots == len(pkey)  # lookup_probe_slots_total
     where = {int(k): i for i, k in enumerate(bkey)}
     found = np.array([int(k) in where for k in pkey])
     brow = np.array([where.get(int(k), 0) for k in pkey])

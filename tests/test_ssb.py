@@ -145,6 +145,14 @@ def test_q4_1_plan_and_counters(loaded, arms):
     joins = [line for line in plan if "Join" in line]
     assert len(joins) == 4
     assert all("Broadcast Join" in j and "fused lookup" in j for j in joins)
+    # the join that keeps the fewest rows first (PR 34; EXPLAIN prints
+    # the root first): `supplier` and `customer` keep 1 in 5, `part` 1
+    # in 3, the unfiltered `dwdate` every row and is probed last, over
+    # what the other three kept
+    assert [(j.split(" = ")[1].split(")")[0].split(".")[1],
+             j.split("est keep ")[1][:4]) for j in joins] == [
+        ("d_datekey", "1.00"), ("p_partkey", "0.33"),
+        ("c_custkey", "0.20"), ("s_suppkey", "0.20")]
     assert sum("sorted lookup" in j for j in joins) == n_sorted
     assert sum("dense directory" in j for j in joins) == 4 - n_sorted
     keys = ("c_custkey", "s_suppkey", "p_partkey", "d_datekey")
@@ -164,6 +172,11 @@ def test_q4_1_plan_and_counters(loaded, arms):
         sc.LOOKUP_SORTED_TOTAL, sc.LOOKUP_DENSE_TOTAL,
         sc.LOOKUP_SORTED_JOINS_TOTAL, sc.LOOKUP_DENSE_JOINS_TOTAL,
         sc.BROADCAST_JOINS_TOTAL, sc.CAPACITY_RETRIES)}
+    # the four probes: the fact feed's 300,160 slots, then what each
+    # lookup kept (the parent's order: 300,160 + 90,624 + 2 × 18,560)
+    assert after[sc.LOOKUP_PROBE_SLOTS_TOTAL] \
+        - before[sc.LOOKUP_PROBE_SLOTS_TOTAL] == 300_160 + 90_624 + 18_560 \
+        + 4_992
     assert moved == {sc.LOOKUP_SORTED_TOTAL: min(n_sorted, 1),
                      sc.LOOKUP_DENSE_TOTAL: 1,
                      sc.LOOKUP_SORTED_JOINS_TOTAL: n_sorted,
@@ -204,11 +217,14 @@ def test_q4_1_program_gathers_what_is_read(loaded):
     """Columns cross a compaction or a lookup as a row index and are
     gathered where they are first read (PR 32).  At this scale the
     first `join_out` compaction packs 300,160 probe slots into 90,624,
-    and the parent's program gathered six times at that size: the five
+    and PR 32's parent gathered six times at that size: the five
     fact columns through the compaction and the next lookup's probe.
     Now two: the one column read at that size (the next join's key) and
     that probe — the other four cross the next compaction as an index.
-    Each statement says so in the two counters."""
+    Each statement says so in the two counters.  At the second
+    compaction's 18,560 slots six gathers became four when the
+    unfiltered `dwdate` moved to the end of the join order (PR 34): its
+    probe and `lo_orderdate` are read at the third's 4,992."""
     import re
 
     sess = loaded(1, SEEDS[0])
@@ -226,9 +242,10 @@ def test_q4_1_program_gathers_what_is_read(loaded):
     first_compaction = 90_624
     assert "ct.join_out/ct.compact" in text
     assert f"[{first_compaction}]" in text
-    assert sizes.count(first_compaction) == 2    # the parent's count: 6
+    assert sizes.count(first_compaction) == 2    # PR 32's parent: 6
     assert max(sizes) == 300_160                 # the first probe, alone
-    assert sizes.count(300_160) == 1
+    assert {n: sizes.count(n) for n in (300_160, 90_624, 18_560)} == {
+        300_160: 1, 90_624: 2, 18_560: 4}        # PR 34's parent: 1, 2, 6
     # every gather of a carried column stands under the sub-scope the
     # benchmark's stage_deferred_ms reads
     assert "ct.join_out/ct.deferred" in text

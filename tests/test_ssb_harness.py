@@ -23,7 +23,7 @@ SEED = 2_147_483_777  # past 32 signed bits, as the driver's are
 SCALE = 0.05
 NEW_READERS = ("stage_lookup_sorted_ms", "sorted_lookup_joins",
                "dense_lookup_joins", "resident_feed_bytes", "refed_bytes",
-               "governor_events")
+               "governor_events", "lookup_probe_slots")
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +83,11 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, cell,
     assert carried > gathered > 0
     if trace:
         assert got["window_compiles"] == 0
+        # the four lookups' probe slots (PR 34): the whole fact feed
+        # under the first, what each earlier lookup kept under the rest
+        assert got["lookup_probe_slots"] * result["attempted"] \
+            == counters["lookup_probe_slots_total"]
+        assert 300_160 < got["lookup_probe_slots"] < 2 * 300_160
         # device metrics need a device trace: none on the CPU
         assert not {"device_busy_ms", "stage_deferred_ms",
                     "stage_lookup_dense_ms", "stage_lookup_sorted_ms"} \
@@ -90,7 +95,7 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, cell,
         if cell == "ssb1.q4_1":
             assert got["broadcast_joins"] == 4
             assert got["deferred_columns"] == carried
-            assert not set(NEW_READERS) & set(got)
+            assert set(NEW_READERS) & set(got) == {"lookup_probe_slots"}
         else:
             assert counters["broadcast_joins_total"] \
                 == 4 * result["attempted"]
