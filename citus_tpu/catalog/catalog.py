@@ -137,6 +137,15 @@ class TableMetadata:
 # (persisted allocations grow from ~102008 and can never reach this)
 TEMP_ID_BASE = 1 << 40
 
+# session-private temp reference tables that hold a subplan's rows
+# (recursive planning's intermediate results): named from this prefix
+# and a per-session counter, never persisted
+INTERMEDIATE_PREFIX = "__intermediate_"
+
+
+def is_intermediate(name: str) -> bool:
+    return name.startswith(INTERMEDIATE_PREFIX)
+
 
 class Catalog:
     """In-memory catalog with JSON persistence and a version counter.
@@ -728,7 +737,7 @@ class Catalog:
             group = self.get_or_create_colocation_group(1, None)
             meta = TableMetadata(name, schema, DistributionMethod.REFERENCE,
                                  None, group.colocation_id)
-            temp = name.startswith("__intermediate_")
+            temp = is_intermediate(name)
             if temp:
                 sid = self._next_temp_shard_id
                 self._next_temp_shard_id += 1
@@ -854,7 +863,7 @@ class Catalog:
             # STATEMENT; a wholesale swap would drop them and the outer
             # query's scan of its own CTE would fail (ADVICE r5).
             temps = {n: m for n, m in self.tables.items()
-                     if n.startswith("__intermediate_")
+                     if is_intermediate(n)
                      and n not in fresh.tables}
             temp_shards = {sid: s for sid, s in self.shards.items()
                            if s.table_name in temps}

@@ -53,7 +53,7 @@ def node_fingerprint(node: PlanNode) -> str:
     stable across processes.
     """
     if isinstance(node, ScanNode):
-        return (f"S({node.rel.rel_index};{node.rel.table};{node.columns};"
+        return (f"S({node.rel.rel_index};{node.rel.identity};{node.columns};"
                 f"{node.pruned_shards};{node.filter!r};"
                 f"{_dist_sig(node.dist)})")
     if isinstance(node, ProjectNode):

@@ -394,6 +394,10 @@ class PlanCompiler:
                 self._overflow = jnp.zeros((), dtype=jnp.int64)
                 self._dense_oob = jnp.zeros((), dtype=jnp.int64)
                 self._stage_actual = {}
+                # (fullest bucket, rows sent) of each recorded exchange,
+                # in trace order: what a skewed key does to the one
+                # static capacity every bucket of a shuffle shares
+                self._exchange_rows = []
                 # static all_to_all volume this program moves across
                 # the mesh — assigned (not accumulated across traces:
                 # eval_shape and the jit both trace this body) and
@@ -430,9 +434,11 @@ class PlanCompiler:
                 # on this thread after the trace completes
                 set_device_params(None)
             # overflow block per device: [capacity_overflow, dense_oob,
-            # *stage_actuals] — the host grows buffers for the first,
-            # drops stale dense structures for the second, and tightens
-            # over-sized buffers from the rest (feedback)
+            # *stage_actuals, *exchange_rows] — the host grows buffers
+            # for the first, drops stale dense structures for the
+            # second, tightens over-sized buffers from the third
+            # (feedback) and counts the last (repartition_rows_total,
+            # repartition_hot_bucket_rows_total)
             skeys = sorted(self._stage_actual,
                            key=lambda k: (self._walk_order.get(
                                k[0], 1 << 30), k[1]))
@@ -449,7 +455,9 @@ class PlanCompiler:
                 return (cols, nulls, out.valid[None, :],
                         jnp.stack([self._overflow, self._dense_oob]
                                   + [self._stage_actual[k]
-                                     for k in skeys]))
+                                     for k in skeys]
+                                  + [c.astype(jnp.int64) for pair in
+                                     self._exchange_rows for c in pair]))
 
         mapped = shard_map(body, mesh=self.mesh,
                            in_specs=tuple(in_specs), out_specs=out_specs,
@@ -947,7 +955,9 @@ class PlanCompiler:
                 # (source device → target device) bucket
                 sent = jnp.zeros(self.n_dev, jnp.int32).at[target].add(
                     valid.astype(jnp.int32), mode="drop")
-                self._record(record_nid, "repartition", sent.max(), capacity)
+                hot = sent.max()
+                self._record(record_nid, "repartition", hot, capacity)
+                self._exchange_rows.append((hot, sent.sum()))
 
             all_cols = dict(blk.columns)
             for cid, nmask in blk.nulls.items():

@@ -49,7 +49,8 @@ def _bind_single_table(session, table: str, alias: str | None,
     sel = ast.Select(items=items,
                      from_items=(ast.TableRef(table, alias),),
                      where=where)
-    binder = Binder(session.catalog, _StoreDicts(session.store))
+    binder = Binder(session.catalog, _StoreDicts(session.store),
+                    counters=session.stats.counters)
     bound = binder.bind_select(sel)
     return bound, bound.rels[0]
 

@@ -52,7 +52,9 @@ from ..errors import StorageError
 # environment, not the engine's code, so a data directory written
 # before the upgrade must not keep serving the old program
 # 3 (PR 34): beside them the probe slots of its fused lookups
-EXEC_CACHE_VERSION = 3
+# 4 (PR 35): a program with a recorded exchange returns the exchange's
+# fullest bucket and rows sent after its stage actuals
+EXEC_CACHE_VERSION = 4
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)

@@ -449,7 +449,11 @@ class Executor:
                 with self.accountant.lease("plan", est_per_dev):
                     packed, overflow = _dispatch()
             with trace_span("settle"):
-                ov = np.asarray(overflow).reshape(-1, 2 + len(stage_keys))
+                # a row a device: [overflow, dense_oob, *stage actuals,
+                # *(fullest bucket, rows sent) of each recorded exchange]
+                ov = np.asarray(overflow).reshape(plan.n_devices, -1)
+                exchanges = ov[:, 2 + len(stage_keys):]
+                ov = ov[:, :2 + len(stage_keys)]
                 cap_overflow = int(ov[:, 0].sum())
                 dense_oob = int(ov[:, 1].sum())
                 if cap_overflow == 0 and dense_oob == 0:
@@ -479,11 +483,19 @@ class Executor:
                         # stages that actually exist — the psum-directory
                         # pushdown compiles shuffles away; stream paths
                         # pass here per batch, so the counter scales with
-                        # what actually crossed the mesh)
+                        # what actually crossed the mesh), and what its
+                        # recorded exchanges sent: the fullest bucket of
+                        # each over the mesh, and the rows of all
                         from ..stats import counters as sc
 
                         self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
                                                 shuffle_bytes)
+                        self.counters.increment(
+                            sc.REPARTITION_HOT_BUCKET_ROWS_TOTAL,
+                            int(exchanges[:, 0::2].max(axis=0).sum()))
+                        self.counters.increment(
+                            sc.REPARTITION_ROWS_TOTAL,
+                            int(exchanges[:, 1::2].sum()))
                     return packed, out_meta, caps, retries, tallies
             retries += 1
             from ..utils.faultinjection import fault_point

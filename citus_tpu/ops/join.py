@@ -439,40 +439,42 @@ def expand_join_pairs(build_keys, build_matchable, probe_keys, probe_valid,
     so the host can distinguish "grow buffers" from "stats were stale,
     drop the directory".
     """
-    order, lo, hi, dense_oob = _bounds(build_keys, build_matchable,
-                                       probe_keys, dense)
-    m = build_keys[0].shape[0]
-    n = probe_keys[0].shape[0]
-    counts = jnp.where(probe_matchable, hi - lo, 0).astype(jnp.int32)
-    if probe_outer:
-        emit = jnp.where(probe_valid & (counts == 0), 1, counts)
-    else:
-        emit = counts
-    total = emit.sum(dtype=jnp.int64)
-    # exclusive prefix in int64 (cross joins can exceed int32), clamped to
-    # capacity for the int32 slot arithmetic — slots past the clamp are
-    # invalid anyway (slot < total fails or offset goes negative)
-    starts64 = jnp.cumsum(emit.astype(jnp.int64)) - emit.astype(jnp.int64)
-    starts = jnp.minimum(starts64, capacity).astype(jnp.int32)
+    with stage_scope("expand"):
+        order, lo, hi, dense_oob = _bounds(build_keys, build_matchable,
+                                           probe_keys, dense)
+        m = build_keys[0].shape[0]
+        n = probe_keys[0].shape[0]
+        counts = jnp.where(probe_matchable, hi - lo, 0).astype(jnp.int32)
+        if probe_outer:
+            emit = jnp.where(probe_valid & (counts == 0), 1, counts)
+        else:
+            emit = counts
+        total = emit.sum(dtype=jnp.int64)
+        # exclusive prefix in int64 (cross joins can exceed int32), clamped to
+        # capacity for the int32 slot arithmetic — slots past the clamp are
+        # invalid anyway (slot < total fails or offset goes negative)
+        starts64 = jnp.cumsum(emit.astype(jnp.int64)) - emit.astype(jnp.int64)
+        starts = jnp.minimum(starts64, capacity).astype(jnp.int32)
 
-    # probe id per output slot: each emitting probe scatters its index at
-    # its start slot; a running max fills the run (sort-free emission —
-    # replaces a log2(N) searchsorted chain over every output slot)
-    marker = jnp.full(capacity, -1, jnp.int32).at[
-        jnp.where(emit > 0, starts, capacity)].max(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")
-    probe_idx = jnp.maximum(jax.lax.cummax(marker), 0)
+        # probe id per output slot: each emitting probe scatters its index at
+        # its start slot; a running max fills the run (sort-free emission —
+        # replaces a log2(N) searchsorted chain over every output slot)
+        marker = jnp.full(capacity, -1, jnp.int32).at[
+            jnp.where(emit > 0, starts, capacity)].max(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+        probe_idx = jnp.maximum(jax.lax.cummax(marker), 0)
 
-    slots = jnp.arange(capacity, dtype=jnp.int32)
-    offset = slots - starts[probe_idx]
-    out_valid = ((slots.astype(jnp.int64) < total)
-                 & (offset >= 0) & (offset < emit[probe_idx]))
-    sorted_pos = jnp.clip(lo[probe_idx] + offset, 0, m - 1)
-    build_idx = order[sorted_pos]
-    build_missing = out_valid & (counts[probe_idx] == 0)
-    build_idx = jnp.where(build_missing, 0, build_idx)
-    overflow = jnp.maximum(total - capacity, 0)
-    return build_idx, probe_idx, out_valid, build_missing, overflow, dense_oob
+        slots = jnp.arange(capacity, dtype=jnp.int32)
+        offset = slots - starts[probe_idx]
+        out_valid = ((slots.astype(jnp.int64) < total)
+                     & (offset >= 0) & (offset < emit[probe_idx]))
+        sorted_pos = jnp.clip(lo[probe_idx] + offset, 0, m - 1)
+        build_idx = order[sorted_pos]
+        build_missing = out_valid & (counts[probe_idx] == 0)
+        build_idx = jnp.where(build_missing, 0, build_idx)
+        overflow = jnp.maximum(total - capacity, 0)
+        return (build_idx, probe_idx, out_valid, build_missing, overflow,
+                dense_oob)
 
 
 def expand_join_outer(build_keys: list[jnp.ndarray], build_valid: jnp.ndarray,

@@ -24,6 +24,7 @@ import re
 from dataclasses import dataclass, field
 
 from ..catalog import Catalog, DistributionMethod
+from ..catalog.catalog import INTERMEDIATE_PREFIX, is_intermediate
 from ..errors import PlanningError, UnsupportedQueryError
 from ..sql import ast
 from ..types import ColumnDef, DataType, TableSchema, date_to_days
@@ -41,6 +42,21 @@ class BoundRel:
 
     def cid(self, column: str) -> str:
         return f"{self.rel_index}.{column}"
+
+    @property
+    def identity(self) -> str:
+        """What a plan fingerprint calls this relation.  A table is its
+        name.  An intermediate result is what a program over it depends
+        on — its columns and their types (its placement, feed shapes and
+        place in the statement are in the fingerprint beside it) — and
+        not its name, which is minted from a counter at every execution:
+        keyed by that, a statement with a subplan found neither its
+        compiled program nor its converged capacities again."""
+        if not is_intermediate(self.table):
+            return self.table
+        cols = ",".join(f"{c.name}:{c.dtype.value}"
+                        for c in self.schema.columns)
+        return f"{INTERMEDIATE_PREFIX}({cols})"
 
 
 @dataclass(frozen=True)
@@ -110,11 +126,13 @@ MISSING_CODE = -2  # equality target for strings absent from the dictionary
 
 class Binder:
     def __init__(self, catalog: Catalog, dicts: DictProvider,
-                 params: tuple = ()):
+                 params: tuple = (), counters=None):
         self.catalog = catalog
         self.dicts = dicts
         # prepared-statement argument values ($1 → params[0]); see BParam
         self.params = params
+        # StatCounters that learn of dictionary walks (_codes_where)
+        self.counters = counters
 
     # -- entry -------------------------------------------------------------
     def bind_select(self, sel: ast.Select) -> BoundQuery:
@@ -311,7 +329,7 @@ class Binder:
             if operand.dtype == DataType.STRING:
                 lo = self._expect_str_literal(e.low)
                 hi = self._expect_str_literal(e.high)
-                codes = self._codes_where(operand,
+                codes = self._codes_where(operand, ("between", lo, hi),
                                           lambda v: lo <= v <= hi)
                 return ir.BInConst(operand, codes, e.negated)
             low = self._coerce(self.bind_expr(e.low, scope, allow_agg),
@@ -325,7 +343,9 @@ class Binder:
             operand = self.bind_expr(e.operand, scope, allow_agg)
             if operand.dtype == DataType.STRING:
                 wanted = {self._expect_str_literal(x) for x in e.items}
-                codes = self._codes_where(operand, lambda v: v in wanted)
+                codes = self._codes_where(
+                    operand, ("in", frozenset(wanted)),
+                    lambda v: v in wanted)
                 return ir.BInConst(operand, codes, e.negated)
             vals = []
             for x in e.items:
@@ -340,7 +360,8 @@ class Binder:
                 raise PlanningError("LIKE requires a string operand")
             pattern = self._expect_str_literal(e.pattern)
             rx = like_to_regex(pattern)
-            codes = self._codes_where(operand, lambda v: bool(rx.match(v)))
+            codes = self._codes_where(operand, ("like", pattern),
+                                      lambda v: bool(rx.match(v)))
             return ir.BInConst(operand, codes, e.negated)
         if isinstance(e, ast.FuncCall):
             return self._bind_func(e, scope, allow_agg)
@@ -526,7 +547,8 @@ class Binder:
                     # range predicates lower to a code SET (value-
                     # dependent shape): bake for this execution
                     codes = self._codes_where(
-                        left, _str_cmp_fn(op, str(right.value)))
+                        left, (op, str(right.value)),
+                        _str_cmp_fn(op, str(right.value)))
                     return ir.BInConst(left, codes)
                 code = self._code_of(left, str(right.value))
                 return ir.BCmp(op, left,
@@ -542,7 +564,8 @@ class Binder:
             if op == "<>":
                 code = self._code_of(left, text)
                 return ir.BCmp("<>", left, ir.BConst(code, DataType.STRING))
-            codes = self._codes_where(left, _str_cmp_fn(op, text))
+            codes = self._codes_where(left, (op, text),
+                                      _str_cmp_fn(op, text))
             return ir.BInConst(left, codes)
         dtype = ir.promote(left.dtype, right.dtype)
         return ir.BCmp(op, self._coerce(left, dtype),
@@ -680,11 +703,22 @@ class Binder:
         code = d.code_of(text)
         return MISSING_CODE if code is None else code
 
-    def _codes_where(self, col: ir.BExpr, pred) -> tuple[int, ...]:
+    def _codes_where(self, col: ir.BExpr, key: tuple,
+                     pred) -> tuple[int, ...]:
+        """Codes of `col`'s values that `pred` holds for.  A column's
+        dictionary keeps the answer under `key` (what the predicate is
+        made of, never the lambda) and walks only the values it has
+        gained since: a statement bound again walks nothing.  A remap's
+        value list lives in the expression and is walked as it was."""
         if isinstance(col, ir.BStrRemap):
             return tuple(i for i, v in enumerate(col.values) if pred(v))
-        d = self._dict_for(col)
-        return tuple(i for i, v in enumerate(d.values) if pred(v))
+        codes, visited = self._dict_for(col).codes_where(key, pred)
+        if visited and self.counters is not None:
+            from ..stats import counters as sc
+
+            self.counters.increment(sc.DICT_PREDICATE_WALKS_TOTAL)
+            self.counters.increment(sc.DICT_PREDICATE_VALUES_TOTAL, visited)
+        return codes
 
     def _bind_alias_or_expr(self, e: ast.Expr, scope: "_Scope",
                             alias_map: dict, select, allow_agg=False):

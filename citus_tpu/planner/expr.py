@@ -32,8 +32,12 @@ class BCol(BExpr):
 
     cid: str
     dtype: DataType
-    # provenance for planning decisions (pruning, colocation):
-    table: str = ""
+    # provenance for planning decisions (pruning, colocation).  The
+    # table's name stays out of the repr, which plan fingerprints are
+    # made of: the scan of `rel_index` names its relation there
+    # (`BoundRel.identity`), and an intermediate result's name is new at
+    # every execution
+    table: str = field(default="", repr=False)
     column: str = ""
     rel_index: int = -1
 

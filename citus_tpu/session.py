@@ -26,6 +26,7 @@ from dataclasses import replace as dc_replace
 import numpy as np
 
 from .catalog import Catalog, DistributionMethod
+from .catalog.catalog import INTERMEDIATE_PREFIX
 from .config import Settings
 from .errors import (
     CatalogError,
@@ -2027,6 +2028,12 @@ class Session:
     def _execute_subselect(self, sel: ast.Select):
         """Nested (recursive-planning / MERGE-source) execution: counts as
         a subplan, not as user query traffic."""
+        from .stats.tracing import trace_span
+
+        with trace_span("subplan"):
+            return self._run_subplan(sel)
+
+    def _run_subplan(self, sel: ast.Select):
         from .stats import counters as sc
 
         self.stats.counters.increment(sc.SUBPLANS_EXECUTED)
@@ -2068,7 +2075,7 @@ class Session:
             finally:
                 self._params_tls.value = prev
             binder = Binder(self.catalog, _StoreDicts(self.store),
-                            params=params)
+                            params=params, counters=self.stats.counters)
             bound = binder.bind_select(sel)
             planner = DistributedPlanner(
                 self.catalog, _StoreStats(self.store), self.n_devices,
@@ -2766,57 +2773,76 @@ class Session:
                      column_names: tuple[str, ...] = ()) -> str:
         """Execute a subquery and store its rows as a temp reference table
         (the intermediate-result broadcast analogue)."""
-        result = self._execute_subselect(sel)
-        return self._store_result(result, cleanup, column_names)
+        from .stats.tracing import trace_span
+
+        with trace_span("subplan"):
+            result = self._run_subplan(sel)
+            return self._store_result(result, cleanup, column_names)
 
     def _store_result(self, result, cleanup: list[str],
                       column_names: tuple[str, ...] = ()) -> str:
         """ResultSet (or shim with column_names/columns/row_count/dtypes)
         → temp reference table."""
-        # itertools.count is GIL-atomic — concurrent query threads must
-        # not mint the same intermediate-table name
-        name = f"__intermediate_{next(self._temp_counter)}"
-        names = (list(column_names) if column_names
-                 else result.column_names)
-        cols = []
-        arrays = {}
-        dicts = {}
-        for out_name, col_name in zip(result.column_names, names):
-            data = result.columns[out_name]
-            rdt = _result_dtype(result, out_name)
-            if rdt == DataType.DATE:
-                # keep DATE columns as day numbers in the temp table (the
-                # combine phase formatted them to ISO text)
-                from .types import date_to_days
+        from .stats.tracing import trace_span
 
-                arr = np.array([None if x is None else date_to_days(str(x))
-                                for x in data], dtype=object)
-                dtype, dvals = DataType.DATE, None
-            else:
-                dtype, arr, dvals = _infer_column(data, result.row_count)
-            cols.append(ColumnDef(col_name, dtype))
-            arrays[col_name] = arr
-            if dvals is not None:
-                dicts[col_name] = dvals
-        self.catalog.create_reference_table(name, TableSchema(tuple(cols)))
-        cleanup.append(name)
-        if result.row_count > 0:
-            # validity from the pre-intern object arrays (None = NULL)
-            validity = {c: (~_none_mask(a) if a.dtype == object
-                            else np.ones(result.row_count, dtype=bool))
-                        for c, a in arrays.items()}
-            for col_name, values in dicts.items():
-                d = self.store.dictionary(name, col_name)
-                arrays[col_name] = d.intern_array(values)
-            arrays = {c: _object_to_typed(a) for c, a in arrays.items()}
-            shard = self.catalog.table_shards(name)[0]
-            # intermediate results are query plumbing, not logical data
-            # changes — the change feed must not see them (and a read-only
-            # SELECT must not pay a journal fsync)
-            with self.store.change_log.suppress():
-                self.store.append_stripe(name, shard.shard_id, arrays,
-                                         validity)
-        return name
+        with trace_span("subplan.store"):
+            # itertools.count is GIL-atomic — concurrent query threads must
+            # not mint the same intermediate-table name.  The name is what
+            # the catalog, the store and the feed cache know the rows by,
+            # and never recurs; plan fingerprints know them by their schema
+            # (planner/bind.py BoundRel.identity), which does
+            name = f"{INTERMEDIATE_PREFIX}{next(self._temp_counter)}"
+            names = (list(column_names) if column_names
+                     else result.column_names)
+            cols = []
+            arrays = {}
+            dicts = {}
+            for out_name, col_name in zip(result.column_names, names):
+                data = result.columns[out_name]
+                rdt = _result_dtype(result, out_name)
+                if rdt == DataType.DATE:
+                    # keep DATE columns as day numbers in the temp table (the
+                    # combine phase formatted them to ISO text)
+                    from .types import date_to_days
+
+                    arr = np.array(
+                        [None if x is None else date_to_days(str(x))
+                         for x in data], dtype=object)
+                    dtype, dvals = DataType.DATE, None
+                else:
+                    dtype, arr, dvals = _infer_column(data, result.row_count)
+                cols.append(ColumnDef(col_name, dtype))
+                arrays[col_name] = arr
+                if dvals is not None:
+                    dicts[col_name] = dvals
+            self.catalog.create_reference_table(
+                name, TableSchema(tuple(cols)))
+            cleanup.append(name)
+            if result.row_count > 0:
+                # validity from the pre-intern object arrays (None = NULL)
+                validity = {c: (~_none_mask(a) if a.dtype == object
+                                else np.ones(result.row_count, dtype=bool))
+                            for c, a in arrays.items()}
+                for col_name, values in dicts.items():
+                    d = self.store.dictionary(name, col_name)
+                    arrays[col_name] = d.intern_array(values)
+                arrays = {c: _object_to_typed(a) for c, a in arrays.items()}
+                shard = self.catalog.table_shards(name)[0]
+                # intermediate results are query plumbing, not logical
+                # data changes — the change feed must not see them (and a
+                # read-only SELECT must not pay a journal fsync)
+                with self.store.change_log.suppress():
+                    self.store.append_stripe(name, shard.shard_id, arrays,
+                                             validity)
+                from .stats import counters as sc
+
+                self.stats.counters.increment(sc.INTERMEDIATE_ROWS_TOTAL,
+                                              result.row_count)
+                self.stats.counters.increment(
+                    sc.INTERMEDIATE_BYTES_TOTAL,
+                    sum(a.nbytes for a in arrays.values())
+                    + sum(v.nbytes for v in validity.values()))
+            return name
 
     # -- set operations ----------------------------------------------------
     def _execute_setop(self, stmt: "ast.SetOp"):
@@ -2917,6 +2943,9 @@ class Session:
         except CatalogError:
             pass
         self.store.drop_table_storage(name)
+        # the name never recurs, so its feed can never hit again: free
+        # the device bytes now and not when the LRU reaches them
+        self.executor.feed_cache.invalidate_table(name)
 
     def _save_catalog(self):
         self.catalog.save(os.path.join(self.data_dir, "catalog.json"))

@@ -22,6 +22,24 @@ QUERIES_REPARTITION = "queries_repartition"
 QUERIES_FAST_PATH = "queries_fast_path"
 POINT_INDEX_LOOKUPS = "point_index_lookups"
 SUBPLANS_EXECUTED = "subplans_executed"
+# what recursive planning stored as temp reference tables for the outer
+# statement to scan (session._store_result): rows, and bytes of their
+# typed columns and validity masks
+INTERMEDIATE_ROWS_TOTAL = "intermediate_rows_total"
+INTERMEDIATE_BYTES_TOTAL = "intermediate_bytes_total"
+# string predicates (LIKE, IN, BETWEEN, <) resolved to dictionary codes
+# at bind time by walking a dictionary's values (planner/bind.py
+# _codes_where): walks that visited any value, and the values visited.
+# A dictionary keeps each predicate's codes, so both stay at 0 for a
+# statement bound again over dictionaries that have not grown
+DICT_PREDICATE_WALKS_TOTAL = "dict_predicate_walks_total"
+DICT_PREDICATE_VALUES_TOTAL = "dict_predicate_values_total"
+# rows the executed statements' converged programs sent through their
+# repartition exchanges, over the mesh, and rows of the fullest
+# (source device, target device) bucket of each exchange: hot × buckets
+# / rows is the imbalance the static per-bucket capacity has to absorb
+REPARTITION_ROWS_TOTAL = "repartition_rows_total"
+REPARTITION_HOT_BUCKET_ROWS_TOTAL = "repartition_hot_bucket_rows_total"
 ROWS_INGESTED = "rows_ingested"
 ROWS_RETURNED = "rows_returned"
 DML_UPDATE = "dml_update_count"
@@ -149,7 +167,10 @@ SCRUB_REPAIRS_TOTAL = "scrub_repairs_total"
 ALL_COUNTERS = [
     QUERIES_SINGLE_SHARD, QUERIES_MULTI_SHARD, QUERIES_REPARTITION,
     QUERIES_FAST_PATH, POINT_INDEX_LOOKUPS,
-    SUBPLANS_EXECUTED, ROWS_INGESTED, ROWS_RETURNED,
+    SUBPLANS_EXECUTED, INTERMEDIATE_ROWS_TOTAL, INTERMEDIATE_BYTES_TOTAL,
+    DICT_PREDICATE_WALKS_TOTAL, DICT_PREDICATE_VALUES_TOTAL,
+    REPARTITION_ROWS_TOTAL, REPARTITION_HOT_BUCKET_ROWS_TOTAL,
+    ROWS_INGESTED, ROWS_RETURNED,
     DML_UPDATE, DML_DELETE, DML_MERGE, DDL_COMMANDS,
     CAPACITY_RETRIES, DEVICE_ROWS_SCANNED,
     INSERT_SELECT_PUSHDOWN, INSERT_SELECT_REPARTITION, INSERT_SELECT_PULL,

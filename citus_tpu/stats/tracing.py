@@ -87,6 +87,11 @@ SPAN_NAMES: dict[str, str] = {
     "gate": "session gate before a statement runs: the replica's "
             "read-only and staleness checks",
     "plan": "recursive planning + bind + distributed planning",
+    "subplan": "recursive planning: one subplan (derived table, CTE, set-"
+               "operation side, expression subquery) planned and executed "
+               "— its own plan … combine are children",
+    "subplan.store": "a subplan's rows → temp reference table: typing, "
+                     "dictionary interning, one stripe appended",
     "route": "path choice between plan and feed: plan-shape "
              "counters, manifest staleness refresh, stream eligibility",
     "feed": "device feed build (eager, pipelined or per-batch)",
@@ -196,6 +201,9 @@ STAGE_NAMES: dict[str, str] = {
              "index forward to its probe rows",
     "join_out": "join keys, pair emission / build-column gathers, "
                 "residual filter, compaction",
+    "expand": "sub: join_out — the general (build side not unique) "
+              "path's pair emission: match ranges, prefix sum, one slot "
+              "a pair",
     "compact": "sub: scan_out, join_out, agg_out — survivors' positions "
                "by one sort; the columns follow as that row index and "
                "are gathered where they are read (`deferred`)",

@@ -87,8 +87,19 @@ def string_hash_tokens(values: list[str]) -> np.ndarray:
 class Dictionary:
     """Append-only value↔code mapping for one STRING column."""
 
+    # predicates whose matching codes are kept (codes_where), and the
+    # codes they may hold between them (a range or a NOT LIKE over a
+    # 1.5 M-value column matches most of it): least recently used
+    # beyond either bound go
+    PREDICATE_MEMO_MAX = 32
+    PREDICATE_MEMO_MAX_CODES = 1 << 20
+
     def __init__(self, values: list[str] | None = None):
         import threading
+        from collections import OrderedDict
+
+        # predicate key → (values walked, codes that matched among them)
+        self._pred_memo: OrderedDict = OrderedDict()
 
         self._values: list[str] = []
         # value → code; None after a native bulk append (rebuilt lazily —
@@ -230,6 +241,30 @@ class Dictionary:
         # doing that unlocked races intern_array (one string, two codes)
         with self._mu:
             return self._codes_map().get(value)
+
+    def codes_where(self, key, pred) -> tuple[tuple[int, ...], int]:
+        """(codes of the values `pred` holds for, values this call
+        visited).  The answer is kept under `key`, which must say all
+        that `pred` depends on — a LIKE pattern, a literal set, an
+        operator and its bound.  Codes are append-only, so an entry
+        records how far its walk went and a later call walks only what
+        the dictionary has grown by since: no value at all on a
+        dictionary that has not changed."""
+        with self._mu:
+            seen, codes = self._pred_memo.get(key, (0, ()))
+            tail = self._values[seen:]
+        if tail:
+            codes += tuple(seen + i for i, v in enumerate(tail) if pred(v))
+        with self._mu:
+            if self._pred_memo.get(key, (0,))[0] <= seen + len(tail):
+                self._pred_memo[key] = (seen + len(tail), codes)
+            self._pred_memo.move_to_end(key)
+            held = sum(len(c) for _seen, c in self._pred_memo.values())
+            while self._pred_memo and (
+                    len(self._pred_memo) > self.PREDICATE_MEMO_MAX
+                    or held > self.PREDICATE_MEMO_MAX_CODES):
+                held -= len(self._pred_memo.popitem(last=False)[1][1])
+        return codes, len(tail)
 
     def value_of(self, code: int) -> str:
         if not 0 <= code < len(self._values):

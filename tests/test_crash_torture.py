@@ -171,13 +171,36 @@ def _run_workload(sess):
     return i
 
 
+class _OwnDiskSim(CrashSim):
+    """A CrashSim that sees only writes under `root`, the simulated
+    process's data directory.  CrashSim counts every durable write of
+    the PROCESS; under xdist the maintenance threads of sessions that
+    earlier test files left open write `cleanup.json` meanwhile, and
+    one more op in the rehearsal than in a replay reads as "op N never
+    reached"."""
+
+    def __init__(self, crash_at, mode, root):
+        super().__init__(crash_at, mode)
+        self.root = os.path.abspath(root) + os.sep
+
+    def op(self, kind, path, payload=None, tmp=None):
+        if os.path.abspath(path).startswith(self.root):
+            super().op(kind, path, payload, tmp)
+
+
+def _own_disk_power_cut(crash_at, mode, root) -> power_cut_at:
+    cut = power_cut_at(crash_at, mode)
+    cut.sim = _OwnDiskSim(crash_at, mode, str(root))
+    return cut
+
+
 def _rehearse(base_dir, tmp_path) -> int:
     """Count the workload's durable write ops (no crash) and pin the
     final state against the model."""
     work = tmp_path / "rehearsal"
     shutil.copytree(base_dir, work)
     sess = _connect(work)
-    with power_cut_at(None) as sim:
+    with _own_disk_power_cut(None, None, work) as sim:
         _run_workload(sess)
     sess.close()
     cat, store, state = _cold_restart(str(work))
@@ -197,7 +220,7 @@ def _torture_one(base_dir, tmp_path, n: int,
     sess = _connect(work)
     crashed_unit = None
     completed_units = 0
-    with power_cut_at(n, mode=mode) as sim:
+    with _own_disk_power_cut(n, mode, work) as sim:
         try:
             for i, (stmts, _apply) in enumerate(UNITS):
                 for sql in stmts:
