@@ -437,75 +437,102 @@ def bench_carry(small=False, out="chiprun_out"):
     return rows
 
 
-def bench_groupby(regimes=None, repeats=3, reps=8):
-    """High-cardinality GROUP BY A/B (round 7): the sort path
-    (packed-key `segment_aggregate`, exactly what the executor runs)
-    vs the bucketed dense-grid path (`ops.groupby.
-    bucketed_grid_aggregate`) in its XLA and Pallas formulations — the
-    measurement behind the planner's `group_bucket_eligible` gate and
-    the `group_by_kernel` config var.
+def _pack_chunks_by_gather(slot, valid, columns, n_buckets, tile, nc,
+                           chunk):
+    """`ops.groupby._pack_chunks` as ISSUE 36 first wrote it, kept here
+    alone: an argsort by slot, then every lane of every chunk gathers
+    its sorted position's source row and each column at that row —
+    an element gather a column over all NC x C slots, where the
+    program's form carries the columns through the sort and cuts whole
+    chunks out of them."""
+    from citus_tpu.ops.groupby import _chunk_layout
 
-    Prints a rows/s table across (n, k) regimes (k = packed slot-space
-    size) and a winner histogram.  Runs on any backend — the 8-device
-    CPU test mesh included, with smaller default regimes there; the
-    authoritative hardware numbers are whatever the driver captures on
-    a real chip.  Pallas is TIMED only off-CPU (interpret mode is not
-    a measurement) but its outputs are parity-checked via a small
-    interpreted run.
+    n = slot.shape[0]
+    trash = n_buckets * tile
+    key = jnp.where(valid, slot, trash).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    cb, base, live = _chunk_layout(key[order], n_buckets, tile, nc, chunk)
+    lane = jnp.arange(chunk, dtype=jnp.int32)[None, :]
+    pos, lane_ok = base[:, None] + lane, lane < live[:, None]
+    src = order[jnp.clip(pos, 0, n - 1)]
+    packed = {c: jnp.where(lane_ok, col[src], jnp.zeros((), col.dtype))
+              for c, col in columns.items()}
+    return packed, jnp.where(lane_ok, key[src], trash), lane_ok, cb
 
-    Usage:  python bench_kernels.py groupby
+
+def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out",
+                  pallas=False):
+    """High-cardinality GROUP BY A/B: the sort path (packed-key
+    `segment_aggregate`, exactly what the executor runs) against the
+    bucketed dense-grid path (`ops.groupby.bucketed_grid_aggregate`)
+    at each candidate chunk size of its pack — 1,024 rows, 4,096, and
+    the uniform expectation `_round_cap(ceil(n / n_buckets))` — the
+    measurement behind `ops.groupby.GROUP_CHUNK_ROWS`, the planner's
+    `group_bucket_eligible` gate and the `group_by_kernel` config var.
+    Beside them `_pack_chunks_by_gather`, the pack's element-gather
+    form, at 4,096.
+
+    A regime is (n, k, skew): n input slots, k = the packed slot
+    space's size, and the key uniform over it or SKEWED — all but
+    0.1 % of the rows in the first tile, what a Zipf key or a count
+    column does to a value-range partition (Q13's two group-bys on
+    `tpch4z.q13`, whose shapes lead the chip's list).  Prints a line a
+    (regime, form) and a winner histogram, and writes
+    `<out>/bench_groupby.json`.  Runs on any backend, with small
+    default regimes on the CPU; a time is a device time only on the
+    chip.  `pallas` adds the Pallas tile kernel (interpret mode on the
+    CPU is parity-checked, never timed).
+
+    Usage:  python bench_kernels.py groupby [pallas]
     """
+    import json
+    import os
+
     from citus_tpu.runtime import ensure_jax_configured
 
     ensure_jax_configured()  # int64 packed keys need x64 standalone
     import citus_tpu.ops.groupby as G
+    from citus_tpu.executor.compiler import _round_cap
     from citus_tpu.ops.aggregate import segment_aggregate
-    platform = jax.devices()[0].platform
+    dev = jax.devices()[0]
+    platform = dev.platform
     if regimes is None:
-        regimes = ([(1 << 18, 4096), (1 << 18, 1 << 16),
-                    (1 << 20, 4096), (1 << 20, 1 << 18)]
+        regimes = ([(1 << 18, 1 << 16, "uniform"),
+                    (1 << 18, 1 << 16, "skewed")]
                    if platform == "cpu" else
-                   # TPU: the ISSUE grid — n ∈ {1M, 8M}, k ∈ {4k,
-                   # 64k, 1M} (k > n regimes are planner-ineligible:
+                   # Q13's inner group-by a chip (the join's pair
+                   # buffer over 37 tiles of c_custkey), its outer (30
+                   # tiles of c_count), then 1 M and 8 M rows over 16
+                   # and 256 tiles (k > n is planner-ineligible:
                    # occupancy < 1/4 keeps the sort path)
-                   [(1 << 20, 4096), (1 << 20, 1 << 16),
-                    (1 << 20, 1 << 20),
-                    (1 << 23, 4096), (1 << 23, 1 << 16),
-                    (1 << 23, 1 << 20)])
-    print(f"backend: {platform} ({jax.devices()[0].device_kind}); "
-          f"tile = {G.GROUP_TILE_SLOTS} slots")
+                   [(834_048, 150_002, "uniform"),
+                    (834_048, 150_002, "skewed"),
+                    (150_016, 118_800, "skewed"),
+                    (1 << 20, 1 << 16, "uniform"),
+                    (1 << 20, 1 << 16, "skewed"),
+                    (1 << 23, 1 << 20, "uniform"),
+                    (1 << 23, 1 << 20, "skewed")])
+    tile = G.GROUP_TILE_SLOTS
+    print(f"backend: {platform} ({dev.device_kind}); tile = {tile} slots")
     rng = np.random.default_rng(0)
-    if platform == "cpu":
-        # CPU: the Pallas kernel is never TIMED (interpret mode is not
-        # a measurement) but its outputs ARE parity-checked once via a
-        # small interpreted run — full bench sizes would take minutes
-        # per grid step under the interpreter
-        pn, pk = 1 << 12, 256
-        ps = jnp.asarray(rng.integers(0, pk, pn).astype(np.int32))
-        pv = jnp.asarray(rng.uniform(0, 10, pn).astype(np.float32))
-        pvalid = jnp.ones(pn, bool)
-        pcap = pn
-        args = (ps, pvalid, [(pv, "sum")], pk, pcap)
-        rx = G.bucketed_grid_aggregate(*args, kernel="xla")
-        rp = G.bucketed_grid_aggregate(*args, kernel="pallas",
-                                       interpret=True)
-        pall_ok = bool(np.allclose(np.asarray(rx[0][0]),
-                                   np.asarray(rp[0][0]),
-                                   rtol=1e-4, atol=1e-2))
-        print(f"pallas interpret parity (n={pn}, k={pk}): {pall_ok}")
     rows = []
-    for n, k in regimes:
-        slot0 = jnp.asarray(rng.integers(0, k, n).astype(np.int64))
+    for n, k, skew in regimes:
+        nb = G.group_bucket_count(k)
+        base = rng.integers(0, k, n)
+        if skew == "skewed":
+            base = np.where(rng.random(n) < 0.999,
+                            rng.integers(0, min(tile, k), n), base)
+        slot0 = jnp.asarray(base.astype(np.int64))
         valid = jnp.asarray(rng.random(n) > 0.05)
         v0 = jnp.asarray(rng.uniform(0, 100, n).astype(np.float32))
         v1 = jnp.asarray(rng.uniform(0, 100, n).astype(np.float32))
         ones = jnp.asarray(np.ones(n, np.int32))
-        nb = G.group_bucket_count(k)
-        # uniform slots with 2× skew headroom: overflow-free by design
-        cap = -(-n // nb) * 2 + 128
 
         def sort_path(i):
-            s = (slot0 + i) % k
+            # the rotation stays inside a tile, so a skewed key stays
+            # skewed at every iteration
+            s = slot0 - slot0 % tile + (slot0 + i) % tile
+            s = jnp.minimum(s, k - 1)
             packed = jnp.where(valid, s, jnp.iinfo(jnp.int64).max)
             _gk, res, _gv, ng = segment_aggregate(
                 [packed],
@@ -513,58 +540,62 @@ def bench_groupby(regimes=None, repeats=3, reps=8):
                  (ones, "count", None)], valid, out_keys=[s])
             return (res[2].sum() + ng).astype(jnp.int64)
 
-        def bucketed(i, kernel="xla", interpret=False):
-            s32 = ((slot0 + i) % k).astype(jnp.int32)
-            res, rps, ov, _fill = G.bucketed_grid_aggregate(
+        def bucketed(i, kernel="xla"):
+            s = slot0 - slot0 % tile + (slot0 + i) % tile
+            s32 = jnp.minimum(s, k - 1).astype(jnp.int32)
+            res, rps = G.bucketed_grid_aggregate(
                 s32, valid,
-                [(v0, "sum"), (v1, "sum"), (ones, "count")],
-                k, cap, kernel=kernel, interpret=interpret)
-            # fold overflow in so a capacity bug cannot be silently
-            # timed as a win (it stays 0 by construction)
+                [(v0, "sum"), (v1, "sum"), (ones, "count")], k,
+                kernel=kernel)
             return (res[2].sum().astype(jnp.int64)
-                    + (rps > 0).sum() + ov).astype(jnp.int64)
+                    + (rps > 0).sum()).astype(jnp.int64)
 
-        # correctness gate before timing: identical row totals AND
-        # identical live-group counts — per formulation, so a Pallas
-        # parity failure cannot implicate the XLA result (and a broken
-        # path can never be crowned winner below)
-        want = int(jax.device_get(sort_path(jnp.int64(0))))
-        ok_xla = want == int(jax.device_get(bucketed(jnp.int64(0))))
-        t_sort = _slope_time(sort_path, repeats, reps)
-        t_bx = _slope_time(bucketed, repeats, reps)
-        t_bp = None
-        ok_pallas = True
-        if platform != "cpu":
-            try:
-                f_bp = functools.partial(bucketed, kernel="pallas")
-                ok_pallas = want == int(jax.device_get(
-                    f_bp(jnp.int64(0))))
-                t_bp = _slope_time(f_bp, repeats, reps)
-            except Exception as e:
-                ok_pallas = False
-                print(f"  pallas failed at k={k}: "
-                      f"{str(e).splitlines()[0][:120]}")
-        rows.append((n, k, t_sort, t_bx if ok_xla else None,
-                     t_bp if ok_pallas else None,
-                     ok_xla and ok_pallas))
-        bp = ("n/a" if t_bp is None
-              else f"{n / t_bp / 1e6:8.1f}M/s")
-        print(f"n=2^{n.bit_length() - 1} k={k:>8}  "
-              f"sort={n / t_sort / 1e6:8.1f}M/s  "
-              f"bucketed_xla={n / t_bx / 1e6:8.1f}M/s "
-              f"(correct={ok_xla})  "
-              f"bucketed_pallas={bp} (correct={ok_pallas})")
-    best = {"sort": 0, "bucketed_xla": 0, "bucketed_pallas": 0}
-    for _n, _k, t_sort, t_bx, t_bp, _ok in rows:
-        # only formulations that passed their own correctness gate
-        # compete (an incorrect path must never be timed as a win)
-        opts = {"sort": t_sort}
-        if t_bx is not None:
-            opts["bucketed_xla"] = t_bx
-        if t_bp is not None:
-            opts["bucketed_pallas"] = t_bp
-        best[min(opts, key=opts.get)] += 1
+        chunks = sorted({1024, 4096, _round_cap(-(-n // nb))})
+        forms = [("sort", None, None, sort_path)]
+        forms += [(f"chunked_{c}", c, G._pack_chunks, bucketed)
+                  for c in chunks]
+        forms.append(("chunked_4096_by_gather", 4096,
+                      _pack_chunks_by_gather, bucketed))
+        if pallas and platform != "cpu":
+            forms.append(("chunked_4096_pallas", 4096, G._pack_chunks,
+                          functools.partial(bucketed, kernel="pallas")))
+        chunk_rows, pack = G.GROUP_CHUNK_ROWS, G._pack_chunks
+        want = None
+        try:
+            for name, c, pack_form, fn in forms:
+                if c is not None:
+                    G.GROUP_CHUNK_ROWS, G._pack_chunks = c, pack_form
+                try:
+                    t, compile_s, got = _slope_time_once(fn, repeats, reps)
+                except Exception as e:  # a form the compiler refuses
+                    print(f"  {name} failed: "
+                          f"{str(e).splitlines()[0][:160]}")
+                    continue
+                # identical row totals AND live-group counts, a form:
+                # a broken one is never crowned below
+                want = got if want is None else want
+                rows.append({
+                    "form": name, "n": n, "k": k, "skew": skew,
+                    "buckets": nb, "chunk": c,
+                    "slots": (None if c is None
+                              else (-(-n // c) + nb) * c),
+                    "ms": t * 1e3, "ns_per_row": t * 1e9 / n,
+                    "compile_s": compile_s, "agrees": got == want})
+                print(json.dumps(rows[-1]), flush=True)
+        finally:
+            G.GROUP_CHUNK_ROWS, G._pack_chunks = chunk_rows, pack
+    best: dict[str, int] = {}
+    for n, k, skew in regimes:
+        timed = [r for r in rows if (r["n"], r["k"], r["skew"])
+                 == (n, k, skew) and r["agrees"]]
+        if timed:
+            w = min(timed, key=lambda r: r["ms"])["form"]
+            best[w] = best.get(w, 0) + 1
     print("winner histogram:", best)
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "bench_groupby.json"), "w") as f:
+        json.dump({"device": [dev.platform, dev.device_kind],
+                   "tile": tile, "rows": rows}, f, indent=1)
     return rows
 
 
@@ -636,6 +667,6 @@ if __name__ == "__main__":
     elif len(sys.argv) > 1 and sys.argv[1] == "carry":
         bench_carry(small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "groupby":
-        bench_groupby()
+        bench_groupby(pallas="pallas" in sys.argv[2:])
     else:
         main()

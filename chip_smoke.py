@@ -310,7 +310,7 @@ class Smoke:
     @staticmethod
     def programs(sess) -> dict:
         """What the executable cache holds for this data_dir: entry →
-        the capacity stages compiled into that program (`agg_bucket`
+        the capacity stages compiled into that program (`agg_grid`
         means the bucketed group-by is in it).  EXPLAIN's tags come from
         row estimates; these come from the programs that were built.  A
         sort-and-scan lookup join has no capacity and so no stage here:

@@ -110,18 +110,14 @@ _register(ConfigVar(
     "High-cardinality GROUP BY path: 'auto' (planner pick — bucketed "
     "dense-grid aggregation on TPU where structurally eligible, sort "
     "path elsewhere), 'sort' (always the argsort/segmented-scan path), "
-    "'bucketed' (force the bucketed grid, XLA one-hot dot_general "
-    "inner), 'bucketed_pallas' (force it with the Pallas tile kernel). "
-    "bench_kernels.py groupby A/Bs all three on the target hardware; "
-    "auto stays measurement-gated so CPU meshes keep the sort path.",
+    "'bucketed' (force the bucketed grid: one sort by slot, each "
+    "tile's rows cut into fixed-size chunks, XLA one-hot dot_general "
+    "a chunk; sized by the rows whatever the key's distribution, so "
+    "it has no capacity to set), 'bucketed_pallas' (force it with the "
+    "Pallas tile kernel a chunk). bench_kernels.py groupby A/Bs them "
+    "and the chunk size on the target hardware; auto stays "
+    "measurement-gated so CPU meshes keep the sort path.",
     str, choices=("auto", "sort", "bucketed", "bucketed_pallas")))
-_register(ConfigVar(
-    "agg_bucket_capacity_factor", 2.0,
-    "Per-bucket row-slot headroom over the uniform expectation for "
-    "bucketed dense-grid aggregation (ops/groupby.py). Hot buckets "
-    "overflow and regrow through the normal retry path; capacity "
-    "feedback tightens converged sizes.",
-    float, min_value=1.0, max_value=64.0))
 _register(ConfigVar(
     "enable_capacity_feedback", True,
     "After a clean execution, shrink buffers whose recorded actual row "

@@ -103,9 +103,7 @@ def caps_signature(plan: QueryPlan, caps) -> tuple:
             tuple(sorted((order[k], v) for k, v in caps.agg_out.items())),
             caps.dense_off,
             tuple(sorted((order[k], v) for k, v in caps.scan_out.items())),
-            caps.output_repart,
-            tuple(sorted((order[k], v)
-                         for k, v in caps.agg_bucket.items())))
+            caps.output_repart)
 
 
 def feeds_signature(plan: QueryPlan, feeds) -> tuple:

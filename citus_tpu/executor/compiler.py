@@ -235,19 +235,12 @@ class Capacities:
     # per-(source, target) bucket slots for the INSERT..SELECT output
     # shuffle (QueryPlan.output_repart); None when the plan has none
     output_repart: int | None = None
-    # per-bucket row slots for bucketed dense-grid aggregation
-    # (AggregateNode.bucket_keys): the packed input buffer is
-    # [n_buckets, this]; a hot bucket overflows and regrows through
-    # the normal retry path, feedback tightens at 0.85
-    agg_bucket: dict[int, int] = None
 
     def __post_init__(self):
         if self.agg_out is None:
             self.agg_out = {}
         if self.scan_out is None:
             self.scan_out = {}
-        if self.agg_bucket is None:
-            self.agg_bucket = {}
 
     def grown(self, overflow: int) -> "Capacities":
         """Retry sizing: at least double, and at least enough for the
@@ -263,8 +256,7 @@ class Capacities:
                           self.dense_off,
                           {k: g(v) for k, v in self.scan_out.items()},
                           g(self.output_repart)
-                          if self.output_repart else None,
-                          {k: g(v) for k, v in self.agg_bucket.items()})
+                          if self.output_repart else None)
 
 
 def survivor_positions(valid, k: int):
@@ -379,7 +371,8 @@ class PlanCompiler:
             # assigned, not accumulated, like _shuffle_bytes: published
             # as PlanCompiler.tallies after build
             self._tallies = (tally.columns, tally.gathers,
-                             self._lookup_probe_slots)
+                             self._lookup_probe_slots,
+                             self._agg_bucket_slots)
             return packed
 
         def traced(flat_feeds):
@@ -406,6 +399,9 @@ class PlanCompiler:
                 # probe slots of the fused lookups, over the mesh: the
                 # same rule
                 self._lookup_probe_slots = 0
+                # packed slots of the bucketed group-bys, over the
+                # mesh: the same rule
+                self._agg_bucket_slots = 0
                 out = self._exec(self.plan.root, blocks)
                 if self.plan.output_repart is not None:
                     # INSERT..SELECT device routing: shuffle the final
@@ -470,8 +466,10 @@ class PlanCompiler:
         self.shuffle_bytes = int(self._shuffle_bytes)
         # (columns this program carries as a row index across a
         # compaction or a lookup, gathers it issues for them later,
-        # probe slots of its fused lookups): deferred_columns_total /
-        # deferred_gathers_total / lookup_probe_slots_total
+        # probe slots of its fused lookups, packed slots of its
+        # bucketed group-bys): deferred_columns_total /
+        # deferred_gathers_total / lookup_probe_slots_total /
+        # agg_bucket_slots_total
         self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
@@ -1416,10 +1414,9 @@ class PlanCompiler:
     def agg_bucket_shape(node: AggregateNode, group_kernel: str,
                          dense_off: bool) -> bool:
         """Single decision point for the bucketed dense-grid group-by:
-        capacity planning (Capacities.agg_bucket sizing), the compiler
-        dispatch, EXPLAIN's tag and the groupby_bucketed_total counter
-        must all agree, or a compiled plan would look up per-bucket
-        capacities that were never allocated."""
+        capacity planning (the result grid's agg_out sizing), the
+        compiler dispatch, EXPLAIN's tag and the groupby_bucketed_total
+        counter must all agree."""
         if dense_off or node.combine not in ("local", "repartition"):
             return False
         if not getattr(node, "bucket_keys", None) or \
@@ -1678,8 +1675,7 @@ class PlanCompiler:
             with stage_scope("agg_grid"):
                 return self._exec_dense_aggregate(node, blk)
         if self.agg_bucket_shape(node, self.group_kernel,
-                                 self.caps.dense_off) and \
-                id(node) in self.caps.agg_bucket:
+                                 self.caps.dense_off):
             with stage_scope("agg_bucket"):
                 bucketed = self._exec_bucketed_aggregate(node, blk)
             if bucketed is not None:
@@ -1976,9 +1972,13 @@ class PlanCompiler:
         no all_to_all combine (cross-device merge is psum/pmin/pmax
         over the slot grid, exactly like the flat dense grid).  Stale
         key ranges count into dense_oob and the host retries on the
-        sort path; a hot bucket overflows its static per-bucket
-        capacity and regrows through the normal retry."""
-        from ..ops.groupby import bucketed_grid_aggregate
+        sort path; the pack is sized by the input's slots whatever the
+        key's distribution, so it has no capacity to overflow."""
+        from ..ops.groupby import (
+            bucketed_grid_aggregate,
+            group_bucket_count,
+            group_pack_shape,
+        )
         from ..utils.faultinjection import fault_point
 
         # named seam: a failure while building the bucketed pack must
@@ -2033,13 +2033,13 @@ class PlanCompiler:
             comp_idx.append(len(op_values))
             op_values.append((contrib.astype(jnp.int32), "count"))
 
-        cap = self.caps.agg_bucket[id(node)]
         kernel = ("pallas" if self.group_kernel == "bucketed_pallas"
                   else "xla")
-        res, rows_per_slot, boverflow, bfill = bucketed_grid_aggregate(
-            slot32, blk.valid, op_values, total, cap, kernel=kernel)
-        self._overflow = self._overflow + boverflow
-        self._record(id(node), "agg_bucket", bfill, cap)
+        res, rows_per_slot = bucketed_grid_aggregate(
+            slot32, blk.valid, op_values, total, kernel=kernel)
+        nc, chunk = group_pack_shape(int(slot32.shape[0]),
+                                     group_bucket_count(total))
+        self._agg_bucket_slots += self.n_dev * nc * chunk
 
         results = []
         companions = []

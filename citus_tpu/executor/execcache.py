@@ -54,7 +54,8 @@ from ..errors import StorageError
 # 3 (PR 34): beside them the probe slots of its fused lookups
 # 4 (PR 35): a program with a recorded exchange returns the exchange's
 # fullest bucket and rows sent after its stage actuals
-EXEC_CACHE_VERSION = 4
+# 5 (PR 36): a fourth tally, the packed slots of its bucketed group-bys
+EXEC_CACHE_VERSION = 5
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)
@@ -437,15 +438,16 @@ class ExecutableCache:
         out_meta = [(kind, cid, np.dtype(dt))
                     for kind, cid, dt in meta["out_meta"]]
         stage_keys = [tuple(sk) for sk in meta["stage_keys"]]
-        carried, gathered, probe_slots = (int(n) for n in meta["tallies"])
+        carried, gathered, probe_slots, bucket_slots = (
+            int(n) for n in meta["tallies"])
         return (compiled, out_meta, stage_keys,
                 int(meta["shuffle_bytes"]),
-                (carried, gathered, probe_slots))
+                (carried, gathered, probe_slots, bucket_slots))
 
     # -- store ---------------------------------------------------------------
     def store(self, key, mesh, compiled, out_meta, stage_keys,
               shuffle_bytes: int,
-              tallies: tuple[int, int, int] = (0, 0, 0)) -> bool:
+              tallies: tuple[int, int, int, int] = (0, 0, 0, 0)) -> bool:
         """Persist one compiled entry.  Best-effort for REAL IO errors
         (the in-memory entry still answers the statement; persistence
         is a warm-start optimization, like the caps memo) — but the

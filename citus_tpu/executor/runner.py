@@ -269,9 +269,9 @@ class Executor:
             orp_sig = (None if orp is None
                        else (orp[0], orp[1], orp[2], repr(orp[3])))
             # group_by_kernel changes which CAPACITY TABLES exist
-            # (agg_bucket vs sort-path buffers), so converged sizes memoized
-            # under one mode must not be replayed under another — it joins
-            # the fingerprint
+            # (the bucketed grid's agg_out vs sort-path buffers), so
+            # converged sizes memoized under one mode must not be
+            # replayed under another — it joins the fingerprint
             fingerprint = (node_fingerprint(plan.root), plan.n_devices,
                            str(compute_dtype), feeds_signature(plan, feeds),
                            topk_sig, orp_sig,
@@ -335,13 +335,14 @@ class Executor:
         from ..utils.cancellation import check_cancel
 
         limit = self.settings.get("max_plan_buffer_bytes")
+        group_kernel = self.settings.get("group_by_kernel")
         retries = 0
         tightened = False
         while True:
             check_cancel()  # overflow-retry iterations are cancel seams
             with trace_span("caps"):
                 # one estimate serves the guard here and the lease below
-                est = _plan_buffer_bytes(plan, caps)
+                est = _plan_buffer_bytes(plan, caps, group_kernel)
                 if limit and est > limit:
                     if self._plan_degradable(plan):
                         # eligible over-limit plans route into the OOM
@@ -359,7 +360,6 @@ class Executor:
                         f"{limit / 1e9:.1f} GB) — usually a cartesian "
                         "or extreme-fanout join; rewrite the query or "
                         "raise the limit")
-                group_kernel = self.settings.get("group_by_kernel")
                 key = fingerprint + (caps_signature(plan, caps),)
                 entry = self.plan_cache.get(key)
             if entry is None:
@@ -529,9 +529,7 @@ class Executor:
                     scan_out={k: max(v, caps.scan_out.get(k, 0))
                               for k, v in fresh.scan_out.items()},
                     output_repart=max(fresh.output_repart or 0,
-                                      caps.output_repart or 0) or None,
-                    agg_bucket={k: max(v, caps.agg_bucket.get(k, 0))
-                                for k, v in fresh.agg_bucket.items()})
+                                      caps.output_repart or 0) or None)
             if cap_overflow:
                 caps = caps.grown(cap_overflow)
             # overflow-regrow bounded by the accountant: a regrow whose
@@ -540,7 +538,7 @@ class Executor:
             # (stream / multi-pass) instead of burning the retries
             budget = self.accountant.budget_bytes(self.settings)
             if budget:
-                need = _plan_buffer_bytes(plan, caps) \
+                need = _plan_buffer_bytes(plan, caps, group_kernel) \
                     // max(1, plan.n_devices)
                 room = budget - self.accountant.pressure_bytes()
                 if need > room and self._plan_degradable(plan):
@@ -777,7 +775,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def count_picks(self, plan: QueryPlan, caps: Capacities,
-                    tallies: tuple[int, int, int]) -> None:
+                    tallies: tuple[int, int, int, int]) -> None:
         """groupby_bucketed_total, lookup_sorted_total,
         lookup_dense_total and broadcast_joins_total: each bumped once
         per executed STATEMENT whose converged plan ran the bucketed
@@ -791,21 +789,24 @@ class Executor:
         batch), and a dense_oob fallback onto the general paths
         (caps.dense_off) correctly counts no pick (its broadcast joins
         stay broadcast joins).  deferred_columns_total,
-        deferred_gathers_total and lookup_probe_slots_total take
-        `tallies`, the three counts the converged program's compiler
-        recorded at trace time (PlanCompiler.tallies; they ride in the
-        plan-cache entry)."""
+        deferred_gathers_total, lookup_probe_slots_total and
+        agg_bucket_slots_total take `tallies`, the four counts the
+        converged program's compiler recorded at trace time
+        (PlanCompiler.tallies; they ride in the plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
-        carried, gathered, probe_slots = tallies
+        carried, gathered, probe_slots, bucket_slots = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
         if probe_slots:
             self.counters.increment(sc.LOOKUP_PROBE_SLOTS_TOTAL,
                                     probe_slots)
+        if bucket_slots:
+            self.counters.increment(sc.AGG_BUCKET_SLOTS_TOTAL,
+                                    bucket_slots)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         nbk = sum(1 for nd in nodes
@@ -836,7 +837,7 @@ class Executor:
             self.counters.increment(sc.BROADCAST_JOINS_TOTAL, nbc)
 
     # ------------------------------------------------------------------
-    CAPS_MEMO_VERSION = 7  # bump when capacity semantics change
+    CAPS_MEMO_VERSION = 8  # bump when capacity semantics change
 
     def _memo_path(self) -> str:
         import os
@@ -971,7 +972,6 @@ class Executor:
     # agg_out capacity table but shrinking it INSTALLS a compaction
     # pass over the slot grid, so it pays the compaction economics
     TIGHTEN_THRESHOLD = {"repartition": 0.85, "agg_out": 0.85,
-                         "agg_bucket": 0.85,
                          "scan_out": 1.0 / COMPACTION_MIN_SHRINK,
                          "join_out": 1.0 / COMPACTION_MIN_SHRINK,
                          "agg_grid": 1.0 / COMPACTION_MIN_SHRINK}
@@ -988,8 +988,7 @@ class Executor:
         new = {"repartition": dict(caps.repartition),
                "join_out": dict(caps.join_out),
                "agg_out": dict(caps.agg_out),
-               "scan_out": dict(caps.scan_out),
-               "agg_bucket": dict(caps.agg_bucket)}
+               "scan_out": dict(caps.scan_out)}
         changed = False
         for (widx, kind, width), actual in zip(stage_keys, actuals):
             nid = rev.get(widx)
@@ -1005,8 +1004,7 @@ class Executor:
             return None
         return Capacities(new["repartition"], new["join_out"],
                           new["agg_out"], caps.dense_off,
-                          new["scan_out"], caps.output_repart,
-                          new["agg_bucket"])
+                          new["scan_out"], caps.output_repart)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1021,23 +1019,22 @@ class Executor:
                 {order[k]: v for k, v in caps.agg_out.items()},
                 caps.dense_off,
                 {order[k]: v for k, v in caps.scan_out.items()},
-                caps.output_repart,
-                {order[k]: v for k, v in caps.agg_bucket.items()})
+                caps.output_repart)
 
     @staticmethod
     def _caps_from_order(plan: QueryPlan, memo: tuple) -> Capacities:
         from .cache import plan_order
 
         rev = {i: nid for nid, i in plan_order(plan).items()}
-        repart, join_out, agg_out, dense_off, scan_out, output_repart, \
-            agg_bucket = memo
+        repart, join_out, agg_out, dense_off, scan_out, output_repart = \
+            memo
 
         def by_node(table: dict) -> dict:
             return {rev[i]: v for i, v in table.items()}
 
         return Capacities(by_node(repart), by_node(join_out),
                           by_node(agg_out), dense_off, by_node(scan_out),
-                          output_repart, by_node(agg_bucket))
+                          output_repart)
 
     def _initial_capacities(self, plan: QueryPlan, feeds,
                             dense_off: bool = False) -> Capacities:
@@ -1045,14 +1042,12 @@ class Executor:
         repart_factor = self.settings.get("repartition_capacity_factor")
         join_factor = self.settings.get("join_output_capacity_factor")
         group_factor = self.settings.get("agg_group_capacity_factor")
-        agg_bucket_factor = self.settings.get("agg_bucket_capacity_factor")
         group_kernel = self.settings.get("group_by_kernel")
         n_dev = plan.n_devices
         repart: dict[int, int] = {}
         join_out: dict[int, int] = {}
         agg_out: dict[int, int] = {}
         scan_out: dict[int, int] = {}
-        agg_bucket: dict[int, int] = {}
 
         def cap_of(node, skip_emit: bool = False) -> int:
             """skip_emit: the node's OWN output buffer is never
@@ -1166,17 +1161,11 @@ class Executor:
                     return node.dense_total  # fixed dense-grid output
                 if PlanCompiler.agg_bucket_shape(node, group_kernel,
                                                  dense_off):
-                    # bucketed dense grid: the packed input buffer is
-                    # [n_buckets, cap] at the uniform expectation ×
-                    # skew headroom (a hot bucket overflows and
-                    # regrows; feedback tightens converged sizes), and
-                    # the [bucket_total] output grid compacts to the
-                    # estimated group count where compaction pays
-                    from ..ops.groupby import group_bucket_count
-
-                    nb = group_bucket_count(node.bucket_total)
-                    agg_bucket[id(node)] = _round_cap(
-                        int(-(-in_cap // nb) * agg_bucket_factor) + 128)
+                    # bucketed dense grid: the pack is sized by in_cap
+                    # alone (ops.groupby.group_pack_shape), so it has
+                    # no capacity here; the [bucket_total] output grid
+                    # compacts to the estimated group count where
+                    # compaction pays
                     out = node.bucket_total
                     est_g = node.est_groups
                     if est_g:
@@ -1212,7 +1201,7 @@ class Executor:
             out_rp = _round_cap(
                 int(-(-root_cap // n_dev) * repart_factor) + 256)
         return Capacities(repart, join_out, agg_out, dense_off, scan_out,
-                          out_rp, agg_bucket)
+                          out_rp)
 
     # ------------------------------------------------------------------
     def _host_combine(self, plan: QueryPlan, cols, nulls, valid,
@@ -1352,7 +1341,8 @@ def feed_device_rows(feeds, n_dev: int) -> list[int] | None:
     return totals if seen else None
 
 
-def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities) -> int:
+def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities,
+                       group_kernel: str) -> int:
     """Worst single-buffer estimate for a capacity assignment: each
     join/repartition/aggregate buffer holds its node's output columns at
     the static capacity, per device.  Guards against executing plans
@@ -1368,24 +1358,29 @@ def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities) -> int:
             ncols = len(node.out_columns) if node is not None else 4
             worst = max(worst,
                         cap * factor * (ncols + 2) * 8 * plan.n_devices)
-    for nid, cap in caps.agg_bucket.items():
-        # bucketed group-by: the [n_buckets, cap] pack per value column
-        # (int64-worst, per device — a hot-bucket overflow retry
-        # regrows the PER-BUCKET cap, so this is the buffer that can
-        # explode under skew and must be visible to the guard) AND the
-        # [bucket_total]-slot result grid (results + companions + key
-        # reconstruction), which at the 2^24 slot cap is the largest
-        # buffer this path allocates when no agg_out compaction applies
-        node = nodes.get(nid)
-        total = getattr(node, "bucket_total", 0) if node is not None else 0
-        if total:
-            from ..ops.groupby import group_bucket_count
+    from ..ops.groupby import group_bucket_count, group_pack_shape
 
-            nb = group_bucket_count(total)
-            ncols = len(node.out_columns) if node is not None else 4
-            worst = max(worst,
-                        cap * nb * (ncols + 2) * 8 * plan.n_devices,
-                        total * (ncols + 2) * 8 * plan.n_devices)
+    for node in nodes.values():
+        if not (isinstance(node, AggregateNode)
+                and PlanCompiler.agg_bucket_shape(node, group_kernel,
+                                                  caps.dense_off)):
+            continue
+        # bucketed group-by: the chunked pack per value column
+        # (int64-worst, per device) — its input's slots and one chunk a
+        # tile more, whatever the key's distribution (a bare scan's
+        # feed is no buffer of this estimate, as everywhere here) — AND
+        # the [bucket_total]-slot result grid (results + companions +
+        # key reconstruction), which at the 2^24 slot cap is the
+        # largest buffer this path allocates when no agg_out
+        # compaction applies
+        in_cap = max(table.get(id(node.input), 0) for table in
+                     (caps.join_out, caps.scan_out, caps.agg_out))
+        nc, chunk = group_pack_shape(
+            in_cap, group_bucket_count(node.bucket_total))
+        ncols = len(node.out_columns)
+        worst = max(worst,
+                    nc * chunk * (ncols + 2) * 8 * plan.n_devices,
+                    node.bucket_total * (ncols + 2) * 8 * plan.n_devices)
     return worst
 
 

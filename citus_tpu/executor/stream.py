@@ -589,7 +589,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
         (repr(e), d, nf) for e, d, nf in plan.host_order_by)
         if plan.device_topk is not None else ())
     caps = None
-    tallies = (0, 0, 0)   # the last batch's program's, as caps is
+    tallies = (0, 0, 0, 0)   # the last batch's program's, as caps is
     fingerprint = None
     fn = out_meta = None
     parts = []

@@ -10,7 +10,6 @@ from .hashing import (
     hash_token_jax,
     shard_index_for_values_jax,
     shard_index_from_token,
-    tile_buckets,
 )
 from .join import (
     dense_unique_lookup,
@@ -29,7 +28,6 @@ __all__ = [
     "group_bucket_eligible",
     "combine_hash64", "fmix32_jax",
     "hash_token_jax", "shard_index_for_values_jax", "shard_index_from_token",
-    "tile_buckets",
     "dense_unique_lookup",
     "expand_join", "expand_join_pairs", "lookup_join", "lower_bound",
     "match_counts",

@@ -77,23 +77,6 @@ def shard_index_for_values_jax(values: jnp.ndarray, shard_count: int) -> jnp.nda
     return shard_index_from_token(hash_token_jax(values), shard_count)
 
 
-def tile_buckets(slots: jnp.ndarray, tile_slots: int,
-                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Dense slot → (bucket, tile-local slot) for the tiled group-by's
-    pack (ops.groupby.bucketed_grid_aggregate).
-
-    Buckets are contiguous slot ranges — value-range partitioning, the
-    degenerate perfect hash over an already-dense slot space — so every
-    row landing in bucket b touches only tile b, and the pack
-    (ops.partition.pack_by_target) turns random slot traffic into
-    per-tile streams.  Lives beside the routing hashes because it
-    is the same partition-for-locality contract the shard tokens
-    implement cross-device, minus the mixing step (dense directory
-    slots need no avalanche; sparse keys would hash first)."""
-    bucket = slots // tile_slots
-    return bucket, slots - bucket * tile_slots
-
-
 def combine_hash64(parts: list[jnp.ndarray]) -> jnp.ndarray:
     """Mix several key columns into one uint64 (group-by composite key).
 

@@ -79,6 +79,11 @@ LOOKUP_DENSE_JOINS_TOTAL = "lookup_dense_joins_total"
 # each fused lookup, over the mesh — what every later probe, gather and
 # sort of a star join is sized by, so what the join order decides
 LOOKUP_PROBE_SLOTS_TOTAL = "lookup_probe_slots_total"
+# packed slots of the bucketed group-bys: the sum, over the executed
+# statements' converged programs, of the chunked pack's static size
+# (ops.groupby.group_pack_shape: NC x C) at each bucketed group-by,
+# over the mesh — what the pack's layout decides
+AGG_BUCKET_SLOTS_TOTAL = "agg_bucket_slots_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
@@ -177,7 +182,8 @@ ALL_COUNTERS = [
     CHUNKS_SKIPPED, QUERIES_STREAMED, GROUPBY_BUCKETED_TOTAL,
     LOOKUP_SORTED_TOTAL, LOOKUP_DENSE_TOTAL,
     LOOKUP_SORTED_JOINS_TOTAL, LOOKUP_DENSE_JOINS_TOTAL,
-    LOOKUP_PROBE_SLOTS_TOTAL, BROADCAST_JOINS_TOTAL,
+    LOOKUP_PROBE_SLOTS_TOTAL, AGG_BUCKET_SLOTS_TOTAL,
+    BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     FEED_CACHE_HIT_BYTES_TOTAL, FEED_CACHE_MISS_BYTES_TOTAL,
     SHUFFLE_BYTES_TOTAL,

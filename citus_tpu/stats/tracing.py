@@ -188,7 +188,8 @@ STAGE_NAMES: dict[str, str] = {
     "valid": "sub: decode — row-validity expand",
     "scan_out": "scan filter mask + compaction to the filtered size",
     "repartition": "shuffle: route, pack, all_to_all, flatten",
-    "pack": "sub: repartition, agg_bucket — pack_by_target's radix pack",
+    "pack": "sub: repartition — pack_by_target's radix pack; "
+            "agg_bucket — the sort by slot and the chunks cut from it",
     "exchange": "sub: repartition — the all_to_all",
     "unpack": "sub: repartition — flatten of the exchanged pack",
     "lookup_join": "lookup join: dense directory, or sort-and-scan over "

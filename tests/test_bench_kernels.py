@@ -16,15 +16,22 @@ if str(root) not in sys.path:
     sys.path.insert(0, str(root))
 
 
-def test_groupby_harness_smoke():
-    """`python bench_kernels.py groupby` at a toy shape: the table
-    prints, and the sort-vs-bucketed correctness gate holds."""
+def test_groupby_harness_smoke(tmp_path):
+    """`python bench_kernels.py groupby` at a toy shape, uniform and
+    skewed: every form of the chunked grid (each chunk size, and the
+    element-gather pack kept in the tool) agrees with the sort path,
+    and the table is written where asked."""
     import bench_kernels
 
     rows = bench_kernels.bench_groupby(
-        regimes=[(1 << 13, 512)], repeats=1, reps=2)
-    assert len(rows) == 1
-    assert rows[0][-1] is True  # sort vs bucketed parity gate
+        regimes=[(1 << 13, 3 << 12, "uniform"),
+                 (1 << 13, 3 << 12, "skewed")],
+        repeats=1, reps=2, out=str(tmp_path))
+    assert len(rows) == 10 and all(r["agrees"] for r in rows)
+    assert {r["form"] for r in rows} == {
+        "sort", "chunked_1024", "chunked_2816", "chunked_4096",
+        "chunked_4096_by_gather"}
+    assert (tmp_path / "bench_groupby.json").exists()
 
 
 def test_dense_aggregate_harness_smoke():

@@ -536,10 +536,11 @@ class TestCapsMemoRegressions:
 
 
     def test_parent_version_memo_is_ignored_not_misread(self, tmp_path):
-        """A memo persisted at CAPS_MEMO_VERSION 6 holds eight slots a
-        statement, the retired probe's table the seventh: the seven-slot
-        reader must drop the file by its version — never unpack it —
-        and the statement converges its capacities anew."""
+        """A memo persisted at CAPS_MEMO_VERSION 7 holds seven slots a
+        statement, the retired per-bucket capacities of the bucketed
+        group-by the last: the six-slot reader must drop the file by
+        its version — never unpack it — and the statement converges its
+        capacities anew."""
         data_dir = str(tmp_path / "d")
         sql = "SELECT x.a, y.a FROM t x JOIN t y ON x.b = y.b"
 
@@ -558,12 +559,12 @@ class TestCapsMemoRegressions:
         path = os.path.join(data_dir, "caps_memo.json")
         with open(path) as f:
             obj = json.load(f)
-        assert obj["version"] == 7 and len(obj["memo"]) == 1
+        assert obj["version"] == 8 and len(obj["memo"]) == 1
         # rewrite it as the parent wrote it
         slots = obj["memo"][0][1]["t"]
-        assert len(slots) == 7
-        slots.insert(6, {"d": []})
-        obj["version"] = 6
+        assert len(slots) == 6
+        slots.append({"d": []})
+        obj["version"] = 7
         with open(path, "w") as f:
             json.dump(obj, f)
         s = connect()
@@ -573,8 +574,8 @@ class TestCapsMemoRegressions:
         s.close()
         with open(path) as f:
             obj = json.load(f)
-        assert obj["version"] == 7
-        assert [len(v["t"]) for _k, v in obj["memo"]] == [7]
+        assert obj["version"] == 8
+        assert [len(v["t"]) for _k, v in obj["memo"]] == [6]
 
 
 class TestHygiene:

@@ -4,9 +4,10 @@ Oracle contract: with `group_by_kernel` forced onto the bucketed path,
 every GROUP BY shape must return exactly what the sort path returns —
 nulls form their own groups, filtered-out rows never contribute,
 all-duplicate keys collapse to one group, empty inputs yield zero
-groups.  Stale planner key ranges retry onto the sort path (dense_oob
-protocol), hot buckets overflow + regrow (count-then-emit), and the
-observability surfaces (EXPLAIN tag, groupby_bucketed_total counter,
+groups, under every distribution of the key over the buckets (the
+pack is sized by the rows: no per-bucket capacity, no retry).  Stale
+planner key ranges retry onto the sort path (dense_oob protocol), and
+the observability surfaces (EXPLAIN tag, groupby_bucketed_total counter,
 EXPLAIN ANALYZE "Caches:" line, citus_stat_activity cache columns,
 executor.agg_bucket_fill fault point) all show the path."""
 
@@ -14,7 +15,7 @@ import pytest
 
 import citus_tpu
 import citus_tpu.ops.groupby as G
-from citus_tpu.executor.feed import walk_plan
+from citus_tpu.executor.feed import build_feeds, walk_plan
 from citus_tpu.planner.plan import AggregateNode
 from citus_tpu.sql.parser import parse_one
 from citus_tpu.utils.faultinjection import InjectedFault, inject
@@ -173,13 +174,97 @@ def test_stale_key_ranges_retry_on_sort_path(sess, monkeypatch):
     assert sorted(tuple(r) for r in result.rows()) == _rows(sess, sql)
 
 
-def test_hot_bucket_overflow_regrows_and_converges(sess, monkeypatch):
-    """Extreme skew: nearly every row lands in ONE slot's bucket while
-    the initial per-bucket capacity assumes uniformity — the overflow
-    must be REPORTED and the retry must regrow to a complete answer
-    (count-then-emit; rows are never silently dropped)."""
+# key distributions over the buckets of a 5-tile slot space (TILE = 64
+# slots; keys 0..299 in slots 1..300 and the NULL key, None, in slot 0:
+# tiles 0..4), as (g, rows with that key) pairs.  Chunks of CHUNK rows.
+TILE, CHUNK, EXTENT = 64, 16, 300
+DISTRIBUTIONS = {
+    # (a) every row in one bucket, the null group with them
+    "one_bucket": ([(None, 40), (0, 500), (7, 3), (62, 61)], CHUNK),
+    # (b) every row in the LAST bucket
+    "last_bucket": ([(255, 200), (298, 1), (299, 77)], CHUNK),
+    # (c) buckets holding exactly C, C + 1, C - 1 and 0 rows (tile 3
+    # stays empty)
+    "chunk_edges": ([(3, CHUNK), (70, CHUNK + 1), (130, 5),
+                     (131, CHUNK - 6), (290, 2)], CHUNK),
+    # (d) uniform keys
+    "uniform": ([(g, 3) for g in range(EXTENT)] + [(None, 3)], CHUNK),
+    # (e) an input whose slots are no whole number of chunks (feeds are
+    # cut in 128-row classes, and 48 divides no such size below 384)
+    "ragged_input": ([(g, 1 + g % 5) for g in range(0, EXTENT, 3)], 48),
+    # the default chunk, far over the input: one chunk a bucket
+    "default_chunk": ([(g, 2) for g in range(EXTENT)] + [(None, 9)], None),
+}
+
+
+@pytest.fixture()
+def sess1(tmp_path):
+    """One device and float32 on it, so that a bucket's count is what
+    the case says and sum(f) takes the one-hot matmul."""
+    s = citus_tpu.connect(data_dir=str(tmp_path / "d1"), n_devices=1,
+                          compute_dtype="float32")
+    yield s
+    s.close()
+
+
+def _close(got, want):
+    """Row sets equal, floats to the kernel's own tolerance
+    (tests/test_ops.py: rtol 1e-4, atol 1e-4)."""
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert len(g) == len(w)
+        for a, b in zip(g, w):
+            if isinstance(b, float):
+                assert a == pytest.approx(b, rel=1e-4, abs=1e-4)
+            else:
+                assert a == b
+
+
+@pytest.mark.parametrize("where", ["", " where v > 100000"],
+                         ids=["rows", "all_invalid"])
+@pytest.mark.parametrize("case", list(DISTRIBUTIONS))
+def test_chunked_grid_matches_sort_path(sess1, monkeypatch, case, where):
+    """The chunked grid against the sort path, value for value: count,
+    int64 sum, float32 sum, min, max over a nullable key, under each
+    distribution of the key over the buckets — and (f) with every row
+    filtered out."""
+    dist, chunk = DISTRIBUTIONS[case]
+    monkeypatch.setattr(G, "GROUP_TILE_SLOTS", TILE)
+    if chunk is not None:
+        monkeypatch.setattr(G, "GROUP_CHUNK_ROWS", chunk)
+    sess1.execute("create table gq (k bigint, g bigint, v int, f float)")
+    sess1.create_distributed_table("gq", "k", shard_count=2)
+    rows, k = [], 0
+    for g, count in dist:
+        for _ in range(count):
+            v = "null" if k % 11 == 0 else k % 97 - 40
+            rows.append(f"({k},{'null' if g is None else g},{v},"
+                        f"{(k % 23) * 0.25})")
+            k += 1
+    sess1.execute("insert into gq values " + ",".join(rows))
+    sql = ("select g, count(*), count(v), sum(v), sum(f), min(v), max(v) "
+           f"from gq{where} group by g")
+    sess1.execute("set group_by_kernel = 'sort'")
+    want = _rows(sess1, sql)
+    assert bool(want) == (where == "")
+    sess1.execute("set group_by_kernel = 'bucketed'")
+    plan, _cleanup = sess1._plan_select(parse_one(sql))
+    _force_bucketed_groupby(plan, [(0, EXTENT, True)])
+    result = sess1.executor.execute_plan(plan)
+    assert result.retries == 0
+    _close(_sorted(result.rows()), want)
+
+
+def test_hot_bucket_compiles_once_and_never_retries(sess, monkeypatch):
+    """Extreme skew: nearly every row lands in ONE slot's bucket.  The
+    pack is sized by the input's slots, not by its fullest bucket, so
+    the statement compiles one program, returns at its first execution
+    and counts n_dev x (NC x C) packed slots, whatever the key does."""
+    from citus_tpu.executor.execcache import exec_cache_for
+    from citus_tpu.stats import counters as sc
+
     monkeypatch.setattr(G, "GROUP_TILE_SLOTS", 16)
-    sess.execute("set agg_bucket_capacity_factor = 1.0")
+    monkeypatch.setattr(G, "GROUP_CHUNK_ROWS", 32)
     sess.execute("set group_by_kernel = 'bucketed'")
     sess.execute("create table gh (k bigint, g bigint, v int)")
     sess.create_distributed_table("gh", "k", shard_count=4)
@@ -189,11 +274,61 @@ def test_hot_bucket_overflow_regrows_and_converges(sess, monkeypatch):
     sql = "select g, count(*) from gh group by g"
     plan, _cleanup = sess._plan_select(parse_one(sql))
     _force_bucketed_groupby(plan, [(0, 120, False)])
+    cache = exec_cache_for(sess.executor.store.data_dir)
+    compiles0 = cache.snapshot()["compiles_total"]
+    misses0 = sess.executor.plan_cache.misses
+    slots0 = sess.stats.counters.snapshot()[sc.AGG_BUCKET_SLOTS_TOTAL]
     result = sess.executor.execute_plan(plan)
-    assert result.retries >= 1  # the hot bucket overflowed and regrew
+    assert result.retries == 0
+    assert cache.snapshot()["compiles_total"] == compiles0 + 1
+    assert sess.executor.plan_cache.misses == misses0 + 1
     got = dict(tuple(r) for r in result.rows())
     assert got[7] == 3000 + 1  # skewed rows + one spread row (7 % 120)
     assert sum(got.values()) == 3120
+    # the group-by reads the scan's feed: n slots a device, 8 tiles
+    ex = sess.executor
+    (feed,) = build_feeds(plan, ex.catalog, ex.store, ex.mesh).values()
+    nc, chunk = G.group_pack_shape(feed.capacity, 8)
+    assert (nc, chunk) == (-(-feed.capacity // 32) + 8, 32)
+    slots = sess.stats.counters.snapshot()[sc.AGG_BUCKET_SLOTS_TOTAL]
+    assert slots - slots0 == 4 * nc * chunk
+
+
+def test_buffer_estimate_counts_the_chunked_pack(sess, monkeypatch):
+    """The guard's estimate (`max_plan_buffer_bytes`, the regrow
+    budget) sees the pack at its input's slots in whole chunks and a
+    chunk a tile more — and only where the bucketed grid runs."""
+    from citus_tpu.executor.compiler import Capacities
+    from citus_tpu.executor.runner import _plan_buffer_bytes
+
+    monkeypatch.setattr(G, "GROUP_TILE_SLOTS", 16)
+    monkeypatch.setattr(G, "GROUP_CHUNK_ROWS", 32)
+    sess.execute("create table gb (k bigint, g bigint, v int)")
+    sess.create_distributed_table("gb", "k", shard_count=4)
+    plan, _cleanup = sess._plan_select(parse_one(
+        "select g, count(*) from gb group by g"))
+    _force_bucketed_groupby(plan, [(0, 120, False)])  # 121 slots: 8 tiles
+    (agg,) = [n for n in walk_plan(plan.root)
+              if isinstance(n, AggregateNode)]
+    caps = Capacities({}, {}, {}, False, {id(agg.input): 1024})
+    nc, chunk = G.group_pack_shape(1024, 8)
+    assert (nc, chunk) == (1024 // 32 + 8, 32)
+    width = (len(agg.out_columns) + 2) * 8 * plan.n_devices
+    assert _plan_buffer_bytes(plan, caps, "bucketed") == nc * chunk * width
+    assert _plan_buffer_bytes(plan, caps, "sort") < 1024 * width
+
+
+def test_retired_bucket_capacity_setting_is_unknown(sess):
+    """The per-bucket capacity's setting left with it: SET refuses it
+    as it refuses any name that was never registered."""
+    from citus_tpu import config
+    from citus_tpu.errors import ConfigError
+
+    name = "agg_bucket_capacity_factor"
+    assert name not in config.registered_vars()
+    assert len(config.registered_vars()) == 64
+    with pytest.raises(ConfigError, match="unrecognized configuration"):
+        sess.execute(f"set {name} = 2.0")
 
 
 def test_planner_annotates_structural_eligibility(sess, monkeypatch):

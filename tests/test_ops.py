@@ -399,21 +399,22 @@ class TestSortedUniqueLookup:
 
 class TestBucketedGridAggregate:
     """Bucketed dense-grid aggregation (ops.groupby) vs a numpy oracle:
-    sums/counts/min/max, garbage-lane hygiene, overflow accounting and
-    realized-fill reporting.  The tile is patched small so tiny slot
-    spaces still span many buckets."""
+    sums/counts/min/max and garbage-lane hygiene.  The tile and the
+    chunk are patched small so tiny slot spaces still span many
+    buckets and tiny inputs many chunks."""
 
     TILE = 64
+    CHUNK = 32
 
-    def _run(self, monkeypatch, slot, valid, values, total, cap, **kw):
+    def _run(self, monkeypatch, slot, valid, values, total, **kw):
         import citus_tpu.ops.groupby as G
 
         monkeypatch.setattr(G, "GROUP_TILE_SLOTS", self.TILE)
-        res, rows, ov, fill = G.bucketed_grid_aggregate(
+        monkeypatch.setattr(G, "GROUP_CHUNK_ROWS", self.CHUNK)
+        res, rows = G.bucketed_grid_aggregate(
             jnp.asarray(slot.astype(np.int32)), jnp.asarray(valid),
-            values, total, cap, **kw)
-        return ([np.asarray(r) for r in res], np.asarray(rows),
-                int(ov), int(fill))
+            values, total, **kw)
+        return [np.asarray(r) for r in res], np.asarray(rows)
 
     def _inputs(self, rng, n=4000, total=500):
         slot = rng.integers(0, total, n).astype(np.int32)
@@ -435,9 +436,7 @@ class TestBucketedGridAggregate:
             (jnp.where(c, jnp.asarray(vi), imax), "min"),
             (jnp.where(c, jnp.asarray(vi), -imax - 1), "max"),
         ]
-        res, rows, ov, fill = self._run(monkeypatch, slot, valid,
-                                        values, total, cap=n)
-        assert ov == 0
+        res, rows = self._run(monkeypatch, slot, valid, values, total)
         osum = np.zeros(total)
         oisum = np.zeros(total, np.int64)
         ocnt = np.zeros(total, np.int64)
@@ -461,32 +460,25 @@ class TestBucketedGridAggregate:
         live = ocnt > 0
         np.testing.assert_array_equal(res[3][live], omin[live])
         np.testing.assert_array_equal(res[4][live], omax[live])
-        # realized skew: max bucket fill over valid rows
-        fills = np.bincount(slot[valid] // self.TILE,
-                            minlength=-(-total // self.TILE))
-        assert fill == int(fills.max())
 
-    def test_overflow_reported_not_dropped_silently(self, rng,
-                                                    monkeypatch):
-        # every row lands in bucket 0; cap 8 → the rest must be
-        # REPORTED so the host regrows per-bucket capacity and retries
-        n, total, cap = 300, 4 * 64, 8
+    def test_one_hot_bucket_needs_no_capacity(self, rng, monkeypatch):
+        # every row lands in bucket 0: the pack is sized by the rows
+        # (ceil(300 / 32) + 4 chunks), so nothing is dropped, reported
+        # or retried whatever one bucket holds
+        n, total = 300, 4 * 64
         slot = np.zeros(n, np.int32)
         valid = np.ones(n, bool)
         values = [(jnp.asarray(np.ones(n, np.int32)), "count")]
-        res, rows, ov, fill = self._run(monkeypatch, slot, valid,
-                                        values, total, cap=cap)
-        assert ov == n - cap
-        assert fill == cap  # capacity-clipped
-        assert int(rows.sum()) == cap  # survivors still counted
+        res, rows = self._run(monkeypatch, slot, valid, values, total)
+        assert rows[0] == n and int(rows.sum()) == n
+        assert res[0][0] == n and int(res[0].sum()) == n
 
     def test_all_invalid_rows(self, rng, monkeypatch):
         n, total = 64, 128
         values = [(jnp.asarray(np.ones(n, np.int32)), "count")]
-        res, rows, ov, _ = self._run(
+        res, rows = self._run(
             monkeypatch, np.zeros(n, np.int32), np.zeros(n, bool),
-            values, total, cap=16)
-        assert ov == 0
+            values, total)
         assert int(rows.sum()) == 0
         assert int(res[0].sum()) == 0
 
@@ -498,9 +490,7 @@ class TestBucketedGridAggregate:
         slot, valid, _c, _vf, vi = self._inputs(rng, 2000, 300)
         values = [(jnp.where(jnp.asarray(valid), jnp.asarray(vi), 0),
                    "sum")]  # int64 → segment path
-        res, rows, ov, _ = self._run(monkeypatch, slot, valid, values,
-                                     300, cap=2000)
-        monkeypatch.setattr(G, "GROUP_TILE_SLOTS", self.TILE)
+        res, rows = self._run(monkeypatch, slot, valid, values, 300)
         want = np.zeros(300, np.int64)
         for i in range(2000):
             if valid[i]:

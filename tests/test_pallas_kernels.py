@@ -84,9 +84,9 @@ class TestBucketedGroupbySums:
         try:
             G.GROUP_TILE_SLOTS = 64
             rx = G.bucketed_grid_aggregate(slot, valid, values, total,
-                                           n, kernel="xla")
+                                           kernel="xla")
             rp = G.bucketed_grid_aggregate(slot, valid, values, total,
-                                           n, kernel="pallas",
+                                           kernel="pallas",
                                            interpret=True)
         finally:
             G.GROUP_TILE_SLOTS = orig_tile

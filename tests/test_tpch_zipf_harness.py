@@ -3,7 +3,9 @@
 in the shape of tests/test_ssb_harness.py: the platform check and the
 data directory are overridden from here, never through an option of the
 harness, and the configuration is the cell's own but for its scale
-factor."""
+factor and for `group_by_kernel`, forced onto the bucketed grid that the
+planner picks by itself only on the chip (and the flat grid's limit is
+lowered with the scale, so that 7,501 slots are over it as 150,001 are)."""
 
 import json
 import os
@@ -21,7 +23,8 @@ SEED = 2_147_483_777  # past 32 signed bits, as the driver's are
 SCALE = 0.05
 NEW_READERS = ("subplans", "subplan_ms", "intermediate_rows",
                "dict_predicate_walks", "repartition_imbalance",
-               "window_capacity_retries", "stage_join_expand_ms")
+               "window_capacity_retries", "stage_join_expand_ms",
+               "agg_bucket_slots")
 
 
 @pytest.fixture(scope="module")
@@ -35,7 +38,11 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         def __init__(self, workload):
             super().__init__(workload)
             self.config["dataset_params"]["scale_factor"] = SCALE
+            self.config["session_settings"]["group_by_kernel"] = "bucketed"
 
+    from citus_tpu.planner.plan import DistributedPlanner
+
+    monkeypatch.setattr(DistributedPlanner, "DENSE_GROUP_LIMIT", 4096)
     monkeypatch.setattr(harness, "Cell", TinyCell)
     monkeypatch.setattr(harness, "REQUIRED_PLATFORM", "cpu")
     monkeypatch.setattr(harness, "DATA_ROOT", data_root)
@@ -71,6 +78,15 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         # Zipf(1) over 7,500 customers: the hottest holds a tenth of
         # the orders, and the bucket it lands in stands out
         assert 1.05 < got["repartition_imbalance"] < 2.0
+        # both programs group on the bucketed grid, as on the chip
+        # (7,501 slots of `c_custkey`, the hot account's ≈ 7,500 of
+        # `c_count`: 2 tiles each): a pack is its input's slots in
+        # whole chunks and a chunk a tile more, on each of four devices
+        from citus_tpu.ops.groupby import group_pack_shape
+
+        slots = got["agg_bucket_slots"]
+        chunk = group_pack_shape(0, 2)[1]
+        assert slots % (4 * chunk) == 0 and slots >= 4 * 2 * 3 * chunk
         # a device metric needs a device trace: none on the CPU
         assert set(NEW_READERS) - set(got) == {"stage_join_expand_ms"}
     else:

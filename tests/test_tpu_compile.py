@@ -32,6 +32,7 @@ from citus_tpu.ops.groupby import (
     GROUP_TILE_SLOTS,
     bucketed_grid_aggregate,
     group_bucket_count,
+    group_pack_shape,
 )
 from citus_tpu.ops.join import sorted_unique_lookup
 
@@ -41,15 +42,10 @@ ORDERS_CAP = _round_cap(1_500_000)
 ORDERKEY_EXTENT = 6_000_000          # o_orderkey = 4i + 1, i < 1.5 M
 
 
-def _group_cap(rows: int, buckets: int) -> int:
-    # runner.py: expectation × agg_bucket_capacity_factor (2.0) + 128
-    return _round_cap(int(-(-rows // buckets) * 2.0) + 128)
-
-
 # group by l_orderkey: the packed slot space is the key extent plus the
-# null slot, in 4096-slot tiles
+# null slot, in 4096-slot tiles; its pack is [NC, C] chunks, sized by
+# the rows and the tile count alone (ops.groupby.group_pack_shape)
 ORDERKEY_GROUP_BUCKETS = group_bucket_count(ORDERKEY_EXTENT + 1)
-ORDERKEY_GROUP_CAP = _group_cap(LINEITEM_CAP, ORDERKEY_GROUP_BUCKETS)
 
 @pytest.fixture(scope="module")
 def topo():
@@ -96,14 +92,12 @@ def test_dense_grid_aggregate_pallas_compiles(chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("buckets,cap", [
+@pytest.mark.parametrize("rows,buckets", [
     # group by o_custkey: 150 k groups over 1.5 M orders
-    pytest.param(group_bucket_count(150_001),
-                 _group_cap(ORDERS_CAP, group_bucket_count(150_001)),
-                 id="o_custkey"),
+    pytest.param(ORDERS_CAP, group_bucket_count(150_001), id="o_custkey"),
     # group by l_orderkey: 6 M slots over 6 M lineitem rows
     pytest.param(
-        ORDERKEY_GROUP_BUCKETS, ORDERKEY_GROUP_CAP, id="l_orderkey",
+        LINEITEM_CAP, ORDERKEY_GROUP_BUCKETS, id="l_orderkey",
         marks=pytest.mark.xfail(
             strict=True, raises=jax.errors.JaxRuntimeError,
             reason="'RESOURCE_EXHAUSTED: XLA:TPU compile permanent "
@@ -114,11 +108,12 @@ def test_dense_grid_aggregate_pallas_compiles(chip):
                    "rows on sublanes, which the chip's tiled HBM layout "
                    "pads 128-fold")),
 ])
-def test_bucketed_groupby_sums_pallas_compiles(chip, buckets, cap):
+def test_bucketed_groupby_sums_pallas_compiles(chip, rows, buckets):
+    chunks, chunk = group_pack_shape(rows, buckets)
     c = _compile(lambda loc, stack: pk.bucketed_groupby_sums_pallas(
         loc, stack, GROUP_TILE_SLOTS),
-        chip((buckets, cap), jnp.int32),
-        chip((buckets, cap, 3), jnp.float32))
+        chip((chunks, chunk), jnp.int32),
+        chip((chunks, chunk, 3), jnp.float32))
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -177,17 +172,30 @@ def test_compact_compiles(chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-def test_bucketed_grid_aggregate_xla_compiles(chip):
+def test_bucketed_grid_aggregate_xla_compiles(chip, monkeypatch):
     """The high-cardinality group-by at SF1 (group by l_orderkey over
-    6 M rows; planner/plan.py `group_bucketed`)."""
-    total, rows, cap = ORDERKEY_EXTENT + 1, LINEITEM_CAP, ORDERKEY_GROUP_CAP
+    6 M rows; planner/plan.py `group_bucketed`), on the branch the
+    chip takes: `_onehot_ok` asks `jax.default_backend()`, which is
+    the CPU here, so the test answers for the chip.  One sort carries
+    the columns, the chunks are cut by slices in a loop (no gather an
+    element), the one-hot product is a convolution, and the chunks of
+    a bucket are added by one scatter of whole rows."""
+    import citus_tpu.ops.groupby as G
+
+    monkeypatch.setattr(G, "_onehot_ok", lambda slots, tile: True)
+    total, rows = ORDERKEY_EXTENT + 1, LINEITEM_CAP
     c = _compile(
         lambda slot, valid, v, n: bucketed_grid_aggregate(
-            slot, valid, [(v, "sum"), (n, "count")], total, cap,
-            kernel="xla"),
+            slot, valid, [(v, "sum"), (n, "count")], total, kernel="xla"),
         chip((rows,), jnp.int32), chip((rows,), jnp.bool_),
         chip((rows,), jnp.float32), chip((rows,), jnp.int32))
-    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+    ops = [ln for ln in c.as_text().splitlines() if " = " in ln]
+    assert sum(" sort(" in ln for ln in ops) == 1
+    assert any(" convolution(" in ln for ln in ops)
+    chunks, chunk = group_pack_shape(rows, ORDERKEY_GROUP_BUCKETS)
+    assert not any(f"[{chunks * chunk}" in ln and " gather(" in ln
+                   for ln in ops)
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 # -- the on-device scan decode every TPU scan now takes (scanpipe.py) ---

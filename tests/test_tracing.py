@@ -645,8 +645,12 @@ class TestProfilerClock:
                  and n.func.attr == "_record" and len(n.args) >= 2
                  and isinstance(n.args[1], ast.Constant)}
         assert kinds >= {"scan_out", "repartition", "join_out",
-                         "agg_bucket", "agg_grid", "agg_out"}
+                         "agg_grid", "agg_out"}
         assert kinds <= set(STAGE_NAMES)
+        # the bucketed group-by's pack has no capacity to record since
+        # PR 36; its scope and its pack's sub-scope keep their names
+        assert "agg_bucket" not in kinds
+        assert {"agg_bucket", "pack"} <= set(STAGE_NAMES)
         with pytest.raises(KeyError):
             stage_scope("not_a_stage")
         import jax
