@@ -414,7 +414,25 @@ class Executor:
                 with trace_span("mesh.fetch"):
                     fault_point("mesh.fetch")
                     mesh_device_check("mesh.fetch", dev_ids)
-                    return jax.device_get(out)
+                    # cut where the program ends, for a statement that
+                    # is traced: what idles the device under `wait` is
+                    # the launch (and the host's wake-up), under `pull`
+                    # the copy of the two blocks.  The copies are queued
+                    # behind the program BEFORE the host waits for its
+                    # end, as device_get alone would: waiting first
+                    # starts them a host wake-up later (0.13 ms a
+                    # statement on the chip, PERF.md §6, PR 37)
+                    with trace_span("mesh.fetch.wait") as wait:
+                        if wait is not None:
+                            for arr in out:
+                                arr.copy_to_host_async()
+                            jax.block_until_ready(out)
+                    with trace_span("mesh.fetch.pull") as pull:
+                        packed, overflow = jax.device_get(out)
+                        if pull is not None:
+                            pull.meta = {"bytes": packed.nbytes
+                                         + overflow.nbytes}
+                    return packed, overflow
 
             from ..utils.faultinjection import fault_point
 
@@ -452,6 +470,12 @@ class Executor:
                 # a row a device: [overflow, dense_oob, *stage actuals,
                 # *(fullest bucket, rows sent) of each recorded exchange]
                 ov = np.asarray(overflow).reshape(plan.n_devices, -1)
+                if self.counters is not None:
+                    from ..stats import counters as sc
+
+                    self.counters.increment(
+                        sc.FETCH_BYTES_TOTAL,
+                        packed.nbytes + overflow.nbytes)
                 exchanges = ov[:, 2 + len(stage_keys):]
                 ov = ov[:, :2 + len(stage_keys)]
                 cap_overflow = int(ov[:, 0].sum())
@@ -486,8 +510,6 @@ class Executor:
                         # what actually crossed the mesh), and what its
                         # recorded exchanges sent: the fullest bucket of
                         # each over the mesh, and the rows of all
-                        from ..stats import counters as sc
-
                         self.counters.increment(sc.SHUFFLE_BYTES_TOTAL,
                                                 shuffle_bytes)
                         self.counters.increment(

@@ -2794,54 +2794,67 @@ class Session:
             name = f"{INTERMEDIATE_PREFIX}{next(self._temp_counter)}"
             names = (list(column_names) if column_names
                      else result.column_names)
+            n_rows = result.row_count
+            # listed before anything carries the name: whichever step
+            # below fails, the caller's `finally` drops what exists by then
+            cleanup.append(name)
             cols = []
             arrays = {}
             dicts = {}
-            for out_name, col_name in zip(result.column_names, names):
-                data = result.columns[out_name]
-                rdt = _result_dtype(result, out_name)
-                if rdt == DataType.DATE:
-                    # keep DATE columns as day numbers in the temp table (the
-                    # combine phase formatted them to ISO text)
-                    from .types import date_to_days
+            with trace_span("subplan.store.type", rows=n_rows,
+                            cols=len(names)):
+                for out_name, col_name in zip(result.column_names, names):
+                    data = result.columns[out_name]
+                    rdt = _result_dtype(result, out_name)
+                    if rdt == DataType.DATE:
+                        # keep DATE columns as day numbers in the temp table
+                        # (the combine phase formatted them to ISO text)
+                        from .types import date_to_days
 
-                    arr = np.array(
-                        [None if x is None else date_to_days(str(x))
-                         for x in data], dtype=object)
-                    dtype, dvals = DataType.DATE, None
-                else:
-                    dtype, arr, dvals = _infer_column(data, result.row_count)
-                cols.append(ColumnDef(col_name, dtype))
-                arrays[col_name] = arr
-                if dvals is not None:
-                    dicts[col_name] = dvals
-            self.catalog.create_reference_table(
-                name, TableSchema(tuple(cols)))
-            cleanup.append(name)
-            if result.row_count > 0:
-                # validity from the pre-intern object arrays (None = NULL)
-                validity = {c: (~_none_mask(a) if a.dtype == object
-                                else np.ones(result.row_count, dtype=bool))
-                            for c, a in arrays.items()}
-                for col_name, values in dicts.items():
-                    d = self.store.dictionary(name, col_name)
-                    arrays[col_name] = d.intern_array(values)
-                arrays = {c: _object_to_typed(a) for c, a in arrays.items()}
-                shard = self.catalog.table_shards(name)[0]
-                # intermediate results are query plumbing, not logical
-                # data changes — the change feed must not see them (and a
-                # read-only SELECT must not pay a journal fsync)
-                with self.store.change_log.suppress():
-                    self.store.append_stripe(name, shard.shard_id, arrays,
-                                             validity)
-                from .stats import counters as sc
+                        arr = np.array(
+                            [None if x is None else date_to_days(str(x))
+                             for x in data], dtype=object)
+                        dtype, dvals = DataType.DATE, None
+                    else:
+                        dtype, arr, dvals = _infer_column(data, n_rows)
+                    cols.append(ColumnDef(col_name, dtype))
+                    arrays[col_name] = arr
+                    if dvals is not None:
+                        dicts[col_name] = dvals
+                if n_rows > 0:
+                    # validity from the object arrays (None = NULL); a
+                    # string column's typed form is its codes, below
+                    validity = {c: (~_none_mask(a) if a.dtype == object
+                                    else np.ones(n_rows, dtype=bool))
+                                for c, a in arrays.items()}
+                    arrays = {c: a if c in dicts else _object_to_typed(a)
+                              for c, a in arrays.items()}
+            if n_rows > 0 and dicts:
+                with trace_span("subplan.store.intern"):
+                    for col_name, values in dicts.items():
+                        d = self.store.dictionary(name, col_name)
+                        arrays[col_name] = d.intern_array(values)
+            with trace_span("subplan.store.append") as append:
+                self.catalog.create_reference_table(
+                    name, TableSchema(tuple(cols)))
+                if n_rows > 0:
+                    shard = self.catalog.table_shards(name)[0]
+                    # intermediate results are query plumbing, not logical
+                    # data changes — the change feed must not see them (and
+                    # a read-only SELECT must not pay a journal fsync)
+                    with self.store.change_log.suppress():
+                        self.store.append_stripe(name, shard.shard_id,
+                                                 arrays, validity)
+                    n_bytes = sum(a.nbytes for a in arrays.values()) \
+                        + sum(v.nbytes for v in validity.values())
+                    if append is not None:
+                        append.meta = {"bytes": n_bytes}
+                    from .stats import counters as sc
 
-                self.stats.counters.increment(sc.INTERMEDIATE_ROWS_TOTAL,
-                                              result.row_count)
-                self.stats.counters.increment(
-                    sc.INTERMEDIATE_BYTES_TOTAL,
-                    sum(a.nbytes for a in arrays.values())
-                    + sum(v.nbytes for v in validity.values()))
+                    self.stats.counters.increment(
+                        sc.INTERMEDIATE_ROWS_TOTAL, n_rows)
+                    self.stats.counters.increment(
+                        sc.INTERMEDIATE_BYTES_TOTAL, n_bytes)
             return name
 
     # -- set operations ----------------------------------------------------
@@ -2938,14 +2951,17 @@ class Session:
                                  column_names)
 
     def _drop_temp(self, name: str):
-        try:
-            self.catalog.drop_table(name)
-        except CatalogError:
-            pass
-        self.store.drop_table_storage(name)
-        # the name never recurs, so its feed can never hit again: free
-        # the device bytes now and not when the LRU reaches them
-        self.executor.feed_cache.invalidate_table(name)
+        from .stats.tracing import trace_span
+
+        with trace_span("subplan.drop"):
+            try:
+                self.catalog.drop_table(name)
+            except CatalogError:
+                pass
+            self.store.drop_table_storage(name)
+            # the name never recurs, so its feed can never hit again: free
+            # the device bytes now and not when the LRU reaches them
+            self.executor.feed_cache.invalidate_table(name)
 
     def _save_catalog(self):
         self.catalog.save(os.path.join(self.data_dir, "catalog.json"))

@@ -23,7 +23,7 @@ class SessionStats:
         self.tenants = TenantStats()
         self.progress = ProgressRegistry()
         self.activity = ActivityRegistry()
-        self.tracing = TraceRecorder(data_dir, settings)
+        self.tracing = TraceRecorder(data_dir, settings, self.counters)
 
 
 __all__ = [

@@ -106,6 +106,19 @@ FEED_CACHE_MISS_BYTES_TOTAL = "feed_cache_miss_bytes_total"
 # ANALYZE Mesh: line and bench_multichip.py read the per-statement
 # delta to show what cross-device scaling actually costs
 SHUFFLE_BYTES_TOTAL = "shuffle_bytes_total"
+# bytes every execution pulled back from the mesh: the packed output
+# block ([2 x columns + 1, devices x capacity] 64-bit words: each
+# column, each null mask and the valid mask) and the overflow block,
+# as the host received them (executor/runner.py, beside the `settle`
+# verdict; a capacity retry's execution counts too)
+FETCH_BYTES_TOTAL = "fetch_bytes_total"
+# collections of generation 1 or 2 that ran on a thread while a
+# statement of this session was open on it, and the microseconds they
+# took (stats/tracing.py _gc_hook; a traced statement also records a
+# `gc.pause` span).  Generation 0 and collections between statements
+# are not counted: gc.get_stats() has the process's totals
+GC_PAUSES_TOTAL = "gc_pauses_total"
+GC_PAUSE_US_TOTAL = "gc_pause_us_total"
 # resilient statement execution (session retry loop / deadline seams)
 RETRIES_TOTAL = "retries_total"
 FAILOVERS_TOTAL = "failovers_total"
@@ -186,7 +199,8 @@ ALL_COUNTERS = [
     BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     FEED_CACHE_HIT_BYTES_TOTAL, FEED_CACHE_MISS_BYTES_TOTAL,
-    SHUFFLE_BYTES_TOTAL,
+    SHUFFLE_BYTES_TOTAL, FETCH_BYTES_TOTAL,
+    GC_PAUSES_TOTAL, GC_PAUSE_US_TOTAL,
     CHUNKS_PREFETCHED_TOTAL, PREFETCH_STALLS_TOTAL,
     DEVICE_DECODED_BYTES_TOTAL,
     RETRIES_TOTAL, FAILOVERS_TOTAL, TIMEOUTS_TOTAL, QUERIES_CANCELED,

@@ -48,6 +48,11 @@ the reference, grown into a flight recorder:
   builds none.  The device programs' side of the same vocabulary is
   ``STAGE_NAMES`` / :func:`stage_scope`: ``ct.<stage>`` name scopes
   in the operations' ``op_name`` metadata.
+* **The collector** — one ``gc.callbacks`` hook for the process
+  (:func:`install_gc_hook`): a collection of generation 1 or 2 on a
+  thread with a statement open moves that session's
+  ``gc_pauses_total`` / ``gc_pause_us_total`` and, on a traced
+  statement, is a ``gc.pause`` span under whatever span it stalled.
 
 Overhead: an unarmed `trace_span` is one thread-local read and a None
 check; an active span is two `perf_counter` calls, one small object
@@ -61,7 +66,11 @@ slower: its `plan` span reads 1.08 ms, 0.62 ms here).  On the chip,
 the change's p50 read 0.4 % under the parent's, inside the cell's 2 %
 spread (the off-cost is not resolvable); with the profiler on, p50
 inside the profiled stretch is 1.0 % over p50 outside it (the parent's
-own: 0.5 %).
+own: 0.5 %).  PR 37 (PERF.md §6): two spans more a statement, the
+counters and the collector hook cost nothing resolvable there (p50
+9.3528 against the parent's 9.3514 ms); what cost was waiting for the
+program's end before its copies were queued, 0.13 ms a statement
+(executor/runner.py `_dispatch` queues them first).
 """
 
 from __future__ import annotations
@@ -92,6 +101,16 @@ SPAN_NAMES: dict[str, str] = {
                "— its own plan … combine are children",
     "subplan.store": "a subplan's rows → temp reference table: typing, "
                      "dictionary interning, one stripe appended",
+    "subplan.store.type": "the result's columns typed: DATE text back "
+                          "to days, object arrays walked for their "
+                          "type, NULL masks, typed copies (meta rows, "
+                          "cols)",
+    "subplan.store.intern": "a string column's values interned in the "
+                            "temp table's dictionary",
+    "subplan.store.append": "the temp reference table created and its "
+                            "one stripe appended (meta bytes)",
+    "subplan.drop": "a temp table dropped: catalog entry, the stripe's "
+                    "files, its resident feed",
     "route": "path choice between plan and feed: plan-shape "
              "counters, manifest staleness refresh, stream eligibility",
     "feed": "device feed build (eager, pipelined or per-batch)",
@@ -108,6 +127,11 @@ SPAN_NAMES: dict[str, str] = {
                   "adopted into the plan cache pre-admission",
     "mesh.dispatch": "compiled program dispatch + on-mesh collectives",
     "mesh.fetch": "device→host pull of outputs + overflow counters",
+    "mesh.fetch.wait": "the program has been dispatched and has not "
+                       "ended: its launch, its run, the host's wake-up",
+    "mesh.fetch.pull": "the program has ended: the packed block and "
+                       "the overflow block copied to the host (meta "
+                       "bytes)",
     "settle": "after the fetch: overflow verdict, capacity feedback "
               "and memo, shuffle counter",
     "combine": "host-side combine (having/order/limit/decode)",
@@ -124,6 +148,9 @@ SPAN_NAMES: dict[str, str] = {
     "serving.batch_wait": "follower waiting on a batch leader",
     "serving.batch_probe": "leader executing one coalesced batch",
     "retry.backoff": "resilience envelope backoff sleep",
+    "gc.pause": "a collection of generation 1 or 2 that ran on the "
+                "statement's thread, under whatever span was open "
+                "(meta gen, collected)",
     "oom.degrade": "OOM ladder rung application",
     "mesh.degrade": "mesh shrink + failover after device loss",
     "replication.ship": "leader→follower batch staging (file diff + "
@@ -410,8 +437,12 @@ _stacks: dict[int, list] = {}
 def _tls_state():
     st = getattr(_tls, "state", None)
     if st is None:
-        # "stmt": the statement number while a context is adopted
-        st = _tls.state = {"trace": None, "stack": [], "stmt": None}
+        # "stmt": the statement number while a context is adopted;
+        # "counters": the StatCounters of the session whose statement
+        # is open on this thread, traced or not, and "gc": a collection
+        # in progress here (what the collector hook below reads)
+        st = _tls.state = {"trace": None, "stack": [], "stmt": None,
+                           "counters": None, "gc": None}
         tid = threading.get_ident()
         with _stacks_lock:
             live = {t.ident for t in threading.enumerate()}
@@ -474,7 +505,7 @@ def capture_context():
     st = getattr(_tls, "state", None)
     if st is None or st["trace"] is None or not st["stack"]:
         return None
-    return (st["trace"], st["stack"][-1])
+    return (st["trace"], st["stack"][-1], st["counters"])
 
 
 class _AdoptCtx:
@@ -487,12 +518,14 @@ class _AdoptCtx:
     def __enter__(self):
         if self.token is None:
             return None
-        trace, parent = self.token
+        trace, parent, counters = self.token
         st = _tls_state()
-        self.prev = (st["trace"], list(st["stack"]), st["stmt"])
+        self.prev = (st["trace"], list(st["stack"]), st["stmt"],
+                     st["counters"])
         st["trace"] = trace
         st["stack"][:] = [parent]
         st["stmt"] = trace.stmt_id
+        st["counters"] = counters
         return trace
 
     def __exit__(self, exc_type, exc, tb):
@@ -510,7 +543,8 @@ class _AdoptCtx:
                 sp.t1 = now
             _leave(sp)
             trace.leaked += 1
-        st["trace"], st["stack"][:], st["stmt"] = self.prev
+        (st["trace"], st["stack"][:], st["stmt"],
+         st["counters"]) = self.prev
         return False
 
 
@@ -520,6 +554,58 @@ def adopt_context(token):
     that was open at capture time.  Leak-proof by construction — on
     exit anything the thread left open is force-closed and counted."""
     return _AdoptCtx(token)
+
+
+# -- the collector on the statement's clock -----------------------------------
+# One `gc.callbacks` hook for the process, installed by the first
+# TraceRecorder.  A collection of generation 1 or 2 that starts on a
+# thread with a statement open adds to that session's `gc_pauses_total`
+# and `gc_pause_us_total` and, where the statement is traced, records a
+# `gc.pause` span under whatever span is open (so a profiler trace
+# shows it as `ct:gc.pause`, and benchmark/xspans.py cuts the device's
+# idle time to it).  Generation 0 returns after one comparison; a
+# collection on a thread with no statement open is not counted
+# (`gc.get_stats()` has the process's totals).
+_gc_hook_lock = threading.Lock()
+
+
+def _gc_hook(phase: str, info: dict, _pc=time.perf_counter) -> None:
+    if info["generation"] == 0:
+        return
+    st = getattr(_tls, "state", None)
+    if st is None:
+        return
+    if phase == "start":
+        if st["counters"] is None and st["trace"] is None:
+            return
+        # the span opens before the clock is read and closes after: the
+        # counter's microseconds lie inside the span's
+        st["gc"] = (trace_span("gc.pause", gen=info["generation"]), _pc())
+        return
+    began = st["gc"]
+    if began is None:
+        return
+    t1 = _pc()
+    st["gc"] = None
+    sp, t0 = began
+    if sp is not _NOOP:
+        sp.meta["collected"] = info["collected"]
+        sp.__exit__(None, None, None)
+    counters = st["counters"]
+    if counters is not None:
+        from . import counters as sc
+
+        counters.increment(sc.GC_PAUSES_TOTAL)
+        counters.increment(sc.GC_PAUSE_US_TOTAL, int((t1 - t0) * 1e6))
+
+
+def install_gc_hook() -> None:
+    """Idempotent; the hook stays for the life of the process."""
+    import gc
+
+    with _gc_hook_lock:
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
 
 
 # -- per-class latency histograms (DDSketch) --------------------------------
@@ -562,13 +648,16 @@ class _StatementHandle:
     """What begin() returns and end() consumes: the wall clock always,
     the span tree only when this statement samples in."""
 
-    __slots__ = ("sql", "t0", "trace", "nested")
+    __slots__ = ("sql", "t0", "trace", "nested", "outermost")
 
     def __init__(self, sql, t0, trace, nested=False):
         self.sql = sql
         self.t0 = t0
         self.trace = trace
         self.nested = nested
+        # this statement put its session's counters on the thread (a
+        # re-entrant execute finds them there) and takes them off
+        self.outermost = False
 
 
 class TraceRecorder:
@@ -576,9 +665,14 @@ class TraceRecorder:
     execute() callers each trace their own statement on their own
     thread; the ring/histograms fold under a lock once per statement."""
 
-    def __init__(self, data_dir: str | None = None, settings=None):
+    def __init__(self, data_dir: str | None = None, settings=None,
+                 counters=None):
         self.data_dir = data_dir
         self.settings = settings
+        # the session's StatCounters: what a collection on a
+        # statement's thread adds to (`_gc_hook`)
+        self.counters = counters
+        install_gc_hook()
         import itertools
 
         self._mu = threading.Lock()
@@ -614,8 +708,16 @@ class TraceRecorder:
 
     # -- statement lifecycle ------------------------------------------------
     def begin(self, sql: str, t0: float | None = None) -> _StatementHandle:
-        t0 = time.perf_counter() if t0 is None else t0
         st = _tls_state()
+        h = self._begin(sql, t0, st)
+        if st["counters"] is None and self.counters is not None:
+            st["counters"] = self.counters
+            h.outermost = True
+        return h
+
+    def _begin(self, sql: str, t0: float | None, st: dict,
+               ) -> _StatementHandle:
+        t0 = time.perf_counter() if t0 is None else t0
         if st["trace"] is not None:
             # re-entrant execute on one thread (internal fallback
             # paths): never corrupt the outer statement's stack, and
@@ -655,6 +757,8 @@ class TraceRecorder:
         t1 = time.perf_counter()
         wall_ms = (t1 - h.t0) * 1000.0
         trace = h.trace
+        if h.outermost:
+            _tls_state()["counters"] = None
         if trace is not None:
             st = _tls_state()
             root = trace.root

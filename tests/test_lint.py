@@ -218,6 +218,56 @@ def test_span_registry_in_sync(tree_scan):
             if f.rule == "span-registry"] == []
 
 
+# the host path's names (PR 37) and the file that records each
+HOST_PATH_SPANS = {
+    "mesh.fetch.wait": "citus_tpu/executor/runner.py",
+    "mesh.fetch.pull": "citus_tpu/executor/runner.py",
+    "subplan.store.type": "citus_tpu/session.py",
+    "subplan.store.intern": "citus_tpu/session.py",
+    "subplan.store.append": "citus_tpu/session.py",
+    "subplan.drop": "citus_tpu/session.py",
+    "gc.pause": "citus_tpu/stats/tracing.py",
+}
+
+
+@pytest.mark.parametrize("name", sorted(HOST_PATH_SPANS))
+def test_host_path_span_is_held_both_ways(tree_scan, tmp_path, name):
+    """Declared and recorded in the tree; and the rule would say so if
+    either half went: an entry without a record site is dead, a record
+    site without an entry is a ghost."""
+    from citus_tpu.stats.tracing import SPAN_NAMES
+
+    assert name in SPAN_NAMES
+    with open(os.path.join(ROOT, HOST_PATH_SPANS[name])) as f:
+        assert f'trace_span("{name}"' in f.read()
+    assert not [f for f in tree_scan[0]
+                if f.rule == "span-registry" and repr(name) in f.message]
+    pkg = tmp_path / "citus_tpu"
+    (pkg / "stats").mkdir(parents=True)
+    declared = f"SPAN_NAMES = {{{name!r}: 'x', 'statement': 'root'}}\n"
+    recorded = ("from .stats.tracing import span_name, trace_span\n"
+                "def f():\n"
+                "    span_name('statement')\n"
+                f"    with trace_span({name!r}):\n"
+                "        pass\n")
+
+    def findings():
+        return [(f.path, f.message) for f in run_lint(str(tmp_path))
+                if f.rule == "span-registry"]
+
+    (pkg / "stats" / "tracing.py").write_text(declared)
+    (pkg / "site.py").write_text(recorded)
+    assert findings() == []
+    (pkg / "site.py").write_text("def f():\n    span_name('statement')\n")
+    (dead,) = findings()
+    assert dead[0] == "citus_tpu/stats/tracing.py" and repr(name) in dead[1]
+    (pkg / "stats" / "tracing.py").write_text(
+        "SPAN_NAMES = {'statement': 'root'}\n")
+    (pkg / "site.py").write_text(recorded)
+    (ghost,) = findings()
+    assert ghost[0] == "citus_tpu/site.py" and repr(name) in ghost[1]
+
+
 def test_config_registry_in_sync_modulo_baseline(tree_scan):
     findings = [f for f in tree_scan[0] if f.rule == "config-registry"]
     baseline = load_baseline(os.path.join(ROOT, BASELINE_NAME))

@@ -84,6 +84,49 @@ def test_trace_summarize_prints_phase_breakdown(tmp_path, capsys):
     assert "slowest spans" in out
 
 
+def test_trace_summarize_prints_children_under_their_parents(tmp_path,
+                                                            capsys):
+    """The host path's children (PR 37) need no change to the tool: a
+    span prints with its tree path, so `mesh.fetch.pull` stands under
+    `mesh.fetch` and a `gc.pause` under whatever it stalled."""
+    import gc
+    import time
+
+    import trace_summarize
+    from citus_tpu.config import Settings
+    from citus_tpu.stats.tracing import TraceRecorder, trace_span
+
+    rec = TraceRecorder(str(tmp_path),
+                        Settings({"trace_slow_statement_ms": 1}))
+    h = rec.begin("select 1")
+    with trace_span("execute"):
+        with trace_span("plan"):
+            with trace_span("subplan"):
+                with trace_span("subplan.store"):
+                    with trace_span("subplan.store.type", rows=1, cols=1):
+                        gc.collect()
+                    with trace_span("subplan.store.append"):
+                        time.sleep(0.002)
+        with trace_span("mesh.fetch"):
+            with trace_span("mesh.fetch.wait"):
+                time.sleep(0.002)
+            with trace_span("mesh.fetch.pull"):
+                pass
+        with trace_span("subplan.drop"):
+            pass
+    assert rec.end(h) is not None
+    assert trace_summarize.main([str(tmp_path), "--top", "20"]) == 0
+    out = capsys.readouterr().out
+    store = "execute/plan/subplan/subplan.store"
+    for path in (f"{store}/subplan.store.type",
+                 f"{store}/subplan.store.type/gc.pause",
+                 f"{store}/subplan.store.append",
+                 "execute/mesh.fetch/mesh.fetch.wait",
+                 "execute/mesh.fetch/mesh.fetch.pull",
+                 "execute/subplan.drop"):
+        assert f"  {path}\n" in out, path
+
+
 def test_trace_summarize_errors_cleanly_without_traces(tmp_path, capsys):
     import trace_summarize
 

@@ -89,6 +89,16 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         assert slots % (4 * chunk) == 0 and slots >= 4 * 2 * 3 * chunk
         # a device metric needs a device trace: none on the CPU
         assert set(NEW_READERS) - set(got) == {"stage_join_expand_ms"}
+        # the host path's owners (PR 37): two programs' blocks pulled,
+        # the intermediate result's store and drop step by step
+        assert got["fetch_bytes"] > 2 * 8 * 7500
+        assert got["subplan_store_type_ms"] > 0
+        assert got["subplan_store_append_ms"] > 0
+        assert got["subplan_drop_ms"] > 0
+        assert got["subplan_store_type_ms"] \
+            + got["subplan_store_append_ms"] < got["subplan_ms"]
+        assert got["gc_pause_ms"] >= 0
+        assert not {"idle_fetch_wait_ms", "idle_fetch_pull_ms"} & set(got)
     else:
         assert set(got) == {"stmts_per_s", "latency_p50_ms", "setup_s"}
 
