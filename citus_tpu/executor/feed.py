@@ -19,6 +19,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..catalog import Catalog, DistributionMethod
+from ..catalog.catalog import is_intermediate
 from ..errors import ExecutionError
 from ..planner.plan import (
     AggregateNode,
@@ -220,6 +221,17 @@ def _feed_scan(node: ScanNode, catalog: Catalog, store: TableStore,
                mesh: Mesh, n_dev: int, compute_dtype,
                counters=None, accountant=None,
                category: str = "feed", stats=None) -> FeedSpec:
+    if is_intermediate(node.rel.table):
+        # a subplan's rows, which the store holds in memory as typed
+        # arrays (TableStore.hold_resident): there is no stripe to
+        # prefetch or decode, so the reference-table branch below reads
+        # them as they are
+        from ..stats.tracing import trace_span
+
+        with trace_span("subplan.feed"):
+            return _feed_eager(node, catalog, store, mesh, n_dev,
+                               compute_dtype, counters, accountant,
+                               category)
     # pipelined path first (executor/scanpipe.py): prefetch + decode on
     # a producer thread overlapped with accounted placement, optional
     # on-device decode.  None ⇒ ineligible (scan_pipeline off, tiny
@@ -233,6 +245,14 @@ def _feed_scan(node: ScanNode, catalog: Catalog, store: TableStore,
                                      category, stats)
     if pipelined is not None:
         return pipelined
+    return _feed_eager(node, catalog, store, mesh, n_dev, compute_dtype,
+                       counters, accountant, category)
+
+
+def _feed_eager(node: ScanNode, catalog: Catalog, store: TableStore,
+                mesh: Mesh, n_dev: int, compute_dtype,
+                counters=None, accountant=None,
+                category: str = "feed") -> FeedSpec:
     rel = node.rel
     meta = catalog.table(rel.table)
     colnames = [cid.split(".", 1)[1] for cid in node.columns]

@@ -32,6 +32,7 @@ import os
 import time
 from dataclasses import dataclass, field
 
+from ..catalog.catalog import is_intermediate
 from ..errors import CorruptStripe
 from ..storage import integrity
 from .shard_transfer import repair_shard_placement
@@ -59,6 +60,8 @@ def scrub_store(catalog, store, report: ScrubReport | None = None,
     """One full scrub pass over every table/shard/copy of a store."""
     rep = report or ScrubReport()
     for table in sorted(catalog.tables):
+        if is_intermediate(table):
+            continue  # another thread's live subplan rows: no files
         try:
             store.manifest(table)
         except CorruptStripe as e:

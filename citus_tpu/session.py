@@ -12,8 +12,9 @@ Recursive planning (GenerateSubplansForSubqueriesAndCTEs analogue,
 /root/reference/src/backend/distributed/planner/recursive_planning.c:223):
 CTEs, FROM-subqueries, IN/EXISTS/scalar subqueries execute first, bottom-up;
 row results materialize as temporary *reference* tables (the
-read_intermediate_result analogue — broadcast-visible to every device) or
-fold into literals, then the rewritten outer query plans normally.
+read_intermediate_result analogue — broadcast-visible to every device;
+their rows stay in memory, TableStore.hold_resident) or fold into literals,
+then the rewritten outer query plans normally.
 """
 
 from __future__ import annotations
@@ -2782,7 +2783,8 @@ class Session:
     def _store_result(self, result, cleanup: list[str],
                       column_names: tuple[str, ...] = ()) -> str:
         """ResultSet (or shim with column_names/columns/row_count/dtypes)
-        → temp reference table."""
+        → temp reference table, its rows held in memory by the store."""
+        from .stats import counters as sc
         from .stats.tracing import trace_span
 
         with trace_span("subplan.store"):
@@ -2839,22 +2841,19 @@ class Session:
                     name, TableSchema(tuple(cols)))
                 if n_rows > 0:
                     shard = self.catalog.table_shards(name)[0]
-                    # intermediate results are query plumbing, not logical
-                    # data changes — the change feed must not see them (and
-                    # a read-only SELECT must not pay a journal fsync)
-                    with self.store.change_log.suppress():
-                        self.store.append_stripe(name, shard.shard_id,
-                                                 arrays, validity)
-                    n_bytes = sum(a.nbytes for a in arrays.values()) \
-                        + sum(v.nbytes for v in validity.values())
+                    # the store holds the typed arrays until _drop_temp
+                    # and the outer statement's feed is built from them:
+                    # no stripe, no manifest file, no change-feed event
+                    record = self.store.hold_resident(
+                        name, shard.shard_id, arrays, validity)
                     if append is not None:
-                        append.meta = {"bytes": n_bytes}
-                    from .stats import counters as sc
-
+                        append.meta = {"bytes": record["bytes"]}
                     self.stats.counters.increment(
                         sc.INTERMEDIATE_ROWS_TOTAL, n_rows)
                     self.stats.counters.increment(
-                        sc.INTERMEDIATE_BYTES_TOTAL, n_bytes)
+                        sc.INTERMEDIATE_BYTES_TOTAL, record["bytes"])
+                self.stats.counters.increment(
+                    sc.INTERMEDIATE_RESIDENT_TOTAL)
             return name
 
     # -- set operations ----------------------------------------------------

@@ -27,6 +27,9 @@ SUBPLANS_EXECUTED = "subplans_executed"
 # typed columns and validity masks
 INTERMEDIATE_ROWS_TOTAL = "intermediate_rows_total"
 INTERMEDIATE_BYTES_TOTAL = "intermediate_bytes_total"
+# … and the results handed to the outer statement's feed in memory
+# (storage/table_store.py hold_resident; one of zero rows counts too)
+INTERMEDIATE_RESIDENT_TOTAL = "intermediate_resident_total"
 # string predicates (LIKE, IN, BETWEEN, <) resolved to dictionary codes
 # at bind time by walking a dictionary's values (planner/bind.py
 # _codes_where): walks that visited any value, and the values visited.
@@ -186,6 +189,7 @@ ALL_COUNTERS = [
     QUERIES_SINGLE_SHARD, QUERIES_MULTI_SHARD, QUERIES_REPARTITION,
     QUERIES_FAST_PATH, POINT_INDEX_LOOKUPS,
     SUBPLANS_EXECUTED, INTERMEDIATE_ROWS_TOTAL, INTERMEDIATE_BYTES_TOTAL,
+    INTERMEDIATE_RESIDENT_TOTAL,
     DICT_PREDICATE_WALKS_TOTAL, DICT_PREDICATE_VALUES_TOTAL,
     REPARTITION_ROWS_TOTAL, REPARTITION_HOT_BUCKET_ROWS_TOTAL,
     ROWS_INGESTED, ROWS_RETURNED,

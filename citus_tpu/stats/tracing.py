@@ -100,7 +100,8 @@ SPAN_NAMES: dict[str, str] = {
                "operation side, expression subquery) planned and executed "
                "— its own plan … combine are children",
     "subplan.store": "a subplan's rows → temp reference table: typing, "
-                     "dictionary interning, one stripe appended",
+                     "dictionary interning, the typed arrays handed to "
+                     "the store, which holds them in memory",
     "subplan.store.type": "the result's columns typed: DATE text back "
                           "to days, object arrays walked for their "
                           "type, NULL masks, typed copies (meta rows, "
@@ -108,9 +109,13 @@ SPAN_NAMES: dict[str, str] = {
     "subplan.store.intern": "a string column's values interned in the "
                             "temp table's dictionary",
     "subplan.store.append": "the temp reference table created and its "
-                            "one stripe appended (meta bytes)",
-    "subplan.drop": "a temp table dropped: catalog entry, the stripe's "
-                    "files, its resident feed",
+                            "rows held by the store with a stripe "
+                            "record's statistics (meta bytes)",
+    "subplan.feed": "under feed: an intermediate result's held arrays "
+                    "padded and placed on every device, no producer "
+                    "thread and no codec",
+    "subplan.drop": "a temp table dropped: catalog entry, the held "
+                    "rows, its resident feed",
     "route": "path choice between plan and feed: plan-shape "
              "counters, manifest staleness refresh, stream eligibility",
     "feed": "device feed build (eager, pipelined or per-batch)",

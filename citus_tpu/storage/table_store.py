@@ -15,6 +15,11 @@ Directory layout::
         MANIFEST.json
         dict_<column>.json
         shard_<shard_id>/stripe_<n>.ctps
+
+An intermediate result (`catalog.is_intermediate`: a subplan's rows, held
+for the one statement that scans them) has none of these: `hold_resident`
+keeps its typed arrays and its manifest in memory, the readers below
+answer from them, and nothing under its name on disk is ever read.
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ import threading
 import numpy as np
 
 from ..catalog import Catalog
+from ..catalog.catalog import is_intermediate
 from ..errors import CorruptStripe, StorageError
 from ..utils import io as dio
 from . import integrity
@@ -54,6 +60,10 @@ def _column_stats(columns: dict[str, np.ndarray],
     return out
 
 
+# `file` of the one stripe record of an intermediate result held in
+# memory (TableStore.hold_resident): no file of a table's directory
+RESIDENT_STRIPE = "(resident)"
+
 # Process-wide per-(data_dir, table) manifest write locks: sessions sharing
 # a data_dir each cache manifests, so every manifest read-modify-write must
 # serialize AND re-read disk state first, or one session's save can clobber
@@ -73,6 +83,9 @@ class TableStore:
         self._lock = threading.RLock()
         self._manifests: dict[str, dict] = {}
         self._dicts: dict[tuple[str, str], Dictionary] = {}
+        # intermediate results' rows, from hold_resident to
+        # drop_table_storage: table → (values, validity, rows)
+        self._resident: dict[str, tuple[dict, dict, int]] = {}
         # per-table data version: bumped on every visible mutation; the
         # executor's device-feed cache keys on it (the metadata-cache
         # invalidation analogue, metadata/metadata_cache.c:287)
@@ -133,7 +146,9 @@ class TableStore:
         with self._lock:
             if table not in self._manifests:
                 path = self._manifest_path(table)
-                if os.path.exists(path):
+                # an intermediate result's manifest lives here alone: a
+                # file under its name is a dead process's leftover
+                if not is_intermediate(table) and os.path.exists(path):
                     # identity BEFORE content: another session's commit
                     # can rename a new manifest between our read and a
                     # stat.  Stat-first pairs the cached identity with
@@ -212,8 +227,10 @@ class TableStore:
         read-committed visibility holds without invalidating warm feed
         caches on every query.  Returns True when a reload happened."""
         with self._lock:
-            if table not in self._manifests:
-                return False  # next read loads from disk anyway
+            if table not in self._manifests or is_intermediate(table):
+                # next read loads from disk anyway; an intermediate
+                # result has no disk state to be stale against
+                return False
             disk = self._stat_identity(self._manifest_path(table))
             if self._manifest_stats.get(table) == disk:
                 return False
@@ -231,7 +248,8 @@ class TableStore:
     def _reload_manifest_locked(self, table: str) -> dict:
         """Drop the cached manifest and re-read disk (caller holds
         self._lock AND the table write lock)."""
-        self._manifests.pop(table, None)
+        if not is_intermediate(table):
+            self._manifests.pop(table, None)
         return self.manifest(table)
 
     def data_version(self, table: str) -> int:
@@ -267,7 +285,9 @@ class TableStore:
             self._manifests.pop(table, None)
             self._dicts = {k: v for k, v in self._dicts.items() if k[0] != table}
             self.bump_data_version(table)
-            if os.path.exists(self.table_dir(table)):
+            if is_intermediate(table):
+                self._resident.pop(table, None)
+            elif os.path.exists(self.table_dir(table)):
                 shutil.rmtree(self.table_dir(table))
 
     # -- dictionaries ------------------------------------------------------
@@ -319,11 +339,15 @@ class TableStore:
             key = (table, column)
             if key not in self._dicts:
                 path = os.path.join(self.table_dir(table), f"dict_{column}.json")
-                self._dicts[key] = (Dictionary.load(path)
-                                    if os.path.exists(path) else Dictionary())
+                self._dicts[key] = (
+                    Dictionary.load(path)
+                    if not is_intermediate(table) and os.path.exists(path)
+                    else Dictionary())
             return self._dicts[key]
 
     def save_dictionaries(self, table: str) -> None:
+        if is_intermediate(table):
+            return  # its dictionaries live and die in self._dicts
         with self._lock:
             os.makedirs(self.table_dir(table), exist_ok=True)
             for (t, col), d in self._dicts.items():
@@ -374,6 +398,32 @@ class TableStore:
                   "stats": _column_stats(columns, validity)}
         if commit:
             self.commit_pending(table, [(shard_id, record)])
+        return record
+
+    def hold_resident(self, table: str, shard_id: int,
+                      columns: dict[str, np.ndarray],
+                      validity: dict[str, np.ndarray]) -> dict:
+        """Keep an intermediate result's rows in memory as its shard's one
+        stripe, until `drop_table_storage`: what `append_stripe` is to a
+        user table, without the file, the manifest file, the dictionary
+        files and the change feed.  The record carries a stripe record's
+        rows, bytes and statistics, so row counts, planning statistics
+        and `read_shard` answer as they would over the stripe.  Returns
+        the record."""
+        if not is_intermediate(table):
+            raise StorageError(
+                f"table {table}: only an intermediate result is held "
+                "in memory")
+        n_rows = len(next(iter(columns.values()))) if columns else 0
+        record = {"file": RESIDENT_STRIPE, "rows": n_rows,
+                  "bytes": sum(a.nbytes for a in columns.values())
+                  + sum(v.nbytes for v in validity.values()),
+                  "stats": _column_stats(columns, validity)}
+        with self._lock:
+            self._resident[table] = (columns, validity, n_rows)
+            self._manifests[table] = {"next_stripe": 2,
+                                      "shards": {str(shard_id): [record]}}
+            self.bump_data_version(table)
         return record
 
     # -- placement copies (replication-factor ≥ 2 physical replicas) -------
@@ -819,6 +869,10 @@ class TableStore:
         this one stripe at a time instead of materializing the shard)."""
         meta = self.catalog.table(table)
         columns = columns or meta.schema.names
+        held = self._resident.get(table)
+        if held is not None:
+            yield self._resident_columns(held, columns)
+            return
         # translate renamed columns to their on-disk names for the
         # stripe readers, but key all outputs by the REQUESTED names
         storage_of = {c: self.storage_column_name(table, c)
@@ -866,6 +920,14 @@ class TableStore:
                 n = int(keep.sum())
             yield v, m, n
 
+    @staticmethod
+    def _resident_columns(held, columns: list[str]):
+        """(values, validity, rows) of a held intermediate result,
+        projected: the arrays themselves, which no reader writes to."""
+        values, validity, n_rows = held
+        return ({c: values[c] for c in columns},
+                {c: validity[c] for c in columns}, n_rows)
+
     def read_shard(self, table: str, shard_id: int,
                    columns: list[str] | None = None, chunk_filter=None,
                    ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray], int]:
@@ -892,6 +954,9 @@ class TableStore:
                     ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray], int]:
         meta = self.catalog.table(table)
         columns = columns or meta.schema.names
+        held = self._resident.get(table)
+        if held is not None:
+            return self._resident_columns(held, columns)
         vals: dict[str, list[np.ndarray]] = {c: [] for c in columns}
         mask: dict[str, list[np.ndarray]] = {c: [] for c in columns}
         total = 0

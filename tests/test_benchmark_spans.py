@@ -46,8 +46,8 @@ def test_new_metrics_are_entries_of_the_benchmark():
 
 
 # ---------------------------------------------------------------------------
-# the seven readers of PR 37: silent without their source, the value on
-# a run built by hand
+# the seven readers of PR 37 and the two of PR 38: silent without their
+# source, the value on a run built by hand
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ALL_CELLS = ["tpch1.q1", "tpch1.q3", "tpch4.q3", "ssb1.q4_1",
@@ -71,7 +71,14 @@ HOST_PATH_READERS = {
         ["tpch4z.q13"], 0.5),
     "gc_pause_ms": ("ms/stmt", "program_counter",
                     "session front end and planner", ALL_CELLS, 0.25),
+    "resident_intermediates": (
+        "count/stmt", "program_counter",
+        "recursive planning and intermediate results", ["tpch4z.q13"], 0.75),
+    "subplan_feed_ms": (
+        "ms", "program_span", "recursive planning and intermediate results",
+        ["tpch4z.q13"], 2.5),
 }
+BETTER_HIGHER = {"resident_intermediates"}
 
 
 def _built_run():
@@ -85,10 +92,12 @@ def _built_run():
     records = [
         {"t1": 0.0, "spans": {**fetch, "subplan.store.type": 3.0,
                               "subplan.store.append": 2.0,
+                              "subplan.feed": 2.0,
                               "subplan.drop": 0.25}},
         {"t1": 0.0, "spans": {**fetch, "subplan.store.type": 5.0,
                               "subplan.store.append": 4.0,
                               "subplan.store.intern": 1.0,
+                              "subplan.feed": 3.0,
                               "subplan.drop": 0.75}},
         {"t1": 0.0, "spans": dict(fetch)},
         {"t1": 0.0, "spans": None},
@@ -96,7 +105,9 @@ def _built_run():
     run = SimpleNamespace(
         window={"counters": {"fetch_bytes_total": 8192,
                              "gc_pause_us_total": 1000,
-                             "gc_pauses_total": 2}, "profile": None},
+                             "gc_pauses_total": 2,
+                             "intermediate_resident_total": 3},
+                "profile": None},
         records=records, trace_dir="/nonexistent",
         cell=SimpleNamespace(config={"n_devices": 4}))
     # what xspans.of_run keeps on the run once it has reduced a trace
@@ -143,5 +154,7 @@ def test_host_path_reader_reads_a_hand_built_run(name):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entries = {m["name"]: m for m in json.load(f)["per_layer"]}
     assert entries[name] == {
-        "name": name, "unit": unit, "better": "lower", "source": source,
+        "name": name, "unit": unit,
+        "better": "higher" if name in BETTER_HIGHER else "lower",
+        "source": source,
         "layer": layer, "moves": "latency_p50_ms", "workloads": cells}

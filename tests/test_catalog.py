@@ -281,14 +281,15 @@ class TestMaybeReloadPreservesTemps:
         s1.execute("SELECT create_distributed_table('t', 'id', 2)")
         s1.execute("INSERT INTO t VALUES (1, 10), (2, 20), (3, 30)")
         # hook the store so the reload fires while the temp is live:
-        # after the CTE materializes (reference-table append), another
-        # session commits DDL and s1's catalog reloads mid-statement
-        orig_append = s1.store.append_stripe
+        # after the CTE materializes (its rows handed to the store),
+        # another session commits DDL and s1's catalog reloads
+        # mid-statement
+        orig_hold = s1.store.hold_resident
         fired = {"n": 0}
 
-        def append_hook(table, *a, **kw):
-            rec = orig_append(table, *a, **kw)
-            if table.startswith("__intermediate_") and not fired["n"]:
+        def hold_hook(table, *a, **kw):
+            rec = orig_hold(table, *a, **kw)
+            if not fired["n"]:
                 fired["n"] += 1
                 s2.execute("CREATE TABLE other (x INT)")
                 import os
@@ -297,13 +298,13 @@ class TestMaybeReloadPreservesTemps:
                     os.path.join(data_dir, "catalog.json"))
             return rec
 
-        s1.store.append_stripe = append_hook
+        s1.store.hold_resident = hold_hook
         try:
             r = s1.execute(
                 "WITH c AS (SELECT id, v FROM t WHERE v >= 20) "
                 "SELECT count(*), sum(v) FROM c")
         finally:
-            s1.store.append_stripe = orig_append
+            del s1.store.hold_resident
         assert fired["n"] == 1
         assert [tuple(int(x) for x in row) for row in r.rows()] == \
             [(2, 50)]

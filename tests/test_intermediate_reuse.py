@@ -102,6 +102,10 @@ def test_intermediates_are_counted(sess, data):
     # c_custkey and c_count as int64, a validity byte each
     assert c1["intermediate_bytes_total"] - c0["intermediate_bytes_total"] \
         == n_customers * (8 + 8 + 1 + 1)
+    # the one result, handed over in memory: as many as subplans ran
+    assert c1["intermediate_resident_total"] \
+        - c0["intermediate_resident_total"] == 1 \
+        == c1["subplans_executed"] - c0["subplans_executed"]
     assert c1["dict_predicate_walks_total"] == c0["dict_predicate_walks_total"]
     if sess.n_devices > 1:
         rows = c1["repartition_rows_total"] - c0["repartition_rows_total"]

@@ -226,6 +226,7 @@ HOST_PATH_SPANS = {
     "subplan.store.intern": "citus_tpu/session.py",
     "subplan.store.append": "citus_tpu/session.py",
     "subplan.drop": "citus_tpu/session.py",
+    "subplan.feed": "citus_tpu/executor/feed.py",
     "gc.pause": "citus_tpu/stats/tracing.py",
 }
 

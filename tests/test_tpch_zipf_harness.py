@@ -98,6 +98,9 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         assert got["subplan_store_type_ms"] \
             + got["subplan_store_append_ms"] < got["subplan_ms"]
         assert got["gc_pause_ms"] >= 0
+        # … and since PR 38 the result goes to the outer feed in memory
+        assert got["resident_intermediates"] == 1 == got["subplans"]
+        assert got["subplan_feed_ms"] > 0
         assert not {"idle_fetch_wait_ms", "idle_fetch_pull_ms"} & set(got)
     else:
         assert set(got) == {"stmts_per_s", "latency_p50_ms", "setup_s"}

@@ -604,6 +604,36 @@ class TestHostPathOwners:
         assert len(_find(root, "subplan.drop")) == 1
         sess.close()
 
+    def test_the_outer_feed_is_built_from_the_held_arrays(self, tmp_path,
+                                                          no_gc):
+        """`subplan.feed` stands under the outer statement's `feed` (so
+        `idle_dispatch_ms` keeps counting it) and is its one child: no
+        `scan.*` span of the pipeline, which the inner statement's feed
+        of the user table still runs at this size."""
+        n = 6000  # over scanpipe.AUTO_MIN_ROWS, as a table and as a result
+        sess = _mk(str(tmp_path / "d"))
+        _bulk_kv(sess, n)
+        c0 = sess.stats.counters.snapshot()
+        r = sess.execute(
+            "SELECT count(*), sum(t.c) FROM "
+            "(SELECT id, count(*) AS c FROM kv GROUP BY id) t")
+        assert r.rows() == [(n, n)]
+        c1 = sess.stats.counters.snapshot()
+        assert c1["intermediate_resident_total"] \
+            - c0["intermediate_resident_total"] == 1
+        root = sess.stats.tracing.last_trace()["root"]
+        (execute,) = [c for c in root["children"] if c["name"] == "execute"]
+        (outer,) = [c for c in execute["children"] if c["name"] == "feed"]
+        assert [k["name"] for k in outer["children"]] == ["subplan.feed"]
+        assert _find(root, "subplan.feed") == outer["children"]
+        (subplan,) = _find(root, "subplan")
+        (inner,) = [c for c in subplan["children"] if c["name"] == "feed"]
+        assert any(k["name"].startswith("scan.")
+                   for k in inner.get("children", ()))
+        assert os.listdir(os.path.join(sess.data_dir, "tables")) == ["kv"]
+        assert open_span_count() == 0
+        sess.close()
+
     def test_every_execution_waits_then_pulls_and_counts_its_bytes(
             self, tmp_path, monkeypatch, no_gc):
         import jax
