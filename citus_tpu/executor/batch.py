@@ -32,11 +32,19 @@ class DeferredTally:
     crossed a `take` without a gather, a null mask counting as one) and
     what it gathered for them afterwards (`gathers`: values, null masks
     and index compositions).  Their difference is what deferring
-    saved, by the program's own count."""
+    saved, by the program's own count.  `slots`: the gathers' index
+    sizes summed, what they cost; `topdown`: chains of three or more
+    indexes composed from the top; `recomposed`: the times a middle
+    index such a chain gathered through was gathered through again, by
+    another chain or composed at its own size by a later read of its
+    own group."""
 
     def __init__(self):
         self.columns = 0
         self.gathers = 0
+        self.slots = 0
+        self.topdown = 0
+        self.recomposed = 0
 
 
 # the tally of the trace running on this thread (tracing runs on the
@@ -56,17 +64,17 @@ def deferred_tally():
         _tracing.tally = outer
 
 
-def _tally(columns: int = 0, gathers: int = 0) -> None:
+def _tally(**counts: int) -> None:
     tally = getattr(_tracing, "tally", None)
     if tally is not None:
-        tally.columns += columns
-        tally.gathers += gathers
+        for name, n in counts.items():
+            setattr(tally, name, getattr(tally, name) + n)
 
 
 def _gather(src, idx):
     """`src[idx]`, counted, under the `deferred` sub-scope of whichever
     stage reads."""
-    _tally(gathers=1)
+    _tally(gathers=1, slots=int(idx.shape[0]))
     with stage_scope("deferred"):
         return src[idx]
 
@@ -75,17 +83,33 @@ class _RowIndex:
     """The row index a group of deferred columns shares: `idx` alone
     for columns that were arrays when the `take` was made, `below[idx]`
     for a group that was itself still deferred — composed on first use,
-    once for the group, at the size of `idx`."""
+    once for the group.  A chain is composed from the top down: `idx`
+    is gathered through each index below that is still uncomposed, then
+    through the first composed one, so every gather runs at the size of
+    `idx`, the narrowest of the chain (`Block.take` composes only on a
+    take that narrows or keeps the size), never at a middle one's."""
 
-    __slots__ = ("_idx", "_below")
+    __slots__ = ("_idx", "_below", "_passed")
 
     def __init__(self, idx, below: "_RowIndex | None" = None):
         self._idx = idx
         self._below = below
+        self._passed = False   # a top-down walk gathered through it
 
     def get(self):
         if self._below is not None:
-            self._idx = _gather(self._below.get(), self._idx)
+            if self._passed:
+                _tally(recomposed=1)
+            idx, below = self._idx, self._below
+            if below._below is not None:
+                _tally(topdown=1)
+            while below._below is not None:
+                if below._passed:
+                    _tally(recomposed=1)
+                below._passed = True
+                idx = _gather(below._idx, idx)
+                below = below._below
+            self._idx = _gather(below._idx, idx)
             self._below = None
         return self._idx
 
@@ -234,17 +258,20 @@ class Block:
         nothing gathered: every column and null mask is deferred on
         `idx` and gathered where it is first read, once.  Columns
         deferred by one `take` are a group.  A group still unread at the
-        next `take` has its index composed with the new one (one
-        gather at the new size for the whole group, made when the first
-        of them is read); a column that was read in between is deferred
-        on its gathered array with the new index alone.  Only a `take`
-        that narrows or keeps the size composes (a compaction's, a
+        next `take` has its index chained to the new one; the chain is
+        composed when the first of the group is read, from the top down
+        (`_RowIndex.get`): one gather at the newest index's size for
+        each index below it, for the whole group, and none at a middle
+        index's larger size.  A column that was read in between is
+        deferred on its gathered array with the new index alone.  Only a
+        `take` that narrows or keeps the size chains (a compaction's, a
         top-k's); one that widens (a lookup's index over a smaller
         build side) would compose at the larger size, so a column
         still unread there is read at its own size first, as a gather
-        at every step would.  So no column ever costs more gathered
-        elements than a gather at every step, and one that is read
-        right after a `take` costs that one gather."""
+        at every step would, and the lookup's index starts a chain.  So
+        no column ever costs more gathered elements than a gather at
+        every step, and one that is read right after a `take` costs that
+        one gather."""
         _tally(columns=len(self.columns) + len(self.nulls))
         fresh = _RowIndex(idx)
         composed = {} if idx.shape[0] <= self.capacity else None

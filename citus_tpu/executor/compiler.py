@@ -373,7 +373,9 @@ class PlanCompiler:
             # as PlanCompiler.tallies after build
             self._tallies = (tally.columns, tally.gathers,
                              self._lookup_probe_slots,
-                             self._agg_bucket_slots, self._window_rows)
+                             self._agg_bucket_slots, self._window_rows,
+                             tally.slots * self.n_dev, tally.topdown,
+                             tally.recomposed)
             return packed
 
         def traced(flat_feeds):
@@ -471,10 +473,14 @@ class PlanCompiler:
         # (columns this program carries as a row index across a
         # compaction or a lookup, gathers it issues for them later,
         # probe slots of its fused lookups, packed slots of its
-        # bucketed group-bys, slots of its window operators):
-        # deferred_columns_total / deferred_gathers_total /
-        # lookup_probe_slots_total / agg_bucket_slots_total /
-        # window_rows_total
+        # bucketed group-bys, slots of its window operators, the index
+        # slots of those later gathers over the mesh, the index chains
+        # it composed from the top and the middle indexes gathered
+        # through twice): deferred_columns_total /
+        # deferred_gathers_total / lookup_probe_slots_total /
+        # agg_bucket_slots_total / window_rows_total /
+        # deferred_gather_slots_total / deferred_topdown_chains_total /
+        # deferred_topdown_recomposed_total
         self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []

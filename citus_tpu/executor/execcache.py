@@ -56,7 +56,9 @@ from ..errors import StorageError
 # fullest bucket and rows sent after its stage actuals
 # 5 (PR 36): a fourth tally, the packed slots of its bucketed group-bys
 # 6 (PR 40): a fifth, the slots of its window operators
-EXEC_CACHE_VERSION = 6
+# 7: three more, the index slots of its deferred gathers and its index
+# chains composed from the top, with their middles gathered twice
+EXEC_CACHE_VERSION = 7
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)
@@ -446,7 +448,7 @@ class ExecutableCache:
     # -- store ---------------------------------------------------------------
     def store(self, key, mesh, compiled, out_meta, stage_keys,
               shuffle_bytes: int,
-              tallies: tuple[int, ...] = (0, 0, 0, 0, 0)) -> bool:
+              tallies: tuple[int, ...] = (0,) * 8) -> bool:
         """Persist one compiled entry.  Best-effort for REAL IO errors
         (the in-memory entry still answers the statement; persistence
         is a warm-start optimization, like the caps memo) — but the

@@ -813,18 +813,29 @@ class Executor:
         stay broadcast joins); window_over_group_total counts the
         window operators over an aggregate's groups.
         deferred_columns_total, deferred_gathers_total,
-        lookup_probe_slots_total, agg_bucket_slots_total and
-        window_rows_total take `tallies`, the five counts the converged
-        program's compiler recorded at trace time (PlanCompiler.tallies;
-        they ride in the plan-cache entry)."""
+        lookup_probe_slots_total, agg_bucket_slots_total,
+        window_rows_total, deferred_gather_slots_total,
+        deferred_topdown_chains_total and
+        deferred_topdown_recomposed_total take `tallies`, the eight
+        counts the converged program's compiler recorded at trace time
+        (PlanCompiler.tallies; they ride in the plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
-        carried, gathered, probe_slots, bucket_slots, window_rows = tallies
+        (carried, gathered, probe_slots, bucket_slots, window_rows,
+         gather_slots, topdown, recomposed) = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
+            self.counters.increment(sc.DEFERRED_GATHER_SLOTS_TOTAL,
+                                    gather_slots)
+        if topdown:
+            self.counters.increment(sc.DEFERRED_TOPDOWN_CHAINS_TOTAL,
+                                    topdown)
+        if recomposed:
+            self.counters.increment(sc.DEFERRED_TOPDOWN_RECOMPOSED_TOTAL,
+                                    recomposed)
         if probe_slots:
             self.counters.increment(sc.LOOKUP_PROBE_SLOTS_TOTAL,
                                     probe_slots)

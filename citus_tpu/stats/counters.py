@@ -104,6 +104,17 @@ DEFERRED_COLUMNS_TOTAL = "deferred_columns_total"
 # … and the gathers those programs issued for them later: values, null
 # masks and index compositions.  The difference is what deferring saved
 DEFERRED_GATHERS_TOTAL = "deferred_gathers_total"
+# … the sum of those gathers' index sizes over the mesh: the slots they
+# read, what the order of an index chain's composition decides …
+DEFERRED_GATHER_SLOTS_TOTAL = "deferred_gather_slots_total"
+# … the chains of three or more row indexes composed from the top
+# (executor/batch.py _RowIndex.get: each gather at the newest, narrowest
+# index's size) …
+DEFERRED_TOPDOWN_CHAINS_TOTAL = "deferred_topdown_chains_total"
+# … and the times a middle index such a chain gathered through was
+# gathered through again: by another chain, or composed at its own size
+# by a later read of its own group.  What composing from the top cost
+DEFERRED_TOPDOWN_RECOMPOSED_TOTAL = "deferred_topdown_recomposed_total"
 # device bytes of the scan feeds the executed statements were served
 # from the feed cache (executor/cache.py FeedCache: resident between
 # statements) and of those they had to build — read, decode, place —
@@ -210,6 +221,8 @@ ALL_COUNTERS = [
     WINDOW_ROWS_TOTAL, WINDOW_OVER_GROUP_TOTAL,
     BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
+    DEFERRED_GATHER_SLOTS_TOTAL, DEFERRED_TOPDOWN_CHAINS_TOTAL,
+    DEFERRED_TOPDOWN_RECOMPOSED_TOTAL,
     FEED_CACHE_HIT_BYTES_TOTAL, FEED_CACHE_MISS_BYTES_TOTAL,
     SHUFFLE_BYTES_TOTAL, FETCH_BYTES_TOTAL,
     GC_PAUSES_TOTAL, GC_PAUSE_US_TOTAL,

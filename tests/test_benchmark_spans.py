@@ -77,6 +77,11 @@ HOST_PATH_READERS = {
     "subplan_feed_ms": (
         "ms", "program_span", "recursive planning and intermediate results",
         ["tpch4z.q13"], 2.5),
+    # the index slots of the deferred gathers, over the cells whose
+    # programs carry a column as a row index
+    "deferred_gather_slots": (
+        "count/stmt", "program_counter", "device program",
+        ALL_CELLS[1:] + ["tpcds10.q89"], 900.0),
 }
 BETTER_HIGHER = {"resident_intermediates"}
 
@@ -106,7 +111,8 @@ def _built_run():
         window={"counters": {"fetch_bytes_total": 8192,
                              "gc_pause_us_total": 1000,
                              "gc_pauses_total": 2,
-                             "intermediate_resident_total": 3},
+                             "intermediate_resident_total": 3,
+                             "deferred_gather_slots_total": 4 * 900},
                 "profile": None},
         records=records, trace_dir="/nonexistent",
         cell=SimpleNamespace(config={"n_devices": 4}))

@@ -201,6 +201,91 @@ def test_widening_take_does_not_compose():
         np.asarray(blk.columns["i64"])[rows])
 
 
+def _star_chain(blk: Block, wide, n1, n2):
+    """A dimension's block through a lookup's widening take, then two
+    compactions: (the block after the first compaction, after the
+    second)."""
+    mid = blk.take(wide, jnp.ones(wide.shape, bool)) \
+        .take(n1, jnp.ones(n1.shape, bool))
+    return mid, mid.take(n2, jnp.ones(n2.shape, bool))
+
+
+def _star_indexes(seed: int = 3):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(0, 50, 5000).astype(np.int32)),
+            jnp.asarray(rng.integers(0, 5000, 1200).astype(np.int32)),
+            jnp.asarray(rng.integers(0, 1200, 300).astype(np.int32)))
+
+
+def _gather_sizes(fn, *args) -> list[int]:
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return sorted(eqn.outvars[0].aval.shape[0] for eqn in jaxpr.eqns
+                  if eqn.primitive.name == "gather")
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_chain_of_three_is_composed_at_the_last_takes_width(jitted):
+    """Q89's `d_moy`: a lookup's index over the dimension (a widening
+    take, which starts a chain), then two compactions.  The chain is
+    composed from the top: the second compaction's index through the
+    first's, then through the lookup's — every gather at the last
+    take's 300 slots, none at the first compaction's 1,200; as many
+    gathers as a composition from the bottom; numpy's value."""
+    blk = _block(50, with_nulls=False)
+    wide, n1, n2 = _star_indexes()
+
+    def read(b, w, a, c):
+        return _star_chain(b, w, a, c)[1].columns["i64"]
+
+    assert _gather_sizes(read, blk, wide, n1, n2) == [300, 300, 300]
+    got = (jax.jit(read) if jitted else read)(blk, wide, n1, n2)
+    rows = np.asarray(wide)[np.asarray(n1)][np.asarray(n2)]
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(blk.columns["i64"])[rows])
+    with deferred_tally() as tally:
+        read(blk, wide, n1, n2)
+    # two index gathers and the value's, as from the bottom; 900 slots
+    # where the bottom-up order read 1,200 + 300 + 300
+    assert (tally.gathers, tally.slots) == (3, 900)
+    assert (tally.topdown, tally.recomposed) == (1, 0)
+
+
+@pytest.mark.parametrize("second", ["middle_group", "second_chain"])
+def test_middle_index_read_again_is_right_and_counted(second):
+    """The cost composing from the top leaves: the first compaction's
+    index, passed over by the top-down walk, is gathered through again —
+    by its own group read later (a composition at its own 1,200 slots)
+    or by a second chain taken from the same block.  The values are
+    numpy's all the same, and `recomposed` counts each second pass."""
+    blk = _block(50, with_nulls=False)
+    wide, n1, n2 = _star_indexes()
+    n3 = jnp.asarray(np.arange(0, 1200, 7, dtype=np.int32))
+
+    def read(b, w, a, c, d):
+        mid, top = _star_chain(b, w, a, c)
+        first = top.columns["i64"]
+        if second == "middle_group":
+            return first, mid.columns["f32"]
+        return first, mid.take(d, jnp.ones(d.shape, bool)).columns["f32"]
+
+    with deferred_tally() as tally:
+        first, again = read(blk, wide, n1, n2, n3)
+    rows = np.asarray(wide)[np.asarray(n1)]
+    np.testing.assert_array_equal(
+        np.asarray(first), np.asarray(blk.columns["i64"])[rows[np.asarray(n2)]])
+    rows = rows if second == "middle_group" else rows[np.asarray(n3)]
+    np.testing.assert_array_equal(np.asarray(again),
+                                  np.asarray(blk.columns["f32"])[rows])
+    assert (tally.topdown, tally.recomposed) == (
+        (1, 1) if second == "middle_group" else (2, 1))
+    sizes = _gather_sizes(lambda *a: read(*a), blk, wide, n1, n2, n3)
+    if second == "middle_group":
+        # the top's three at 300, then the middle's index and value
+        assert sizes == [300, 300, 300, 1200, 1200]
+    else:
+        assert sizes == [172] * 3 + [300] * 3
+
+
 def test_shared_cell_is_gathered_once_and_the_scope_names_it():
     """`with_filter` and `joined` share the cells: whichever block
     reads first, the gather is made once; it runs under `ct.deferred`

@@ -224,7 +224,10 @@ def test_q4_1_program_gathers_what_is_read(loaded):
     Each statement says so in the two counters.  At the second
     compaction's 18,560 slots six gathers became four when the
     unfiltered `dwdate` moved to the end of the join order (PR 34): its
-    probe and `lo_orderdate` are read at the third's 4,992."""
+    probe and `lo_orderdate` are read at the third's 4,992; and three
+    since a chain of three row indexes is composed from the top: the
+    second compaction's index is gathered through at the third's 4,992
+    slots, not composed at its own 18,560."""
     import re
 
     sess = loaded(1, SEEDS[0])
@@ -245,7 +248,7 @@ def test_q4_1_program_gathers_what_is_read(loaded):
     assert sizes.count(first_compaction) == 2    # PR 32's parent: 6
     assert max(sizes) == 300_160                 # the first probe, alone
     assert {n: sizes.count(n) for n in (300_160, 90_624, 18_560)} == {
-        300_160: 1, 90_624: 2, 18_560: 4}        # PR 34's parent: 1, 2, 6
+        300_160: 1, 90_624: 2, 18_560: 3}        # before the join order: 1, 2, 6
     # every gather of a carried column stands under the sub-scope the
     # benchmark's stage_deferred_ms reads
     assert "ct.join_out/ct.deferred" in text

@@ -5,13 +5,14 @@ as text that two commits can be compared by.
     python3 tools/hlo_dump.py <checkout> <out_dir> <n_devices>
 
 Loads TPC-H (SF 0.01, `benchmark/selftest.py`'s scale), SSB (SF 0.05,
-`tests/test_ssb.py`'s: at 0.01 Q4.1 has no row to answer with) and TPC-H
+`tests/test_ssb.py`'s: at 0.01 Q4.1 has no row to answer with), TPC-H
 with Zipf(1) customer keys (SF 0.1, the smallest decade whose 15,001
 `c_custkey` slots are past the flat grid's limit, with `group_by_kernel`
 forced onto the bucketed grid that the planner picks by itself only on
-the chip) through <checkout>'s program on <n_devices> CPU devices, answers
-Q1, Q3, SSB Q4.1 and Q13 (both of its programs) until their capacities
-have settled, and writes the optimized HLO of every plan-cache entry to
+the chip) and TPC-DS (SF 0.01, `tests/test_tpcds.py`'s) through
+<checkout>'s program on <n_devices> CPU devices, answers Q1, Q3, SSB
+Q4.1, Q13 and TPC-DS Q89 (both programs of each of the last two) until
+their capacities have settled, and writes the optimized HLO of every plan-cache entry to
 <out_dir>/<config>.<n>dev.<i>.hlo, without what is no part of JAX's
 persistent-cache key: each operation's `metadata={…}` and the module's
 source-location tables.  A change that must not move a device program
@@ -48,6 +49,8 @@ JOBS = (
     ("ssb", ("ssb-sf1-1chip", "ssb-sf1-1chip"), 0.05, ("ssb_q4_1",), {}),
     ("tpch_zipf", ("tpch-sf1-4chip-zipf1", "tpch-sf1-4chip-zipf1"), 0.1,
      ("tpch_q13",), {"group_by_kernel": "bucketed"}),
+    ("tpcds", ("tpcds-sf10-1chip", "tpcds-sf10-1chip"), 0.01,
+     ("tpcds_q89",), {}),
 )
 
 
