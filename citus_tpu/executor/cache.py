@@ -64,7 +64,7 @@ def node_fingerprint(node: PlanNode) -> str:
                 f"{node.build_side};{node.left_key_extents};"
                 f"{node.right_key_extents};{node.key_int32};"
                 f"{node.fuse_lookup};{node.lookup_sorted};"
-                f"{node.flag_combine};"
+                f"{node.flag_combine};{node.key_pack};"
                 f"{node_fingerprint(node.left)};"
                 f"{node_fingerprint(node.right)};"
                 f"{[repr(k) for k in node.left_keys]};"
@@ -85,7 +85,7 @@ def node_fingerprint(node: PlanNode) -> str:
                 f"{groups};{aggs};{node.dense_keys};{node.dense_total};"
                 f"{node.key_ranges};{node.bucket_keys};"
                 f"{node.bucket_total};{node.group_bucketed};"
-                f"{_dist_sig(node.dist)})")
+                f"{node.nulls_last};{_dist_sig(node.dist)})")
     raise TypeError(f"unknown plan node {type(node).__name__}")
 
 

@@ -200,6 +200,11 @@ def unpack_outputs(packed: np.ndarray, out_meta):
     return cols, nulls, valid
 
 
+# PlanCompiler.tallies of a program that recorded nothing: one count a
+# counter that Executor.count_picks bumps from them
+NO_TALLIES = (0,) * 11
+
+
 @dataclass
 class FeedSpec:
     """Host-side data feed for one scan: arrays indexed like the plan."""
@@ -375,7 +380,9 @@ class PlanCompiler:
                              self._lookup_probe_slots,
                              self._agg_bucket_slots, self._window_rows,
                              tally.slots * self.n_dev, tally.topdown,
-                             tally.recomposed)
+                             tally.recomposed, self._window_sorts,
+                             self._window_presorted,
+                             self._join_sort_operands)
             return packed
 
         def traced(flat_feeds):
@@ -408,6 +415,12 @@ class PlanCompiler:
                 # slots the window operators run over, over the mesh:
                 # the same rule
                 self._window_rows = 0
+                # the windows' lexsorts and the ORDER BY specs read in
+                # the groups' own order, and the operands of the
+                # multi-key joins' build-side sorts: the same rule
+                self._window_sorts = 0
+                self._window_presorted = 0
+                self._join_sort_operands = 0
                 out = self._exec(self.plan.root, blocks)
                 if self.plan.output_repart is not None:
                     # INSERT..SELECT device routing: shuffle the final
@@ -475,12 +488,15 @@ class PlanCompiler:
         # probe slots of its fused lookups, packed slots of its
         # bucketed group-bys, slots of its window operators, the index
         # slots of those later gathers over the mesh, the index chains
-        # it composed from the top and the middle indexes gathered
-        # through twice): deferred_columns_total /
+        # it composed from the top, the middle indexes gathered
+        # through twice, its windows' lexsorts, the window ORDER BY
+        # specs it read in the groups' order and the operands of its
+        # multi-key joins' build-side sorts): deferred_columns_total /
         # deferred_gathers_total / lookup_probe_slots_total /
         # agg_bucket_slots_total / window_rows_total /
         # deferred_gather_slots_total / deferred_topdown_chains_total /
-        # deferred_topdown_recomposed_total
+        # deferred_topdown_recomposed_total / window_sorts_total /
+        # window_presorted_total / join_sort_operands_total
         self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
@@ -613,20 +629,41 @@ class PlanCompiler:
         out_nulls = dict(blk.nulls)
         iota = jnp.arange(n, dtype=jnp.int32)
         for order_spec, fns in by_order.items():
-            if node.presorted:
+            # after a dense_oob retry the group keys are not packed, and
+            # a NULL order key no longer sorts last: sort
+            if node.presorted and not (order_spec and self.caps.dense_off):
+                self._window_presorted += 1
                 order, valid_s = None, blk.valid
-                part_boundary = peer_boundary = \
-                    self._window_runs(blk.valid, pkeys, iota)
+                # peers compare by (zeroed value, null flag), as in
+                # _window_sort: a NULL group's lane holds filler that
+                # may equal the value before it
+                okeys = []
+                for e, _d in order_spec:
+                    v, nm = evaluate(e, src, jnp)
+                    v = jnp.broadcast_to(v, (n,))
+                    if nm is not None:
+                        nmb = jnp.broadcast_to(nm, (n,))
+                        v = jnp.where(nmb, jnp.zeros((), v.dtype), v)
+                        okeys.append(nmb)
+                    okeys.append(v)
+                part_boundary, peer_boundary = self._window_runs(
+                    blk.valid, pkeys, okeys, iota)
+                # the rank a row's position gives: rows HAVING left out
+                # and empty grid slots sit inside a partition, so the
+                # valid rows before it
+                at = jnp.cumsum(blk.valid.astype(jnp.int32)) - 1
             else:
+                self._window_sorts += 1
                 order, valid_s, part_boundary, peer_boundary = \
                     self._window_sort(blk, src, pkeys, order_spec)
+                at = iota    # the sort put the invalid rows last
             with stage_scope("scan"):
                 # partition/peer start positions via running max over
-                # iota
+                # the rows' positions
                 part_start = jax.lax.cummax(
-                    jnp.where(part_boundary, iota, jnp.int32(0)))
+                    jnp.where(part_boundary, at, jnp.int32(0)))
                 peer_start = jax.lax.cummax(
-                    jnp.where(peer_boundary, iota, jnp.int32(0)))
+                    jnp.where(peer_boundary, at, jnp.int32(0)))
                 # position of the LAST row of each peer group (running
                 # aggregates include peers)
                 peer_end = _seg_last(peer_boundary, iota)
@@ -635,7 +672,7 @@ class PlanCompiler:
                     res_s, null_s = self._window_value(
                         w, blk, src, order, valid_s, part_boundary,
                         peer_boundary, part_start, peer_start, peer_end,
-                        iota, _segmented_scan)
+                        at, _segmented_scan)
                     if order is not None:
                         res_s = jnp.zeros(n, res_s.dtype).at[order].set(
                             res_s)
@@ -648,13 +685,15 @@ class PlanCompiler:
         return Block(out_cols, blk.valid, out_nulls)
 
     @staticmethod
-    def _window_runs(valid, pkeys, iota):
-        """Partition starts of an input whose partitions are already
-        contiguous runs (WindowNode.presorted): a valid row that differs
-        from the previous VALID row in some partition key.  Rows HAVING
-        left out, and empty grid slots, may sit inside a run; they open
-        none and contribute nothing."""
+    def _window_runs(valid, pkeys, okeys, iota):
+        """Partition and peer starts of an input whose partitions are
+        already contiguous runs, each in its ORDER BY order
+        (WindowNode.presorted): a valid row that differs from the
+        previous VALID row in some partition key, or else in some order
+        key.  Rows HAVING left out, and empty grid slots, may sit inside
+        a run; they open none and contribute nothing."""
         with stage_scope("sort"):
+            n = valid.shape[0]
             prev = jax.lax.cummax(jnp.where(valid, iota, jnp.int32(-1)))
             prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32),
                                     prev[:-1]])
@@ -662,7 +701,10 @@ class PlanCompiler:
             start = valid & (prev < 0)
             for k in pkeys:
                 start = start | (valid & (k != k[at]))
-            return start
+            peer = start
+            for k in okeys:
+                peer = peer | (valid & (k != k[at]))
+            return start, peer
 
     @staticmethod
     def _window_sort(blk: Block, src, pkeys, order_spec):
@@ -949,6 +991,24 @@ class PlanCompiler:
                 valid = valid & ~nmask  # SQL: NULL never joins
         return arrays, valid
 
+    def _pack_for(self, node: JoinNode, keys: list) -> tuple | None:
+        """The packed key ranges of a multi-key join whose every range
+        the planner knows (JoinNode.key_pack; not after a dense_oob
+        retry, nor where the packed key and a build row's position do
+        not fit one int64), or None; tallies the operands its
+        build-side sort takes (join_sort_operands_total).  Asked once
+        where the join sorts its build side."""
+        from ..ops.join import build_sort_operands, packed_sort_fits
+
+        if len(keys) < 2:
+            return None
+        pack = None if self.caps.dense_off else node.key_pack
+        if pack is not None and not packed_sort_fits(
+                pack, int(keys[0].shape[0])):
+            pack = None
+        self._join_sort_operands += build_sort_operands(len(keys), pack)
+        return pack
+
     def _dense_for(self, extents: tuple, keys: list) -> tuple | None:
         """(base, extent) for a single-key build side, or None."""
         from ..ops.join import dense_directory_ok
@@ -1162,8 +1222,9 @@ class PlanCompiler:
                 counts = jnp.where(pmatch, counts, 0)
         else:
             with stage_scope("lookup_join"):
-                order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys,
-                                                   dense)
+                order, lo, hi, dense_oob = _bounds(
+                    bkeys, bmatch, pkeys, dense,
+                    self._pack_for(node, bkeys))
                 counts = jnp.where(pmatch, hi - lo, 0)
                 m0 = bkeys[0].shape[0]
                 bidx = order[jnp.clip(lo, 0, m0 - 1)]
@@ -1235,7 +1296,8 @@ class PlanCompiler:
                 dense = self._dense_for(extents, bkeys)
                 bidx, pidx, out_valid, _miss, overflow, dense_oob = \
                     expand_join_pairs(bkeys, bmatch, pkeys, pmatch, pmatch,
-                                      out_cap, probe_outer=False, dense=dense)
+                                      out_cap, probe_outer=False, dense=dense,
+                                      pack=self._pack_for(node, bkeys))
                 self._overflow = self._overflow + overflow.astype(jnp.int64)
                 self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
                 self._record(id(node), "join_out", out_valid.sum(), out_cap)
@@ -1270,7 +1332,8 @@ class PlanCompiler:
                                     rkeys)
             n = lkeys[0].shape[0] if lkeys else lblk.valid.shape[0]
             if node.residual is None:
-                order, lo, hi, dense_oob = _bounds(rkeys, rmatch, lkeys, dense)
+                order, lo, hi, dense_oob = _bounds(
+                    rkeys, rmatch, lkeys, dense, self._pack_for(node, rkeys))
                 self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
                 matched = lmatch & (hi > lo)
             else:
@@ -1279,7 +1342,8 @@ class PlanCompiler:
                 cap = self.caps.join_out[id(node)]
                 bidx, pidx, out_valid, _miss, overflow, dense_oob = \
                     expand_join_pairs(rkeys, rmatch, lkeys, lmatch, lmatch,
-                                      cap, probe_outer=False, dense=dense)
+                                      cap, probe_outer=False, dense=dense,
+                                      pack=self._pack_for(node, rkeys))
                 self._overflow = self._overflow + overflow.astype(jnp.int64)
                 self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
                 self._record(id(node), "join_out", out_valid.sum(), cap)
@@ -1329,7 +1393,8 @@ class PlanCompiler:
             = expand_join_outer(rkeys, rblk.valid, rmatch,
                                 lkeys, lblk.valid, lmatch, out_cap,
                                 probe_outer, build_outer,
-                                replicated_build, SHARD_AXIS, dense=dense)
+                                replicated_build, SHARD_AXIS, dense=dense,
+                                pack=self._pack_for(node, rkeys))
         self._overflow = self._overflow + overflow.astype(jnp.int64)
         self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
         self._record(id(node), "join_out", pair_valid.sum(), out_cap)
@@ -1426,21 +1491,17 @@ class PlanCompiler:
     @staticmethod
     def _composite_order(node: AggregateNode, packed, valid):
         """The rows' order by packed key, invalid rows last, from ONE
-        single-operand sort of (key, row position) packed into an int64
-        — what a stable argsort gives, at a fraction of its compile
-        (PERF.md §6, PR 40).  None where the two do not fit 63 bits."""
-        n = valid.shape[0]
-        bits = max(1, (n - 1).bit_length())
+        single-operand sort of (key, row position)
+        (`ops.aggregate.sort_packed`).  None where the two do not fit
+        63 bits."""
+        from ..ops.aggregate import sort_packed
+
         top = 1
         for _base, extent, _hn in node.key_ranges:
             top *= extent + 1     # the packed slot space, invalid = top
-        if (top + 1) << bits >= 1 << 63:
-            return None
         with stage_scope("sort"):
-            key = jnp.where(valid, packed, jnp.int64(top))
-            comp = (key << bits) | jnp.arange(n, dtype=jnp.int64)
-            return (jax.lax.sort(comp, is_stable=False)
-                    & jnp.int64((1 << bits) - 1)).astype(jnp.int32)
+            by_key = sort_packed(packed, valid, top)
+        return None if by_key is None else by_key[1]
 
     def _pack_group_keys(self, node: AggregateNode, key_arrays, key_meta,
                          valid, kr=None):
@@ -1454,7 +1515,10 @@ class PlanCompiler:
         range are COUNTED (they would alias another slot) so the
         dense_oob retry recompiles with packing off.  The null slot is
         always reserved — runtime null masks may exist even when the
-        planner believed a key non-nullable."""
+        planner believed a key non-nullable — and is a key's first but
+        for the keys of `nulls_last` (a window reads the groups in
+        ascending order of those, NULLS LAST), whose values take slots
+        0 … extent - 1 and NULL the last."""
         if kr is None:
             kr = getattr(node, "key_ranges", None)
         if kr is None or self.caps.dense_off or len(kr) != len(key_meta):
@@ -1466,7 +1530,8 @@ class PlanCompiler:
         packed = jnp.zeros(n, jnp.int64)
         oob = jnp.zeros((), jnp.int64)
         ai = 0
-        for (base, extent, _hn), (cid, has_flag) in zip(kr, key_meta):
+        for i, ((base, extent, _hn), (cid, has_flag)) in enumerate(
+                zip(kr, key_meta)):
             v = key_arrays[ai].astype(jnp.int64)
             ai += 1
             nm = None
@@ -1476,11 +1541,14 @@ class PlanCompiler:
             raw = v - jnp.int64(base)
             inb = (raw >= 0) & (raw < extent)
             width = extent + 1           # slot 0 = NULL
+            null_slot, value_slot = 0, raw + 1
+            if i in node.nulls_last:     # slot extent = NULL
+                null_slot, value_slot = extent, raw
             if nm is not None:
-                slot = jnp.where(nm, 0, raw + 1)
+                slot = jnp.where(nm, null_slot, value_slot)
                 oob = oob + (valid & ~nm & ~inb).sum().astype(jnp.int64)
             else:
-                slot = raw + 1
+                slot = value_slot
                 oob = oob + (valid & ~inb).sum().astype(jnp.int64)
             packed = packed * width + jnp.clip(slot, 0, width - 1)
         # invalid rows sort last (PACK_SLOT_LIMIT headroom guarantees no
@@ -1623,7 +1691,8 @@ class PlanCompiler:
             extents = getattr(j, "left_key_extents", ())
         with stage_scope("lookup_join"):
             dense = self._dense_for(extents, bkeys)
-            _order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys, dense)
+            _order, lo, hi, dense_oob = _bounds(bkeys, bmatch, pkeys, dense,
+                                                self._pack_for(j, bkeys))
             self._dense_oob = self._dense_oob + dense_oob.astype(jnp.int64)
             counts = jnp.where(pmatch, (hi - lo).astype(jnp.int64), 0)
         return self._agg_from_match_counts(node, pblk, counts)
@@ -2067,7 +2136,8 @@ class PlanCompiler:
         total = node.bucket_total
 
         # packed slot per row — _pack_group_keys IS the slot layout
-        # (width = extent + 1 per key, slot 0 = NULL, out-of-range
+        # (width = extent + 1 per key, slot 0 = NULL or, for a key of
+        # nulls_last, slot extent, out-of-range
         # values clipped but COUNTED into dense_oob so stale statistics
         # recompile on the sort path instead of returning aliased
         # groups); sharing the helper keeps the grid bit-identical to
@@ -2138,15 +2208,19 @@ class PlanCompiler:
                      (rows_per_slot > 0).sum(), total)
 
         # reconstruct key columns from the packed slot (first key is
-        # most significant; lane 0 of each key's width is NULL)
+        # most significant; lane 0 of each key's width is NULL, the last
+        # lane for a key of nulls_last)
         iota = jnp.arange(total, dtype=jnp.int32)
         cols: dict[str, jnp.ndarray] = {}
         nulls: dict[str, jnp.ndarray] = {}
         stride = total
-        for (base, extent, _hn), (g, cid) in zip(specs, node.group_keys):
+        for i, ((base, extent, _hn), (g, cid)) in enumerate(
+                zip(specs, node.group_keys)):
             width = extent + 1
             stride //= width
             idx = (iota // stride) % width
+            if i in node.nulls_last:
+                idx = (idx + 1) % width
             cols[cid] = ((idx - 1).clip(0, extent - 1).astype(jnp.int64)
                          + base).astype(g.dtype.numpy_dtype)
             nulls[cid] = idx == 0

@@ -58,7 +58,11 @@ from ..errors import StorageError
 # 6 (PR 40): a fifth, the slots of its window operators
 # 7: three more, the index slots of its deferred gathers and its index
 # chains composed from the top, with their middles gathered twice
-EXEC_CACHE_VERSION = 7
+# 8: three more, its windows' lexsorts and ORDER BY specs read in the
+# groups' order, and the operands of its multi-key joins' sorts; a
+# window over groups with an ORDER BY or a multi-key join with known
+# key ranges compiles another program
+EXEC_CACHE_VERSION = 8
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)
@@ -448,7 +452,7 @@ class ExecutableCache:
     # -- store ---------------------------------------------------------------
     def store(self, key, mesh, compiled, out_meta, stage_keys,
               shuffle_bytes: int,
-              tallies: tuple[int, ...] = (0,) * 8) -> bool:
+              tallies: tuple[int, ...] | None = None) -> bool:
         """Persist one compiled entry.  Best-effort for REAL IO errors
         (the in-memory entry still answers the statement; persistence
         is a warm-start optimization, like the caps memo) — but the
@@ -462,6 +466,7 @@ class ExecutableCache:
             atomic_write_bytes,
             atomic_write_json_checked,
         )
+        from .compiler import NO_TALLIES
 
         fault_point("executor.exec_cache_store")
         stamp = env_stamp(mesh)
@@ -485,7 +490,7 @@ class ExecutableCache:
                              for kind, cid, dt in out_meta],
                 "stage_keys": [list(sk) for sk in stage_keys],
                 "shuffle_bytes": int(shuffle_bytes),
-                "tallies": [int(n) for n in tallies],
+                "tallies": [int(n) for n in (tallies or NO_TALLIES)],
                 "payload_crc32": zlib.crc32(data),
                 "payload_bytes": len(data),
             })

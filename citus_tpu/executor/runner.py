@@ -815,16 +815,19 @@ class Executor:
         deferred_columns_total, deferred_gathers_total,
         lookup_probe_slots_total, agg_bucket_slots_total,
         window_rows_total, deferred_gather_slots_total,
-        deferred_topdown_chains_total and
-        deferred_topdown_recomposed_total take `tallies`, the eight
-        counts the converged program's compiler recorded at trace time
-        (PlanCompiler.tallies; they ride in the plan-cache entry)."""
+        deferred_topdown_chains_total,
+        deferred_topdown_recomposed_total, window_sorts_total,
+        window_presorted_total and join_sort_operands_total take
+        `tallies`, the eleven counts the converged program's compiler
+        recorded at trace time (PlanCompiler.tallies; they ride in the
+        plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
         (carried, gathered, probe_slots, bucket_slots, window_rows,
-         gather_slots, topdown, recomposed) = tallies
+         gather_slots, topdown, recomposed, window_sorts, presorted,
+         join_operands) = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
@@ -844,10 +847,17 @@ class Executor:
                                     bucket_slots)
         if window_rows:
             self.counters.increment(sc.WINDOW_ROWS_TOTAL, window_rows)
+        if window_sorts:
+            self.counters.increment(sc.WINDOW_SORTS_TOTAL, window_sorts)
+        if presorted:
+            self.counters.increment(sc.WINDOW_PRESORTED_TOTAL, presorted)
+        if join_operands:
+            self.counters.increment(sc.JOIN_SORT_OPERANDS_TOTAL,
+                                    join_operands)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         n_over_groups = sum(1 for nd in nodes if isinstance(nd, WindowNode)
-                            and isinstance(nd.input, AggregateNode))
+                            and _over_groups(nd))
         if n_over_groups:
             self.counters.increment(sc.WINDOW_OVER_GROUP_TOTAL,
                                     n_over_groups)
@@ -1365,6 +1375,14 @@ class Executor:
         while f"{name}_{i}" in taken:
             i += 1
         return f"{name}_{i}"
+
+
+def _over_groups(node: WindowNode) -> bool:
+    """A window operator over an aggregate's groups, or stacked over
+    one that is."""
+    while isinstance(node.input, WindowNode):
+        node = node.input
+    return isinstance(node.input, AggregateNode)
 
 
 def feed_device_rows(feeds, n_dev: int) -> list[int] | None:

@@ -45,7 +45,7 @@ from ..planner.plan import (
 )
 from ..catalog import DistributionMethod
 from .cache import feeds_signature, node_fingerprint
-from .compiler import FeedSpec, _round_cap, unpack_outputs
+from .compiler import NO_TALLIES, FeedSpec, _round_cap, unpack_outputs
 from .feed import _feed_scan_cached, walk_plan
 
 
@@ -589,7 +589,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
         (repr(e), d, nf) for e, d, nf in plan.host_order_by)
         if plan.device_topk is not None else ())
     caps = None
-    tallies = (0,) * 8   # the last batch's program's, as caps is
+    tallies = NO_TALLIES   # the last batch's program's, as caps is
     fingerprint = None
     fn = out_meta = None
     parts = []

@@ -49,6 +49,24 @@ def _sort_order(keys: list[jnp.ndarray], valid: jnp.ndarray) -> jnp.ndarray:
     return jnp.lexsort(tuple(reversed(keys)) + (invalid,)).astype(jnp.int32)
 
 
+def sort_packed(key: jnp.ndarray, valid: jnp.ndarray, top: int):
+    """(sorted_key, order) of rows by a packed int64 key below `top`,
+    invalid rows (keyed `top`) last and ties in row order — what a
+    stable argsort gives — from ONE single-operand sort of (key, row
+    position) packed into an int64: a stable or multi-operand sort costs
+    the chip's compiler minutes at 10^5 rows and more (PERF.md §6).
+    None where the two do not fit 63 bits."""
+    n = valid.shape[0]
+    bits = max(1, (n - 1).bit_length())
+    if (top + 1) << bits >= 1 << 63:
+        return None
+    key = jnp.where(valid, key, jnp.int64(top))
+    comp = jax.lax.sort((key << bits) | jnp.arange(n, dtype=jnp.int64),
+                        is_stable=False)
+    return (comp >> bits,
+            (comp & jnp.int64((1 << bits) - 1)).astype(jnp.int32))
+
+
 @dataclass(frozen=True)
 class AggSpec:
     """One aggregate over one input array."""

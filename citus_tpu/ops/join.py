@@ -31,7 +31,11 @@ Probe paths, chosen at trace time:
 * **Lexicographic binary search** (general path): multi-column or
   unbounded keys fall back to an exact vectorized binary search.  The
   lower and upper bounds run in ONE fused loop whose two gather chains
-  are independent, letting the TPU overlap their memory traffic.
+  are independent, letting the TPU overlap their memory traffic.  A
+  multi-column key whose every range the planner knows is packed into
+  one int64 first (`pack_keys`), so the build side sorts as one
+  operand and the search compares one key: a stable lexsort of five
+  operands costs the chip's compiler minutes at 10^5 rows.
 
 Pair emission is sort-free: probe start offsets scatter into the output
 slot space and a `cummax` scan fills each probe's run (replacing a
@@ -48,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ..stats.tracing import stage_scope
+from .aggregate import sort_packed
 
 
 # dense-directory planning limits: the starts[] table costs O(extent)
@@ -126,6 +131,39 @@ def sort_build_side(build_keys: list[jnp.ndarray], build_valid: jnp.ndarray,
     sorted_keys = [k[order] for k in build_keys]
     n_valid = build_valid.sum().astype(jnp.int32)
     return sorted_keys, order, n_valid
+
+
+def pack_keys(keys: list[jnp.ndarray], ranges) -> tuple[jnp.ndarray,
+                                                        jnp.ndarray]:
+    """Several join keys → ONE int64 over the product of their value
+    ranges, each key packed as its value less its base, first key major
+    (the group-by's packing, `PlanCompiler._pack_group_keys`, without
+    its NULL slot: a NULL key joins nothing, so no NULL lane is read).
+    `ranges` holds (base, extent) per key.  Returns (packed, in_range):
+    a row whose key lies outside its range packs to a clipped slot that
+    may alias another key, so the caller must not match it."""
+    packed = jnp.zeros(keys[0].shape, jnp.int64)
+    inb = jnp.ones(keys[0].shape, jnp.bool_)
+    for k, (base, extent) in zip(keys, ranges):
+        raw = k.astype(jnp.int64) - jnp.int64(base)
+        inb = inb & (raw >= 0) & (raw < extent)
+        packed = packed * extent + jnp.clip(raw, 0, extent - 1)
+    return packed, inb
+
+
+def packed_sort_fits(pack, m: int) -> bool:
+    """Whether a packed key over the ranges `pack` and the position of
+    one of `m` build rows fit one int64 (`ops.aggregate.sort_packed`);
+    where they do not the join keeps its lexsort."""
+    top = math.prod(e for _b, e in pack)
+    return (top + 1) << max(1, (m - 1).bit_length()) < 1 << 63
+
+
+def build_sort_operands(n_keys: int, pack) -> int:
+    """The operands of a join's build-side sort: the keys and the
+    validity of a lexsort, or with packed ranges one (key, position)
+    composite."""
+    return n_keys + 1 if pack is None else 1
 
 
 def _search(sorted_keys: list[jnp.ndarray], n_valid: jnp.ndarray,
@@ -365,12 +403,34 @@ def sorted_unique_lookup(build_key: jnp.ndarray,
 
 
 def _bounds(build_keys, build_matchable, probe_keys,
-            dense: tuple[int, int] | None,
+            dense: tuple[int, int] | None, pack=None,
             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(order, lo, hi, dense_oob) via directory or binary search."""
+    """(order, lo, hi, dense_oob) via directory or binary search.
+
+    `pack`, (base, extent) per key of a multi-key join whose ranges the
+    planner knows, searches one packed key (`pack_keys`) over a build
+    side sorted as one (key, position) operand (`sort_packed`; the
+    caller checked `packed_sort_fits`).  A matchable build row
+    outside the ranges (stale statistics) is counted into dense_oob, so
+    the host retries without packing, and a probe row outside them
+    matches nothing: no pair is wrong."""
     if dense is not None and len(build_keys) == 1:
         return _dense_bounds(build_keys[0], build_matchable, probe_keys[0],
                              dense[0], dense[1])
+    if pack is not None and len(build_keys) > 1:
+        top = math.prod(e for _b, e in pack)
+        bkey, b_in = pack_keys(build_keys, pack)
+        pkey, p_in = pack_keys(probe_keys, pack)
+        oob = (build_matchable & ~b_in).sum().astype(jnp.int64)
+        b_ok = build_matchable & b_in
+        sorted_key, order = sort_packed(bkey, b_ok, top)
+        n_valid = b_ok.sum().astype(jnp.int32)
+        if top < (1 << 31) - 1:
+            # 64-bit compares and gathers are emulated on the chip
+            sorted_key, pkey = (sorted_key.astype(jnp.int32),
+                                pkey.astype(jnp.int32))
+        lo, hi = _dual_search([sorted_key], n_valid, [pkey])
+        return order, lo, jnp.where(p_in, hi, lo), oob
     sorted_keys, order, n_valid = sort_build_side(build_keys,
                                                   build_matchable)
     lo, hi = _dual_search(sorted_keys, n_valid, probe_keys)
@@ -426,7 +486,7 @@ def expand_join(build_keys: list[jnp.ndarray], build_valid: jnp.ndarray,
 
 def expand_join_pairs(build_keys, build_matchable, probe_keys, probe_valid,
                       probe_matchable, capacity: int, probe_outer: bool,
-                      dense: tuple[int, int] | None = None):
+                      dense: tuple[int, int] | None = None, pack=None):
     """Pair emission core.
 
     probe_valid = rows that exist; probe_matchable = rows whose keys may
@@ -437,11 +497,11 @@ def expand_join_pairs(build_keys, build_matchable, probe_keys, probe_valid,
     Returns (build_idx, probe_idx, out_valid, build_missing,
     capacity_overflow, dense_oob) — the two overflow kinds stay separate
     so the host can distinguish "grow buffers" from "stats were stale,
-    drop the directory".
+    drop the directory".  `pack` as `_bounds` takes it.
     """
     with stage_scope("expand"):
         order, lo, hi, dense_oob = _bounds(build_keys, build_matchable,
-                                           probe_keys, dense)
+                                           probe_keys, dense, pack)
         m = build_keys[0].shape[0]
         n = probe_keys[0].shape[0]
         counts = jnp.where(probe_matchable, hi - lo, 0).astype(jnp.int32)
@@ -485,7 +545,7 @@ def expand_join_outer(build_keys: list[jnp.ndarray], build_valid: jnp.ndarray,
                       probe_outer: bool, build_outer: bool,
                       replicated_build: bool = False,
                       axis_name: str | None = None,
-                      dense: tuple[int, int] | None = None):
+                      dense: tuple[int, int] | None = None, pack=None):
     """Outer-join pair emission (LEFT/RIGHT/FULL null extension).
 
     Returns (build_idx [C], probe_idx [C], out_valid [C],
@@ -503,7 +563,7 @@ def expand_join_outer(build_keys: list[jnp.ndarray], build_valid: jnp.ndarray,
     build_idx, probe_idx, out_valid, build_missing, overflow, dense_oob = \
         expand_join_pairs(build_keys, build_matchable, probe_keys,
                           probe_valid, probe_matchable, capacity,
-                          probe_outer, dense=dense)
+                          probe_outer, dense=dense, pack=pack)
     m = build_keys[0].shape[0]
     if build_outer:
         hit = out_valid & ~build_missing

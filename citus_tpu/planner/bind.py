@@ -553,6 +553,11 @@ class Binder:
                 code = self._code_of(left, str(right.value))
                 return ir.BCmp(op, left,
                                ir.BParam(right.idx, DataType.STRING, code))
+            if op in ("=", "<>") and _same_dictionary(left, right):
+                # one column of one table under two aliases (a CTE read
+                # twice): its codes are one dictionary's, and equal codes
+                # are equal strings
+                return ir.BCmp(op, left, right)
             if not isinstance(right, ir.BConst):
                 raise PlanningError(
                     "string-to-string column comparisons need dictionary "
@@ -812,6 +817,14 @@ def _default_name(e: ast.Expr, i: int) -> str:
     if isinstance(e, ast.FuncCall):
         return e.name
     return f"column{i + 1}"
+
+
+def _same_dictionary(a: ir.BExpr, b: ir.BExpr) -> bool:
+    """Two string columns whose codes come from one dictionary: the same
+    column of the same table, whatever the aliases."""
+    return (isinstance(a, ir.BCol) and isinstance(b, ir.BCol)
+            and a.dtype == b.dtype == DataType.STRING and bool(a.table)
+            and (a.table, a.column) == (b.table, b.column))
 
 
 def _flip_cmp(op: str) -> str:

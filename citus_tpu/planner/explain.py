@@ -8,6 +8,8 @@ and EXPLAIN ANALYZE appends wall-clock + retry stats from the runner.
 
 from __future__ import annotations
 
+import math
+
 from ..catalog import Catalog
 from .plan import (
     AggregateNode,
@@ -46,6 +48,8 @@ EXPLAIN_TAGS: dict[str, str] = {
                 "coming in that find a match (the looked-up side's "
                 "surviving fraction); among joins of one strategy the "
                 "planner takes the one that keeps the fewest rows first",
+    "packed key": "multi-key join whose keys pack into one int64 over "
+                  "their known ranges: the build side sorts one operand",
     "bucketed group-by": "dense-grid bucketed aggregation path",
     "Chunks Skipped": "chunk groups pruned by min/max skip nodes",
     "pipelined scan":
@@ -184,6 +188,11 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
             mods.append(f"{explain_tag('est keep')} {node.est_keep:.2f}")
         if sorted_lookup:
             mods.append(explain_tag("sorted lookup"))
+        if node.key_pack is not None:
+            # the build side sorts one operand: the keys packed into one
+            # int64 over these ranges' product
+            mods.append(f"{explain_tag('packed key')} "
+                        f"{math.prod(e for _b, e in node.key_pack)} slots")
         lines.append(f"{pad}-> {label} on ({conds})  "
                      f"[{', '.join(mods)}]")
         if node.residual is not None:
@@ -198,6 +207,11 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
                    "repartition": "all_to_all partitions"}[node.combine]
         if node.presorted:
             combine += ", groups in partition order"
+            if any(w.order_by for w, _ in node.functions):
+                combine += " and ORDER BY order"
+        else:
+            combine += ", sorts"
+        # each function prints its PARTITION BY and ORDER BY
         fns = ", ".join(str(w) for w, _ in node.functions)
         lines.append(f"{pad}-> WindowAgg [{combine}] {fns}")
         if node.having is not None:

@@ -22,6 +22,7 @@ the placement-map equality check is the colocation test
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from dataclasses import replace as dc_replace
 from typing import NamedTuple, Optional
@@ -142,6 +143,13 @@ class JoinNode(PlanNode):
     # that find their PK row, i.e. the PK side's surviving fraction
     # (EXPLAIN's `est keep`); None for every other join
     est_keep: Optional[float] = None
+    # a join on several keys whose every range statistics give (a
+    # column's, or a column's plus or minus an integer), over both
+    # sides: (base, extent) per key pair.  The executor packs the keys
+    # into one int64 so the build side sorts as one operand
+    # (ops.join.pack_keys); a stale range is caught at runtime
+    # (dense_oob).  None for every other join
+    key_pack: Optional[tuple] = None
 
 
 @dataclass
@@ -189,6 +197,10 @@ class AggregateNode(PlanNode):
     # group_by_kernel='bucketed'/'bucketed_pallas' overrides the gate
     # wherever bucket_keys is structurally set.
     group_bucketed: bool = False
+    # indices of the group keys whose packed NULL lane comes LAST (a
+    # window reads the groups in ORDER BY order, ascending with NULLS
+    # LAST); every other key's NULL lane is its first
+    nulls_last: tuple[int, ...] = ()
 
 
 @dataclass
@@ -1088,6 +1100,7 @@ class DistributedPlanner:
                 ok = lo >= -(1 << 31) and hi <= (1 << 31) - 1
             int32_ok.append(ok)
         node.key_int32 = tuple(int32_ok)
+        node.key_pack = self._key_pack(node)
         if node.fuse_lookup and len(node.left_keys) == 1:
             from ..ops.join import sorted_lookup_eligible
 
@@ -1115,25 +1128,58 @@ class DistributedPlanner:
             return self.stats.column_extent(e.table, e.column, e.dtype)
         return None
 
+    def _key_pack(self, node: JoinNode) -> Optional[tuple]:
+        """JoinNode.key_pack: per key pair the range that holds both
+        sides' values, where the join has several keys and statistics
+        give every range, and their product fits the packed key."""
+        if len(node.left_keys) < 2:
+            return None
+        ranges, total = [], 1
+        for lk, rk in zip(node.left_keys, node.right_keys):
+            le, re = self._shifted_extent(lk), self._shifted_extent(rk)
+            if le is None or re is None:
+                return None
+            lo = min(le[0], re[0])
+            hi = max(le[0] + le[1], re[0] + re[1])
+            ranges.append((lo, hi - lo))
+            total *= hi - lo
+        return tuple(ranges) if total <= self.PACK_SLOT_LIMIT else None
+
+    def _shifted_extent(self, e: ir.BExpr) -> tuple[int, int] | None:
+        """_key_extent of a column, or of a column plus or minus an
+        integer constant (`v1.rn = v1_lag.rn + 1`): its range shifted."""
+        col, shift = _shifted_column(e)
+        ext = self._key_extent(col)
+        return None if ext is None else (ext[0] + shift, ext[1])
+
     def _estimate_expansion_for(self, build_node: PlanNode,
                                 build_keys) -> float | None:
         """Matches per probe row ≈ build_rows / ndv(build key) for one
         side as build — the pg_statistic-style selectivity estimate for
-        equi-joins; min over edges (every key must match); None = no
-        usable statistics.  A value <= 1.0 marks the side as PK-unique
-        on the key (lookup-fusion eligible — verified at runtime, stale
-        claims retry on the expansion path)."""
-        best = None
-        rows = max(1, build_node.est_rows)
+        equi-joins; over several edges the ndvs multiply (every key must
+        match, and PostgreSQL's estimate takes the keys as independent);
+        None = no usable statistics.  The product is held to the side's
+        unfiltered rows, which hold no more distinct combinations (keys
+        one of which determines another multiply past them).  A key
+        that is a column plus or minus an integer has the column's ndv.
+        A value <= 1.0 marks the side as PK-unique on the key
+        (lookup-fusion eligible — verified at runtime, stale claims
+        retry on the expansion path)."""
+        ndvs = []
         for k in build_keys:
+            k, _shift = _shifted_column(k)
             if not (isinstance(k, ir.BCol) and k.table):
                 continue
             ndv = self.stats.column_ndv(k.table, k.column, k.dtype)
             if ndv is None or ndv <= 0:
                 continue
-            e = rows / ndv
-            best = e if best is None else min(best, e)
-        return best
+            ndvs.append(ndv)
+        if not ndvs:
+            return None
+        distinct = ndvs[0]
+        if len(ndvs) > 1:
+            distinct = min(math.prod(ndvs), self._unfiltered_rows(build_node))
+        return max(1, build_node.est_rows) / distinct
 
     # -- aggregation -------------------------------------------------------
     def _plan_aggregate(self, q: BoundQuery, input_node: PlanNode,
@@ -1558,12 +1604,39 @@ class DistributedPlanner:
 
         new_select = [(rewrite(e), n) for e, n in select]
         new_order = [rewrite(e) for e in order]
-        parts = {w.partition_by for w, _ in windows}
-        if len(parts) > 1:
-            raise PlanningError(
-                "all window functions in one query must share the same "
-                "PARTITION BY clause")
-        partition_by = next(iter(parts))
+        # one WindowNode a PARTITION BY clause, stacked over the input
+        # (each passes its input's columns through): the fewest keys
+        # lowest, so that on a mesh a node whose keys hold the lower
+        # node's finds its partitions device-disjoint already
+        by_part: dict[tuple, list] = {}
+        for w, cid in windows:
+            by_part.setdefault(w.partition_by, []).append((w, cid))
+        nodes = []
+        node = input_node
+        for partition_by in sorted(by_part, key=len):
+            node = self._window_node(node, partition_by,
+                                     by_part[partition_by],
+                                     having if not nodes else None)
+            nodes.append(node)
+        if isinstance(input_node, AggregateNode):
+            # the nodes that keep the groups' order: those under the
+            # first exchange (a window's output keeps its input's order)
+            local = []
+            for nd in nodes:
+                if nd.combine != "local":
+                    break
+                local.append(nd)
+            if local and _lead_with_window_keys(
+                    input_node, [w for nd in local
+                                 for w, _cid in nd.functions]):
+                for nd in local:
+                    nd.presorted = True
+        return node, new_select, new_order
+
+    def _window_node(self, input_node: PlanNode, partition_by, windows,
+                     having: Optional[ir.BExpr]) -> WindowNode:
+        """One PARTITION BY clause's WindowNode over `input_node`, with
+        its combine and distribution."""
         node = WindowNode(input=input_node, functions=windows,
                           partition_by=partition_by, having=having)
         p_cids = {p.cid for p in partition_by if isinstance(p, ir.BCol)}
@@ -1574,6 +1647,11 @@ class DistributedPlanner:
         elif partition_by and input_node.dist.kind in ("hash", "device") \
                 and (input_node.dist.cids & p_cids):
             node.combine = "local"   # partitions already device-disjoint
+        elif isinstance(input_node, WindowNode) and \
+                set(input_node.partition_by) <= set(partition_by):
+            # the node below co-located its partitions, and each of
+            # this node's lies inside one of them
+            node.combine = "local"
         else:
             # all_to_all by partition-key hash (an empty PARTITION BY is
             # one global partition: every row routes to one device)
@@ -1588,11 +1666,7 @@ class DistributedPlanner:
         node.out_columns = dict(input_node.out_columns)
         for w, cid in windows:
             node.out_columns[cid] = w.dtype
-        if node.combine == "local" and isinstance(input_node, AggregateNode):
-            node.presorted = _lead_with_partition_keys(input_node,
-                                                       node.partition_by,
-                                                       windows)
-        return node, new_select, new_order
+        return node
 
     def _plan_projection(self, q: BoundQuery, input_node: PlanNode,
                          decode: dict):
@@ -1678,6 +1752,16 @@ class JoinEstimate(NamedTuple):
     keep: Optional[float]  # fused inner lookup: P(an FK row finds its PK row)
 
 
+def _shifted_column(e: ir.BExpr) -> tuple[ir.BExpr, int]:
+    """A join key that is an expression plus or minus an integer
+    constant, as (that expression, the shift); any other as (itself,
+    0)."""
+    if isinstance(e, ir.BArith) and e.op in ("+", "-") and \
+            isinstance(e.right, ir.BConst) and type(e.right.value) is int:
+        return e.left, e.right.value if e.op == "+" else -e.right.value
+    return e, 0
+
+
 def _split_edge_keys(join_edges, right_rel_index: int):
     """Equi-join edges → (left_keys, right_keys), index-aligned, the
     right ones being the expressions over `right_rel_index`."""
@@ -1698,36 +1782,68 @@ _STRATEGY_RANK = {"broadcast": 0, "broadcast_left": 0, "local": 1,
                   "cartesian_broadcast": 4, "cartesian": 5}
 
 
-def _lead_with_partition_keys(agg: AggregateNode, partition_by,
-                              windows) -> bool:
-    """Put a window's PARTITION BY keys first among the aggregate's
-    group keys, so that the groups leave it with each partition's rows
-    contiguous: the sort path and the bucketed grid emit their groups
-    in the order of the packed key, first key major.  Only where every
-    partition key is a group key, no window orders within its
-    partition, the aggregate is one level (the DISTINCT and approx
-    splits keep their key order) and not on the flat dense grid (whose
-    slots run last key major).  Returns whether the window may skip its
-    sort."""
-    if any(w.order_by for w, _cid in windows) or \
-            isinstance(agg.input, AggregateNode) or \
-            agg.dense_keys is not None:
+def _lead_with_window_keys(agg: AggregateNode, windows) -> bool:
+    """Order the aggregate's group keys so that the groups leave it in
+    the order every window of `windows` reads them in: each window's
+    PARTITION BY keys (as a set) a prefix of the order, its ORDER BY
+    keys right after them.  The sort path and the bucketed grid emit
+    their groups in the order of the packed key, first key major, each
+    key packed as its value less its base: a key's values ascend.  Only
+    where every such key is a group key, an ORDER BY key ascends (NULLS
+    LAST, PostgreSQL's ASC) over an integer the planner knows the range
+    of (its packed NULL lane then comes last: `nulls_last`), the
+    aggregate is one level (the DISTINCT and approx splits
+    keep their key order) and not on the flat dense grid (whose slots
+    run last key major).  Returns whether the windows may skip their
+    sort; the key order is left as it was where they may not."""
+    if isinstance(agg.input, AggregateNode) or agg.dense_keys is not None:
         return False
     pos = {cid: i for i, (_g, cid) in enumerate(agg.group_keys)}
-    lead = []
-    for p in partition_by:
-        if not isinstance(p, ir.BCol) or p.cid not in pos:
+    specs = []
+    for w in windows:
+        part = []
+        for p in w.partition_by:
+            if not isinstance(p, ir.BCol) or p.cid not in pos:
+                return False
+            if pos[p.cid] not in part:
+                part.append(pos[p.cid])
+        order = []
+        for e, desc in w.order_by:
+            # (the binder refuses a string ORDER BY in a window)
+            if desc or not isinstance(e, ir.BCol) or e.cid not in pos or \
+                    agg.key_ranges is None:
+                return False
+            order.append(pos[e.cid])
+        specs.append((part, order))
+    lead: list[int] = []
+    # fewest partition keys first: a wider partition's set then only
+    # has to hold the narrower one's prefix
+    for part, order in sorted(specs, key=lambda s: len(s[0])):
+        k = len(part)
+        if len(lead) < k:
+            if not set(lead) <= set(part):
+                return False
+            lead.extend(i for i in part if i not in lead)
+        elif set(lead[:k]) != set(part):
             return False
-        if pos[p.cid] not in lead:
-            lead.append(pos[p.cid])
+        for j, i in enumerate(order):
+            if k + j < len(lead):
+                if lead[k + j] != i:
+                    return False
+            elif i in lead:
+                return False
+            else:
+                lead.append(i)
     perm = lead + [i for i in range(len(agg.group_keys)) if i not in lead]
 
-    def permuted(specs):
-        return None if specs is None else tuple(specs[i] for i in perm)
+    def permuted(ranges):
+        return None if ranges is None else tuple(ranges[i] for i in perm)
 
+    ordered = {i for _part, order in specs for i in order}
     agg.group_keys = [agg.group_keys[i] for i in perm]
     agg.key_ranges = permuted(agg.key_ranges)
     agg.bucket_keys = permuted(agg.bucket_keys)
+    agg.nulls_last = tuple(j for j, i in enumerate(perm) if i in ordered)
     return True
 
 

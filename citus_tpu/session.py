@@ -2836,6 +2836,8 @@ class Session:
                     for col_name, values in dicts.items():
                         d = self.store.dictionary(name, col_name)
                         arrays[col_name] = d.intern_array(values)
+                self.stats.counters.increment(
+                    sc.INTERMEDIATE_STRING_VALUES_TOTAL, n_rows * len(dicts))
             with trace_span("subplan.store.append") as append:
                 self.catalog.create_reference_table(
                     name, TableSchema(tuple(cols)))

@@ -30,6 +30,9 @@ INTERMEDIATE_BYTES_TOTAL = "intermediate_bytes_total"
 # … and the results handed to the outer statement's feed in memory
 # (storage/table_store.py hold_resident; one of zero rows counts too)
 INTERMEDIATE_RESIDENT_TOTAL = "intermediate_resident_total"
+# … and the string values of those results typed and interned into a
+# temp table's dictionaries on the host (rows times string columns)
+INTERMEDIATE_STRING_VALUES_TOTAL = "intermediate_string_values_total"
 # string predicates (LIKE, IN, BETWEEN, <) resolved to dictionary codes
 # at bind time by walking a dictionary's values (planner/bind.py
 # _codes_where): walks that visited any value, and the values visited.
@@ -92,8 +95,20 @@ AGG_BUCKET_SLOTS_TOTAL = "agg_bucket_slots_total"
 # over the mesh (what its lexsort and segmented scans run over) …
 WINDOW_ROWS_TOTAL = "window_rows_total"
 # … and the window operators of those plans that run over the groups
-# of an aggregate (a window over GROUP BY: after it and its HAVING)
+# of an aggregate (a window over GROUP BY: after it and its HAVING, or
+# stacked over such a window, one a PARTITION BY clause) …
 WINDOW_OVER_GROUP_TOTAL = "window_over_group_total"
+# … the lexsorts of validity, partition and order keys those operators
+# ran (executor/compiler.py _window_sort: one a window ORDER BY spec) …
+WINDOW_SORTS_TOTAL = "window_sorts_total"
+# … and the ORDER BY specs they read in the groups' own order instead,
+# with no sort (WindowNode.presorted)
+WINDOW_PRESORTED_TOTAL = "window_presorted_total"
+# the operands of the build-side sorts of the executed statements'
+# converged programs' multi-key joins: one a sort of the keys packed
+# into one int64 with the row's position (the planner knew every key's
+# range), else the keys and the validity of a lexsort
+JOIN_SORT_OPERANDS_TOTAL = "join_sort_operands_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
@@ -207,7 +222,7 @@ ALL_COUNTERS = [
     QUERIES_SINGLE_SHARD, QUERIES_MULTI_SHARD, QUERIES_REPARTITION,
     QUERIES_FAST_PATH, POINT_INDEX_LOOKUPS,
     SUBPLANS_EXECUTED, INTERMEDIATE_ROWS_TOTAL, INTERMEDIATE_BYTES_TOTAL,
-    INTERMEDIATE_RESIDENT_TOTAL,
+    INTERMEDIATE_RESIDENT_TOTAL, INTERMEDIATE_STRING_VALUES_TOTAL,
     DICT_PREDICATE_WALKS_TOTAL, DICT_PREDICATE_VALUES_TOTAL,
     REPARTITION_ROWS_TOTAL, REPARTITION_HOT_BUCKET_ROWS_TOTAL,
     ROWS_INGESTED, ROWS_RETURNED,
@@ -219,6 +234,7 @@ ALL_COUNTERS = [
     LOOKUP_SORTED_JOINS_TOTAL, LOOKUP_DENSE_JOINS_TOTAL,
     LOOKUP_PROBE_SLOTS_TOTAL, AGG_BUCKET_SLOTS_TOTAL,
     WINDOW_ROWS_TOTAL, WINDOW_OVER_GROUP_TOTAL,
+    WINDOW_SORTS_TOTAL, WINDOW_PRESORTED_TOTAL, JOIN_SORT_OPERANDS_TOTAL,
     BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     DEFERRED_GATHER_SLOTS_TOTAL, DEFERRED_TOPDOWN_CHAINS_TOTAL,

@@ -312,4 +312,4 @@ def test_cell_loads_and_entries_fit():
     assert 1 <= len(entry["source"]) <= 200
     assert set(entry["reduced"]) == set(cell.config["reduced_why"])
     assert len(cell.entry["why"]) <= 200
-    assert len(cell.bench["workloads"]) == 7
+    assert len(cell.bench["workloads"]) == 8

@@ -498,3 +498,97 @@ def test_tpcds_q89_inner_program_compiles(topo, tmp_path):
     assert "f64[" not in text
     assert not any(" sort(" in ln and "ct.window" in ln
                    for ln in text.splitlines())
+
+
+def test_tpcds_q57_programs_compile(topo, tmp_path, monkeypatch):
+    """The benchmark cell `tpcds10cs.q57`'s converged device programs
+    at TPC-DS SF 0.1's shapes (144,126 catalog_sales rows), for
+    one described v5e: `v1` (three broadcast lookups, the five-key
+    group-by and its two stacked windows), `v2` (the CTE self-joined
+    three ways) and the outer statement.  The groups reach both windows
+    in their partition and ORDER BY order, so nothing under `ct.window`
+    sorts; each self-join sorts its build side by one packed int64 key
+    (on the chip two 32-bit halves of one operand, where the lexsort of
+    four keys and validity would be ten); no float64.  Recorded as the
+    statement runs on the CPU, then built again for the described chip,
+    with the planner's `tpu` pick of the group-by path."""
+    import dataclasses
+    import os
+    import re
+    import sys
+    import time
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import citus_tpu
+    from benchmark.datasets import tpcds_catalog as ds
+    from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
+    from citus_tpu.executor import compiler as C
+    from citus_tpu.executor.cache import node_fingerprint
+    from citus_tpu.executor.feed import walk_plan
+    from citus_tpu.planner.plan import AggregateNode
+
+    built = {}
+    real_init = C.PlanCompiler.__init__
+
+    def recording(self, plan, mesh, feeds, caps, *args, **kw):
+        # the last program of each plan is its converged one
+        built[node_fingerprint(plan.root)] = (plan, feeds, caps)
+        real_init(self, plan, mesh, feeds, caps, *args, **kw)
+
+    monkeypatch.setattr(C.PlanCompiler, "__init__", recording)
+    with open(os.path.join(root, "benchmark", "statements",
+                           "tpcds_q57.sql")) as f:
+        sql = f.read()
+    data = ds.generate({"scale_factor": 0.1}, 5)
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "cs"), n_devices=1,
+                             serving_result_cache_bytes=0)
+    try:
+        ds.load(sess, data, {"shard_count": 8})
+        del data
+        for _ in range(2):
+            sess.execute(sql).rows()
+    finally:
+        sess.close()
+    monkeypatch.undo()
+    assert len(built) == 3
+    mesh = make_mesh(devices=topo.devices[:1])
+
+    def abstract(arr, sharded):
+        return jax.ShapeDtypeStruct(arr.shape, arr.dtype, sharding=NamedSharding(
+            mesh, P(SHARD_AXIS) if sharded else P()))
+
+    texts, seconds = [], []
+    for plan, feeds, caps in built.values():
+        for nd in walk_plan(plan.root):
+            if isinstance(nd, AggregateNode) and nd.bucket_keys is not None:
+                nd.group_bucketed = True    # as the planner picks on tpu
+        feeds = {nid: dataclasses.replace(
+            f, arrays={c: abstract(a, f.sharded) for c, a in f.arrays.items()},
+            nulls={c: abstract(a, f.sharded) for c, a in f.nulls.items()},
+            valid=abstract(f.valid, f.sharded)) for nid, f in feeds.items()}
+        fn, feed_arrays, _meta, _keys = C.PlanCompiler(
+            plan, mesh, feeds, caps, np.dtype("float32")).build()
+        t0 = time.perf_counter()
+        texts.append(fn.lower(*feed_arrays).compile().as_text())
+        seconds.append(time.perf_counter() - t0)
+    print(f"tpcds q57 at SF 0.1 shapes: v1, v2 and the outer statement "
+          f"compiled for a described v5e in "
+          f"{', '.join(f'{s:.1f}' for s in seconds)} s")
+    v1, v2, outer = texts
+    for text in texts:
+        assert "f64[" not in text
+    assert "ct.window/ct.scan" in v1 and "ct.agg_sort" in v1
+    assert not any(" sort(" in ln and "ct.window" in ln
+                   for ln in v1.splitlines())
+    join_sorts = [ln for ln in v2.splitlines()
+                  if " sort(" in ln and "ct.lookup_join" in ln]
+    assert len(join_sorts) == 2
+    for ln in join_sorts:
+        operands = re.search(r" sort\(([^)]*)\)", ln).group(1)
+        assert operands.count("%") <= 2, ln
+    assert "ct.window" not in v2 + outer

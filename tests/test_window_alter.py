@@ -81,9 +81,12 @@ def test_window_with_join_and_mixed_select(sess):
 
 def test_window_restrictions(sess):
     s, _ = sess
-    with pytest.raises(PlanningError, match="PARTITION BY"):
-        s.execute("select row_number() over (partition by g order by k), "
-                  "row_number() over (partition by v order by k) from w")
+    # legal: one window node a PARTITION BY clause, stacked
+    _check(sess, "select k, row_number() over (partition by g order by k), "
+                 "row_number() over (partition by v order by k) from w")
+    with pytest.raises(PlanningError, match="DISTINCT"):
+        s.execute("select distinct g, row_number() over (partition by g "
+                  "order by k) from w")
     with pytest.raises(PlanningError, match="OVER"):
         s.execute("select row_number() from w")
     # legal since PR 40: the window runs over the groups

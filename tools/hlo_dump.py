@@ -9,9 +9,11 @@ Loads TPC-H (SF 0.01, `benchmark/selftest.py`'s scale), SSB (SF 0.05,
 with Zipf(1) customer keys (SF 0.1, the smallest decade whose 15,001
 `c_custkey` slots are past the flat grid's limit, with `group_by_kernel`
 forced onto the bucketed grid that the planner picks by itself only on
-the chip) and TPC-DS (SF 0.01, `tests/test_tpcds.py`'s) through
+the chip) and TPC-DS's store and catalog channels (SF 0.01,
+`tests/test_tpcds.py`'s and `tests/test_tpcds_q57.py`'s) through
 <checkout>'s program on <n_devices> CPU devices, answers Q1, Q3, SSB
-Q4.1, Q13 and TPC-DS Q89 (both programs of each of the last two) until
+Q4.1, Q13, TPC-DS Q89 and Q57 (every program of the last three; a
+configuration the checkout lacks is skipped) until
 their capacities have settled, and writes the optimized HLO of every plan-cache entry to
 <out_dir>/<config>.<n>dev.<i>.hlo, without what is no part of JAX's
 persistent-cache key: each operation's `metadata={…}` and the module's
@@ -51,6 +53,9 @@ JOBS = (
      ("tpch_q13",), {"group_by_kernel": "bucketed"}),
     ("tpcds", ("tpcds-sf10-1chip", "tpcds-sf10-1chip"), 0.01,
      ("tpcds_q89",), {}),
+    ("tpcds_catalog", ("tpcds-sf10-catalog-1chip",
+                       "tpcds-sf10-catalog-1chip"), 0.01,
+     ("tpcds_q57",), {}),
 )
 
 
@@ -72,6 +77,10 @@ def main(root: str, out: str, n_devices: int) -> None:
     bench = os.path.join(root, "benchmark")
     for dataset, configs, scale, statements, settings in JOBS:
         config = configs[n_devices > 1]
+        if not os.path.exists(os.path.join(bench, "configs",
+                                           config + ".json")):
+            print(f"{config}: not in this checkout, skipped")
+            continue
         with open(os.path.join(bench, "configs", config + ".json")) as f:
             cfg = json.load(f)
         params = {**cfg["dataset_params"], "scale_factor": scale}
