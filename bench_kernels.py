@@ -287,7 +287,8 @@ def bench_compact(small=False, out="chiprun_out"):
     from citus_tpu.runtime import ensure_jax_configured
 
     ensure_jax_configured()
-    from citus_tpu.executor.compiler import _round_cap, survivor_positions
+    from citus_tpu.executor.compiler import _round_cap
+    from citus_tpu.ops.aggregate import survivor_positions
     dev = jax.devices()[0]
     print(f"backend: {dev.platform} ({dev.device_kind})")
     rng = np.random.default_rng(0)

@@ -47,6 +47,7 @@ def shard_map(body, mesh, in_specs, out_specs, check_vma=False):
 from ..catalog.distribution import HASH_TOKEN_COUNT, INT32_MIN
 from ..errors import ExecutionError, PlanningError
 from ..ops import pack_by_target, segment_aggregate
+from ..ops.aggregate import sort_packed, survivor_positions
 from ..ops.join import expand_join_outer, expand_join_pairs
 from ..ops.hashing import hash_token_jax
 from ..planner.plan import (
@@ -202,7 +203,7 @@ def unpack_outputs(packed: np.ndarray, out_meta):
 
 # PlanCompiler.tallies of a program that recorded nothing: one count a
 # counter that Executor.count_picks bumps from them
-NO_TALLIES = (0,) * 11
+NO_TALLIES = (0,) * 12
 
 
 @dataclass
@@ -263,25 +264,6 @@ class Capacities:
                           {k: g(v) for k, v in self.scan_out.items()},
                           g(self.output_repart)
                           if self.output_repart else None)
-
-
-def survivor_positions(valid, k: int):
-    """→ (the positions of the first k True rows of `valid`, in row
-    order; the count of True rows), by ONE sort: survivors keep their
-    position as key, the rest take the sentinel n, so the first k of the
-    sorted keys are the survivors (slots past the count hold n).  Keys
-    are distinct, so the sort need not be stable.
-
-    A sort, not a scatter of each survivor's rank: on the v5e XLA's
-    scatter and the `cumsum` that feeds it cost 4.7–6.6 ns a row of the
-    uncompacted size (36.7 ms for 6.0 M rows) where the one-operand
-    sort costs 0.4–1.5 (5.0 ms), at every size from 30 k rows up
-    (`python bench_kernels.py compact`; PERF.md §6, my chip run,
-    PR 30)."""
-    n = valid.shape[0]
-    key = jnp.where(valid, jnp.arange(n, dtype=jnp.int32), n)
-    return (jax.lax.sort(key, is_stable=False)[:k],
-            valid.sum(dtype=jnp.int32))
 
 
 class PlanCompiler:
@@ -382,7 +364,8 @@ class PlanCompiler:
                              tally.slots * self.n_dev, tally.topdown,
                              tally.recomposed, self._window_sorts,
                              self._window_presorted,
-                             self._join_sort_operands)
+                             self._join_sort_operands,
+                             self._agg_sort_gather_slots)
             return packed
 
         def traced(flat_feeds):
@@ -421,6 +404,9 @@ class PlanCompiler:
                 self._window_sorts = 0
                 self._window_presorted = 0
                 self._join_sort_operands = 0
+                # the index slots of the sort-path group-bys' gathers,
+                # over the mesh: the same rule
+                self._agg_sort_gather_slots = 0
                 out = self._exec(self.plan.root, blocks)
                 if self.plan.output_repart is not None:
                     # INSERT..SELECT device routing: shuffle the final
@@ -490,13 +476,15 @@ class PlanCompiler:
         # slots of those later gathers over the mesh, the index chains
         # it composed from the top, the middle indexes gathered
         # through twice, its windows' lexsorts, the window ORDER BY
-        # specs it read in the groups' order and the operands of its
-        # multi-key joins' build-side sorts): deferred_columns_total /
+        # specs it read in the groups' order, the operands of its
+        # multi-key joins' build-side sorts and the index slots of its
+        # sort-path group-bys' gathers): deferred_columns_total /
         # deferred_gathers_total / lookup_probe_slots_total /
         # agg_bucket_slots_total / window_rows_total /
         # deferred_gather_slots_total / deferred_topdown_chains_total /
         # deferred_topdown_recomposed_total / window_sorts_total /
-        # window_presorted_total / join_sort_operands_total
+        # window_presorted_total / join_sort_operands_total /
+        # agg_sort_gather_slots_total
         self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
@@ -1478,30 +1466,44 @@ class PlanCompiler:
                                         valid):
         """One dispatch point for both sort-path aggregation stages:
         pack the composite key when ranges are known (accumulating the
-        stale-range oob), plain multi-key segment_aggregate otherwise."""
+        stale-range oob), plain multi-key segment_aggregate otherwise.
+        The groups come out at the aggregate's capacity where it is
+        below the input's slots (`_group_slots`), every per-group
+        operation at that width; the gathers' index slots count into
+        agg_sort_gather_slots_total, and the groups past the capacity
+        into the overflow (`_group_overflow`).  → (group keys, results,
+        group validity)."""
+        n = valid.shape[0]
+        out = self._group_slots(node, n)
+        gathered = []
         packed, pack_oob = self._pack_group_keys(node, key_arrays,
                                                  key_meta, valid)
         if packed is not None:
             self._dense_oob = self._dense_oob + pack_oob
-            return segment_aggregate(
+            sorted_key, order = self._composite_order(
+                node, packed, valid) or (None, None)
+            gk, res, gvalid, ngroups = segment_aggregate(
                 [packed], values, valid, out_keys=key_arrays,
-                order=self._composite_order(node, packed, valid))
-        return segment_aggregate(key_arrays, values, valid)
+                order=order, sorted_key=sorted_key, out=out,
+                gathered=gathered)
+        else:
+            gk, res, gvalid, ngroups = segment_aggregate(
+                key_arrays, values, valid, out=out, gathered=gathered)
+        self._agg_sort_gather_slots += sum(gathered) * self.n_dev
+        self._group_overflow(node, ngroups, n)
+        return gk, res, gvalid
 
     @staticmethod
     def _composite_order(node: AggregateNode, packed, valid):
-        """The rows' order by packed key, invalid rows last, from ONE
-        single-operand sort of (key, row position)
+        """(sorted key, order) of the rows by packed key, invalid rows
+        last, from ONE single-operand sort of (key, row position)
         (`ops.aggregate.sort_packed`).  None where the two do not fit
         63 bits."""
-        from ..ops.aggregate import sort_packed
-
         top = 1
         for _base, extent, _hn in node.key_ranges:
             top *= extent + 1     # the packed slot space, invalid = top
         with stage_scope("sort"):
-            by_key = sort_packed(packed, valid, top)
-        return None if by_key is None else by_key[1]
+            return sort_packed(packed, valid, top)
 
     def _pack_group_keys(self, node: AggregateNode, key_arrays, key_meta,
                          valid, kr=None):
@@ -1882,10 +1884,8 @@ class PlanCompiler:
                 else:
                     companions.append(None)
             all_values = values + [c for c in companions if c is not None]
-            gk, res, gvalid, ngroups = self._segment_aggregate_maybe_packed(
+            gk, res, gvalid = self._segment_aggregate_maybe_packed(
                 node, key_arrays, key_meta, all_values, blk.valid)
-            gk, res, gvalid = self._slice_groups(node, gk, res, gvalid,
-                                                 ngroups)
             main_res = res[:len(values)]
             comp_res = res[len(values):]
             partial = self._partial_block(node, key_meta, gk, main_res, gvalid)
@@ -1951,11 +1951,8 @@ class PlanCompiler:
                     comp_cids.append(cid)
             for cid in comp_cids:
                 values2.append((shuffled.columns[f"__cnt_{cid}"], "sum", None))
-            gk2, res2, gvalid2, ngroups2 = \
-                self._segment_aggregate_maybe_packed(
-                    node, key_arrays2, key_meta, values2, shuffled.valid)
-            gk2, res2, gvalid2 = self._slice_groups(node, gk2, res2, gvalid2,
-                                                    ngroups2)
+            gk2, res2, gvalid2 = self._segment_aggregate_maybe_packed(
+                node, key_arrays2, key_meta, values2, shuffled.valid)
             final = self._partial_block(node, key_meta, gk2,
                                         res2[:len(node.aggs)], gvalid2)
             for cid, cnt in zip(comp_cids, res2[len(node.aggs):]):
@@ -2273,18 +2270,23 @@ class PlanCompiler:
             preferred_element_type=jnp.float32)
         return red.astype(dt) if dt == jnp.int32 else red
 
-    def _slice_groups(self, node: AggregateNode, gk, res, gvalid, ngroups):
-        """Slice front-packed group slots down to the planner's estimated
-        capacity; groups beyond it count as overflow (→ retry, doubled)."""
+    def _group_slots(self, node: AggregateNode, n: int) -> int:
+        """The group slots a sort-path aggregation over `n` input slots
+        produces: the planner's (or capacity feedback's) estimate where
+        it is below `n`, else `n`."""
+        agg_cap = self.caps.agg_out.get(id(node))
+        return n if agg_cap is None or agg_cap >= n else agg_cap
+
+    def _group_overflow(self, node: AggregateNode, ngroups, n: int):
+        """Record the true group count over `n` input slots (capacity
+        feedback); groups beyond the capacity count as overflow (→
+        retry, doubled)."""
         with stage_scope("agg_out"):
-            self._record(id(node), "agg_out", ngroups, gvalid.shape[0])
-            agg_cap = self.caps.agg_out.get(id(node))
-            if agg_cap is None or agg_cap >= gvalid.shape[0]:
-                return gk, res, gvalid
-            self._overflow = self._overflow + jnp.maximum(
-                ngroups.astype(jnp.int64) - agg_cap, 0)
-            return ([k[:agg_cap] for k in gk], [r[:agg_cap] for r in res],
-                    gvalid[:agg_cap])
+            self._record(id(node), "agg_out", ngroups, n)
+            out = self._group_slots(node, n)
+            if out < n:
+                self._overflow = self._overflow + jnp.maximum(
+                    ngroups.astype(jnp.int64) - out, 0)
 
     def _partial_block(self, node: AggregateNode, key_meta, gk, res,
                        gvalid) -> Block:

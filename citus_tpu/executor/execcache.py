@@ -62,7 +62,9 @@ from ..errors import StorageError
 # groups' order, and the operands of its multi-key joins' sorts; a
 # window over groups with an ORDER BY or a multi-key join with known
 # key ranges compiles another program
-EXEC_CACHE_VERSION = 8
+# 9: a twelfth, the index slots of its sort-path group-bys' gathers; a
+# sort-path group-by under a capacity compiles another program
+EXEC_CACHE_VERSION = 9
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)

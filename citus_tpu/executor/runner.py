@@ -817,8 +817,9 @@ class Executor:
         window_rows_total, deferred_gather_slots_total,
         deferred_topdown_chains_total,
         deferred_topdown_recomposed_total, window_sorts_total,
-        window_presorted_total and join_sort_operands_total take
-        `tallies`, the eleven counts the converged program's compiler
+        window_presorted_total, join_sort_operands_total and
+        agg_sort_gather_slots_total take
+        `tallies`, the twelve counts the converged program's compiler
         recorded at trace time (PlanCompiler.tallies; they ride in the
         plan-cache entry)."""
         if self.counters is None:
@@ -827,7 +828,7 @@ class Executor:
 
         (carried, gathered, probe_slots, bucket_slots, window_rows,
          gather_slots, topdown, recomposed, window_sorts, presorted,
-         join_operands) = tallies
+         join_operands, agg_sort_slots) = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
@@ -854,6 +855,9 @@ class Executor:
         if join_operands:
             self.counters.increment(sc.JOIN_SORT_OPERANDS_TOTAL,
                                     join_operands)
+        if agg_sort_slots:
+            self.counters.increment(sc.AGG_SORT_GATHER_SLOTS_TOTAL,
+                                    agg_sort_slots)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         n_over_groups = sum(1 for nd in nodes if isinstance(nd, WindowNode)

@@ -1,13 +1,21 @@
 """Sort-based grouped aggregation with static output capacity.
 
 The TPU-native replacement for the reference's two-level aggregation
-(worker partial aggregate + coordinator combine,
-/root/reference/src/backend/distributed/planner/multi_logical_optimizer.c:1419
+(worker partial aggregate + coordinator combine, Citus's
+src/backend/distributed/planner/multi_logical_optimizer.c:1419
 MasterExtendedOpNode / WorkerExtendedOpNode): instead of a dynamic hash
 table, rows are sorted by group key (XLA-friendly, deterministic) and
-reduced over the sorted runs.  Output capacity == input capacity, so
-there is NO overflow case: in the worst degenerate case every row is its
-own group.  `group_valid` marks which output slots hold real groups.
+reduced over the sorted runs.  The caller names how many group slots it
+keeps (`out`, the aggregate's planned or fed-back capacity; the input's
+size where it has none): groups past it are counted in `n_groups` and
+dropped, and the caller retries larger.  `group_valid` marks which
+output slots hold real groups.
+
+Every operation runs at the narrowest width its result needs.  Per row,
+at the input's N: the sort, the value columns and their null masks in
+sorted order, the boundary compare, the segmented scans and the count's
+prefix sum.  Per group, at `out`: the group starts and ends, each
+group's first row, key values and reductions.
 
 Reduction strategy (the part that matters on TPU): `jax.ops.segment_*`
 lowers to scatter-add/min/max, which the TPU executes element-at-a-time —
@@ -22,9 +30,13 @@ SORTED by group, every reduction is over a contiguous run instead:
   value's own dtype, each run from its own start, so nothing cancels
   (a float64 prefix difference would: and its emulated cumsum costs the
   chip's compiler 120-290 s a program, PERF.md §6, PR 40).
-* group keys / first positions — one scatter-SET with provably unique
-  indices (each group has exactly one boundary row), which the TPU
-  handles vectorized, unlike combining scatters.
+* group starts — the first `out + 1` boundary rows' positions, by ONE
+  sort of the positions (`survivor_positions`): no scatter, whose
+  unique-index form and the `cumsum` that fed it cost the v5e 4.7-6.6
+  ns a row against the sort's 0.4-1.5 (`bench_kernels.py compact`).
+* the sorted key and validity — the sort's own output where the caller
+  sorted a packed key (`sort_packed`), and valid rows first under every
+  order: neither is gathered.
 
 This same primitive serves: GROUP BY (partial + final), DISTINCT, and the
 merge step after an all_to_all repartition.
@@ -67,20 +79,30 @@ def sort_packed(key: jnp.ndarray, valid: jnp.ndarray, top: int):
             (comp & jnp.int64((1 << bits) - 1)).astype(jnp.int32))
 
 
+def survivor_positions(valid, k: int):
+    """→ (the positions of the first k True rows of `valid`, in row
+    order; the count of True rows), by ONE sort: survivors keep their
+    position as key, the rest take the sentinel n, so the first k of the
+    sorted keys are the survivors (slots past the count hold n).  Keys
+    are distinct, so the sort need not be stable.
+
+    A sort, not a scatter of each survivor's rank: on the v5e XLA's
+    scatter and the `cumsum` that feeds it cost 4.7–6.6 ns a row of the
+    uncompacted size (36.7 ms for 6.0 M rows) where the one-operand
+    sort costs 0.4–1.5 (5.0 ms), at every size from 30 k rows up
+    (`python bench_kernels.py compact`; PERF.md §6)."""
+    n = valid.shape[0]
+    key = jnp.where(valid, jnp.arange(n, dtype=jnp.int32), n)
+    return (jax.lax.sort(key, is_stable=False)[:k],
+            valid.sum(dtype=jnp.int32))
+
+
 @dataclass(frozen=True)
 class AggSpec:
     """One aggregate over one input array."""
 
     kind: str            # sum | count | min | max
     # count counts rows where contributing value is non-null (input_valid)
-
-
-def _run_sum(x: jnp.ndarray, starts: jnp.ndarray, ends: jnp.ndarray,
-             acc_dtype) -> jnp.ndarray:
-    """Sum of each [starts[g], ends[g]) run via prefix-sum difference."""
-    prefix = jnp.concatenate([jnp.zeros(1, acc_dtype),
-                              jnp.cumsum(x.astype(acc_dtype))])
-    return prefix[ends] - prefix[starts]
 
 
 def _segmented_scan(x: jnp.ndarray, boundary: jnp.ndarray, op):
@@ -118,6 +140,9 @@ def segment_aggregate(keys: list[jnp.ndarray],
                       valid: jnp.ndarray,
                       out_keys: list[jnp.ndarray] | None = None,
                       order: jnp.ndarray | None = None,
+                      sorted_key: jnp.ndarray | None = None,
+                      out: int | None = None,
+                      gathered: list[int] | None = None,
                       ) -> tuple[list[jnp.ndarray], list[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
     """Group rows by `keys` and reduce.
 
@@ -134,14 +159,34 @@ def segment_aggregate(keys: list[jnp.ndarray],
               over in-range rows, so the groupings agree).
       order:  the rows' sorted order, when the caller sorted them (valid
               rows first, grouped by `keys`); the sort is skipped.
+      sorted_key: with `order`, the one packed key in that order, as
+              the caller's sort returned it (`sort_packed`): not gathered.
+      out:    the group slots to produce, at most N (N where None):
+              groups past them are counted in n_groups and dropped.
+      gathered: when given, the index size of every gather this call
+              makes is appended to it, each distinct gather once.
 
     Returns (group_keys, agg_results, group_valid, n_groups):
-      group_keys:  each [N], key value of each group slot,
-      agg_results: each [N],
-      group_valid: [N] bool, slots < n_groups,
-      n_groups:    scalar int32.
+      group_keys:  each [out], key value of each group slot,
+      agg_results: each [out],
+      group_valid: [out] bool, slots < n_groups,
+      n_groups:    scalar int32, every group of the input.
     """
     n = valid.shape[0]
+    out = n if out is None else min(out, n)
+    taken = {}
+
+    def take(arr, idx):
+        # one gather a distinct (array, index): a value's null mask
+        # serves its aggregate and the companion count.  The entry
+        # holds both operands, so no id is reused while it stands
+        key = (id(arr), id(idx))
+        if key not in taken:
+            if gathered is not None:
+                gathered.append(int(idx.shape[0]))
+            taken[key] = (arr, idx, arr[idx])
+        return taken[key][2]
+
     with stage_scope("sort"):
         if order is None and out_keys is not None:
             # packed mode: the single int64 key already encodes invalid rows
@@ -151,8 +196,11 @@ def segment_aggregate(keys: list[jnp.ndarray],
             order = jnp.argsort(keys[0], stable=True).astype(jnp.int32)
         elif order is None:
             order = _sort_order(keys, valid)
-        keys_s = [k[order] for k in keys]
-        valid_s = valid[order]
+        keys_s = ([sorted_key] if sorted_key is not None
+                  else [take(k, order) for k in keys])
+        # every order above puts the valid rows first
+        valid_s = jnp.arange(n, dtype=jnp.int32) < valid.sum(
+            dtype=jnp.int32)
 
     with stage_scope("reduce"):
         # boundary: first row of each (valid) group
@@ -164,57 +212,55 @@ def segment_aggregate(keys: list[jnp.ndarray],
         for k in keys_s:
             diff = diff | _shift_ne(k)
         boundary = diff & valid_s
-        seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-        n_groups = boundary.sum().astype(jnp.int32)
-        # invalid rows (sorted last) land in the last group's run with
-        # identity contributions; the clip only guards the all-invalid case
-        seg_id = jnp.clip(seg_id, 0, None)
 
-        # group g's run is [starts[g], ends[g]) in sorted space.  One
-        # boundary per group ⇒ the scatter indices are unique ⇒ scatter-set
-        # (no combining — fast on TPU, unlike scatter-add/min)
-        gpos = jnp.full(n + 1, n, jnp.int32).at[
-            jnp.where(boundary, seg_id, n + 1)].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
-        starts = gpos[:n]
-        ends = gpos[1:]  # last real group runs to n (trailing invalid rows
-        #                  carry identity contributions, as before)
+        # group g's run is [starts[g], ends[g]) in sorted space: the
+        # first out + 1 boundaries' positions; slots past the group
+        # count hold n, so the last real group runs to n (trailing
+        # invalid rows carry identity contributions)
+        pos, n_groups = survivor_positions(boundary, out + 1)
+        if pos.shape[0] == out:          # out == n: the last end is n
+            pos = jnp.concatenate([pos, jnp.full((1,), n, jnp.int32)])
+        starts, ends = pos[:out], pos[1:]
+        last = jnp.clip(ends - 1, 0, n - 1)
 
-        group_keys = []
+        def run_sum(x, acc_dtype):
+            # each run's sum: a prefix-sum difference at its two ends
+            prefix = jnp.concatenate([jnp.zeros(1, acc_dtype),
+                                      jnp.cumsum(x.astype(acc_dtype))])
+            return take(prefix, ends) - take(prefix, starts)
+
         first_c = jnp.minimum(starts, n - 1)
         if out_keys is None:
-            group_keys = [k[first_c] for k in keys_s]
+            group_keys = [take(k, first_c) for k in keys_s]
         else:
-            first_idx = order[first_c]
-            group_keys = [k[first_idx] for k in out_keys]
+            first_idx = take(order, first_c)
+            group_keys = [take(k, first_idx) for k in out_keys]
 
         results = []
         for arr, kind, value_valid in values:
-            arr_s = arr[order]
             contrib_valid = valid_s if value_valid is None else (
-                valid_s & value_valid[order])
+                valid_s & take(value_valid, order))
+            arr_s = None if kind == "count" else take(arr, order)
             if kind == "count":
-                res = _run_sum(contrib_valid.astype(jnp.int32), starts, ends,
-                               jnp.int32).astype(jnp.int64)
+                res = run_sum(contrib_valid.astype(jnp.int32),
+                              jnp.int32).astype(jnp.int64)
             elif kind == "sum":
                 z = jnp.zeros((), dtype=arr_s.dtype)
                 x = jnp.where(contrib_valid, arr_s, z)
                 if jnp.issubdtype(arr_s.dtype, jnp.floating):
-                    sv = _segmented_scan(x, boundary, jnp.add)
-                    res = sv[jnp.clip(ends - 1, 0, n - 1)]
+                    res = take(_segmented_scan(x, boundary, jnp.add), last)
                 else:
-                    res = _run_sum(x, starts, ends, jnp.int64)
+                    res = run_sum(x, jnp.int64)
             elif kind in ("min", "max"):
                 ident = _identity_for(arr_s.dtype, kind)
                 x = jnp.where(contrib_valid, arr_s, ident)
                 op = jnp.minimum if kind == "min" else jnp.maximum
-                sv = _segmented_scan(x, boundary, op)
-                res = sv[jnp.clip(ends - 1, 0, n - 1)]
+                res = take(_segmented_scan(x, boundary, op), last)
             else:
                 raise ValueError(f"unsupported aggregate kind {kind!r}")
             results.append(res)
 
-        group_valid = jnp.arange(n) < n_groups
+        group_valid = jnp.arange(out) < n_groups
         group_keys = [jnp.where(group_valid, k,
                                 jnp.zeros((), dtype=k.dtype))
                       for k in group_keys]

@@ -109,6 +109,12 @@ WINDOW_PRESORTED_TOTAL = "window_presorted_total"
 # into one int64 with the row's position (the planner knew every key's
 # range), else the keys and the validity of a lexsort
 JOIN_SORT_OPERANDS_TOTAL = "join_sort_operands_total"
+# the index slots of every gather the executed statements' converged
+# programs' sort-path group-bys made (ops/aggregate.py
+# segment_aggregate: the values in sorted order at the input's width,
+# each group's first row, keys and reductions at the group capacity),
+# summed over both levels of a repartition and over the mesh
+AGG_SORT_GATHER_SLOTS_TOTAL = "agg_sort_gather_slots_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
@@ -235,6 +241,7 @@ ALL_COUNTERS = [
     LOOKUP_PROBE_SLOTS_TOTAL, AGG_BUCKET_SLOTS_TOTAL,
     WINDOW_ROWS_TOTAL, WINDOW_OVER_GROUP_TOTAL,
     WINDOW_SORTS_TOTAL, WINDOW_PRESORTED_TOTAL, JOIN_SORT_OPERANDS_TOTAL,
+    AGG_SORT_GATHER_SLOTS_TOTAL,
     BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     DEFERRED_GATHER_SLOTS_TOTAL, DEFERRED_TOPDOWN_CHAINS_TOTAL,

@@ -82,6 +82,11 @@ HOST_PATH_READERS = {
     "deferred_gather_slots": (
         "count/stmt", "program_counter", "device program",
         ALL_CELLS[1:] + ["tpcds10.q89"], 900.0),
+    # the index slots of the sort-path group-bys' gathers, over the
+    # cells whose group-by takes the sort path
+    "agg_sort_gather_slots": (
+        "count/stmt", "program_counter", "device program",
+        ["tpch1.q3", "tpch4.q3", "tpcds10.q89", "tpcds10cs.q57"], 1200.0),
 }
 BETTER_HIGHER = {"resident_intermediates"}
 
@@ -112,7 +117,8 @@ def _built_run():
                              "gc_pause_us_total": 1000,
                              "gc_pauses_total": 2,
                              "intermediate_resident_total": 3,
-                             "deferred_gather_slots_total": 4 * 900},
+                             "deferred_gather_slots_total": 4 * 900,
+                             "agg_sort_gather_slots_total": 4 * 1200},
                 "profile": None},
         records=records, trace_dir="/nonexistent",
         cell=SimpleNamespace(config={"n_devices": 4}))

@@ -134,6 +134,181 @@ class TestSegmentAggregate:
         assert len(traces) == 1
 
 
+class TestGroupCapacity:
+    """`segment_aggregate` at `out` group slots is its N-wide result cut
+    to `out`, bit for bit — keys, validity, the group count, sums,
+    counts, min and max — on the packed key (the sorted key taken from
+    `sort_packed`, not gathered) and on the lexsort; only the values
+    and their null masks are gathered at N."""
+
+    N, EXTENT1, EXTENT2 = 3000, 700, 9
+
+    def _inputs(self, rng, case):
+        n = self.N
+        k1 = rng.integers(0, self.EXTENT1, n).astype(np.int64)
+        k2 = rng.integers(0, self.EXTENT2, n).astype(np.int32)
+        k2_null = rng.random(n) < 0.1
+        valid = rng.random(n) > 0.2
+        if case == "all_invalid":
+            valid[:] = False
+        if case == "int64_sum":
+            v = rng.integers(-10**12, 10**12, n).astype(np.int64)
+        else:
+            v = rng.normal(size=n).astype(np.float32)
+        vv = None if case != "null_values" else rng.random(n) > 0.3
+        return k1, k2, k2_null, valid, v, vv
+
+    def _packed(self, k1, k2, k2_null, valid, nulls_last):
+        # `PlanCompiler._pack_group_keys`' layout: slot 0 of each key is
+        # NULL, but for a `nulls_last` key's, whose NULL is its last slot
+        w2 = self.EXTENT2 + 1
+        s1 = k1 + 1
+        s2 = (np.where(k2_null, self.EXTENT2, k2) if nulls_last
+              else np.where(k2_null, 0, k2.astype(np.int64) + 1))
+        packed = np.where(valid, s1 * w2 + s2, np.iinfo(np.int64).max)
+        return packed, (self.EXTENT1 + 1) * w2
+
+    @pytest.mark.parametrize("path,case", [
+        ("packed", "out_eq_n"), ("packed", "out_above_groups"),
+        ("packed", "overflow"), ("packed", "all_invalid"),
+        ("packed", "int64_sum"), ("packed", "null_values"),
+        ("packed", "nulls_last"),
+        ("lexsort", "out_eq_n"), ("lexsort", "out_above_groups"),
+        ("lexsort", "overflow"), ("lexsort", "all_invalid"),
+        ("lexsort", "int64_sum"), ("lexsort", "null_values"),
+    ])
+    def test_capacity_is_the_full_width_cut(self, rng, path, case):
+        from citus_tpu.ops.aggregate import sort_packed
+
+        k1, k2, k2_null, valid, v, vv = self._inputs(rng, case)
+        flag = k2_null.astype(np.int32)
+        k2z = np.where(k2_null, 0, k2)
+        j = [jnp.asarray(a) for a in (k1, k2z, flag)]
+        jv = jnp.asarray(v)
+        jvv = None if vv is None else jnp.asarray(vv)
+        values = [(jv, "sum", jvv), (jv, "count", jvv),
+                  (jv, "min", jvv), (jv, "max", jvv)]
+        groups = len({(a, b, c) for a, b, c, ok
+                      in zip(k1, k2z, flag, valid) if ok})
+        out = {"out_eq_n": self.N, "out_above_groups": groups + 37,
+               "overflow": groups // 3}.get(case, groups + 1)
+        jvalid = jnp.asarray(valid)
+        gathered = []
+        if path == "packed":
+            packed, top = self._packed(k1, k2, k2_null, valid,
+                                       case == "nulls_last")
+            jp = jnp.asarray(packed)
+            sorted_key, order = sort_packed(jp, jvalid, top)
+            # the reference sorts and gathers the key itself
+            full = segment_aggregate([jp], values, jvalid, out_keys=j)
+            got = segment_aggregate([jp], values, jvalid, out_keys=j,
+                                    order=order, sorted_key=sorted_key,
+                                    out=out, gathered=gathered)
+            wide = 2             # the value, its mask, nothing of the key
+        else:
+            full = segment_aggregate(j, values, jvalid)
+            got = segment_aggregate(j, values, jvalid, out=out,
+                                    gathered=gathered)
+            wide = 2 + len(j)    # the keys in sorted order besides
+        if vv is None:
+            wide -= 1
+        gk, res, gv, ng = got
+        assert int(ng) == int(full[3]) == groups
+        assert gv.shape == (out,)
+        np.testing.assert_array_equal(np.asarray(gv),
+                                      np.asarray(full[2])[:out])
+        for a, b in zip(full[0] + full[1], gk + res):
+            assert b.shape == (out,) and b.dtype == a.dtype
+            np.testing.assert_array_equal(np.asarray(b),
+                                          np.asarray(a)[:out])
+        # groups past `out` are counted, never silently kept
+        assert int(np.asarray(gv).sum()) == min(groups, out)
+        assert sorted(w for w in gathered if w > out) == [self.N] * (
+            wide if out < self.N else 0)
+        if out < self.N:
+            assert all(w in (out, self.N) for w in gathered)
+
+    def test_gathered_lists_nothing_twice(self, rng):
+        # the companion count shares its aggregate's null mask: one gather
+        n = 512
+        k = jnp.asarray(rng.integers(0, 40, n).astype(np.int64))
+        v = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        vv = jnp.asarray(rng.random(n) > 0.5)
+        gathered = []
+        segment_aggregate([k], [(v, "sum", vv), (v, "count", vv)],
+                          jnp.ones(n, bool), out=64, gathered=gathered)
+        # key, value, mask at n; first row, key, sum, two prefix reads
+        assert sorted(gathered) == [64] * 4 + [n] * 3
+
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_statement_groups_at_its_converged_capacity(
+            self, tmp_path, monkeypatch, n_devices):
+        """Through `Session.execute`: a sort-path GROUP BY (1,500 keys
+        spread over 91,500 slots, past the flat grid) whose capacity
+        converges below its input answers as the oracle at every
+        execution with no capacity retry, its converged program groups
+        at that capacity, and `agg_sort_gather_slots_total` moves by
+        what the program's group-bys gathered, over the mesh."""
+        import citus_tpu
+        import citus_tpu.executor.compiler as C
+        from citus_tpu.ingest.copy_from import _ingest_batch
+
+        real, calls = C.segment_aggregate, []
+
+        def recording(keys, values, valid, **kw):
+            got = real(keys, values, valid, **kw)
+            calls.append((valid.shape[0], kw["out"], sum(kw["gathered"])))
+            return got
+
+        monkeypatch.setattr(C, "segment_aggregate", recording)
+        sess = citus_tpu.connect(data_dir=str(tmp_path / "d"),
+                                 n_devices=n_devices,
+                                 serving_result_cache_bytes=0,
+                                 compute_dtype="float64")
+        try:
+            sess.execute("create table gcap (id bigint, g bigint not null,"
+                         " v double precision)")
+            sess.create_distributed_table("gcap", "id", shard_count=4)
+            n = 20_000
+            i = np.arange(n, dtype=np.int64)
+            g = (i % 1500) * 61
+            v = [None if k % 7 == 0 else float(k % 97) for k in range(n)]
+            _ingest_batch(sess, "gcap", ["id", "g", "v"], [i, g, v],
+                          pre_typed=True)
+            want = {}
+            for gk, x in zip(g.tolist(), v):
+                s_, c_, lo, hi, rows = want.get(gk, (0.0, 0, None, None, 0))
+                if x is not None:
+                    s_, c_ = s_ + x, c_ + 1
+                    lo = x if lo is None else min(lo, x)
+                    hi = x if hi is None else max(hi, x)
+                want[gk] = (s_, c_, lo, hi, rows + 1)
+            sql = ("select g, sum(v), count(v), min(v), max(v), count(*) "
+                   "from gcap group by g")
+            moved = []
+            for _ in range(3):
+                before = sess.stats.counters.snapshot()
+                rows = sess.execute(sql).rows()
+                after = sess.stats.counters.snapshot()
+                moved.append({k: after[k] - before[k] for k in (
+                    "capacity_retries", "agg_sort_gather_slots_total")})
+                assert {r[0]: tuple(r[1:]) for r in rows} == want
+                if len(moved) == 2:
+                    traced = len(calls)
+            assert len(calls) == traced      # the third compiled nothing
+            assert all(m["capacity_retries"] == 0 for m in moved)
+            # the partial level, and the merge after the exchange
+            levels = calls[-(1 if n_devices == 1 else 2):]
+            width, out, _ = levels[0]
+            assert out < width
+            assert all(m["agg_sort_gather_slots_total"]
+                       == n_devices * sum(c[2] for c in levels)
+                       for m in moved[1:])
+        finally:
+            sess.close()
+
+
 class TestLookupJoin:
     def test_pk_fk_join_matches_dict_oracle(self, rng):
         m, n = 500, 3000

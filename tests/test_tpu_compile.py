@@ -172,6 +172,58 @@ def test_compact_compiles(chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_sort_path_groupby_compiles_at_its_capacity(chip):
+    """The sort-path group-by as the compiler dispatches it
+    (`PlanCompiler._segment_aggregate_maybe_packed`) — five int32 group
+    keys packed into one int64, a nullable float32 sum and its companion
+    count — over 65,536 rows under a capacity of 2,048 groups.  At the
+    rows' width only the value and its null mask are gathered, in
+    sorted order: the packed key and the validity come from the sort,
+    the group starts from a second sort, so nothing scatters; each
+    group's first row, keys and reductions are read at the capacity."""
+    import collections
+    import re
+    from types import SimpleNamespace
+
+    from citus_tpu.executor.compiler import Capacities, PlanCompiler
+    from citus_tpu.stats.tracing import stage_scope
+
+    n, cap = 65_536, 2_048
+    # the extents of TPC-DS Q57's group keys: call centers, brands,
+    # categories, years, months
+    extents = (12, 533, 99, 201, 12)
+    node = SimpleNamespace(key_ranges=tuple((0, e, False) for e in extents),
+                           nulls_last=())
+    key_meta = [(f"k{i}", False) for i in range(len(extents))]
+    pc = PlanCompiler.__new__(PlanCompiler)
+    pc.caps, pc.n_dev = Capacities({}, {}, {id(node): cap}), 1
+
+    def groupby(keys, price, price_null, valid):
+        pc._dense_oob = pc._overflow = jnp.zeros((), jnp.int64)
+        pc._stage_actual, pc._stage_width = {}, {}
+        pc._agg_sort_gather_slots = 0
+        ok = ~price_null
+        with stage_scope("agg_sort"):
+            gk, res, gvalid = pc._segment_aggregate_maybe_packed(
+                node, list(keys), key_meta,
+                [(price, "sum", ok), (price, "count", ok)], valid)
+        return gk, res, gvalid, pc._overflow
+
+    c = _compile(groupby, tuple(chip((n,), jnp.int32) for _ in extents),
+                 chip((n,), jnp.float32), chip((n,), jnp.bool_),
+                 chip((n,), jnp.bool_))
+    wide = collections.Counter()
+    for ln in c.as_text().splitlines():
+        m = re.search(r"= (\w+)\[(\d+)[^\]]*\]\S* (gather|scatter)\(", ln)
+        if m and (m.group(3) == "scatter" or int(m.group(2)) > cap):
+            wide[(m.group(3), m.group(1))] += 1
+    print(f"gathers and scatters wider than {cap} slots: {dict(wide)}")
+    assert wide == {("gather", "f32"): 1, ("gather", "pred"): 1}
+    # the counter's count: those two at n; the first row, five keys,
+    # the sum and the count's two prefix reads at the capacity
+    assert pc._agg_sort_gather_slots == 2 * n + 9 * cap
+
+
 def test_bucketed_grid_aggregate_xla_compiles(chip, monkeypatch):
     """The high-cardinality group-by at SF1 (group by l_orderkey over
     6 M rows; planner/plan.py `group_bucketed`), on the branch the
