@@ -104,7 +104,9 @@ def caps_signature(plan: QueryPlan, caps) -> tuple:
             tuple(sorted((order[k], v) for k, v in caps.agg_out.items())),
             caps.dense_off,
             tuple(sorted((order[k], v) for k, v in caps.scan_out.items())),
-            caps.output_repart)
+            caps.output_repart,
+            tuple(sorted((order[k], v)
+                         for k, v in caps.join_range.items())))
 
 
 def feeds_signature(plan: QueryPlan, feeds) -> tuple:

@@ -77,6 +77,17 @@ def _round_cap(n: int) -> int:
     return max(128, int(math.ceil(n / 128.0)) * 128)
 
 
+def _compaction(valid, k: int):
+    """A compaction's row index: (the positions of the first k True rows
+    of `valid`, padding slots reading row 0; their row mask; the True
+    rows past k, which count as capacity overflow)."""
+    por, n_valid = survivor_positions(valid, k)
+    out_valid = jnp.arange(k, dtype=jnp.int32) < n_valid
+    # padding slots read row 0, never the sentinel
+    por = jnp.where(out_valid, por, 0)
+    return por, out_valid, jnp.maximum(n_valid - k, 0).astype(jnp.int64)
+
+
 def _scan_ids(plan: QueryPlan) -> list[int]:
     from .feed import walk_plan
 
@@ -203,7 +214,7 @@ def unpack_outputs(packed: np.ndarray, out_meta):
 
 # PlanCompiler.tallies of a program that recorded nothing: one count a
 # counter that Executor.count_picks bumps from them
-NO_TALLIES = (0,) * 12
+NO_TALLIES = (0,) * 13
 
 
 @dataclass
@@ -242,12 +253,18 @@ class Capacities:
     # per-(source, target) bucket slots for the INSERT..SELECT output
     # shuffle (QueryPlan.output_repart); None when the plan has none
     output_repart: int | None = None
+    # probe slots of a fused inner lookup whose probe rows inside the
+    # build keys' range compact to this many before the probe (set by
+    # capacity feedback alone, where the range keeps few enough rows)
+    join_range: dict[int, int] = None
 
     def __post_init__(self):
         if self.agg_out is None:
             self.agg_out = {}
         if self.scan_out is None:
             self.scan_out = {}
+        if self.join_range is None:
+            self.join_range = {}
 
     def grown(self, overflow: int) -> "Capacities":
         """Retry sizing: at least double, and at least enough for the
@@ -263,7 +280,8 @@ class Capacities:
                           self.dense_off,
                           {k: g(v) for k, v in self.scan_out.items()},
                           g(self.output_repart)
-                          if self.output_repart else None)
+                          if self.output_repart else None,
+                          {k: g(v) for k, v in self.join_range.items()})
 
 
 class PlanCompiler:
@@ -365,7 +383,8 @@ class PlanCompiler:
                              tally.recomposed, self._window_sorts,
                              self._window_presorted,
                              self._join_sort_operands,
-                             self._agg_sort_gather_slots)
+                             self._agg_sort_gather_slots,
+                             self._lookup_range_compactions)
             return packed
 
         def traced(flat_feeds):
@@ -407,6 +426,9 @@ class PlanCompiler:
                 # the index slots of the sort-path group-bys' gathers,
                 # over the mesh: the same rule
                 self._agg_sort_gather_slots = 0
+                # the fused lookups whose probe side compacts to the
+                # build keys' range: the same rule
+                self._lookup_range_compactions = 0
                 out = self._exec(self.plan.root, blocks)
                 if self.plan.output_repart is not None:
                     # INSERT..SELECT device routing: shuffle the final
@@ -477,14 +499,16 @@ class PlanCompiler:
         # it composed from the top, the middle indexes gathered
         # through twice, its windows' lexsorts, the window ORDER BY
         # specs it read in the groups' order, the operands of its
-        # multi-key joins' build-side sorts and the index slots of its
-        # sort-path group-bys' gathers): deferred_columns_total /
-        # deferred_gathers_total / lookup_probe_slots_total /
-        # agg_bucket_slots_total / window_rows_total /
+        # multi-key joins' build-side sorts, the index slots of its
+        # sort-path group-bys' gathers and the fused lookups whose probe
+        # side it compacts to the build keys' range):
+        # deferred_columns_total / deferred_gathers_total /
+        # lookup_probe_slots_total / agg_bucket_slots_total /
+        # window_rows_total /
         # deferred_gather_slots_total / deferred_topdown_chains_total /
         # deferred_topdown_recomposed_total / window_sorts_total /
         # window_presorted_total / join_sort_operands_total /
-        # agg_sort_gather_slots_total
+        # agg_sort_gather_slots_total / lookup_range_compactions_total
         self.tallies = self._tallies
         s_cols, s_nulls, s_valid, _ = shapes
         out_meta = []
@@ -881,12 +905,8 @@ class PlanCompiler:
         to the filtered-estimate size.  More survivors than k counts
         as capacity overflow (host retries with doubled slots)."""
         with stage_scope("compact"):
-            por, n_valid = survivor_positions(blk.valid, k)
-            out_valid = jnp.arange(k, dtype=jnp.int32) < n_valid
-            # padding slots read row 0, never the sentinel
-            por = jnp.where(out_valid, por, 0)
-            self._overflow = self._overflow + \
-                jnp.maximum(n_valid - k, 0).astype(jnp.int64)
+            por, out_valid, past = _compaction(blk.valid, k)
+            self._overflow = self._overflow + past
             return blk.take(por, out_valid)
 
     def _project(self, blk: Block, exprs) -> Block:
@@ -1191,6 +1211,9 @@ class PlanCompiler:
             bblk, bkeys, bmatch = rblk, rkeys, rmatch
             pblk, pkeys, pmatch = lblk, lkeys, lmatch
             extents = getattr(node, "right_key_extents", ())
+        if node.join_type == "inner":
+            pblk, pkeys, pmatch = self._range_compact(
+                node, bkeys, bmatch, pblk, pkeys, pmatch)
         self._lookup_probe_slots += self.n_dev * int(pblk.valid.shape[0])
         dense = self._dense_for(extents, bkeys)
         if self.sorted_lookup_shape(node, self.caps.dense_off):
@@ -1244,6 +1267,44 @@ class PlanCompiler:
                     and k < out_valid.shape[0]):
                 blk = self._compact(blk, k)
             return blk
+
+    def _range_compact(self, node: JoinNode, bkeys, bmatch, pblk: Block,
+                       pkeys, pmatch):
+        """A fused inner lookup's probe side cut to the rows whose keys
+        lie inside the build side's matchable key range, before the
+        probe: → (probe block, probe keys, probe match mask).
+
+        A probe row outside [min, max] of a key, or with a NULL key,
+        matches nothing, so no answer changes.  The rows inside are
+        recorded (`join_range`); where capacity feedback installed a
+        capacity (`Capacities.join_range`) and compacting this program's
+        probe side to it pays — the width it was installed against may
+        have shrunk since, under a compaction installed with it — they
+        compact to it by one sort of positions, the keys follow by one
+        gather at the new size and the probe runs there.  A date filter
+        that keeps one interval of a fact table's date keys probes the
+        interval's rows alone.  More rows inside than the capacity count
+        as overflow (the host retries doubled)."""
+        from .runner import compaction_pays
+
+        with stage_scope("join_range"):
+            inside = pmatch
+            for b, p in zip(bkeys, pkeys):
+                info = jnp.iinfo(b.dtype)
+                lo = jnp.where(bmatch, b, info.max).min()
+                hi = jnp.where(bmatch, b, info.min).max()
+                inside = inside & (p >= lo) & (p <= hi)
+            n = inside.shape[0]
+            self._record(id(node), "join_range", inside.sum(), n)
+            k = self.caps.join_range.get(id(node))
+            if k is None or not compaction_pays(k, n):
+                return pblk, pkeys, pmatch
+            self._lookup_range_compactions += 1
+            with stage_scope("compact"):
+                por, out_valid, past = _compaction(inside, k)
+                self._overflow = self._overflow + past
+            return (pblk.take(por, out_valid), [p[por] for p in pkeys],
+                    out_valid)
 
     def _exec_join(self, node: JoinNode, feeds) -> Block:
         lblk, rblk, lkeys, lmatch, rkeys, rmatch = \

@@ -64,7 +64,9 @@ from ..errors import StorageError
 # key ranges compiles another program
 # 9: a twelfth, the index slots of its sort-path group-bys' gathers; a
 # sort-path group-by under a capacity compiles another program
-EXEC_CACHE_VERSION = 9
+# 10: a thirteenth, its fused lookups whose probe side compacts to the
+# build keys' range; every fused inner lookup records that range's rows
+EXEC_CACHE_VERSION = 10
 EXEC_CACHE_DIR = "exec_cache"
 # on-disk entry bound per data_dir: retry/tightening intermediates and
 # dead shapes age out coldest-first (hits, then insertion sequence)

@@ -69,7 +69,9 @@ def compaction_pays(k: int, n: int) -> bool:
     """Whether compacting n slots down to k is taken as worth the pass:
     asked by capacity planning for scan, join and bucketed-grid outputs;
     feedback tightening holds the same kinds to the same ratio
-    (TIGHTEN_THRESHOLD)."""
+    (TIGHTEN_THRESHOLD), and the compiler holds a lookup's range
+    capacity to it against the probe side's width in the program it
+    builds."""
     return k * COMPACTION_MIN_SHRINK < n
 
 
@@ -817,18 +819,18 @@ class Executor:
         window_rows_total, deferred_gather_slots_total,
         deferred_topdown_chains_total,
         deferred_topdown_recomposed_total, window_sorts_total,
-        window_presorted_total, join_sort_operands_total and
-        agg_sort_gather_slots_total take
-        `tallies`, the twelve counts the converged program's compiler
-        recorded at trace time (PlanCompiler.tallies; they ride in the
-        plan-cache entry)."""
+        window_presorted_total, join_sort_operands_total,
+        agg_sort_gather_slots_total and lookup_range_compactions_total
+        take `tallies`, the thirteen counts the converged program's
+        compiler recorded at trace time (PlanCompiler.tallies; they ride
+        in the plan-cache entry)."""
         if self.counters is None:
             return
         from ..stats import counters as sc
 
         (carried, gathered, probe_slots, bucket_slots, window_rows,
          gather_slots, topdown, recomposed, window_sorts, presorted,
-         join_operands, agg_sort_slots) = tallies
+         join_operands, agg_sort_slots, range_compactions) = tallies
         if carried:
             self.counters.increment(sc.DEFERRED_COLUMNS_TOTAL, carried)
             self.counters.increment(sc.DEFERRED_GATHERS_TOTAL, gathered)
@@ -858,6 +860,9 @@ class Executor:
         if agg_sort_slots:
             self.counters.increment(sc.AGG_SORT_GATHER_SLOTS_TOTAL,
                                     agg_sort_slots)
+        if range_compactions:
+            self.counters.increment(sc.LOOKUP_RANGE_COMPACTIONS_TOTAL,
+                                    range_compactions)
         group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         n_over_groups = sum(1 for nd in nodes if isinstance(nd, WindowNode)
@@ -893,7 +898,7 @@ class Executor:
             self.counters.increment(sc.BROADCAST_JOINS_TOTAL, nbc)
 
     # ------------------------------------------------------------------
-    CAPS_MEMO_VERSION = 8  # bump when capacity semantics change
+    CAPS_MEMO_VERSION = 9  # bump when capacity semantics change
 
     def _memo_path(self) -> str:
         import os
@@ -1026,11 +1031,17 @@ class Executor:
     TIGHTEN_SLACK = 1.3
     # agg_grid = the bucketed grid's live-group count: it shares the
     # agg_out capacity table but shrinking it INSTALLS a compaction
-    # pass over the slot grid, so it pays the compaction economics
+    # pass over the slot grid, so it pays the compaction economics.
+    # join_range = a fused inner lookup's probe rows inside the build
+    # keys' range: it has no capacity until this installs one, and
+    # installing one INSTALLS a compaction pass before the probe (one
+    # sort at the probe side's width, the keys' gather at the new
+    # size), so it too shrinks only by compaction_pays' ratio
     TIGHTEN_THRESHOLD = {"repartition": 0.85, "agg_out": 0.85,
                          "scan_out": 1.0 / COMPACTION_MIN_SHRINK,
                          "join_out": 1.0 / COMPACTION_MIN_SHRINK,
-                         "agg_grid": 1.0 / COMPACTION_MIN_SHRINK}
+                         "agg_grid": 1.0 / COMPACTION_MIN_SHRINK,
+                         "join_range": 1.0 / COMPACTION_MIN_SHRINK}
 
     def _tighten_caps(self, plan: QueryPlan, caps: Capacities,
                       stage_keys, actuals) -> Capacities | None:
@@ -1044,8 +1055,10 @@ class Executor:
         new = {"repartition": dict(caps.repartition),
                "join_out": dict(caps.join_out),
                "agg_out": dict(caps.agg_out),
-               "scan_out": dict(caps.scan_out)}
+               "scan_out": dict(caps.scan_out),
+               "join_range": dict(caps.join_range)}
         changed = False
+        ranges = {}
         for (widx, kind, width), actual in zip(stage_keys, actuals):
             nid = rev.get(widx)
             if nid is None:
@@ -1056,11 +1069,21 @@ class Executor:
             if t < cur * self.TIGHTEN_THRESHOLD[kind]:
                 table[nid] = t
                 changed = True
+                if kind == "join_range":
+                    ranges[nid] = int(actual)
         if not changed:
             return None
+        # a lookup's output capacity that already holds its range's rows
+        # serves the range too: the probe side compacts once, to it, and
+        # the compaction after the probe finds nothing left to cut
+        for nid, actual in ranges.items():
+            k2 = new["join_out"].get(nid)
+            if k2 is not None and actual <= k2 < new["join_range"][nid]:
+                new["join_range"][nid] = k2
         return Capacities(new["repartition"], new["join_out"],
                           new["agg_out"], caps.dense_off,
-                          new["scan_out"], caps.output_repart)
+                          new["scan_out"], caps.output_repart,
+                          new["join_range"])
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1075,22 +1098,23 @@ class Executor:
                 {order[k]: v for k, v in caps.agg_out.items()},
                 caps.dense_off,
                 {order[k]: v for k, v in caps.scan_out.items()},
-                caps.output_repart)
+                caps.output_repart,
+                {order[k]: v for k, v in caps.join_range.items()})
 
     @staticmethod
     def _caps_from_order(plan: QueryPlan, memo: tuple) -> Capacities:
         from .cache import plan_order
 
         rev = {i: nid for nid, i in plan_order(plan).items()}
-        repart, join_out, agg_out, dense_off, scan_out, output_repart = \
-            memo
+        (repart, join_out, agg_out, dense_off, scan_out, output_repart,
+         join_range) = memo
 
         def by_node(table: dict) -> dict:
             return {rev[i]: v for i, v in table.items()}
 
         return Capacities(by_node(repart), by_node(join_out),
                           by_node(agg_out), dense_off, by_node(scan_out),
-                          output_repart)
+                          output_repart, by_node(join_range))
 
     def _initial_capacities(self, plan: QueryPlan, feeds,
                             dense_off: bool = False) -> Capacities:

@@ -115,6 +115,12 @@ JOIN_SORT_OPERANDS_TOTAL = "join_sort_operands_total"
 # each group's first row, keys and reductions at the group capacity),
 # summed over both levels of a repartition and over the mesh
 AGG_SORT_GATHER_SLOTS_TOTAL = "agg_sort_gather_slots_total"
+# the fused inner lookups of the executed statements' converged programs
+# whose probe side compacts to the rows inside the build keys' range
+# before the probe (executor/compiler.py _range_compact: capacity
+# feedback installed a capacity there, where the range keeps few
+# enough rows for the compaction to pay)
+LOOKUP_RANGE_COMPACTIONS_TOTAL = "lookup_range_compactions_total"
 # broadcast joins (a replicated side — a reference table — joined in
 # place on every device) in the executed statements' converged plans
 BROADCAST_JOINS_TOTAL = "broadcast_joins_total"
@@ -241,7 +247,7 @@ ALL_COUNTERS = [
     LOOKUP_PROBE_SLOTS_TOTAL, AGG_BUCKET_SLOTS_TOTAL,
     WINDOW_ROWS_TOTAL, WINDOW_OVER_GROUP_TOTAL,
     WINDOW_SORTS_TOTAL, WINDOW_PRESORTED_TOTAL, JOIN_SORT_OPERANDS_TOTAL,
-    AGG_SORT_GATHER_SLOTS_TOTAL,
+    AGG_SORT_GATHER_SLOTS_TOTAL, LOOKUP_RANGE_COMPACTIONS_TOTAL,
     BROADCAST_JOINS_TOTAL,
     DEFERRED_COLUMNS_TOTAL, DEFERRED_GATHERS_TOTAL,
     DEFERRED_GATHER_SLOTS_TOTAL, DEFERRED_TOPDOWN_CHAINS_TOTAL,

@@ -232,14 +232,18 @@ STAGE_NAMES: dict[str, str] = {
              "gather a probe row)",
     "carry": "sub: lookup_join — the scans that carry each build row's "
              "index forward to its probe rows",
+    "join_range": "fused inner lookup: the build keys' range, the probe "
+                  "rows inside it, their compaction and the keys' gather "
+                  "at its size",
     "join_out": "join keys, pair emission / build-column gathers, "
                 "residual filter, compaction",
     "expand": "sub: join_out — the general (build side not unique) "
               "path's pair emission: match ranges, prefix sum, one slot "
               "a pair",
-    "compact": "sub: scan_out, join_out, agg_out — survivors' positions "
-               "by one sort; the columns follow as that row index and "
-               "are gathered where they are read (`deferred`)",
+    "compact": "sub: scan_out, join_range, join_out, agg_out — "
+               "survivors' positions by one sort; the columns follow as "
+               "that row index and are gathered where they are read "
+               "(`deferred`)",
     "deferred": "sub: any stage that reads a column — the gather of a "
                 "column that crossed a compaction or a lookup as a row "
                 "index (executor/batch.py Block.take), and the "

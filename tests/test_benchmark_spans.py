@@ -87,8 +87,14 @@ HOST_PATH_READERS = {
     "agg_sort_gather_slots": (
         "count/stmt", "program_counter", "device program",
         ["tpch1.q3", "tpch4.q3", "tpcds10.q89", "tpcds10cs.q57"], 1200.0),
+    # the fused lookups compacted to their build keys' range, over the
+    # cells whose programs fuse an inner lookup
+    "lookup_range_compactions": (
+        "count/stmt", "program_counter", "device program",
+        ["tpch1.q3", "tpch4.q3", "ssb1.q4_1", "ssb10.q4_1", "tpcds10.q89",
+         "tpcds10cs.q57"], 1.0),
 }
-BETTER_HIGHER = {"resident_intermediates"}
+BETTER_HIGHER = {"resident_intermediates", "lookup_range_compactions"}
 
 
 def _built_run():
@@ -118,7 +124,8 @@ def _built_run():
                              "gc_pauses_total": 2,
                              "intermediate_resident_total": 3,
                              "deferred_gather_slots_total": 4 * 900,
-                             "agg_sort_gather_slots_total": 4 * 1200},
+                             "agg_sort_gather_slots_total": 4 * 1200,
+                             "lookup_range_compactions_total": 4},
                 "profile": None},
         records=records, trace_dir="/nonexistent",
         cell=SimpleNamespace(config={"n_devices": 4}))

@@ -488,3 +488,398 @@ def test_plan_compiler_publishes_the_tally(tmp_path):
     assert carried > gathered > 0
     # the reopened session loaded the program, and its counts with it
     assert counts[1][:2] == (carried, gathered) and counts[1][2] >= 1
+
+
+# -- a fused inner lookup's probe side cut to the build keys' range ---------
+
+def _range_lookup(pc, probe, build, k_range=None, pmatch=None, bmatch=None,
+                  keys=("p_key", "b_key")):
+    """An inner `_exec_lookup_join` of `probe` into `build` whose probe
+    side compacts to `k_range` slots before the probe; `keys` names the
+    probe and build key columns (a tuple of pairs: several keys, probed
+    by the sorted-bounds arm)."""
+    pairs = [keys] if isinstance(keys[0], str) else list(keys)
+    node = SimpleNamespace(
+        join_type="inner", build_side="right", residual=None,
+        right_key_extents=((100, 200),) if len(pairs) == 1 else (),
+        lookup_sorted=False, fuse_lookup=True, key_pack=None)
+    pc._lookup_range_compactions = 0
+    pc._join_sort_operands = 0
+    if k_range is not None:
+        pc.caps.join_range[id(node)] = k_range
+    return pc._exec_lookup_join(
+        node, probe, build, [probe.columns[p] for p, _b in pairs],
+        probe.valid if pmatch is None else pmatch,
+        [build.columns[b] for _p, b in pairs],
+        build.valid if bmatch is None else bmatch), node
+
+
+LO, HI = 110, 125   # the build keys one interval keeps
+
+
+def _interval_build(build, lo: int = LO, hi: int = HI):
+    """The build rows whose key lies in [lo, hi): one key interval, as a
+    date filter keeps."""
+    key = np.asarray(build.columns["b_key"])
+    return jnp.asarray((key >= lo) & (key < hi))
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_range_compaction_equals_the_probe_of_every_row(jitted):
+    """A build side filtered to one key interval: the probe rows inside
+    it compact to the range's capacity before the probe, which runs
+    there, and every probe and build column of the matches — in row
+    order, masks included — is what the probe of every row gives."""
+    probe, build, pkey, bkey = _star()
+    bmatch = _interval_build(build)
+    inside = (pkey >= LO) & (pkey < HI)
+    k = 128 * -(-int(inside.sum()) // 128)
+    assert k * 3 < len(pkey)
+
+    def run(probe, build, bmatch):
+        pc = _compiler()
+        out, node = _range_lookup(pc, probe, build, k, bmatch=bmatch)
+        return (dict(out.columns), dict(out.nulls), out.valid,
+                pc._overflow, pc._dense_oob,
+                pc._stage_actual[(id(node), "join_range")])
+
+    cols, nulls, valid, overflow, oob, recorded = \
+        (jax.jit(run) if jitted else run)(probe, build, bmatch)
+    assert int(overflow) == 0 and int(oob) == 0
+    assert int(recorded) == int(inside.sum())
+    assert np.asarray(valid).shape == (k,)
+    where = {int(b): i for i, b in enumerate(bkey) if LO <= b < HI}
+    hits = np.array([i for i, p in enumerate(pkey) if int(p) in where])
+    brow = np.array([where[int(pkey[i])] for i in hits])
+    assert np.asarray(valid).sum() == len(hits) > 0
+    got = np.flatnonzero(np.asarray(valid))
+    for cid, rows, src in (("p_key", hits, probe), ("p_val", hits, probe),
+                           ("p_opt", hits, probe), ("b_val", brow, build),
+                           ("b_opt", brow, build)):
+        np.testing.assert_array_equal(np.asarray(cols[cid])[got],
+                                      np.asarray(src.columns[cid])[rows])
+    np.testing.assert_array_equal(np.asarray(nulls["p_opt"])[got],
+                                  np.asarray(probe.nulls["p_opt"])[hits])
+    np.testing.assert_array_equal(np.asarray(nulls["b_opt"])[got],
+                                  np.asarray(build.nulls["b_opt"])[brow])
+
+
+def test_range_compaction_probes_at_its_capacity_and_is_tallied():
+    """The probe and its slot count (`lookup_probe_slots_total`) run at
+    the range's capacity; the program tallies one range compaction
+    (`lookup_range_compactions_total`).  Without a capacity the range is
+    only recorded: the probe side keeps its width and nothing is
+    tallied."""
+    probe, build, _pkey, _bkey = _star()
+    bmatch = _interval_build(build)
+    pc = _compiler()
+    out, _node = _range_lookup(pc, probe, build, 128, bmatch=bmatch)
+    assert out.capacity == 128 and pc._lookup_probe_slots == 128
+    assert pc._lookup_range_compactions == 1
+    pc = _compiler()
+    out, node = _range_lookup(pc, probe, build, bmatch=bmatch)
+    assert out.capacity == probe.capacity
+    assert pc._lookup_probe_slots == probe.capacity
+    assert pc._lookup_range_compactions == 0
+    assert pc._stage_width[(id(node), "join_range")] == probe.capacity
+
+
+def test_range_capacity_that_no_longer_pays_is_left_unused():
+    """A range capacity installed against a wider probe side, whose
+    width has shrunk since under a compaction installed with it: where
+    compacting this program's probe side to it saves less than
+    `compaction_pays` asks, the probe side keeps its width."""
+    probe, build, _pkey, _bkey = _star()
+    pc = _compiler()
+    out, node = _range_lookup(pc, probe, build, 256,
+                              bmatch=_interval_build(build))
+    assert 256 * 3 >= probe.capacity
+    assert out.capacity == probe.capacity
+    assert pc._lookup_range_compactions == 0 and int(pc._overflow) == 0
+
+
+def test_range_past_its_capacity_counts_as_overflow():
+    """More probe rows inside the range than its capacity: the surplus
+    is capacity overflow, so the host retries with the capacity grown,
+    as for every compaction."""
+    probe, build, pkey, _bkey = _star()
+    inside = int(((pkey >= LO) & (pkey < HI)).sum())
+    pc = _compiler()
+    _range_lookup(pc, probe, build, 64, bmatch=_interval_build(build))
+    assert inside > 64 and int(pc._overflow) == inside - 64
+
+
+def test_range_of_an_empty_build_side_keeps_no_row():
+    """No matchable build row: its range is empty (min above max), no
+    probe row lies inside it, and the lookup keeps none."""
+    probe, build, _pkey, _bkey = _star()
+    pc = _compiler()
+    out, node = _range_lookup(pc, probe, build, 128,
+                              bmatch=jnp.zeros(build.capacity, bool))
+    assert int(pc._stage_actual[(id(node), "join_range")]) == 0
+    assert int(np.asarray(out.valid).sum()) == 0
+    assert int(pc._overflow) == 0 and int(pc._dense_oob) == 0
+
+
+def test_null_probe_keys_lie_outside_every_range():
+    """A probe row whose key is NULL (outside the match mask) is not
+    counted inside the range even where its stored value is, and does
+    not match after the compaction."""
+    probe, build, pkey, bkey = _star()
+    bmatch = _interval_build(build)
+    inside = (pkey >= LO) & (pkey < HI)
+    null_key = inside & (np.arange(len(pkey)) % 2 == 0)
+    pmatch = jnp.asarray(~null_key)
+    pc = _compiler()
+    out, node = _range_lookup(pc, probe, build, 128, pmatch=pmatch,
+                              bmatch=bmatch)
+    keep = int((inside & ~null_key).sum())
+    assert int(pc._stage_actual[(id(node), "join_range")]) == keep
+    matched = [i for i in range(len(pkey))
+               if inside[i] and not null_key[i]
+               and int(pkey[i]) in set(bkey.tolist())]
+    got = np.asarray(out.columns["p_val"])[np.asarray(out.valid)]
+    np.testing.assert_array_equal(got, np.asarray(matched))
+
+
+def test_range_of_several_keys_holds_every_key():
+    """A two-key lookup (the sorted-bounds arm): a probe row is inside
+    only where each key lies inside its own build range, and the
+    matches are those of the probe of every row."""
+    rng = np.random.default_rng(9)
+    n_build, n_probe = 48, 400
+    b1 = rng.permutation(n_build).astype(np.int32) + 10
+    b2 = (b1 % 6).astype(np.int32)
+    p1 = rng.integers(0, 120, n_probe).astype(np.int32)
+    p2 = rng.integers(0, 12, n_probe).astype(np.int32)
+    build = Block({"b1": jnp.asarray(b1), "b2": jnp.asarray(b2),
+                   "bv": jnp.asarray(b1.astype(np.int64) * 3)},
+                  jnp.ones(n_build, bool), {})
+    probe = Block({"p1": jnp.asarray(p1), "p2": jnp.asarray(p2),
+                   "pv": jnp.asarray(np.arange(n_probe, dtype=np.int64))},
+                  jnp.ones(n_probe, bool), {})
+    keys = (("p1", "b1"), ("p2", "b2"))
+    inside = (p1 >= 10) & (p1 < 10 + n_build) & (p2 < 6)
+    pc = _compiler()
+    out, node = _range_lookup(pc, probe, build, 128, keys=keys)
+    assert out.capacity == 128 and pc._lookup_range_compactions == 1
+    assert int(pc._stage_actual[(id(node), "join_range")]) == inside.sum()
+    assert int(pc._overflow) == 0 and int(pc._dense_oob) == 0
+    pairs = {(int(a), int(b)): i for i, (a, b) in enumerate(zip(b1, b2))}
+    hits = [i for i in range(n_probe)
+            if (int(p1[i]), int(p2[i])) in pairs]
+    valid = np.asarray(out.valid)
+    np.testing.assert_array_equal(np.asarray(out.columns["pv"])[valid], hits)
+    np.testing.assert_array_equal(
+        np.asarray(out.columns["bv"])[valid],
+        [b1[pairs[(int(p1[i]), int(p2[i]))]] * 3 for i in hits])
+
+
+# -- through statements: the capacity feedback installs the range ----------
+
+STAR_ROWS, DATES, SPAN = 24_000, 1_200, 120
+
+
+def _star_tables(seed: int = 7) -> dict:
+    """A fact table `f` whose `dk` refers to a calendar `d` (one row a
+    key, `yr` = dk // 120: a year is one interval of keys, as TPC-DS's
+    date keys are), 5 % of `dk` NULL; and a unique `u` over a smaller
+    `m` that refers to it with repeats, `u.grp` = 1 on 500 of its 4,000
+    keys."""
+    rng = np.random.default_rng(seed)
+    dk = rng.integers(0, DATES, STAR_ROWS)
+    dk_null = rng.random(STAR_ROWS) < 0.05
+    perm = rng.permutation(DATES)
+    uk = np.arange(48_000)
+    return {
+        "f": {"id": np.arange(STAR_ROWS),
+              "dk": [None if n else int(x) for x, n in zip(dk, dk_null)],
+              "g": np.arange(STAR_ROWS) % 7,
+              "v": rng.integers(0, 1000, STAR_ROWS)},
+        "d": {"dk": perm, "yr": perm // SPAN, "tag": perm % 10},
+        "u": {"uk": uk, "grp": ((uk >= 500) & (uk < 1000)).astype(int),
+              "w": uk},
+        "m": {"mk": rng.integers(0, 4000, 5000), "x": np.arange(5000)},
+    }
+
+
+STAR_DDL = {"f": ("create table f (id bigint, dk int, g int, v bigint)",
+                  "id"),
+            "d": ("create table d (dk int, yr int, tag int)", None),
+            "u": ("create table u (uk int, grp int, w bigint)", "uk"),
+            "m": ("create table m (mk int, x bigint)", "mk")}
+STAR_TYPES = {"id": np.int64, "v": np.int64, "w": np.int64, "x": np.int64}
+
+
+def _load_star(sess, tables: dict) -> None:
+    from citus_tpu.ingest.copy_from import _ingest_batch
+
+    for name, (ddl, dist) in STAR_DDL.items():
+        sess.execute(ddl)
+        if dist is None:
+            sess.create_reference_table(name)
+        else:
+            sess.create_distributed_table(name, dist, shard_count=4)
+        cols = tables[name]
+        batch = [cols[c] if isinstance(cols[c], list)
+                 else np.asarray(cols[c]).astype(STAR_TYPES.get(c, np.int32))
+                 for c in cols]
+        _ingest_batch(sess, name, list(cols), batch, pre_typed=True)
+
+
+@pytest.fixture(scope="module")
+def star_oracle():
+    from oracle import make_oracle
+
+    return make_oracle(_star_tables(), {})
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["1dev", "4dev"])
+def star_sess(request, tmp_path_factory):
+    import citus_tpu
+
+    sess = citus_tpu.connect(
+        data_dir=str(tmp_path_factory.mktemp(f"range{request.param}")),
+        n_devices=request.param, serving_result_cache_bytes=0)
+    _load_star(sess, _star_tables())
+    yield sess
+    sess.close()
+
+
+RANGE_SQL = {
+    "interval": "select d.yr, f.g, count(*), sum(f.v) from f, d "
+                "where f.dk = d.dk and d.yr = 3 group by d.yr, f.g",
+    # the year of key 0, which is where a NULL key's storage sits
+    "first_interval": "select f.g, count(*), sum(f.v) from f, d "
+                      "where f.dk = d.dk and d.yr = 0 group by f.g",
+    "every_key": "select f.g, count(*), sum(f.v) from f, d "
+                 "where f.dk = d.dk and d.tag = 3 group by f.g",
+    "empty": "select f.g, count(*) from f, d "
+             "where f.dk = d.dk and d.yr = 99 group by f.g",
+    "build_left": "select u.grp, count(*), sum(m.x), sum(u.w) from u, m "
+                  "where u.uk = m.mk and u.grp = 1 group by u.grp",
+    "left_join": "select f.g, count(d.yr), count(*), sum(f.v) from f "
+                 "left join d on f.dk = d.dk and d.yr = 3 group by f.g",
+}
+
+
+def _converged(sess, oracle, name: str) -> tuple[dict, list]:
+    """Three executions of RANGE_SQL[name], each held to sqlite; → the
+    counters' moves over the last, and the plan-cache entries the
+    statement added (its programs: the first and the converged one)."""
+    from oracle import compare_results, run_oracle
+
+    sql = RANGE_SQL[name]
+    want = run_oracle(oracle, sql)
+    known = set(sess.executor.plan_cache._entries)
+    for _ in range(3):
+        before = sess.stats.counters.snapshot()
+        rows = sess.execute(sql).rows()
+        after = sess.stats.counters.snapshot()
+        compare_results(rows, want, ordered=False)
+    added = [e for k, e in sess.executor.plan_cache._entries.items()
+             if k not in known]
+    assert added
+    return {k: after[k] - before.get(k, 0) for k in after}, added
+
+
+def _range_widths(entries) -> list[int]:
+    """The probe-side widths at which the programs recorded a range."""
+    return [width for e in entries for _w, kind, width in e[2]
+            if kind == "join_range"]
+
+
+def _compacts_to_range(entries) -> bool:
+    return any("ct.join_range/ct.compact" in e[0].as_text()
+               for e in entries)
+
+
+@pytest.mark.parametrize("name", ["interval", "first_interval",
+                                  "build_left"])
+def test_lookup_into_one_key_interval_probes_its_rows_alone(
+        star_sess, star_oracle, name):
+    """A build side that keeps one interval of the keys (a year of the
+    calendar; `u`'s 500 keys, probed from `m` with the build on the
+    left): capacity feedback installs the range's capacity, and the
+    converged program compacts the probe side to it before the probe
+    and probes there — its probe slots are under a third of the probe
+    side's width.  NULL keys (5 % of `f.dk`, stored where the first
+    year's keys are) lie outside.  Answers are sqlite's."""
+    moved, added = _converged(star_sess, star_oracle, name)
+    assert moved["lookup_range_compactions_total"] == 1
+    assert moved["capacity_retries"] == 0
+    n_dev = star_sess.executor.mesh.devices.size
+    width = max(_range_widths(added))   # the first program's, uncut
+    assert moved["lookup_probe_slots_total"] * 3 < n_dev * width
+    assert _compacts_to_range(added)
+
+
+def test_range_covering_every_key_installs_nothing(star_sess, star_oracle):
+    """A build side that keeps a tenth of the keys spread over the whole
+    calendar: its range covers every key, no range capacity is
+    installed, and the probe keeps the fact side's width; only the
+    range's count is left in the program."""
+    moved, added = _converged(star_sess, star_oracle, "every_key")
+    assert moved["lookup_range_compactions_total"] == 0
+    assert moved["lookup_probe_slots_total"] >= STAR_ROWS
+    assert _range_widths(added) and not _compacts_to_range(added)
+
+
+def test_empty_build_side_keeps_no_row(star_sess, star_oracle):
+    """A year with no calendar row: the range is empty (its minimum
+    above its maximum), no probe row lies inside it, and the statement
+    answers no row, as sqlite does; the smallest capacity is installed
+    for the range, so the probe runs over it."""
+    moved, added = _converged(star_sess, star_oracle, "empty")
+    assert star_sess.execute(RANGE_SQL["empty"]).rows() == []
+    assert moved["lookup_range_compactions_total"] == 1
+    assert moved["lookup_probe_slots_total"] == \
+        128 * star_sess.executor.mesh.devices.size
+    assert moved["capacity_retries"] == 0
+
+
+def test_left_lookup_is_untouched(star_sess, star_oracle):
+    """A LEFT lookup keeps every probe row: it records no range and
+    never compacts to one, whatever the ON clause keeps of the build
+    side."""
+    moved, added = _converged(star_sess, star_oracle, "left_join")
+    assert moved["lookup_range_compactions_total"] == 0
+    assert moved["lookup_probe_slots_total"] >= STAR_ROWS
+    assert _range_widths(added) == []
+    assert not any("ct.join_range" in e[0].as_text() for e in added)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_rows_past_the_range_capacity_retry_once(tmp_path, n_dev):
+    """The range's capacity converged on one year; an UPDATE then moves
+    half the fact rows into it.  The next execution overflows the
+    range's capacity, retries once with it grown and answers as sqlite
+    does; the one after runs without a retry."""
+    import citus_tpu
+    from oracle import compare_results, make_oracle, run_oracle
+
+    tables = _star_tables()
+    sess = citus_tpu.connect(data_dir=str(tmp_path / "d"), n_devices=n_dev,
+                             serving_result_cache_bytes=0)
+    try:
+        _load_star(sess, tables)
+        sql = RANGE_SQL["interval"]
+        for _ in range(3):
+            before = sess.stats.counters.snapshot()
+            sess.execute(sql).rows()
+        after = sess.stats.counters.snapshot()
+        assert after["lookup_range_compactions_total"] \
+            - before["lookup_range_compactions_total"] == 1
+        update = "update f set dk = 360 + id % 120 where id % 2 = 0"
+        sess.execute(update)
+        oracle = make_oracle(tables, {})
+        oracle.execute(update)
+        want = run_oracle(oracle, sql)
+        retries = []
+        for _ in range(2):
+            result = sess.execute(sql)
+            compare_results(result.rows(), want, ordered=False)
+            retries.append(result.retries)
+        assert retries == [1, 0]
+    finally:
+        sess.close()

@@ -219,6 +219,49 @@ def test_q89_group_keys_gather_at_the_group_bys_width(loaded, data):
     assert checked >= 1
 
 
+def test_q89_date_probe_reads_at_the_compacted_width(loaded, data,
+                                                     monkeypatch):
+    """`d_year in (1999)` keeps one interval of the date keys, and a
+    fifth of `store_sales` lies inside it: the converged program
+    compacts the fact side to the rows inside before it probes the
+    `date_dim` directory, so every gather of the lookups is under a
+    third of the fact side's width, and one range compaction is counted
+    a statement.  At SF 0.01 the statistics let the `item` lookup build
+    on the filtered fact side (its estimated rows fall under `item`'s
+    key count), a uniqueness claim the first execution refutes, which
+    sends the statement to the general join paths; at SF10 the fact
+    side probes `item`, and that plan is kept here by taking no
+    uniqueness claim from a join's output.  The answers stay the
+    reference's."""
+    from citus_tpu.planner.plan import DistributedPlanner, JoinNode
+
+    claim = DistributedPlanner._estimate_expansion_for
+    monkeypatch.setattr(
+        DistributedPlanner, "_estimate_expansion_for",
+        lambda self, node, keys: (None if isinstance(node, JoinNode)
+                                  else claim(self, node, keys)))
+    known = set(loaded.executor.plan_cache._entries)
+    for _ in range(3):
+        before = loaded.stats.counters.snapshot()
+        rows = loaded.execute(_sql()).rows()
+        after = loaded.stats.counters.snapshot()
+    tol = ref.tolerance(tpcds.row_counts(data))
+    bad, err = ref.compare(rows, ref.build(data), tol)
+    assert bad == [] and err < tol
+    assert after["lookup_range_compactions_total"] \
+        - before["lookup_range_compactions_total"] == 1
+    assert after["capacity_retries"] == before["capacity_retries"]
+    texts = [e[0].as_text()
+             for k, e in loaded.executor.plan_cache._entries.items()
+             if k not in known]
+    converged = [t for t in texts if "ct.join_range/ct.compact" in t]
+    assert converged
+    for text in converged:
+        fact = max(_op_widths(text, "ct.join_range/ct.compact", "sort"))
+        probe = _op_widths(text, "ct.lookup_join", "gather")
+        assert probe and max(probe) * 3 < fact, (probe, fact)
+
+
 @pytest.fixture(scope="module")
 def data_root(tmp_path_factory):
     return str(tmp_path_factory.mktemp("benchdata"))

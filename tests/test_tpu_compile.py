@@ -224,6 +224,65 @@ def test_sort_path_groupby_compiles_at_its_capacity(chip):
     assert pc._agg_sort_gather_slots == 2 * n + 9 * cap
 
 
+def test_lookup_probes_at_its_range_capacity(chip):
+    """A fused inner lookup whose build side keeps one interval of its
+    keys (TPC-DS Q89's `date_dim` under `d_year in (1999)`: 365 of
+    73,049 date keys), under a converged range capacity of a fifth of
+    the fact side: at the fact side's width the program holds one sort
+    (the positions, `ct.join_range/ct.compact`) and no gather, the
+    key is gathered and the directory probed at the capacity, and the
+    columns read after the lookup are gathered there too."""
+    import re
+    from types import SimpleNamespace
+
+    from citus_tpu.executor.batch import Block
+    from citus_tpu.executor.compiler import Capacities, PlanCompiler
+
+    n, m = 1 << 20, 73_049
+    k = _round_cap(n // 5)
+    node = SimpleNamespace(join_type="inner", build_side="right",
+                           residual=None,
+                           right_key_extents=((2_415_022, m),),
+                           lookup_sorted=False, fuse_lookup=True,
+                           key_pack=None)
+    pc = PlanCompiler.__new__(PlanCompiler)
+    pc.caps, pc.n_dev = Capacities({}, {id(node): k}), 1
+    pc.caps.join_range[id(node)] = k
+
+    def lookup(date_sk, item_sk, price, d_date_sk, d_moy, d_year):
+        pc._dense_oob = pc._overflow = jnp.zeros((), jnp.int64)
+        pc._stage_actual, pc._stage_width = {}, {}
+        pc._lookup_probe_slots = pc._lookup_range_compactions = 0
+        probe = Block({"date": date_sk, "item": item_sk, "price": price},
+                      jnp.ones(n, bool), {})
+        build = Block({"d_date_sk": d_date_sk, "moy": d_moy},
+                      jnp.ones(m, bool), {})
+        out = pc._exec_lookup_join(node, probe, build, [date_sk],
+                                   probe.valid, [d_date_sk],
+                                   d_year == 1999)
+        return (out.columns["item"], out.columns["moy"],
+                out.columns["price"], out.valid, pc._overflow)
+
+    c = _compile(lookup, chip((n,), jnp.int32), chip((n,), jnp.int32),
+                 chip((n,), jnp.float32), chip((m,), jnp.int32),
+                 chip((m,), jnp.int32), chip((m,), jnp.int32))
+    widths = {}
+    for ln in c.as_text().splitlines():
+        op = re.search(r"= \w+\[(\d+)\]\S* (sort|gather)\(", ln)
+        name = re.search(r'op_name="([^"]*)"', ln)
+        if op and name:
+            widths.setdefault((op.group(2), int(op.group(1))), []).append(
+                name.group(1))
+    assert [w for (op, w) in widths if w > k] == [n]
+    assert all("ct.join_range/ct.compact" in p
+               for p in widths[("sort", n)])
+    probes = widths[("gather", k)]
+    assert any("ct.join_range" in p for p in probes)
+    assert any("ct.lookup_join/ct.dense" in p for p in probes)
+    assert pc._lookup_range_compactions == 1
+    assert pc._lookup_probe_slots == k
+
+
 def test_bucketed_grid_aggregate_xla_compiles(chip, monkeypatch):
     """The high-cardinality group-by at SF1 (group by l_orderkey over
     6 M rows; planner/plan.py `group_bucketed`), on the branch the
