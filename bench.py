@@ -1382,8 +1382,8 @@ def main() -> None:
             ("tpch_q3_rows_per_sec", QUERIES["Q3"], n_cust + n_ord + n_li),
             # high-cardinality GROUP BY (~0.25·n_li distinct orderkeys
             # over the full lineitem): the aggregation-stage wall the
-            # bucketed dense-grid path (ops/groupby.py, group_by_kernel)
-            # targets — bench_kernels.py groupby is the kernel-level A/B
+            # bucketed dense-grid path (ops/groupby.py) targets —
+            # bench_kernels.py groupby is the kernel-level A/B
             ("high_card_groupby_rows_per_sec",
              "select l_orderkey, count(*), sum(l_quantity) "
              "from lineitem group by l_orderkey",

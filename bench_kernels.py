@@ -1,17 +1,17 @@
-"""Kernel micro-benchmarks: Pallas vs XLA formulations on real hardware.
+"""Kernel micro-benchmarks: the XLA formulations of the executor's hot
+operations, A/B'd against each other on real hardware.
 
-Runs the dense-grid segment aggregation both ways across the (N, K)
-regimes the executor actually hits, prints a table, and says which
-implementation the executor should route to.  This is the measurement
-the BASELINE north star asks for — hand kernels where they win, measured
-justification where XLA already wins.
+The default runs the dense-grid segment aggregation two ways (segment_sum
+and a one-hot matmul) across the (N, K) regimes the executor actually
+hits, prints a table, and says which formulation wins where; the
+subcommands below (lookup, compact, carry, groupby) do the same for
+their operators.
 
-Usage:  python bench_kernels.py          (real TPU)
+Usage:  python bench_kernels.py [lookup|compact|carry|groupby]   (real TPU)
 """
 
 from __future__ import annotations
 
-import functools
 import time
 
 import jax
@@ -55,7 +55,7 @@ def xla_segment_sum(slot, values, total):
 
 
 def xla_onehot_matmul(slot, values, total):
-    # the same one-hot trick expressed in plain XLA (no Pallas)
+    # the one-hot trick: slots x values through the MXU
     k_pad = -(-total // 512) * 512
     onehot = (slot[:, None] ==
               jnp.arange(k_pad, dtype=jnp.int32)[None, :]).astype(
@@ -66,10 +66,7 @@ def xla_onehot_matmul(slot, values, total):
 
 
 def main(regimes=None):
-    from citus_tpu.ops.pallas_kernels import (
-        dense_grid_aggregate_pallas,
-        segment_sum_reference,
-    )
+    from citus_tpu.ops.reference import segment_sum_reference
 
     print(f"backend: {jax.devices()[0].platform} "
           f"({jax.devices()[0].device_kind})")
@@ -87,32 +84,17 @@ def main(regimes=None):
                        slot, vals)
         t_oh = timeit(lambda s, v, total=k: xla_onehot_matmul(s, v, total),
                       slot, vals)
-        t_pl = None
-        ok = True
-        try:
-            f_pl = (lambda s, v, total=k:
-                    dense_grid_aggregate_pallas(s, v, total))
-            got = np.asarray(f_pl(slot, vals))
-            want = segment_sum_reference(np.asarray(slot),
-                                         np.asarray(vals), k)
-            ok = np.allclose(got, want, rtol=1e-3, atol=1.0)
-            t_pl = timeit(f_pl, slot, vals)
-        except Exception as e:
-            t_pl = None
-            print(f"  pallas failed at n={n} k={k}: "
-                  f"{str(e).splitlines()[0][:120]}")
-        rows.append((n, k, t_seg, t_oh, t_pl, ok))
+        want = segment_sum_reference(np.asarray(slot), np.asarray(vals), k)
+        ok = all(np.allclose(np.asarray(jax.jit(f, static_argnums=2)(
+                     slot, vals, k)), want, rtol=1e-3, atol=1.0)
+                 for f in (xla_segment_sum, xla_onehot_matmul))
+        rows.append((n, k, t_seg, t_oh, ok))
         print(f"n={n:>9} k={k:>5}  xla_segsum={t_seg * 1e3:8.2f}ms  "
-              f"xla_onehot={t_oh * 1e3:8.2f}ms  "
-              f"pallas={'n/a' if t_pl is None else f'{t_pl * 1e3:8.2f}ms'}"
-              f"  correct={ok}")
+              f"xla_onehot={t_oh * 1e3:8.2f}ms  correct={ok}")
 
-    best_counts = {"segsum": 0, "onehot": 0, "pallas": 0}
-    for n, k, t_seg, t_oh, t_pl, ok in rows:
-        opts = {"segsum": t_seg, "onehot": t_oh}
-        if t_pl is not None and ok:
-            opts["pallas"] = t_pl
-        best_counts[min(opts, key=opts.get)] += 1
+    best_counts = {"segsum": 0, "onehot": 0}
+    for _n, _k, t_seg, t_oh, _ok in rows:
+        best_counts["segsum" if t_seg <= t_oh else "onehot"] += 1
     print("winner histogram:", best_counts)
     return rows
 
@@ -461,15 +443,14 @@ def _pack_chunks_by_gather(slot, valid, columns, n_buckets, tile, nc,
     return packed, jnp.where(lane_ok, key[src], trash), lane_ok, cb
 
 
-def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out",
-                  pallas=False):
+def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out"):
     """High-cardinality GROUP BY A/B: the sort path (packed-key
     `segment_aggregate`, exactly what the executor runs) against the
     bucketed dense-grid path (`ops.groupby.bucketed_grid_aggregate`)
     at each candidate chunk size of its pack — 1,024 rows, 4,096, and
     the uniform expectation `_round_cap(ceil(n / n_buckets))` — the
-    measurement behind `ops.groupby.GROUP_CHUNK_ROWS`, the planner's
-    `group_bucket_eligible` gate and the `group_by_kernel` config var.
+    measurement behind `ops.groupby.GROUP_CHUNK_ROWS` and the planner's
+    `group_bucket_eligible` gate.
     Beside them `_pack_chunks_by_gather`, the pack's element-gather
     form, at 4,096.
 
@@ -481,10 +462,9 @@ def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out",
     (regime, form) and a winner histogram, and writes
     `<out>/bench_groupby.json`.  Runs on any backend, with small
     default regimes on the CPU; a time is a device time only on the
-    chip.  `pallas` adds the Pallas tile kernel (interpret mode on the
-    CPU is parity-checked, never timed).
+    chip.
 
-    Usage:  python bench_kernels.py groupby [pallas]
+    Usage:  python bench_kernels.py groupby
     """
     import json
     import os
@@ -541,13 +521,12 @@ def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out",
                  (ones, "count", None)], valid, out_keys=[s])
             return (res[2].sum() + ng).astype(jnp.int64)
 
-        def bucketed(i, kernel="xla"):
+        def bucketed(i):
             s = slot0 - slot0 % tile + (slot0 + i) % tile
             s32 = jnp.minimum(s, k - 1).astype(jnp.int32)
             res, rps = G.bucketed_grid_aggregate(
                 s32, valid,
-                [(v0, "sum"), (v1, "sum"), (ones, "count")], k,
-                kernel=kernel)
+                [(v0, "sum"), (v1, "sum"), (ones, "count")], k)
             return (res[2].sum().astype(jnp.int64)
                     + (rps > 0).sum()).astype(jnp.int64)
 
@@ -557,9 +536,6 @@ def bench_groupby(regimes=None, repeats=3, reps=8, out="chiprun_out",
                   for c in chunks]
         forms.append(("chunked_4096_by_gather", 4096,
                       _pack_chunks_by_gather, bucketed))
-        if pallas and platform != "cpu":
-            forms.append(("chunked_4096_pallas", 4096, G._pack_chunks,
-                          functools.partial(bucketed, kernel="pallas")))
         chunk_rows, pack = G.GROUP_CHUNK_ROWS, G._pack_chunks
         want = None
         try:
@@ -668,6 +644,6 @@ if __name__ == "__main__":
     elif len(sys.argv) > 1 and sys.argv[1] == "carry":
         bench_carry(small="small" in sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "groupby":
-        bench_groupby(pallas="pallas" in sys.argv[2:])
+        bench_groupby()
     else:
         main()

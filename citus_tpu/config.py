@@ -106,19 +106,6 @@ _register(ConfigVar(
     "Static aggregate-output headroom over the estimated group count.",
     float, min_value=1.0, max_value=64.0))
 _register(ConfigVar(
-    "group_by_kernel", "auto",
-    "High-cardinality GROUP BY path: 'auto' (planner pick — bucketed "
-    "dense-grid aggregation on TPU where structurally eligible, sort "
-    "path elsewhere), 'sort' (always the argsort/segmented-scan path), "
-    "'bucketed' (force the bucketed grid: one sort by slot, each "
-    "tile's rows cut into fixed-size chunks, XLA one-hot dot_general "
-    "a chunk; sized by the rows whatever the key's distribution, so "
-    "it has no capacity to set), 'bucketed_pallas' (force it with the "
-    "Pallas tile kernel a chunk). bench_kernels.py groupby A/Bs them "
-    "and the chunk size on the target hardware; auto stays "
-    "measurement-gated so CPU meshes keep the sort path.",
-    str, choices=("auto", "sort", "bucketed", "bucketed_pallas")))
-_register(ConfigVar(
     "enable_capacity_feedback", True,
     "After a clean execution, shrink buffers whose recorded actual row "
     "counts sit far below the planner's estimate and recompile once "
@@ -205,8 +192,7 @@ _register(ConfigVar(
     "coded low-NDV columns and bit-packed validity planes cross the "
     "wire and expand on the mesh (XLA formulations). 'auto' picks device on accelerator "
     "backends and host on CPU meshes, engaging only above a small "
-    "row floor (same measurement-gated contract as "
-    "group_by_kernel). No reference GUC — the analogue is the "
+    "row floor. No reference GUC — the analogue is the "
     "columnar reader's chunk streaming, columnar_reader.c:323.",
     str, choices=("auto", "off", "host", "device")))
 _register(ConfigVar(

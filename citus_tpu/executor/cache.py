@@ -84,7 +84,7 @@ def node_fingerprint(node: PlanNode) -> str:
                 f"{node_fingerprint(node.input)};"
                 f"{groups};{aggs};{node.dense_keys};{node.dense_total};"
                 f"{node.key_ranges};{node.bucket_keys};"
-                f"{node.bucket_total};{node.group_bucketed};"
+                f"{node.bucket_total};"
                 f"{node.nulls_last};{_dist_sig(node.dist)})")
     raise TypeError(f"unknown plan node {type(node).__name__}")
 

@@ -289,20 +289,13 @@ class PlanCompiler:
 
     def __init__(self, plan: QueryPlan, mesh: Mesh,
                  feeds: dict[int, FeedSpec], caps: Capacities,
-                 compute_dtype=np.float32, group_kernel: str = "auto"):
+                 compute_dtype=np.float32):
         self.plan = plan
         self.mesh = mesh
         self.feeds = feeds
         self.caps = caps
         self.n_dev = plan.n_devices
         self.compute_dtype = compute_dtype
-        # group-by path pick ('auto' | 'sort' | 'bucketed' |
-        # 'bucketed_pallas'): auto defers to the planner's TPU-gated
-        # group_bucketed annotation; the rest override it where the
-        # plan is structurally eligible (bench_kernels.py groupby is
-        # the measurement behind the default).  Rides in the plan
-        # fingerprint, so in the plan-cache key.
-        self.group_kernel = group_kernel
 
     # ------------------------------------------------------------------
     def build(self):
@@ -1620,25 +1613,18 @@ class PlanCompiler:
         return packed, oob
 
     @staticmethod
-    def agg_bucket_shape(node: AggregateNode, group_kernel: str,
-                         dense_off: bool) -> bool:
+    def agg_bucket_shape(node: AggregateNode, dense_off: bool) -> bool:
         """Single decision point for the bucketed dense-grid group-by:
         capacity planning (the result grid's agg_out sizing), the
         compiler dispatch, EXPLAIN's tag and the groupby_bucketed_total
-        counter must all agree."""
+        counter must all agree.  The pick itself is the planner's
+        (`bucket_keys`, from ops.groupby.group_bucket_eligible)."""
         if dense_off or node.combine not in ("local", "repartition"):
             return False
-        if not getattr(node, "bucket_keys", None) or \
-                getattr(node, "bucket_total", 0) <= 0:
+        if not node.bucket_keys or node.bucket_total <= 0:
             return False
-        if node.dense_keys is not None:
-            return False  # below the cap the flat dense grid wins
-        if group_kernel == "sort":
-            return False
-        if group_kernel in ("bucketed", "bucketed_pallas"):
-            return True
-        # auto: the planner's measurement-gated (TPU-only) pick
-        return bool(getattr(node, "group_bucketed", False))
+        # below the cap the flat dense grid wins
+        return node.dense_keys is None
 
     @staticmethod
     def sorted_lookup_shape(node: JoinNode, dense_off: bool) -> bool:
@@ -1884,8 +1870,7 @@ class PlanCompiler:
                 node.combine in ("local", "repartition"):
             with stage_scope("agg_grid"):
                 return self._exec_dense_aggregate(node, blk)
-        if self.agg_bucket_shape(node, self.group_kernel,
-                                 self.caps.dense_off):
+        if self.agg_bucket_shape(node, self.caps.dense_off):
             with stage_scope("agg_bucket"):
                 bucketed = self._exec_bucketed_aggregate(node, blk)
             if bucketed is not None:
@@ -2239,10 +2224,8 @@ class PlanCompiler:
             comp_idx.append(len(op_values))
             op_values.append((contrib.astype(jnp.int32), "count"))
 
-        kernel = ("pallas" if self.group_kernel == "bucketed_pallas"
-                  else "xla")
         res, rows_per_slot = bucketed_grid_aggregate(
-            slot32, blk.valid, op_values, total, kernel=kernel)
+            slot32, blk.valid, op_values, total)
         nc, chunk = group_pack_shape(int(slot32.shape[0]),
                                      group_bucket_count(total))
         self._agg_bucket_slots += self.n_dev * nc * chunk
@@ -2304,8 +2287,6 @@ class PlanCompiler:
     # one-hot MXU segment-sum eligibility bound: bench_kernels.py on
     # TPU v5e measured the matmul formulation 2-10× faster than XLA's
     # scatter-based segment_sum up to ~4096 slots, slower past ~8192
-    # (a hand Pallas kernel of the same shape measured slower than both
-    # — the measured justification for staying at the XLA level)
     DENSE_ONEHOT_MAX_SLOTS = 4096
 
     def _dense_segment_sum(self, data: jnp.ndarray, slot: jnp.ndarray,

@@ -270,14 +270,9 @@ class Executor:
             orp = plan.output_repart
             orp_sig = (None if orp is None
                        else (orp[0], orp[1], orp[2], repr(orp[3])))
-            # group_by_kernel changes which CAPACITY TABLES exist
-            # (the bucketed grid's agg_out vs sort-path buffers), so
-            # converged sizes memoized under one mode must not be
-            # replayed under another — it joins the fingerprint
             fingerprint = (node_fingerprint(plan.root), plan.n_devices,
                            str(compute_dtype), feeds_signature(plan, feeds),
-                           topk_sig, orp_sig,
-                           self.settings.get("group_by_kernel"))
+                           topk_sig, orp_sig)
             with self._caps_lock:
                 memo = self._caps_memo.get(fingerprint)
             caps = (self._caps_from_order(plan, memo) if memo is not None
@@ -337,14 +332,13 @@ class Executor:
         from ..utils.cancellation import check_cancel
 
         limit = self.settings.get("max_plan_buffer_bytes")
-        group_kernel = self.settings.get("group_by_kernel")
         retries = 0
         tightened = False
         while True:
             check_cancel()  # overflow-retry iterations are cancel seams
             with trace_span("caps"):
                 # one estimate serves the guard here and the lease below
-                est = _plan_buffer_bytes(plan, caps, group_kernel)
+                est = _plan_buffer_bytes(plan, caps)
                 if limit and est > limit:
                     if self._plan_degradable(plan):
                         # eligible over-limit plans route into the OOM
@@ -371,8 +365,7 @@ class Executor:
                 # leave the plan cache without a half-built entry
                 fault_point("executor.plan_cache_fill")
                 entry = self._compile_or_load(plan, feeds, caps,
-                                              compute_dtype, group_kernel,
-                                              key)
+                                              compute_dtype, key)
                 self.plan_cache.put(key, entry)
                 fn, out_meta, stage_keys, shuffle_bytes, tallies = entry
                 feed_arrays = flatten_feed_arrays(plan, feeds,
@@ -562,8 +555,7 @@ class Executor:
             # (stream / multi-pass) instead of burning the retries
             budget = self.accountant.budget_bytes(self.settings)
             if budget:
-                need = _plan_buffer_bytes(plan, caps, group_kernel) \
-                    // max(1, plan.n_devices)
+                need = _plan_buffer_bytes(plan, caps) // max(1, plan.n_devices)
                 room = budget - self.accountant.pressure_bytes()
                 if need > room and self._plan_degradable(plan):
                     raise DeviceMemoryExhausted(
@@ -575,7 +567,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _compile_or_load(self, plan: QueryPlan, feeds, caps: Capacities,
-                         compute_dtype, group_kernel, key) -> tuple:
+                         compute_dtype, key) -> tuple:
         """Plan-cache miss resolution, restart-survivable.  The whole
         resolve — disk load AND compile — runs single-flight through
         the per-data_dir gate: N sessions hitting a cold shape produce
@@ -602,8 +594,7 @@ class Executor:
         def compile_fn():
             with trace_span("compile", cache="miss"):
                 compiler = PlanCompiler(plan, self.mesh, feeds,
-                                        caps, compute_dtype,
-                                        group_kernel=group_kernel)
+                                        caps, compute_dtype)
                 fn, feed_arrays, out_meta, stage_keys = \
                     compiler.build()
                 # AOT: compile NOW (not lazily at first dispatch) so
@@ -863,7 +854,6 @@ class Executor:
         if range_compactions:
             self.counters.increment(sc.LOOKUP_RANGE_COMPACTIONS_TOTAL,
                                     range_compactions)
-        group_kernel = self.settings.get("group_by_kernel")
         nodes = list(walk_plan(plan.root))
         n_over_groups = sum(1 for nd in nodes if isinstance(nd, WindowNode)
                             and _over_groups(nd))
@@ -872,8 +862,7 @@ class Executor:
                                     n_over_groups)
         nbk = sum(1 for nd in nodes
                   if isinstance(nd, AggregateNode)
-                  and PlanCompiler.agg_bucket_shape(
-                      nd, group_kernel, caps.dense_off))
+                  and PlanCompiler.agg_bucket_shape(nd, caps.dense_off))
         if nbk:
             self.counters.increment(sc.GROUPBY_BUCKETED_TOTAL, nbk)
         # a join under the aggregate pushdown is probed through _bounds
@@ -1122,7 +1111,6 @@ class Executor:
         repart_factor = self.settings.get("repartition_capacity_factor")
         join_factor = self.settings.get("join_output_capacity_factor")
         group_factor = self.settings.get("agg_group_capacity_factor")
-        group_kernel = self.settings.get("group_by_kernel")
         n_dev = plan.n_devices
         repart: dict[int, int] = {}
         join_out: dict[int, int] = {}
@@ -1239,8 +1227,7 @@ class Executor:
                 if node.dense_keys is not None and not dense_off and \
                         node.combine in ("local", "repartition"):
                     return node.dense_total  # fixed dense-grid output
-                if PlanCompiler.agg_bucket_shape(node, group_kernel,
-                                                 dense_off):
+                if PlanCompiler.agg_bucket_shape(node, dense_off):
                     # bucketed dense grid: the pack is sized by in_cap
                     # alone (ops.groupby.group_pack_shape), so it has
                     # no capacity here; the [bucket_total] output grid
@@ -1429,8 +1416,7 @@ def feed_device_rows(feeds, n_dev: int) -> list[int] | None:
     return totals if seen else None
 
 
-def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities,
-                       group_kernel: str) -> int:
+def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities) -> int:
     """Worst single-buffer estimate for a capacity assignment: each
     join/repartition/aggregate buffer holds its node's output columns at
     the static capacity, per device.  Guards against executing plans
@@ -1450,8 +1436,7 @@ def _plan_buffer_bytes(plan: QueryPlan, caps: Capacities,
 
     for node in nodes.values():
         if not (isinstance(node, AggregateNode)
-                and PlanCompiler.agg_bucket_shape(node, group_kernel,
-                                                  caps.dense_off)):
+                and PlanCompiler.agg_bucket_shape(node, caps.dense_off)):
             continue
         # bucketed group-by: the chunked pack per value column
         # (int64-worst, per device) — its input's slots and one chunk a

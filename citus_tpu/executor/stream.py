@@ -622,8 +622,7 @@ def try_execute_streamed(executor, plan: QueryPlan, raw: bool,
                 fingerprint = ("stream", batch_cap,
                                node_fingerprint(plan.root), n_dev,
                                str(compute_dtype),
-                               feeds_signature(plan, feeds), topk_sig,
-                               executor.settings.get("group_by_kernel"))
+                               feeds_signature(plan, feeds), topk_sig)
                 memo = executor._caps_memo.get(fingerprint)
                 caps = (executor._caps_from_order(plan, memo)
                         if memo is not None

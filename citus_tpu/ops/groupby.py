@@ -22,9 +22,8 @@ This module removes the cap the radix-partition way (Theseus, arXiv
      inside one bucket (`_pack_chunks`): a `[NC, C]` buffer a column,
   3. each chunk reduces over its bucket's <= GROUP_TILE_SLOTS-wide
      dense tile: sums/counts through the measured-fastest one-hot
-     `dot_general` formulation (batched over chunks; a Pallas variant
-     is A/B'd by `bench_kernels.py groupby`), then the chunks of one
-     bucket are added up (chunk ids are sorted by bucket);
+     `dot_general` formulation (batched over chunks), then the chunks
+     of one bucket are added up (chunk ids are sorted by bucket);
      min/max and the exact integer sums through scatter (segment)
      reductions over the flat slots,
   4. the [total]-slot grid emits exactly like the dense grid today:
@@ -192,8 +191,7 @@ def _onehot_ok(slots: int, tile: int) -> bool:
 
 def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
                             values: list[tuple[jnp.ndarray, str]],
-                            total: int, kernel: str = "xla",
-                            interpret: bool = False):
+                            total: int):
     """Aggregate rows onto a [total]-slot dense grid, bucket-tiled.
 
     Args:
@@ -207,12 +205,10 @@ def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
               min/max arrays the reduction identity (the caller owns
               NULL masking, exactly as with the flat dense grid).
       total:  static slot-space size.
-      kernel: 'xla' (batched take-free one-hot dot_general) or 'pallas'
-              (ops.pallas_kernels.bucketed_groupby_sums_pallas for the
-              f32/int32 sum stacks, a chunk where it took a bucket;
-              min/max and wide dtypes stay on the XLA segment ops
-              either way).  Degrades to 'xla' where pallas cannot
-              compile.
+
+    The f32/int32 sum stacks reduce a chunk at a time through a batched
+    one-hot dot_general; min/max and wide dtypes stay on the XLA
+    segment ops.
 
     Returns (results, rows_per_slot):
       results:       [total] array per input value, same order,
@@ -233,26 +229,13 @@ def bucketed_grid_aggregate(slot: jnp.ndarray, valid: jnp.ndarray,
     loc2d = jnp.where(pvalid, flat2d - cb[:, None] * tile, 0)
     flat_slot = flat2d.reshape(-1)
 
-    if kernel == "pallas" and not interpret:
-        if jax.default_backend() == "cpu":
-            # on the CPU backend a compiled pallas_call is
-            # interpret-only, so the XLA formulation (identical
-            # results) runs instead
-            kernel = "xla"
-
     def _sums(colkeys: list[str], out_dtype):
         """Per-tile sums of same-dtype packed stacks [nc, chunk] each,
         a column a row of the result [A, ext_pad]: a partial a chunk,
         then the chunks of one bucket added up."""
         a = len(colkeys)
         stack = jnp.stack([packed[ck] for ck in colkeys], axis=2)
-        if kernel == "pallas":
-            from .pallas_kernels import bucketed_groupby_sums_pallas
-
-            red = bucketed_groupby_sums_pallas(
-                loc2d, stack.astype(jnp.float32), tile,
-                interpret=interpret).swapaxes(1, 2)
-        elif _onehot_ok(nc * chunk, tile):
+        if _onehot_ok(nc * chunk, tile):
             red = _onehot_bucket_sums(loc2d, stack, tile)
         else:
             flat = stack.reshape(nc * chunk, a)

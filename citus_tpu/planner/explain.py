@@ -225,15 +225,11 @@ def _format_node(node: PlanNode, lines: list[str], depth: int,
                    "repartition": "all_to_all combine"}[node.combine]
         keys = ", ".join(str(g) for g, _ in node.group_keys) or "()"
         aggs = ", ".join(str(a) for a, _ in node.aggs)
-        # same predicate the executor applies (agg_bucket_shape): the
-        # tag reflects what THIS session's group_by_kernel would run
+        # same predicate the executor applies (agg_bucket_shape)
         from ..executor.compiler import PlanCompiler
 
-        mode = (settings.get("group_by_kernel") if settings is not None
-                else "auto")
         extra = (", " + explain_tag("bucketed group-by")
-                 if PlanCompiler.agg_bucket_shape(node, mode, False)
-                 else "")
+                 if PlanCompiler.agg_bucket_shape(node, False) else "")
         lines.append(f"{pad}-> GroupAggregate [{combine}{extra}] "
                      f"keys: {keys}  aggs: {aggs}")
         _format_node(node.input, lines, depth + 1, catalog,

@@ -190,13 +190,6 @@ class AggregateNode(PlanNode):
     # the product of extent+1).  Stale ranges retry via dense_oob.
     bucket_keys: Optional[tuple[tuple[int, int, bool], ...]] = None
     bucket_total: int = 0
-    # the planner's measurement-gated pick for group_by_kernel='auto':
-    # True only on TPU backends, where the pack's argsort buys sort
-    # elimination that measures as a win (bench_kernels.py groupby) —
-    # on XLA:CPU the sort IS the wall, so auto keeps the sort path.
-    # group_by_kernel='bucketed'/'bucketed_pallas' overrides the gate
-    # wherever bucket_keys is structurally set.
-    group_bucketed: bool = False
     # indices of the group keys whose packed NULL lane comes LAST (a
     # window reads the groups in ORDER BY order, ascending with NULLS
     # LAST); every other key's NULL lane is its first
@@ -1518,21 +1511,15 @@ class DistributedPlanner:
         elif pack_total <= self.PACK_SLOT_LIMIT:
             # past the dense grid's cap: the bucketed grid
             # (ops/groupby.py) radix-partitions the packed slot space
-            # into dense tiles.  Structural eligibility (annotated so
-            # group_by_kernel can force the path on any backend) needs
-            # the slot space materializable and occupied; the AUTO pick
-            # is additionally TPU-gated — spending a pack argsort to
-            # skip the group sort only pays where sorts are the
-            # measured wall (bench_kernels.py groupby)
-            import jax
-
+            # into dense tiles where that space is materializable and
+            # occupied; elsewhere the sort path runs.  The pick is from
+            # sizes alone, the same on every backend
             from ..ops.groupby import group_bucket_eligible
 
             if group_bucket_eligible(pack_total,
                                      node.input.est_rows):
                 node.bucket_keys = tuple(specs)
                 node.bucket_total = pack_total
-                node.group_bucketed = jax.default_backend() == "tpu"
 
     def _column_nullable(self, col: ir.BCol) -> bool:
         try:

@@ -36,13 +36,13 @@ def test_groupby_harness_smoke(tmp_path):
 
 def test_dense_aggregate_harness_smoke():
     """The default (dense segment-aggregation) A/B at a toy shape:
-    all three formulations produce a timing row and the pallas
-    correctness flag holds."""
+    both formulations produce a timing row and agree with the numpy
+    reference."""
     import bench_kernels
 
     rows = bench_kernels.main(regimes=[(1 << 12, 64)])
     assert len(rows) == 1
-    n, k, t_seg, t_oh, _t_pl, ok = rows[0]
+    n, k, t_seg, t_oh, ok = rows[0]
     assert t_seg > 0 and t_oh > 0 and ok
 
 

@@ -1,7 +1,7 @@
 """Bucketed dense-grid aggregation (ops/groupby.py): end-to-end tests.
 
-Oracle contract: with `group_by_kernel` forced onto the bucketed path,
-every GROUP BY shape must return exactly what the sort path returns —
+Oracle contract: with the plan forced onto the bucketed path, every
+GROUP BY shape must return exactly what the sort path returns —
 nulls form their own groups, filtered-out rows never contribute,
 all-duplicate keys collapse to one group, empty inputs yield zero
 groups, under every distribution of the key over the buckets (the
@@ -32,8 +32,8 @@ def sess(tmp_path):
 def _force_bucketed_groupby(plan, specs):
     """Flip every aggregate in `plan` onto the bucketed dense-grid path
     with the given (base, extent, has_null) specs (the test analogue of
-    the planner's structural annotation; group_by_kernel='bucketed'
-    must also be set so agg_bucket_shape accepts it on the CPU mesh)."""
+    the planner's pick, which it makes where group_bucket_eligible
+    holds past DENSE_GROUP_LIMIT)."""
     total = 1
     for _b, extent, _hn in specs:
         total *= extent + 1
@@ -46,14 +46,23 @@ def _force_bucketed_groupby(plan, specs):
     return total
 
 
+def _sort_path_rows(sess, sql):
+    """The statement's rows from the sort path: its plan with neither
+    grid's slot space, so that no aggregate is eligible for either."""
+    plan, _cleanup = sess._plan_select(parse_one(sql))
+    for node in walk_plan(plan.root):
+        if isinstance(node, AggregateNode):
+            node.dense_keys = None
+            node.bucket_keys = None
+    result = sess.executor.execute_plan(plan)
+    assert result.retries == 0
+    return _sorted(result.rows())
+
+
 def _sorted(rows):
     """NULL-safe row sort (None has no < against ints)."""
     return sorted((tuple(r) for r in rows),
                   key=lambda t: tuple((x is None, x) for x in t))
-
-
-def _rows(sess, sql):
-    return _sorted(sess.execute(sql).rows())
 
 
 class TestOracleParity:
@@ -61,9 +70,7 @@ class TestOracleParity:
 
     def _parity(self, sess, monkeypatch, sql, specs, tile=64):
         monkeypatch.setattr(G, "GROUP_TILE_SLOTS", tile)
-        sess.execute("set group_by_kernel = 'sort'")
-        want = _rows(sess, sql)
-        sess.execute("set group_by_kernel = 'bucketed'")
+        want = _sort_path_rows(sess, sql)
         plan, _cleanup = sess._plan_select(parse_one(sql))
         _force_bucketed_groupby(plan, specs)
         result = sess.executor.execute_plan(plan)
@@ -134,24 +141,6 @@ class TestOracleParity:
                      "group by g, h",
                      [(0, 53, False), (0, 7, False)])
 
-    def test_pallas_kernel_parity(self, sess, monkeypatch):
-        sess.execute("create table gp (k bigint, g bigint, v int)")
-        sess.create_distributed_table("gp", "k", shard_count=4)
-        sess.execute("insert into gp values " + ",".join(
-            f"({i},{i % 131},{i})" for i in range(500)))
-        monkeypatch.setattr(G, "GROUP_TILE_SLOTS", 64)
-        sess.execute("set group_by_kernel = 'sort'")
-        want = _rows(sess, "select g, count(*), sum(v) from gp group by g")
-        # bucketed_pallas on the CPU backend degrades to the XLA
-        # formulation (compiled pallas_call is interpret-only there) —
-        # the config must execute, not crash, and match the oracle
-        sess.execute("set group_by_kernel = 'bucketed_pallas'")
-        plan, _cleanup = sess._plan_select(parse_one(
-            "select g, count(*), sum(v) from gp group by g"))
-        _force_bucketed_groupby(plan, [(0, 131, False)])
-        result = sess.executor.execute_plan(plan)
-        assert sorted(tuple(r) for r in result.rows()) == want
-
 
 def test_stale_key_ranges_retry_on_sort_path(sess, monkeypatch):
     """Rows whose key falls outside the planned range would alias a
@@ -164,14 +153,12 @@ def test_stale_key_ranges_retry_on_sort_path(sess, monkeypatch):
     # g values 1..120, but the stale claim says extent 40
     sess.execute("insert into gs values " + ",".join(
         f"({i},{i % 120 + 1},{i % 9})" for i in range(360)))
-    sess.execute("set group_by_kernel = 'bucketed'")
     sql = "select g, count(*), sum(v) from gs group by g"
     plan, _cleanup = sess._plan_select(parse_one(sql))
     _force_bucketed_groupby(plan, [(1, 40, False)])
     result = sess.executor.execute_plan(plan)
     assert result.retries >= 1
-    sess.execute("set group_by_kernel = 'sort'")
-    assert sorted(tuple(r) for r in result.rows()) == _rows(sess, sql)
+    assert _sorted(result.rows()) == _sort_path_rows(sess, sql)
 
 
 # key distributions over the buckets of a 5-tile slot space (TILE = 64
@@ -244,10 +231,8 @@ def test_chunked_grid_matches_sort_path(sess1, monkeypatch, case, where):
     sess1.execute("insert into gq values " + ",".join(rows))
     sql = ("select g, count(*), count(v), sum(v), sum(f), min(v), max(v) "
            f"from gq{where} group by g")
-    sess1.execute("set group_by_kernel = 'sort'")
-    want = _rows(sess1, sql)
+    want = _sort_path_rows(sess1, sql)
     assert bool(want) == (where == "")
-    sess1.execute("set group_by_kernel = 'bucketed'")
     plan, _cleanup = sess1._plan_select(parse_one(sql))
     _force_bucketed_groupby(plan, [(0, EXTENT, True)])
     result = sess1.executor.execute_plan(plan)
@@ -265,7 +250,6 @@ def test_hot_bucket_compiles_once_and_never_retries(sess, monkeypatch):
 
     monkeypatch.setattr(G, "GROUP_TILE_SLOTS", 16)
     monkeypatch.setattr(G, "GROUP_CHUNK_ROWS", 32)
-    sess.execute("set group_by_kernel = 'bucketed'")
     sess.execute("create table gh (k bigint, g bigint, v int)")
     sess.create_distributed_table("gh", "k", shard_count=4)
     rows = [f"({i},7,1)" for i in range(3000)]
@@ -314,28 +298,33 @@ def test_buffer_estimate_counts_the_chunked_pack(sess, monkeypatch):
     nc, chunk = G.group_pack_shape(1024, 8)
     assert (nc, chunk) == (1024 // 32 + 8, 32)
     width = (len(agg.out_columns) + 2) * 8 * plan.n_devices
-    assert _plan_buffer_bytes(plan, caps, "bucketed") == nc * chunk * width
-    assert _plan_buffer_bytes(plan, caps, "sort") < 1024 * width
+    assert _plan_buffer_bytes(plan, caps) == nc * chunk * width
+    agg.bucket_keys = None  # not eligible: the sort path
+    assert _plan_buffer_bytes(plan, caps) < 1024 * width
 
 
-def test_retired_bucket_capacity_setting_is_unknown(sess):
-    """The per-bucket capacity's setting left with it: SET refuses it
-    as it refuses any name that was never registered."""
+@pytest.mark.parametrize("name,value", [
+    ("agg_bucket_capacity_factor", "2.0"),
+    ("group_by_kernel", "'bucketed'"),
+])
+def test_retired_bucket_capacity_setting_is_unknown(sess, name, value):
+    """The per-bucket capacity's setting left with it, and the group-by
+    path's with the backend gate: SET refuses each as it refuses any
+    name that was never registered."""
     from citus_tpu import config
     from citus_tpu.errors import ConfigError
 
-    name = "agg_bucket_capacity_factor"
     assert name not in config.registered_vars()
-    assert len(config.registered_vars()) == 64
+    assert len(config.registered_vars()) == 63
     with pytest.raises(ConfigError, match="unrecognized configuration"):
-        sess.execute(f"set {name} = 2.0")
+        sess.execute(f"set {name} = {value}")
 
 
 def test_planner_annotates_structural_eligibility(sess, monkeypatch):
     """Past DENSE_GROUP_LIMIT with a materializable, occupied slot
-    space the planner stores bucket_keys/bucket_total; the AUTO pick
-    stays off on the CPU backend (measurement gate), so the sort path
-    runs unless group_by_kernel forces the grid."""
+    space the planner stores bucket_keys/bucket_total, and that alone
+    is the pick: the bucketed grid runs, on this backend as on any."""
+    from citus_tpu.executor.compiler import PlanCompiler
     from citus_tpu.planner.plan import DistributedPlanner
 
     monkeypatch.setattr(DistributedPlanner, "DENSE_GROUP_LIMIT", 16)
@@ -352,7 +341,8 @@ def test_planner_annotates_structural_eligibility(sess, monkeypatch):
         assert node.dense_keys is None
         assert node.bucket_keys is not None
         assert node.bucket_total == 91  # extent 90 + reserved null slot
-        assert node.group_bucketed is False  # CPU backend: auto = sort
+        assert PlanCompiler.agg_bucket_shape(node, False)
+        assert not PlanCompiler.agg_bucket_shape(node, True)  # dense_off
 
     # sparse key space (occupancy below 1/4) must NOT be eligible
     sess.execute("create table gz2 (k bigint, g bigint)")
@@ -363,20 +353,22 @@ def test_planner_annotates_structural_eligibility(sess, monkeypatch):
     for node in walk_plan(plan2.root):
         if isinstance(node, AggregateNode):
             assert node.bucket_keys is None
+            assert not PlanCompiler.agg_bucket_shape(node, False)
 
 
 def test_explain_shows_bucketed_tag(sess, monkeypatch):
+    """The tag appears exactly where the plan is eligible: not under
+    the flat grid's limit, and past it where the grid is occupied."""
     from citus_tpu.planner.plan import DistributedPlanner
 
-    monkeypatch.setattr(DistributedPlanner, "DENSE_GROUP_LIMIT", 16)
     sess.execute("create table gx (k bigint, g bigint, v int)")
     sess.create_distributed_table("gx", "k", shard_count=4)
     sess.execute("insert into gx values " + ",".join(
         f"({i},{i % 80},{i})" for i in range(400)))
     sql = "explain select g, count(*) from gx group by g"
-    plain = "\n".join(sess.execute(sql).columns["QUERY PLAN"])
-    assert "bucketed group-by" not in plain  # CPU auto pick: sort
-    sess.execute("set group_by_kernel = 'bucketed'")
+    flat = "\n".join(sess.execute(sql).columns["QUERY PLAN"])
+    assert "bucketed group-by" not in flat  # 81 slots: the flat grid
+    monkeypatch.setattr(DistributedPlanner, "DENSE_GROUP_LIMIT", 16)
     tagged = "\n".join(sess.execute(sql).columns["QUERY PLAN"])
     assert "bucketed group-by" in tagged
 
@@ -391,7 +383,6 @@ def test_groupby_bucketed_counter(sess, monkeypatch):
     sess.create_distributed_table("gc", "k", shard_count=4)
     sess.execute("insert into gc values " + ",".join(
         f"({i},{i % 64},{i})" for i in range(300)))
-    sess.execute("set group_by_kernel = 'bucketed'")
     before = sess.stats.counters.snapshot()[sc.GROUPBY_BUCKETED_TOTAL]
     sess.execute("select g, count(*) from gc group by g")
     after = sess.stats.counters.snapshot()[sc.GROUPBY_BUCKETED_TOTAL]
@@ -407,7 +398,6 @@ def test_agg_bucket_fault_point_armed(sess, monkeypatch):
     sess.create_distributed_table("gi", "k", shard_count=4)
     sess.execute("insert into gi values " + ",".join(
         f"({i},{i % 50},{i})" for i in range(200)))
-    sess.execute("set group_by_kernel = 'bucketed'")
     plan, _cleanup = sess._plan_select(parse_one(
         "select g, count(*) from gi group by g"))
     _force_bucketed_groupby(plan, [(0, 50, False)])

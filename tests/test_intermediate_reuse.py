@@ -93,6 +93,9 @@ def test_q13_answer_under_reuse_is_the_reference(sess, data):
 
 
 def test_intermediates_are_counted(sess, data):
+    # a first execution on the session walks Q13's dictionary predicate
+    # once; the one counted here is a repeat, whichever test ran first
+    sess.execute(Q13)
     c0 = sess.stats.counters.snapshot()
     sess.execute(Q13)
     c1 = sess.stats.counters.snapshot()
@@ -114,8 +117,9 @@ def test_intermediates_are_counted(sess, data):
         ref = tpch_q13.build(data)
         kept, groups = int(ref["orders_kept"][0]), len(ref["c_count"])
         # every order the filter keeps crosses the inner program's
-        # exchange; the outer program's combine exchanges its groups
-        assert kept < rows <= kept + sess.n_devices * groups
+        # exchange, and nothing else: the outer program groups its
+        # `c_count`s on the bucketed grid, whose combine sends no rows
+        assert rows == kept
         assert kept / 16 < hot <= kept + groups
 
 

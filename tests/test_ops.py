@@ -657,6 +657,63 @@ class TestBucketedGridAggregate:
         assert int(rows.sum()) == 0
         assert int(res[0].sum()) == 0
 
+    @pytest.mark.parametrize("n,total", [
+        (100, 5),          # tiny: one tile, a few chunks
+        (3000, 16),        # many chunks in one tile
+        (5000, 513),       # the slot space crosses a tile boundary
+        (2048, 1024),      # whole tiles and whole chunks
+    ])
+    def test_sums_match_segment_sum_reference(self, rng, monkeypatch, n,
+                                              total):
+        from citus_tpu.ops.reference import segment_sum_reference
+
+        slot = rng.integers(0, total + 1, n).astype(np.int32)  # total: none
+        vals = rng.uniform(-50, 50, (n, 3)).astype(np.float32)
+        valid = slot < total
+        values = [(jnp.asarray(np.where(valid, vals[:, j], 0)), "sum")
+                  for j in range(3)]
+        res, rows = self._run(monkeypatch, np.minimum(slot, total - 1),
+                              valid, values, total)
+        want = segment_sum_reference(slot, vals, total)
+        np.testing.assert_allclose(np.stack(res, axis=1), want,
+                                   rtol=1e-5, atol=1e-3)
+        np.testing.assert_array_equal(
+            rows, np.bincount(slot[valid], minlength=total))
+
+    def test_single_slot_and_empty(self, rng, monkeypatch):
+        vals = rng.uniform(0, 1, 64).astype(np.float32)
+        res, rows = self._run(monkeypatch, np.zeros(64, np.int32),
+                              np.ones(64, bool),
+                              [(jnp.asarray(vals), "sum")], 1)
+        np.testing.assert_allclose(res[0], [vals.sum()], rtol=1e-5)
+        assert rows.tolist() == [64]
+        # no row in any slot: zeros
+        res, rows = self._run(monkeypatch, np.zeros(64, np.int32),
+                              np.zeros(64, bool),
+                              [(jnp.asarray(vals * 0), "sum")], 3)
+        assert np.abs(res[0]).sum() == 0 and rows.sum() == 0
+
+    @pytest.mark.parametrize("nb,cap,tile,a", [
+        (1, 100, 64, 3),      # one chunk, a tile of 64 slots
+        (7, 333, 128, 1),     # ragged chunks, many of them
+        (4, 1100, 512, 5),    # chunks past a thousand rows
+        (2, 2048, 4096, 6),   # the real tile, whole chunks
+    ])
+    def test_onehot_bucket_sums_match_reference(self, rng, nb, cap, tile,
+                                                a):
+        """A chunk's sums through the one-hot product
+        (`_onehot_bucket_sums`, its tile axis last) against the numpy
+        reference."""
+        import citus_tpu.ops.groupby as G
+        from citus_tpu.ops.reference import groupby_sums_reference
+
+        loc = rng.integers(0, tile, (nb, cap)).astype(np.int32)
+        stack = rng.uniform(-20, 20, (nb, cap, a)).astype(np.float32)
+        got = np.asarray(G._onehot_bucket_sums(
+            jnp.asarray(loc), jnp.asarray(stack), tile)).swapaxes(1, 2)
+        want = groupby_sums_reference(loc, stack, tile)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-2)
+
     def test_matches_flat_segment_path(self, rng, monkeypatch):
         # the segment_sum fallback (wide dtypes / CPU one-hot bound)
         # and the one-hot path must agree exactly for int32 counts

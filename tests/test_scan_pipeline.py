@@ -1,5 +1,5 @@
 """Pipelined columnar scan (executor/scanpipe.py): wire-codec units,
-Pallas kernel goldens, eager-vs-pipelined parity (directed + fuzz slice
+decode goldens against numpy references, eager-vs-pipelined parity (directed + fuzz slice
 with interleaved DML — the serving cache-on ≡ cache-off fuzzer mode is
 the template), fault-point drains with a zero-leak prefetch ledger, and
 the OOM shed-to-eager path."""
@@ -103,7 +103,7 @@ class TestDecodeKernels:
 
         from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
         from citus_tpu.executor import scanpipe
-        from citus_tpu.ops.pallas_kernels import (
+        from citus_tpu.ops.reference import (
             bit_unpack_reference,
             dict_decode_reference,
         )

@@ -263,11 +263,16 @@ def _rows(rows, digits=6):
                                       for v in r])
 
 
+def _pin_group_by(mp, bucketed):
+    """Pin the planner's pick past the flat grid: the bucketed grid
+    wherever a group-by's keys pack, or the sort path everywhere."""
+    import citus_tpu.ops.groupby as G
+
+    mp.setattr(G, "group_bucket_eligible", lambda total, rows: bucketed)
+
+
 @pytest.fixture(scope="module", params=[1, 4])
-def grouped(request, tmp_path_factory):
-    """`t` groups by (a, b, c) into 4 x 50 x 60 slots, past the flat
-    grid: the sort path, whose groups leave in packed-key order.  `n`
-    is a nullable order key."""
+def grouped_session(request, tmp_path_factory):
     d = tmp_path_factory.mktemp(f"q57win{request.param}")
     s = citus_tpu.connect(data_dir=str(d / "d"), n_devices=request.param,
                           compute_dtype="float64",
@@ -287,6 +292,16 @@ def grouped(request, tmp_path_factory):
     con.executemany("insert into t values (?,?,?,?,?,?,?)", rows)
     yield s, con
     s.close()
+
+
+@pytest.fixture()
+def grouped(grouped_session, monkeypatch):
+    """`t` groups by (a, b, c) into 4 x 50 x 60 slots, past the flat
+    grid: the sort path, whose groups leave in packed-key order, as
+    Q57's group-by takes it at SF10 (pinned: at this size the planner
+    would take the bucketed grid).  `n` is a nullable order key."""
+    _pin_group_by(monkeypatch, False)
+    return grouped_session
 
 
 def _check(grouped, sql, lite=None):
@@ -350,7 +365,8 @@ def test_nullable_order_key_reads_nulls_last(grouped):
 
 
 @pytest.mark.parametrize("kernel", ["sort", "bucketed"])
-def test_null_order_key_after_its_partitions_only_value(grouped, kernel):
+def test_null_order_key_after_its_partitions_only_value(grouped, kernel,
+                                                        monkeypatch):
     """A partition whose nullable ORDER BY key holds only its smallest
     value and NULL: the NULL groups' value lane holds filler equal to
     that value (the raw lane on the sort path, the range's base on the
@@ -368,17 +384,18 @@ def test_null_order_key_after_its_partitions_only_value(grouped, kernel):
             for r in rows))
         con.execute("create table tn (k, a, n, c)")
         con.executemany("insert into tn values (?,?,?,?)", rows)
-        s.execute(f"set group_by_kernel = '{kernel}'")
+        _pin_group_by(monkeypatch, kernel == "bucketed")
         sql = ("select a, n, c, rank() over (partition by a order by n), "
                "dense_rank() over (partition by a order by n), "
                "sum(count(*)) over (partition by a order by n) from tn "
                "group by a, n, c")
         lite = sql.replace("order by n)", "order by n nulls last)")
         _check((s, con), sql, lite)
-        sorts, = _moved(s, sql, "window_sorts_total")
+        sorts, bucketed = _moved(s, sql, "window_sorts_total",
+                                 "groupby_bucketed_total")
         assert sorts == (0 if s.executor.mesh.devices.size == 1 else 1)
+        assert bucketed == (kernel == "bucketed")
     finally:
-        s.execute("set group_by_kernel = 'auto'")
         s.execute("drop table tn")
 
 
@@ -401,7 +418,9 @@ def test_stale_range_of_an_order_key_sorts_and_answers(grouped):
     if win.presorted:
         assert [c for g, c in agg.group_keys][:2] == ["g0", "g1"]
         assert agg.nulls_last == (1,)
-    # `b` spans 0..49: claim 0..24
+    # `b` spans 0..49: claim 0..24 (the sort path is pinned, so the
+    # range lives in `key_ranges` alone)
+    assert agg.bucket_keys is None
     base, extent, has_null = agg.key_ranges[1]
     agg.key_ranges = (agg.key_ranges[0], (base, extent // 2, has_null),
                       *agg.key_ranges[2:])

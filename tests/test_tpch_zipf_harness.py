@@ -3,9 +3,9 @@
 in the shape of tests/test_ssb_harness.py: the platform check and the
 data directory are overridden from here, never through an option of the
 harness, and the configuration is the cell's own but for its scale
-factor and for `group_by_kernel`, forced onto the bucketed grid that the
-planner picks by itself only on the chip (and the flat grid's limit is
-lowered with the scale, so that 7,501 slots are over it as 150,001 are)."""
+factor (and the flat grid's limit is lowered with the scale, so that
+7,501 slots are over it as 150,001 are, and the planner takes the
+bucketed grid as it does at SF1)."""
 
 import json
 import os
@@ -38,11 +38,18 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         def __init__(self, workload):
             super().__init__(workload)
             self.config["dataset_params"]["scale_factor"] = SCALE
-            self.config["session_settings"]["group_by_kernel"] = "bucketed"
 
     from citus_tpu.planner.plan import DistributedPlanner
 
+    window_counters = {}
+    read_layer = harness.layer_metric
+
+    def layer_metric(run, name):
+        window_counters.update(run.window["counters"])
+        return read_layer(run, name)
+
     monkeypatch.setattr(DistributedPlanner, "DENSE_GROUP_LIMIT", 4096)
+    monkeypatch.setattr(harness, "layer_metric", layer_metric)
     monkeypatch.setattr(harness, "Cell", TinyCell)
     monkeypatch.setattr(harness, "REQUIRED_PLATFORM", "cpu")
     monkeypatch.setattr(harness, "DATA_ROOT", data_root)
@@ -80,9 +87,13 @@ def test_cell_runs_through_the_harness(monkeypatch, capsys, data_root, trace):
         assert 1.05 < got["repartition_imbalance"] < 2.0
         # both programs group on the bucketed grid, as on the chip
         # (7,501 slots of `c_custkey`, the hot account's ≈ 7,500 of
-        # `c_count`: 2 tiles each): a pack is its input's slots in
-        # whole chunks and a chunk a tile more, on each of four devices
+        # `c_count`: 2 tiles each), picked by the planner by itself: a
+        # pack is its input's slots in whole chunks and a chunk a tile
+        # more, on each of four devices
         from citus_tpu.ops.groupby import group_pack_shape
+
+        assert window_counters["groupby_bucketed_total"] \
+            == 2 * result["attempted"]
 
         slots = got["agg_bucket_slots"]
         chunk = group_pack_shape(0, 2)[1]

@@ -2,17 +2,17 @@
 
 The TPU compiler is installed here and compiles for a device that is
 described, not attached (on-chip-measurement guide §2, third rehearsal).
-These tests compile — never run — the kernels and the XLA formulations
-the planner picks on `tpu`, at the shapes TPC-H SF1 produces on one
-chip (6.0 M lineitem rows, 1.5 M orders, a 6 M-slot order-key
-directory), with `jax_enable_x64` on as the engine runs.  A refusal
-that interpret mode cannot show (tiling, 64-bit types, an unsupported
-gather) shows here and costs no chip time.
+These tests compile — never run — the XLA formulations the chip runs,
+at the shapes TPC-H SF1 produces on one chip (6.0 M lineitem rows,
+1.5 M orders, a 6 M-slot order-key directory), with `jax_enable_x64` on
+as the engine runs.  A refusal that the CPU backend cannot show
+(tiling, 64-bit types, an unsupported gather) shows here and costs no
+chip time.
 
 A shape the compiler refuses stays as `xfail(strict=True)` carrying
-its message, and is statically off the default path; when a later PR
-repairs or deletes the kernel, the strict xfail turns red and the
-marker goes with it.
+its message, and is statically off the default path; once the code is
+repaired or deleted, the strict xfail turns red and the marker goes
+with it.
 
 The topology is described inside a module-scoped fixture and nowhere
 else: the driver runs several xdist workers, each imports every test
@@ -27,9 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from citus_tpu.executor import scanpipe
 from citus_tpu.executor.compiler import _round_cap
-from citus_tpu.ops import pallas_kernels as pk
 from citus_tpu.ops.groupby import (
-    GROUP_TILE_SLOTS,
     bucketed_grid_aggregate,
     group_bucket_count,
     group_pack_shape,
@@ -38,7 +36,6 @@ from citus_tpu.ops.join import sorted_unique_lookup
 
 # TPC-H SF1 on one chip (ingest/tpch.py): padded feed capacities
 LINEITEM_CAP = _round_cap(6_001_520)
-ORDERS_CAP = _round_cap(1_500_000)
 ORDERKEY_EXTENT = 6_000_000          # o_orderkey = 4i + 1, i < 1.5 M
 
 
@@ -82,42 +79,7 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-# -- Pallas kernels a config var selects: repaired, must compile --------
-
-def test_dense_grid_aggregate_pallas_compiles(chip):
-    c = _compile(lambda s, v: pk.dense_grid_aggregate_pallas(
-        s, v, GROUP_TILE_SLOTS),
-        chip((LINEITEM_CAP,), jnp.int32),
-        chip((LINEITEM_CAP, 4), jnp.float32))
-    assert "tpu_custom_call" in c.as_text()
-
-
-@pytest.mark.parametrize("rows,buckets", [
-    # group by o_custkey: 150 k groups over 1.5 M orders
-    pytest.param(ORDERS_CAP, group_bucket_count(150_001), id="o_custkey"),
-    # group by l_orderkey: 6 M slots over 6 M lineitem rows
-    pytest.param(
-        LINEITEM_CAP, ORDERKEY_GROUP_BUCKETS, id="l_orderkey",
-        marks=pytest.mark.xfail(
-            strict=True, raises=jax.errors.JaxRuntimeError,
-            reason="'RESOURCE_EXHAUSTED: XLA:TPU compile permanent "
-                   "error. Ran out of memory in memory space hbm. Used "
-                   "15.92G of 15.75G hbm ... Extra memory due to "
-                   "padding: 6.39G (128.0x expansion)': the kernel "
-                   "takes slots as [rows, 1] and values as [rows, 128], "
-                   "rows on sublanes, which the chip's tiled HBM layout "
-                   "pads 128-fold")),
-])
-def test_bucketed_groupby_sums_pallas_compiles(chip, rows, buckets):
-    chunks, chunk = group_pack_shape(rows, buckets)
-    c = _compile(lambda loc, stack: pk.bucketed_groupby_sums_pallas(
-        loc, stack, GROUP_TILE_SLOTS),
-        chip((chunks, chunk), jnp.int32),
-        chip((chunks, chunk, 3), jnp.float32))
-    assert "tpu_custom_call" in c.as_text()
-
-
-# -- XLA formulations the planner picks on `tpu` -------------------------
+# -- XLA formulations the chip runs ---------------------------------------
 
 def test_sorted_unique_lookup_compiles(chip):
     """Q3's lineitem ⋈ orders lookup on the sort-and-scan arm (the
@@ -285,7 +247,7 @@ def test_lookup_probes_at_its_range_capacity(chip):
 
 def test_bucketed_grid_aggregate_xla_compiles(chip, monkeypatch):
     """The high-cardinality group-by at SF1 (group by l_orderkey over
-    6 M rows; planner/plan.py `group_bucketed`), on the branch the
+    6 M rows; planner/plan.py `group_bucket_eligible`), on the branch the
     chip takes: `_onehot_ok` asks `jax.default_backend()`, which is
     the CPU here, so the test answers for the chip.  One sort carries
     the columns, the chunks are cut by slices in a loop (no gather an
@@ -297,7 +259,7 @@ def test_bucketed_grid_aggregate_xla_compiles(chip, monkeypatch):
     total, rows = ORDERKEY_EXTENT + 1, LINEITEM_CAP
     c = _compile(
         lambda slot, valid, v, n: bucketed_grid_aggregate(
-            slot, valid, [(v, "sum"), (n, "count")], total, kernel="xla"),
+            slot, valid, [(v, "sum"), (n, "count")], total),
         chip((rows,), jnp.int32), chip((rows,), jnp.bool_),
         chip((rows,), jnp.float32), chip((rows,), jnp.int32))
     ops = [ln for ln in c.as_text().splitlines() if " = " in ln]
@@ -640,8 +602,6 @@ def test_tpcds_q57_programs_compile(topo, tmp_path, monkeypatch):
     from citus_tpu.distributed.mesh import SHARD_AXIS, make_mesh
     from citus_tpu.executor import compiler as C
     from citus_tpu.executor.cache import node_fingerprint
-    from citus_tpu.executor.feed import walk_plan
-    from citus_tpu.planner.plan import AggregateNode
 
     built = {}
     real_init = C.PlanCompiler.__init__
@@ -675,9 +635,6 @@ def test_tpcds_q57_programs_compile(topo, tmp_path, monkeypatch):
 
     texts, seconds = [], []
     for plan, feeds, caps in built.values():
-        for nd in walk_plan(plan.root):
-            if isinstance(nd, AggregateNode) and nd.bucket_keys is not None:
-                nd.group_bucketed = True    # as the planner picks on tpu
         feeds = {nid: dataclasses.replace(
             f, arrays={c: abstract(a, f.sharded) for c, a in f.arrays.items()},
             nulls={c: abstract(a, f.sharded) for c, a in f.nulls.items()},

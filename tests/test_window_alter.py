@@ -207,7 +207,7 @@ def test_rename_add_collision_and_null_partitions(tmp_path):
 # every window operand an aggregate, a group key or an expression of those;
 # the windows run after GROUP BY and HAVING.  `big` groups by keys whose
 # slot space is past the flat grid, so the groups take the sort path (or,
-# forced, the bucketed grid) and reach the window already in partition
+# pinned, the bucketed grid) and reach the window already in partition
 # order: the path that skips the window's sort.
 
 OVER_GROUPS = {
@@ -261,13 +261,19 @@ def test_window_over_groups(grouped, name):
     _check(grouped, OVER_GROUPS[name])
 
 
-def test_window_over_groups_bucketed_grid(grouped):
+def test_window_over_groups_bucketed_grid(grouped, monkeypatch):
+    """`big`'s groups on the bucketed grid (pinned: its 3,000 rows
+    occupy too little of the 3,001 x 101 slots for the planner to take
+    it) reach the window as the sort path's do."""
+    import citus_tpu.ops.groupby as G
+
     s, _ = grouped
-    s.execute("set group_by_kernel = 'bucketed'")
-    try:
-        _check(grouped, OVER_GROUPS["big_presorted"])
-    finally:
-        s.execute("set group_by_kernel = 'auto'")
+    monkeypatch.setattr(G, "group_bucket_eligible", lambda total, rows: True)
+    _check(grouped, OVER_GROUPS["big_presorted"])
+    before = s.stats.counters.snapshot()["groupby_bucketed_total"]
+    s.execute(OVER_GROUPS["big_presorted"]).rows()
+    assert s.stats.counters.snapshot()["groupby_bucketed_total"] \
+        == before + 1
 
 
 def test_window_over_groups_plan_and_counters(grouped):

@@ -2,14 +2,13 @@
 """tools/hlo_dump.py — the device programs of the benchmark's statements,
 as text that two commits can be compared by.
 
-    python3 tools/hlo_dump.py <checkout> <out_dir> <n_devices>
+    python3 tools/hlo_dump.py <checkout> <out_dir> <n_devices> [NAME=VALUE ...]
 
 Loads TPC-H (SF 0.01, `benchmark/selftest.py`'s scale), SSB (SF 0.05,
 `tests/test_ssb.py`'s: at 0.01 Q4.1 has no row to answer with), TPC-H
 with Zipf(1) customer keys (SF 0.1, the smallest decade whose 15,001
-`c_custkey` slots are past the flat grid's limit, with `group_by_kernel`
-forced onto the bucketed grid that the planner picks by itself only on
-the chip) and TPC-DS's store and catalog channels (SF 0.01,
+`c_custkey` slots are past the flat grid's limit, so that Q13 takes the
+bucketed grid) and TPC-DS's store and catalog channels (SF 0.01,
 `tests/test_tpcds.py`'s and `tests/test_tpcds_q57.py`'s) through
 <checkout>'s program on <n_devices> CPU devices, answers Q1, Q3, SSB
 Q4.1, Q13, TPC-DS Q89 and Q57 (every program of the last three; a
@@ -17,8 +16,11 @@ configuration the checkout lacks is skipped) until
 their capacities have settled, and writes the optimized HLO of every plan-cache entry to
 <out_dir>/<config>.<n>dev.<i>.hlo, without what is no part of JAX's
 persistent-cache key: each operation's `metadata={…}` and the module's
-source-location tables.  A change that must not move a device program
-shows it by
+source-location tables.  Each NAME=VALUE is a setting of every session
+(VALUE read as JSON where it parses, else as a string): it lets a
+checkout whose defaults planned another program on the CPU than on the
+chip be dumped as the chip runs it.  A change that must not move a
+device program shows it by
 
     git archive <parent> | tar -x -C /tmp/parent
     for n in 1 4; do python3 tools/hlo_dump.py /tmp/parent /tmp/hlo/a $n
@@ -44,18 +46,18 @@ TABLES = re.compile(
     r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*\n",
     re.M)
 # (dataset module, configuration at one device / at more, scale factor,
-#  statements, settings beyond the configuration's own)
+#  statements)
 JOBS = (
     ("tpch", ("tpch-sf1-1chip", "tpch-sf1-4chip"), 0.01,
-     ("tpch_q1", "tpch_q3"), {}),
-    ("ssb", ("ssb-sf1-1chip", "ssb-sf1-1chip"), 0.05, ("ssb_q4_1",), {}),
+     ("tpch_q1", "tpch_q3")),
+    ("ssb", ("ssb-sf1-1chip", "ssb-sf1-1chip"), 0.05, ("ssb_q4_1",)),
     ("tpch_zipf", ("tpch-sf1-4chip-zipf1", "tpch-sf1-4chip-zipf1"), 0.1,
-     ("tpch_q13",), {"group_by_kernel": "bucketed"}),
+     ("tpch_q13",)),
     ("tpcds", ("tpcds-sf10-1chip", "tpcds-sf10-1chip"), 0.01,
-     ("tpcds_q89",), {}),
+     ("tpcds_q89",)),
     ("tpcds_catalog", ("tpcds-sf10-catalog-1chip",
                        "tpcds-sf10-catalog-1chip"), 0.01,
-     ("tpcds_q57",), {}),
+     ("tpcds_q57",)),
 )
 
 
@@ -63,7 +65,15 @@ def strip(text: str) -> str:
     return TABLES.sub("", METADATA.sub("", text))
 
 
-def main(root: str, out: str, n_devices: int) -> None:
+def setting(arg: str) -> tuple[str, object]:
+    name, _, text = arg.partition("=")
+    try:
+        return name, json.loads(text)
+    except ValueError:
+        return name, text
+
+
+def main(root: str, out: str, n_devices: int, settings: dict) -> None:
     root = os.path.abspath(root)
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"  # compile, every time
@@ -75,7 +85,7 @@ def main(root: str, out: str, n_devices: int) -> None:
     assert os.path.dirname(os.path.dirname(citus_tpu.__file__)) == root
     os.makedirs(out, exist_ok=True)
     bench = os.path.join(root, "benchmark")
-    for dataset, configs, scale, statements, settings in JOBS:
+    for dataset, configs, scale, statements in JOBS:
         config = configs[n_devices > 1]
         if not os.path.exists(os.path.join(bench, "configs",
                                            config + ".json")):
@@ -112,4 +122,5 @@ def main(root: str, out: str, n_devices: int) -> None:
 
 
 if __name__ == "__main__":
-    main(sys.argv[1], sys.argv[2], int(sys.argv[3]))
+    main(sys.argv[1], sys.argv[2], int(sys.argv[3]),
+         dict(setting(a) for a in sys.argv[4:]))
